@@ -1,5 +1,5 @@
-"""Deterministic open-loop load generation (docs/PERF.md "Open-loop
-methodology").
+"""Deterministic open-loop load generation (docs/OBSERVABILITY.md "SLO
+ledger").
 
 ``arrivals.py`` builds seeded arrival schedules — Poisson baseline,
 failure-storm bursts, diurnal ramps — with EVERY random draw materialised

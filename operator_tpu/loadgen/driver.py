@@ -3,7 +3,7 @@
 ``run_open_loop`` launches one task per :class:`~.arrivals.ArrivalEvent`
 at its offset WITHOUT awaiting earlier completions — when the system
 falls behind, arrivals keep coming and queues grow; that queueing
-collapse is exactly what closed-loop benchmarks hide (docs/PERF.md).
+collapse is exactly what closed-loop benchmarks hide (PERF.md).
 After the last arrival, a bounded drain collects what it can; stragglers
 past the drain budget are cancelled and counted (an operator reading the
 report must see offered vs achieved diverge, never a silently shrunk

@@ -12,7 +12,6 @@ exactly the ``windows @ patterns.T`` shape that kernel streams.
 
 from __future__ import annotations
 
-import logging
 import threading
 from typing import Optional, Sequence
 
@@ -20,8 +19,6 @@ import numpy as np
 
 from ..patterns.semantic import Embedder, HashingEmbedder
 from .store import Incident
-
-log = logging.getLogger(__name__)
 
 
 class IncidentIndex:
@@ -94,7 +91,7 @@ class IncidentIndex:
     # ------------------------------------------------------------------
     def query(self, text: str, k: int = 3) -> list[tuple[str, float]]:
         """Top-k (digest, cosine score), descending.  Scores on the MXU via
-        the fused Pallas kernel on TPU, XLA/numpy elsewhere."""
+        the fused Pallas kernel on TPU, the XLA reference elsewhere."""
         # graftlint: disable=GL004 reason=deliberate lock-free snapshot read; _state is an immutable tuple swapped atomically under the lock
         digests, matrix = self._state  # one consistent snapshot
         if not digests or not text.strip():
@@ -107,15 +104,12 @@ class IncidentIndex:
 
     @staticmethod
     def _score(query: np.ndarray, matrix: np.ndarray) -> np.ndarray:
-        try:
-            import jax.numpy as jnp
+        import jax.numpy as jnp
 
-            from ..ops.similarity import best_window_scores
+        from ..ops.similarity import best_window_scores
 
-            # one query "window" against the incident matrix as the
-            # pattern side: per-incident best == the cosine itself
-            scores, _ = best_window_scores(jnp.asarray(query), jnp.asarray(matrix))
-            return np.asarray(scores)
-        except Exception:  # pragma: no cover - numpy fallback if jax breaks
-            log.debug("similarity op unavailable; numpy fallback", exc_info=True)
-            return (matrix @ query[0]).astype(np.float32)
+        # one query "window" against the incident matrix as the pattern
+        # side: per-incident best == the cosine itself.  No fallback: on
+        # a TPU a kernel failure raises (ops/_dispatch.py)
+        scores, _ = best_window_scores(jnp.asarray(query), jnp.asarray(matrix))
+        return np.asarray(scores)

@@ -297,7 +297,7 @@ _SCORE_BUDGET_BYTES = int(
 
 #: unroll factor for the layer lax.scan (1 = rolled).  Unrolling lets XLA
 #: schedule/alias per-layer cache updates without the scan's stacked-ys
-#: round trip — a decode-bandwidth experiment knob (scripts/tpu_experiments.sh);
+#: round trip — a decode-bandwidth experiment knob (ROADMAP D2);
 #: compile time grows with the factor.
 _LAYER_UNROLL = int(os.environ.get("OPERATOR_TPU_LAYER_UNROLL", "1"))
 
@@ -545,6 +545,7 @@ def decode_step_paged(
     lora: Optional[dict[str, dict[str, jax.Array]]] = None,  # stacked adapters
     lora_alpha: float = 16.0,
     lora_indices: Optional[jax.Array] = None,  # [B] adapter id per slot
+    mesh: Optional[jax.sharding.Mesh] = None,  # the caller's serving mesh
 ) -> tuple[jax.Array, "PagedKVCache"]:
     """Single-token decode over a paged KV cache (ops/paged_attention.py).
 
@@ -591,7 +592,7 @@ def decode_step_paged(
         attn = paged_attention(
             q[:, 0].astype(k_pages.dtype), k_pages, v_pages,
             paged.page_table, new_lengths,
-            sliding_window=config.sliding_window,
+            sliding_window=config.sliding_window, mesh=mesh,
         )  # [B, QH, D]
         x = x + proj(attn.astype(x.dtype).reshape(b, 1, -1), "wo")
         mlp_in = rms_norm(x, weights["ln_mlp"], config.rms_norm_eps)

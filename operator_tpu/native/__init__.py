@@ -1,14 +1,19 @@
 """Native runtime components (C++ via ctypes) with pure-Python fallbacks.
 
-The scanner (native/logscan.cpp) is compiled once per machine into
-``OPERATOR_TPU_NATIVE_DIR`` (default: alongside this package) the first
-time it's needed; any build/toolchain failure degrades silently to the
-Python fallback so the framework never *requires* a compiler at runtime.
+The scanner (native/logscan.cpp) is compiled the first time it is needed
+into ``OPERATOR_TPU_NATIVE_DIR`` (default: alongside this package) under a
+name that carries a hash of its source, ``liblogscan-<sha256[:16]>.so``:
+a library is only ever loaded if it was built from the source that sits
+in this checkout, and a fresh checkout (which holds no ``.so`` — they are
+git-ignored) builds its own.  A missing source, compiler or writable
+directory selects the Python scanner, and ``MultiPatternScanner.native``
+says which one ran.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
 import os
 import subprocess
@@ -23,36 +28,39 @@ _SOURCE = os.path.join(
     "native",
     "logscan.cpp",
 )
-_LIB_NAME = "liblogscan.so"
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _lib_failed = False
 
 
-def _lib_dir() -> str:
-    configured = os.environ.get("OPERATOR_TPU_NATIVE_DIR")
-    return configured or os.path.dirname(os.path.abspath(__file__))
-
-
-def _build_library(target: str) -> Optional[str]:
-    """Compile logscan.cpp to ``target`` (or a temp cache when the package
-    dir is read-only); returns the built path or None."""
-    if not os.path.exists(_SOURCE):
-        return None
-    if not os.access(os.path.dirname(target), os.W_OK):
-        target = os.path.join(tempfile.gettempdir(), "operator_tpu_" + _LIB_NAME)
+def _lib_path() -> Optional[str]:
+    """Where the library built from THIS source lives (None: no source)."""
     try:
-        with tempfile.TemporaryDirectory() as tmp:
-            scratch = os.path.join(tmp, _LIB_NAME)
+        with open(_SOURCE, "rb") as f:
+            digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    except OSError:
+        return None
+    directory = os.environ.get("OPERATOR_TPU_NATIVE_DIR") or os.path.dirname(
+        os.path.abspath(__file__)
+    )
+    return os.path.join(directory, f"liblogscan-{digest}.so")
+
+
+def _build_library(target: str) -> bool:
+    """Compile logscan.cpp to ``target`` (atomically: concurrent builders
+    each rename a complete file into place)."""
+    try:
+        with tempfile.TemporaryDirectory(dir=os.path.dirname(target)) as tmp:
+            scratch = os.path.join(tmp, os.path.basename(target))
             subprocess.run(
                 ["g++", "-O2", "-shared", "-fPIC", "-std=c++17", _SOURCE, "-o", scratch],
                 check=True, capture_output=True, timeout=120,
             )
             os.replace(scratch, target)
-        return target
+        return True
     except (OSError, subprocess.SubprocessError) as exc:
         log.info("native scanner build skipped: %s", exc)
-        return None
+        return False
 
 
 def _load() -> Optional[ctypes.CDLL]:
@@ -60,14 +68,10 @@ def _load() -> Optional[ctypes.CDLL]:
     with _lock:
         if _lib is not None or _lib_failed:
             return _lib
-        target = os.path.join(_lib_dir(), _LIB_NAME)
-        fallback = os.path.join(tempfile.gettempdir(), "operator_tpu_" + _LIB_NAME)
-        path = next((p for p in (target, fallback) if os.path.exists(p)), None)
-        if path is None:
-            path = _build_library(target)
-            if path is None:
-                _lib_failed = True
-                return None
+        path = _lib_path()
+        if path is None or not (os.path.exists(path) or _build_library(path)):
+            _lib_failed = True
+            return None
         try:
             lib = ctypes.CDLL(path)
             lib.ls_build.restype = ctypes.c_void_p

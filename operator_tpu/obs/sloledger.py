@@ -8,7 +8,7 @@ tokens/s and analyses/min) per class, per replica, and fleet-wide — the
 arbiter metric the open-loop storm harness (``operator_tpu/loadgen/``)
 reports, the way DeepServe gates pre-warmed pools on SLO attainment and
 xLLM judges its async scheduler on deadline satisfaction rather than raw
-throughput (docs/PERF.md "Open-loop methodology").
+throughput (docs/OBSERVABILITY.md "SLO ledger").
 
 Timings are NOT re-measured here: the ledger's stamps come from the same
 injectable clock the deadline envelopes use, stage splits come from the

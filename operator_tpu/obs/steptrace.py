@@ -1,8 +1,7 @@
 """Step clock: bounded per-step records for the serving decode loops.
 
-BENCH_r02 measured decode MFU 0.0064 — the chip is ~99% idle during
-decode — and a single opaque MFU number cannot say *where* a step's wall
-time goes.  Both engine loops (the wave engine's ``step()`` and the
+A single opaque MFU number cannot say *where* a step's wall time goes.
+Both engine loops (the wave engine's ``step()`` and the
 continuous scheduler's ``Scheduler.step()``) record one
 :class:`StepRecord` per dispatched step into a bounded :class:`StepRing`,
 splitting the step's monotonic timeline into three attributed components:
@@ -221,7 +220,9 @@ def attribution(
     device + sample_xfer over all records), so they total 1.0 by
     construction.  With a flops model, ``decode_mfu`` is the measured
     MFU over decode-bearing steps (pure decode + mixed): tokens they
-    produced x flops/token against peak over their attributed wall."""
+    produced x flops/token against peak over their attributed wall.
+    Without a peak (a device serving/perf.py has no row for) the MFU
+    stays None — not measured — and only ``achieved_tflops`` is given."""
     host_gap = sum(r.host_gap_ms for r in records)
     device = sum(r.device_ms for r in records)
     xfer = sum(r.sample_xfer_ms for r in records)
@@ -259,11 +260,12 @@ def attribution(
         "decode_mfu": None,
         "achieved_tflops": None,
     }
-    if flops_per_token and peak_tflops and decode_ms > 0 and decode_tokens:
+    if flops_per_token and decode_ms > 0 and decode_tokens:
         flops = decode_tokens * flops_per_token
         achieved = flops / (decode_ms / 1e3) / 1e12  # TFLOP/s
         out["achieved_tflops"] = round(achieved, 6)
-        out["decode_mfu"] = round(achieved / peak_tflops, 6)
+        if peak_tflops:  # None = device not in the peak table: no MFU
+            out["decode_mfu"] = round(achieved / peak_tflops, 6)
     return out
 
 

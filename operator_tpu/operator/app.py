@@ -23,7 +23,7 @@ from ..obs import build_tracer
 from ..patterns.engine import PatternEngine
 from ..utils.config import OperatorConfig
 from ..utils.timing import METRICS, MetricsRegistry
-from .events import EventService
+from .events import REASON_ANALYSIS_ERROR, EventService
 from .health import (
     ENGINE_DISABLED,
     ENGINE_FAILED,
@@ -768,9 +768,25 @@ class Operator:
 # --------------------------------------------------------------------------
 
 
-async def run_demo(logfile: Optional[str] = None, provider_id: str = "template") -> dict:
+def demo_config() -> OperatorConfig:
+    """The environment's configuration (MODEL_ID, ALLOW_RANDOM_WEIGHTS,
+    MEMORY_PATH, ...) exactly as the deployed operator reads it, plus the
+    two overrides that make it a demo — so ``--demo --provider tpu-native``
+    runs the engine the environment describes, on the device JAX finds, or
+    says why it could not."""
+    config = OperatorConfig.from_env()
+    config.pattern_cache_directory = "/nonexistent-demo-cache"
+    config.health_port = 0  # ephemeral: demo runs shouldn't contend for :8080
+    return config
+
+
+async def run_demo(
+    logfile: Optional[str] = None,
+    provider_id: str = "template",
+    config: Optional[OperatorConfig] = None,
+) -> dict:
     """Full control-plane pass over the fake apiserver; returns a summary
-    dict (also printed by the CLI)."""
+    dict (also printed by the CLI, which passes :func:`demo_config`)."""
     import os
 
     from ..schema import (
@@ -790,10 +806,17 @@ async def run_demo(logfile: Optional[str] = None, provider_id: str = "template")
     from ..schema.crds import Podmortem
 
     api = FakeKubeApi()
-    config = OperatorConfig(
-        pattern_cache_directory="/nonexistent-demo-cache",
-        health_port=0,  # ephemeral: demo runs shouldn't contend for :8080
+    config = config or OperatorConfig(
+        pattern_cache_directory="/nonexistent-demo-cache", health_port=0
     )
+    compile_watch = None
+    if provider_id == "tpu-native":
+        # installed before the first analysis: incident recall's similarity
+        # kernel compiles before the serving engine (and its own watcher)
+        # exists
+        from ..utils.compilewatch import CompileWatcher
+
+        compile_watch = CompileWatcher()
     operator = Operator(api, config=config)
 
     # user objects: one AIProvider + one Podmortem watching app=payment
@@ -859,9 +882,38 @@ async def run_demo(logfile: Optional[str] = None, provider_id: str = "template")
         timeout=config.kube_call_timeout_s,
     )
     readiness = await operator.readiness.check()
+    # the analysis as the flight recorder saw it: which stages ran, what
+    # recall decided, which provider explained, and any error — the
+    # outcome a caller must read, since the operator itself degrades to a
+    # pattern-only result rather than fail
+    trace = [
+        {key: span[key] for key in
+         ("name", "status", "durationMs", "attributes", "error") if key in span}
+        for record in (operator.recorder.traces(1) if operator.recorder else [])
+        for span in record.trace.get("spans") or []
+    ]
+    engine_report = None
+    backend = operator.providers.built("tpu-native")
+    engine = getattr(backend, "engine", None)
+    if engine is not None:
+        engine_report = {
+            "model": backend.model_id,
+            "load": engine.load_report().to_dict(),
+            "compiles": compile_watch.report(),
+        }
     await operator.stop()
+    if engine is not None:
+        await engine.close()
+    if compile_watch is not None:
+        compile_watch.close()
 
+    prefilter = getattr(operator.engine, "prefilter", None)
     return {
+        "trace": trace,
+        "engine": engine_report,
+        # which literal scanner parsed the log: the C++ automaton built
+        # from native/logscan.cpp, or the pure-Python one
+        "native_scanner": bool(prefilter is not None and prefilter.native),
         "events": [
             {"reason": e.get("reason"), "type": e.get("type"),
              "target": f"{e.get('regarding', {}).get('kind')}/{e.get('regarding', {}).get('name')}",
@@ -903,7 +955,9 @@ def _main(argv: Optional[list[str]] = None) -> int:
             )
             return 2
     try:
-        summary = asyncio.run(run_demo(args.logfile, args.provider))
+        summary = asyncio.run(
+            run_demo(args.logfile, args.provider, demo_config())
+        )
     except OSError as exc:
         print(f"error: cannot read demo log file: {exc}", file=sys.stderr)
         return 2
@@ -911,7 +965,36 @@ def _main(argv: Optional[list[str]] = None) -> int:
         print(json.dumps(summary, indent=2))
     except BrokenPipeError:
         sys.stderr.close()
-    return 0
+    failures = demo_failures(summary)
+    for failure in failures:
+        print(f"demo failed: {failure}", file=sys.stderr)
+    return 1 if failures else 0
+
+
+def demo_failures(summary: dict) -> list[str]:
+    """Why a demo run does not count as a working analysis (empty = it
+    does).  The operator degrades an errored AI leg to a pattern-only
+    result and carries on — right in production, where it must not die —
+    so the demo's exit code has to come from the OUTCOME: an error event,
+    an errored stage, or a stored status other than ``Analyzed``."""
+    failures = [
+        f"{event['reason']} on {event['target']}: {event['note']}"
+        for event in summary["events"]
+        if event["reason"] == REASON_ANALYSIS_ERROR
+    ]
+    failures += [
+        f"stage {span['name']} {span['status']}: {span.get('error', '')}"
+        for span in summary["trace"] if span.get("status") != "ok"
+    ]
+    recent = summary["podmortem_status"].get("recentFailures") or []
+    if not recent:
+        failures.append("no analysis was stored")
+    failures += [
+        f"analysisStatus={entry.get('analysisStatus')!r} for "
+        f"{entry.get('podName')}"
+        for entry in recent if entry.get("analysisStatus") != "Analyzed"
+    ]
+    return failures
 
 
 async def _run_real(config: OperatorConfig) -> int:

@@ -90,6 +90,10 @@ class ProviderRegistry:
                 raise ProviderError(f"unknown providerId {pid!r}")
         return backend
 
+    def built(self, provider_id: str) -> Optional[AIProviderBackend]:
+        """The backend if it has materialised — never runs a factory."""
+        return self._backends.get(provider_id)
+
     def has(self, provider_id: str) -> bool:
         """Is a backend (or factory) already registered for this id? —
         wiring code must not clobber an injected test/real backend."""

@@ -1,10 +1,12 @@
-"""Kernel-vs-reference dispatch.
+"""Kernel-vs-reference dispatch: the one rule every op in this package
+follows.
 
-``jax.default_backend()`` alone is wrong here: environments with an
-experimental TPU plugin keep reporting ``tpu`` even when tests pin the
-default *device* to CPU (tests/conftest.py).  The committed device of the
-input arrays is the truth; fall back to the configured default device,
-then the backend.
+A TPU default backend runs the Pallas kernel; any other backend runs the
+dense reference.  Nothing in between: on a TPU a kernel that fails to
+lower or run raises to the caller — no dispatcher, and no caller of one,
+catches the failure and substitutes the reference, interpret mode or
+numpy, because a substituted path is exactly what a chip bring-up must
+not hide.
 """
 
 from __future__ import annotations
@@ -12,29 +14,5 @@ from __future__ import annotations
 import jax
 
 
-def on_tpu(*arrays: jax.Array) -> bool:
-    for array in arrays:
-        devices = getattr(array, "devices", None)
-        if callable(devices):
-            try:
-                platforms = {d.platform for d in array.devices()}
-            except Exception:  # pragma: no cover - uncommitted tracers
-                continue
-            if platforms:
-                return platforms == {"tpu"}
-    default = jax.config.jax_default_device
-    if default is not None:
-        return getattr(default, "platform", None) == "tpu"
+def on_tpu() -> bool:
     return jax.default_backend() == "tpu"
-
-
-def any_memory_space():
-    """``pl.BlockSpec(memory_space=ANY)`` across jax versions: the enum
-    was renamed TPUMemorySpace -> MemorySpace around 0.4.38.  The ONE
-    compat shim for every kernel that keeps an operand in HBM for manual
-    DMA (paged_attention v2, flash_prefill, ragged_attention)."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    memory_space = getattr(pltpu, "MemorySpace", None) or pltpu.TPUMemorySpace
-    return pl.BlockSpec(memory_space=memory_space.ANY)

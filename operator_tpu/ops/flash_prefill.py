@@ -212,9 +212,7 @@ def _flash_prefill_pallas(
         q_block=q_block, kv_block=kv_block, g=g, scale=scale,
         window=sliding_window,
     )
-    from ._dispatch import any_memory_space
-
-    any_space = any_memory_space()
+    any_space = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(b, kh, t // q_block),
@@ -262,7 +260,7 @@ def flash_prefill_attention(
     """Dispatch: Pallas kernel on TPU, dense oracle elsewhere."""
     from ._dispatch import on_tpu
 
-    if on_tpu(q, k):
+    if on_tpu():
         return _flash_prefill_pallas(
             q, k, v, lengths, sliding_window=sliding_window
         )

@@ -363,9 +363,7 @@ def _paged_attention_pallas_v2(
         scale=scale,
         window=sliding_window,
     )
-    from ._dispatch import any_memory_space
-
-    any_space = any_memory_space()
+    any_space = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b,),
@@ -471,19 +469,40 @@ def paged_attention(
     page_table: jax.Array,
     lengths: jax.Array,
     sliding_window: Optional[int] = None,
+    mesh: Optional[jax.sharding.Mesh] = None,
 ) -> jax.Array:
-    """Dispatch: Pallas kernel on TPU, dense reference elsewhere."""
+    """Dispatch: Pallas kernel on TPU, dense reference elsewhere.
+
+    ``mesh`` is the serving mesh when the caller's program is sharded
+    (parallel/mesh.py axes).  GSPMD cannot partition a Mosaic kernel, so
+    on a TPU the kernel then runs inside a ``shard_map``: it is
+    independent per kv head and per row, so heads go on ``tp`` (q-head
+    blocks and their kv heads are contiguous, hence co-located) and rows
+    on ``dp x fsdp``; the page pool is replicated over the data axes
+    (``paged_cache_specs``)."""
     from ._dispatch import on_tpu
 
-    if on_tpu(q, k_pages):
-        impl = (
-            _paged_attention_pallas_v2
-            if _kernel_version() == "v2"
-            else _paged_attention_pallas
-        )
-        return impl(
+    if not on_tpu():
+        return paged_attention_reference(
             q, k_pages, v_pages, page_table, lengths, sliding_window=sliding_window
         )
-    return paged_attention_reference(
-        q, k_pages, v_pages, page_table, lengths, sliding_window=sliding_window
+    impl = functools.partial(
+        _paged_attention_pallas_v2
+        if _kernel_version() == "v2"
+        else _paged_attention_pallas,
+        sliding_window=sliding_window,
     )
+    if mesh is None:
+        return impl(q, k_pages, v_pages, page_table, lengths)
+    from jax.sharding import PartitionSpec as P
+
+    rows, pages = ("dp", "fsdp"), P(None, None, "tp", None)
+    return jax.shard_map(
+        impl,
+        mesh=mesh,
+        in_specs=(P(rows, "tp", None), pages, pages, P(rows, None), P(rows)),
+        out_specs=P(rows, "tp", None),
+        # pallas_call has no replication rule; nothing here is replicated
+        # over tp on the way out, so there is nothing for the check to prove
+        check_vma=False,
+    )(q, k_pages, v_pages, page_table, lengths)

@@ -4,7 +4,7 @@ The serving engine historically ran two program families — batched
 prefill over a right-padded ``[B, T]`` bucket and a fixed
 ``decode_block`` program over ``[B, 1]`` tokens — as separate phases, so
 a long prefill stalled every in-flight decode and short decodes padded
-out the block while the MXU idled (BENCH_r02: decode MFU 0.0064).  This
+out the block while the MXU idled.  This
 module is the kernel half of the fix (PAPERS.md: *Ragged Paged
 Attention*, arxiv 2604.15464): ONE program where every batch row sits at
 an arbitrary position — a decode row contributes one query token, a
@@ -44,6 +44,14 @@ double-buffered DMAs steered by the scalar-prefetched page table (the
 move from HBM) and keeps a flash-attention running (max, sum, acc) per
 (query row, head) in VMEM.  The dense reference is the oracle for parity
 tests and the CPU path.
+
+The page DMA moves one ``[page_size, KH, D]`` page per copy, and Mosaic
+requires the minor dimension of a DMA slice to fill the 128-lane tile, so
+the kernel serves ``head_dim`` in multiples of 128 only.  There is no
+second attention for the continuous scheduler: a model the kernel cannot
+serve is refused at engine build (:func:`require_ragged_kernel_support`),
+never routed to the reference.  Serving head_dim 64 needs a lane-dense
+page layout — a cache redesign (ROADMAP.md).
 """
 
 from __future__ import annotations
@@ -58,6 +66,22 @@ from ._flash_common import finalize, init_state, update_state
 
 _LANE = 128
 _NEG_INF = -1e30
+
+
+class UnsupportedHeadDim(ValueError):
+    """The model's head_dim cannot go through the ragged Pallas kernel."""
+
+
+def require_ragged_kernel_support(config) -> None:
+    """Raise :class:`UnsupportedHeadDim` unless ``_ragged_attention_pallas``
+    can lower for ``config`` (see the module doc for the constraint)."""
+    if config.head_dim % _LANE:
+        raise UnsupportedHeadDim(
+            f"model {config.name!r} has head_dim={config.head_dim}: the "
+            f"ragged paged-attention kernel (_ragged_attention_pallas, the "
+            f"continuous scheduler's only attention on a TPU) DMAs KV pages "
+            f"whose minor dimension must be a multiple of {_LANE} lanes"
+        )
 
 
 # ---------------------------------------------------------------------------
@@ -275,9 +299,7 @@ def _ragged_attention_pallas(
         scale=scale,
         window=sliding_window,
     )
-    from ._dispatch import any_memory_space
-
-    any_space = any_memory_space()
+    any_space = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(b,),
@@ -317,7 +339,7 @@ def ragged_paged_attention(
     """Dispatch: Pallas kernel on TPU, dense reference elsewhere."""
     from ._dispatch import on_tpu
 
-    if on_tpu(q, k_pages):
+    if on_tpu():
         return _ragged_attention_pallas(
             q, k_pages, v_pages, page_table, kv_len, q_count,
             sliding_window=sliding_window,

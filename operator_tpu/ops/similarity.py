@@ -191,6 +191,6 @@ def best_window_scores(
     """Dispatch: fused Pallas kernel on TPU, XLA reference elsewhere."""
     from ._dispatch import on_tpu
 
-    if on_tpu(windows, patterns):
+    if on_tpu():
         return _best_window_pallas(windows, patterns)
     return best_window_scores_reference(windows, patterns)

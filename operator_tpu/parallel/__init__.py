@@ -5,6 +5,7 @@ from .mesh import (
     AXES,
     MeshPlan,
     batch_spec,
+    device_memory_bytes,
     initialize_distributed,
     kv_cache_spec,
     logits_spec,

@@ -53,20 +53,20 @@ class MeshPlan:
         return self.dp * self.fsdp * self.tp
 
 
-def _hbm_budget(devices: Optional[list]) -> float:
-    """Usable HBM per chip: measured when the runtime exposes it, with the
-    v5e constant as fallback (16 GB chip, ~12.5% headroom for XLA scratch)."""
-    fallback = 14e9
-    if not devices:
-        return fallback
-    try:
-        stats = devices[0].memory_stats()
-        limit = stats.get("bytes_limit") or stats.get("bytes_reservable_limit")
-        if limit:
-            return float(limit) * 0.875
-    except Exception:  # backend without memory_stats (cpu, older plugins)
-        pass
-    return fallback
+def device_memory_bytes(device: Any) -> int:
+    """The device's memory size as its runtime reports it
+    (``memory_stats()["bytes_limit"]``).  A backend that reports none (the
+    CPU) raises: sizing a mesh from an assumed chip would hide which
+    device the program is really on."""
+    stats = device.memory_stats() or {}
+    limit = stats.get("bytes_limit") or stats.get("bytes_reservable_limit")
+    if not limit:
+        raise ValueError(
+            f"{device.device_kind!r} reports no memory size "
+            f"(memory_stats() = {stats!r}); give the mesh explicitly "
+            f"('dp=N,tp=N') instead of sizing it from the device"
+        )
+    return int(limit)
 
 
 def plan_for(
@@ -75,17 +75,21 @@ def plan_for(
     tp: Optional[int] = None,
     fsdp: int = 1,
     config: Optional[ModelConfig] = None,
-    devices: Optional[list] = None,
+    hbm_bytes: Optional[int] = None,
 ) -> MeshPlan:
     """Choose a mesh factorisation for ``n_devices``.
 
     Defaults: smallest tp that fits the model's KV heads evenly (tp must
     divide num_kv_heads so attention never crosses chips for one KV head),
     everything else data-parallel — the throughput-first layout for serving.
+    Sizing tp from ``config`` needs ``hbm_bytes``, one device's measured
+    memory (:func:`device_memory_bytes`); 12.5% of it is left to XLA.
     """
     if tp is None:
         tp = 1
         if config is not None:
+            if not hbm_bytes:
+                raise ValueError("plan_for(config=...) needs hbm_bytes")
             # Llama-3-8B wants tp=4 on v5e-4 (16 GB HBM/chip); smaller models
             # run tp=1 and scale with dp alone
             approx_params = (
@@ -95,7 +99,7 @@ def plan_for(
                    + 3 * config.hidden_size * config.intermediate_size)
             )
             bytes_needed = approx_params * 2  # bf16
-            hbm_per_chip = _hbm_budget(devices)
+            hbm_per_chip = hbm_bytes * 0.875
             while tp < n_devices and (bytes_needed / tp) > hbm_per_chip:
                 tp *= 2
             while tp > 1 and config.num_kv_heads % tp != 0:
@@ -111,7 +115,7 @@ def plan_for(
 
 def make_mesh(plan: Optional[MeshPlan] = None, devices: Optional[list] = None) -> Mesh:
     devices = devices if devices is not None else jax.devices()
-    plan = plan or plan_for(len(devices), devices=devices)
+    plan = plan or plan_for(len(devices))
     used = devices[: plan.total]
     array = np.asarray(used).reshape(plan.dp, plan.fsdp, plan.tp)
     return Mesh(array, AXES)

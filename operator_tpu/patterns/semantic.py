@@ -367,19 +367,17 @@ class SemanticMatcher:
         if window_emb.shape[0] == 0:
             n = len(patterns)
             return np.full(n, -1.0, np.float32), np.zeros(n, np.int64)
-        try:
-            import jax.numpy as jnp
+        # one dispatch rule for the whole package (ops/_dispatch.py): the
+        # Pallas kernel on a TPU, the XLA reference elsewhere — and a
+        # kernel failure on the chip raises, it is never papered over
+        import jax.numpy as jnp
 
-            from ..ops.similarity import best_window_scores
+        from ..ops.similarity import best_window_scores
 
-            s, i = best_window_scores(
-                jnp.asarray(window_emb), jnp.asarray(pattern_emb)
-            )
-            return np.asarray(s), np.asarray(i)
-        except Exception:  # pragma: no cover - numpy fallback if jax breaks
-            log.debug("similarity op unavailable; numpy fallback", exc_info=True)
-            matrix = window_emb @ pattern_emb.T
-            return matrix.max(axis=0), matrix.argmax(axis=0)
+        s, i = best_window_scores(
+            jnp.asarray(window_emb), jnp.asarray(pattern_emb)
+        )
+        return np.asarray(s), np.asarray(i)
 
     def _to_event(
         self, pattern: Pattern, window: LogWindow, score: float, lines: list[str]

@@ -306,6 +306,10 @@ class ReplicaLoad:
     #: "decode", or "mixed".  A routing PREFERENCE, never a filter —
     #: unknown/legacy replicas read as mixed and serve everything.
     role: str = "mixed"
+    #: the backend the replica serves on, as JAX reports it there:
+    #: ``{"platform", "kind", "count"}`` (utils/platform.py DeviceInfo).
+    #: None = the replica predates the field or was built without one.
+    device: Optional[dict] = None
     #: value-aware overload ladder totals (router/value.py): requests
     #: this replica shed (dropped by value) and served degraded
     #: (depth-truncated) — rolled up fleet-wide by ``fleet_rollup``
@@ -363,6 +367,7 @@ class ReplicaLoad:
             "kvLookups": self.prefix_lookups,
             "kvBlocks": self.kv_blocks,
             "role": self.role,
+            "device": self.device,
             "shedTotal": self.shed,
             "degradedTotal": self.degraded,
         }
@@ -403,6 +408,10 @@ class ReplicaLoad:
                 if isinstance(data.get("kvBlocks"), list) else None
             ),
             role=str(data.get("role") or "mixed"),
+            device=(
+                data.get("device")
+                if isinstance(data.get("device"), dict) else None
+            ),
             shed=int(data.get("shedTotal") or 0),
             degraded=int(data.get("degradedTotal") or 0),
         )

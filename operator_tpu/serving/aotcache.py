@@ -1,9 +1,10 @@
 """Persisted AOT executables — the warm-start path for the serving engine.
 
-BENCH_r02 (the one real-TPU run) spent ~27 s in warmup compile before the
-first token; the supervisor's device-reset recovery and any scale-from-zero
-autoscaler pay that again on every boot.  XLA's persistent *compilation*
-cache (utils/platform.py) only skips the backend compile — tracing,
+A cold engine compiles its serving programs before the first token (how
+long on the chip: PERF.md, chip_smoke.py's bring-up facts); the
+supervisor's device-reset recovery and any scale-from-zero autoscaler pay
+that again on every boot.  XLA's persistent *compilation* cache
+(utils/platform.py) only skips the backend compile — tracing,
 lowering and executable construction still run per program, and the cache
 key is XLA's, not ours.  This module persists the **compiled executables
 themselves** (``jax.experimental.serialize_executable``): on a warm boot
@@ -52,20 +53,17 @@ _SUFFIX = ".aotx"
 
 def _fresh_compile_scope():
     """Scope that bypasses XLA's persistent compilation cache for a compile
-    whose executable will be serialized.  An executable reconstructed from
-    a persistent-cache HIT serializes WITHOUT its jitted symbol definitions
-    — ``deserialize_and_load`` then fails with "Symbols not found" in the
-    next process, poisoning the stored ``.aotx``.  A fresh build serializes
-    completely; nothing is lost because this cache supersedes XLA's for
-    serving programs."""
-    try:
-        from jax._src import config as _jax_config
+    whose executable will be serialized — BY DESIGN: with ``AOT_CACHE_PATH``
+    set, serving programs never read or feed the persistent cache.  An
+    executable reconstructed from a persistent-cache HIT serializes WITHOUT
+    its jitted symbol definitions — ``deserialize_and_load`` then fails
+    with "Symbols not found" in the next process, poisoning the stored
+    ``.aotx``.  A fresh build serializes completely; nothing is lost
+    because this cache supersedes XLA's for serving programs."""
+    from jax._src import config as _jax_config
 
-        return _jax_config.enable_compilation_cache(False)
-    except Exception:  # noqa: BLE001 - private API moved: compile normally
-        import contextlib
+    return _jax_config.enable_compilation_cache(False)
 
-        return contextlib.nullcontext()
 
 #: jit-ed function names of the serving programs (programs.py inner defs,
 #: engine decode methods, sched/mixed.py) — what a compile-log event must
@@ -93,24 +91,15 @@ def runtime_versions() -> dict:
     TPU).  ``AOT_CACHE_SALT`` folds in so operators (and tests) can force
     a cold boot without deleting anything."""
     import jax
+    import jax.extend
+    import jaxlib
 
-    try:
-        import jaxlib
-
-        jaxlib_version = getattr(jaxlib, "__version__", "?")
-    except Exception:  # noqa: BLE001 - jaxlib is implied by jax, but stay safe
-        jaxlib_version = "?"
-    try:
-        backend = jax.extend.backend.get_backend()
-        platform = backend.platform
-        platform_version = str(getattr(backend, "platform_version", ""))
-    except Exception:  # noqa: BLE001 - no backend yet: fingerprint still works
-        platform, platform_version = "uninitialised", ""
+    backend = jax.extend.backend.get_backend()
     return {
         "jax": jax.__version__,
-        "jaxlib": jaxlib_version,
-        "platform": platform,
-        "platform_version": platform_version,
+        "jaxlib": jaxlib.__version__,
+        "platform": backend.platform,
+        "platform_version": str(backend.platform_version),
         "salt": os.environ.get("AOT_CACHE_SALT", ""),
     }
 

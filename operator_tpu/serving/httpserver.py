@@ -19,9 +19,11 @@ operator/httpserver.py):
   when an encoder checkpoint is mounted, lexical hashing otherwise)
   exposed OpenAI-style for log-similarity tooling
 - ``GET  /healthz``              — liveness for probes, plus this
-  replica's identity and load report (queue depth, roofline decode
+  replica's identity, its load report (the device it runs on — platform /
+  device_kind / count as JAX reports them — queue depth, roofline decode
   estimate, supervisor gave-up flag, step-clock perf summary) for the
-  failover router (operator_tpu/router/)
+  failover router (operator_tpu/router/), per-device memory, and its XLA
+  compile log
 - ``POST /profile?seconds=N``    — on-demand TPU profiler capture
   (``jax.profiler.start_trace``/``stop_trace``): N seconds of device
   trace written under the profile dir, 404 unless enabled
@@ -431,11 +433,19 @@ class CompletionServer:
             # admission roofline's per-token estimate feed the router's
             # shed decision, gaveUp excludes a supervisor-bricked engine
             load = self.engine.load_report()
+            watch = self.engine.compile_watch
             return 200, {
                 "status": "degraded" if load.gave_up else "ok",
                 "uptime_s": round(time.time() - self._started, 1),
                 "replica": self.replica_id,
+                # load.device names the device this replica really runs
+                # on; this is what each local device's runtime says it holds
+                "deviceMemory": self.engine.device_memory(),
                 "load": load.to_dict(),
+                # every XLA compile this process made, with persistent-
+                # cache hits marked: a compile after warm-up is a latency
+                # outlier somebody should be able to see from outside
+                "compiles": watch.report() if watch is not None else None,
             }
         if method == "GET" and path == "/metrics.json":
             # per-stage latency percentiles (prefill, decode_step, ...) from

@@ -3,8 +3,8 @@
 The step clock (obs/steptrace.py) records *where* a decode step's wall
 time goes; this module turns those records into *how fast the chip ran*:
 an analytic flops-per-token model derived from the model config alone
-(no device counters needed), a per-dtype peak-TFLOPs table, and the
-:class:`StepClock` both engine loops record through.
+(no device counters needed), a peak-TFLOPs table keyed by ``device_kind``,
+and the :class:`StepClock` both engine loops record through.
 
 Everything here is host-side orchestration: nothing is reachable from a
 ``jax.jit``/``pallas_call`` entry point, and the clock's only device
@@ -15,23 +15,26 @@ module and the instrumented loops).
 
 from __future__ import annotations
 
-import os
 import time
 from typing import Any, Optional
 
 from ..obs.steptrace import StepRecord, StepRing, attribution
 from ..utils.timing import MetricsRegistry
 
-#: per-dtype dense peak, TFLOP/s, for a single v5e chip (the deploy
-#: target; override with PEAK_TFLOPS / BENCH_PEAK_TFLOPS for other
-#: generations).  int8 runs through the MXU at twice the bf16 rate.
+#: dense matmul peak of ONE chip in TFLOP/s, keyed by ``device_kind`` as
+#: JAX reports it and then by the dtype the MXU multiplies in.  Source:
+#: Google Cloud documentation, "TPU v5e" (197 bf16 TFLOP/s per chip); the
+#: kind string is what ``jax.devices()[0].device_kind`` read on the chip
+#: (chip_smoke.py, PR 21).  A device that is not here has no peak, so no
+#: MFU is reported for it — never another chip's number.
 _PEAK_TFLOPS = {
-    "bf16": 197.0,
-    "bfloat16": 197.0,
-    "int8": 394.0,
-    "float32": 98.5,
-    "f32": 98.5,
+    "TPU v5 lite": {"bfloat16": 197.0},
 }
+
+#: serving dtype -> the dtype its matmuls run in.  int8 is WEIGHT-ONLY
+#: (models/quant.py ``mm`` casts to the activation dtype before every
+#: matmul), so it is judged against the bf16 peak, not the int8 one.
+_MATMUL_DTYPE = {"bf16": "bfloat16", "bfloat16": "bfloat16", "int8": "bfloat16"}
 
 
 def matmul_param_count(config: Any) -> int:
@@ -58,16 +61,11 @@ def flops_per_token(config: Any, dtype: str = "bf16") -> float:
     return 2.0 * matmul_param_count(config)
 
 
-def peak_tflops(dtype: str = "bf16") -> float:
-    """Chip peak for the serving dtype; ``PEAK_TFLOPS`` (or the bench's
-    ``BENCH_PEAK_TFLOPS``) overrides for non-v5e hardware."""
-    env = os.environ.get("PEAK_TFLOPS") or os.environ.get("BENCH_PEAK_TFLOPS")
-    if env:
-        try:
-            return float(env)
-        except ValueError:
-            pass
-    return _PEAK_TFLOPS.get(str(dtype).lower(), _PEAK_TFLOPS["bf16"])
+def peak_tflops(device_kind: str, dtype: str) -> Optional[float]:
+    """The chip's matmul peak for a serving dtype, or None when the device
+    or the dtype is not in the table ("not measured")."""
+    row = _PEAK_TFLOPS.get(device_kind, {})
+    return row.get(_MATMUL_DTYPE.get(str(dtype).lower(), ""))
 
 
 class StepClock:

@@ -29,7 +29,7 @@ class ProgramBuilderMixin:
     #: lax.scan: a scan CARRIES the whole KV cache/page pool, and XLA's
     #: loop handling may double-buffer (copy) the carry every iteration —
     #: unrolled, updates chain without loop plumbing.  Experiment knob
-    #: (scripts/tpu_experiments.sh); compile time grows ~K-fold.
+    #: (ROADMAP D2); compile time grows ~K-fold.
     DECODE_UNROLL = os.environ.get("OPERATOR_TPU_DECODE_UNROLL", "0") == "1"
 
     #: nucleus-sampling candidate-set size (constructor: ``sample_top_k``).
@@ -82,6 +82,7 @@ class ProgramBuilderMixin:
         logits, new_paged = decode_step_paged(
             params, self.config, tokens, paged,
             lora=lora, lora_alpha=self.lora_alpha, lora_indices=lora_idx,
+            mesh=self.mesh,
         )
         if gtables is not None:
             row = gtables[gaut, gstate]  # [B, vocab] allowed-transition rows

@@ -50,11 +50,13 @@ class MissingCheckpoint(RuntimeError):
 
 def _parse_mesh_plan(spec: str, devices: list, model_config):
     """'auto' or 'dp=2,tp=4[,fsdp=1]' -> MeshPlan."""
-    from ..parallel.mesh import MeshPlan, plan_for
+    from ..parallel.mesh import MeshPlan, device_memory_bytes, plan_for
 
     if spec == "auto":
-        # pass devices so tp sizing uses measured HBM, not the v5e constant
-        return plan_for(len(devices), config=model_config, devices=devices)
+        return plan_for(
+            len(devices), config=model_config,
+            hbm_bytes=device_memory_bytes(devices[0]),
+        )
     sizes = {"dp": 1, "fsdp": 1, "tp": 1}
     for part in spec.split(","):
         axis, _, value = part.strip().partition("=")
@@ -238,19 +240,45 @@ def build_serving_engine(
 
     from ..models import get_config, init_params
     from ..models.loader import load_params_async
-    from ..utils.platform import enable_persistent_compilation_cache
-
-    cache_dir = enable_persistent_compilation_cache()
-    if cache_dir:
-        log.info("persistent XLA compilation cache: %s", cache_dir)
     from ..models.tokenizer import load_tokenizer
+    from ..utils.compilewatch import CompileWatcher
+    from ..utils.platform import (
+        enable_persistent_compilation_cache,
+        resolve_device,
+    )
+
+    # the ONE place the serving stack opens its backend: a non-TPU device
+    # nobody asked for by name raises here (utils/platform.py)
+    device = resolve_device()
+    log.info(
+        "serving device: platform=%s device_kind=%s count=%d",
+        device.platform, device.kind, device.count,
+    )
+    log.info(
+        "persistent XLA compilation cache: %s",
+        enable_persistent_compilation_cache(),
+    )
+    # every compile from here on is attributed (GET /healthz "compiles")
+    compile_watch = CompileWatcher()
 
     config = config or OperatorConfig.from_env()
     model_id = os.environ.get("OPERATOR_TPU_MODEL", config.model_id)
     model_config = get_config(model_id)
 
     checkpoint_dir = config.checkpoint_dir
-    tokenizer = load_tokenizer(checkpoint_dir)
+    if checkpoint_dir:
+        tokenizer = load_tokenizer(checkpoint_dir)
+    else:
+        # no checkpoint, so no tokenizer of its own: the committed
+        # log-trained BPE (models/bpe_vocab, vocab 4096) wherever the
+        # model's vocabulary can hold its ids — every served config; the
+        # tiny test config (vocab 512) takes bytes
+        tokenizer = load_tokenizer("builtin-bpe")
+        if tokenizer.vocab_size > model_config.vocab_size:
+            tokenizer = load_tokenizer("byte")
+    log.info(
+        "tokenizer: %s (vocab %d)", type(tokenizer).__name__, tokenizer.vocab_size
+    )
     # legacy WEIGHT_DTYPE (when set) wins over the serving_dtype default —
     # int8 since PR 10, behind the tests/test_quant_parity.py gate
     serving_dtype = (config.weight_dtype or config.serving_dtype or "bf16").lower()
@@ -326,6 +354,39 @@ def build_serving_engine(
             "lora_dir %r does not exist or is not a directory; "
             "multi-LoRA serving disabled", config.lora_dir,
         )
+
+    # continuous-batching scheduler (serving/sched/, docs/SERVING.md): the
+    # default.  A configuration it cannot serve is an ERROR naming the
+    # reason — never a warning and a different engine: which engine a
+    # deployment runs must be readable from its configuration.  Checked
+    # before any weight is loaded.
+    if config.sched_mode not in ("continuous", "wave"):
+        raise ValueError(
+            f"unknown sched_mode {config.sched_mode!r}: expected "
+            "'wave' or 'continuous'"
+        )
+    if config.sched_mode == "continuous":
+        blockers = [
+            reason for blocked, reason in (
+                (config.kv_cache_mode != "paged",
+                 f"kv_cache_mode={config.kv_cache_mode!r} (needs paged KV)"),
+                (mesh is not None,
+                 f"serving_mesh={config.serving_mesh!r} (the mixed program "
+                 "has no sharded path)"),
+                (bool(lora_adapters),
+                 "lora_dir adapters (the mixed program has no LoRA path)"),
+            ) if blocked
+        ]
+        if blockers:
+            raise ValueError(
+                "sched_mode=continuous cannot serve this configuration: "
+                + "; ".join(blockers)
+                + ". Set SCHED_MODE=wave to run the wave engine, which can."
+            )
+        if device.platform == "tpu":
+            from ..ops.ragged_attention import require_ragged_kernel_support
+
+            require_ragged_kernel_support(model_config)
 
     prefill_chunk = config.prefill_chunk or None
     max_slots = config.max_batch_size
@@ -426,66 +487,49 @@ def build_serving_engine(
         aot_cache=aot,
         step_ring_capacity=config.step_ring_capacity,
     )
-    # continuous-batching scheduler (serving/sched/, docs/SERVING.md):
-    # the DEFAULT since the decode-ahead/speculation PR (wave stays as
-    # the explicit SCHED_MODE=wave opt-out); falls back to the wave
-    # engine with a loud warning when the engine shape can't serve it
-    # (the mixed program has no mesh/LoRA path yet).  Decided BEFORE
-    # prefix priming: the scheduler prefills every prompt in full, so
-    # priming would only hold KV pages hostage for the process lifetime.
+    # Decided BEFORE prefix priming: the scheduler prefills every prompt in
+    # full, so priming would only hold KV pages hostage for the process
+    # lifetime.
     scheduler = None
     if config.sched_mode == "continuous":
-        if not generator.paged or mesh is not None or lora_adapters:
-            log.warning(
-                "sched_mode=continuous requires paged KV, no mesh and no "
-                "LoRA adapters (paged=%s mesh=%s lora=%s); falling back "
-                "to the wave engine",
-                generator.paged, mesh is not None, bool(lora_adapters),
+        from .sched import Scheduler
+
+        # automatic block-hash prefix caching (serving/kvstore.py):
+        # the continuous scheduler's generalisation of the wave
+        # engine's registered-shared-prefix — any cached prompt
+        # prefix is reused, with an optional host-RAM offload tier
+        # for evicted blocks (ops/kv_transfer.py)
+        kvstore = None
+        if config.kv_prefix_cache:
+            from .kvstore import PrefixKVStore
+
+            host_pool = None
+            if config.kv_host_pool_mb > 0:
+                from ..ops.kv_transfer import HostKVPool
+
+                host_pool = HostKVPool(config.kv_host_pool_mb)
+            kvstore = PrefixKVStore(
+                config.kv_page_size,
+                host_pool=host_pool,
+                metrics=generator.metrics,
             )
-        else:
-            from .sched import Scheduler
-
-            # automatic block-hash prefix caching (serving/kvstore.py):
-            # the continuous scheduler's generalisation of the wave
-            # engine's registered-shared-prefix — any cached prompt
-            # prefix is reused, with an optional host-RAM offload tier
-            # for evicted blocks (ops/kv_transfer.py)
-            kvstore = None
-            if config.kv_prefix_cache:
-                from .kvstore import PrefixKVStore
-
-                host_pool = None
-                if config.kv_host_pool_mb > 0:
-                    from ..ops.kv_transfer import HostKVPool
-
-                    host_pool = HostKVPool(config.kv_host_pool_mb)
-                kvstore = PrefixKVStore(
-                    config.kv_page_size,
-                    host_pool=host_pool,
-                    metrics=generator.metrics,
-                )
-            scheduler = Scheduler(
-                generator,
-                chunk=config.sched_chunk,
-                token_budget=config.sched_token_budget,
-                pipeline_depth=config.sched_pipeline_depth,
-                spec_decode=config.spec_decode,
-                spec_lookup_k=config.spec_lookup_k,
-                kvstore=kvstore,
-                # fleet KV fabric (operator_tpu/fabric/): mirror newly
-                # registered prompt blocks into the host pool so peers
-                # can fetch them over GET /kv/blocks/{hash}
-                fabric_mirror=(
-                    config.kv_fabric
-                    and config.kv_fabric_mirror
-                    and kvstore is not None
-                    and kvstore.host_pool is not None
-                ),
-            )
-    elif config.sched_mode != "wave":
-        raise ValueError(
-            f"unknown sched_mode {config.sched_mode!r}: expected "
-            "'wave' or 'continuous'"
+        scheduler = Scheduler(
+            generator,
+            chunk=config.sched_chunk,
+            token_budget=config.sched_token_budget,
+            pipeline_depth=config.sched_pipeline_depth,
+            spec_decode=config.spec_decode,
+            spec_lookup_k=config.spec_lookup_k,
+            kvstore=kvstore,
+            # fleet KV fabric (operator_tpu/fabric/): mirror newly
+            # registered prompt blocks into the host pool so peers
+            # can fetch them over GET /kv/blocks/{hash}
+            fabric_mirror=(
+                config.kv_fabric
+                and config.kv_fabric_mirror
+                and kvstore is not None
+                and kvstore.host_pool is not None
+            ),
         )
     # loud, unambiguous mode line: fleet operators grep for it when a
     # rollout flips scheduling behaviour
@@ -493,7 +537,7 @@ def build_serving_engine(
         log.info(
             "serving mode: CONTINUOUS scheduler (pipeline_depth=%d "
             "spec_decode=%s spec_lookup_k=%d kv_prefix_cache=%s "
-            "kv_host_pool_mb=%d); SCHED_MODE=wave opts out",
+            "kv_host_pool_mb=%d)",
             scheduler.depth, scheduler.spec_k > 0, scheduler.spec_k,
             scheduler._kvstore is not None, config.kv_host_pool_mb,
         )
@@ -531,6 +575,8 @@ def build_serving_engine(
     engine = ServingEngine(
         generator, supervisor=supervisor, scheduler=scheduler
     )
+    engine.device = device
+    engine.compile_watch = compile_watch
     # fleet KV fabric + disaggregation role (operator_tpu/fabric/,
     # docs/FABRIC.md).  The fetcher starts with a private empty index;
     # two feeders exist: in-process fleets (loadgen storm, bench, tests)

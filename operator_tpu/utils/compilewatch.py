@@ -10,6 +10,9 @@ can select must be compiled before readiness flips**.  This watcher makes
 violations observable: it taps jax's ``jax_log_compiles`` channel and
 records every "Compiling jit(NAME) ..." event with a timestamp, so a
 soak/bench can assert ``midrun_compiles == 0`` after its warmup mark.
+Each event also says whether the backend compile was served from JAX's
+persistent compilation cache (utils/platform.py) — how a second process
+of the same checkout shows that it reused the first one's programs.
 
 Usage::
 
@@ -17,7 +20,7 @@ Usage::
     ... build + warm the engine ...
     watcher.mark()                      # warmup/steady-state boundary
     ... measured window ...
-    watcher.events_since_mark()         # [(t_since_mark_s, name), ...]
+    watcher.events_since_mark()   # [(t_s, name, seconds, cache_hit), ...]
 """
 
 from __future__ import annotations
@@ -32,6 +35,12 @@ _COMPILING = re.compile(r"Compiling\s+(\S+)\s+with global shapes")
 _FINISHED = re.compile(
     r"Finished XLA compilation of\s+(\S+)\s+in\s+([0-9.]+)\s+sec"
 )
+_CACHE_HIT = re.compile(r"Persistent compilation cache hit for")
+
+#: events kept (oldest dropped first): a server carries the watcher for
+#: its whole life, the eager host-glue compiles never stop entirely, and
+#: the whole list rides on every ``GET /healthz`` poll
+_MAX_EVENTS = 256
 
 
 class _TapHandler(logging.Handler):
@@ -51,16 +60,20 @@ class _TapHandler(logging.Handler):
         m = _FINISHED.search(msg)
         if m:
             self._watcher._record_finish(m.group(1), float(m.group(2)))
+            return
+        if _CACHE_HIT.search(msg):
+            self._watcher._record_cache_hit()
 
 
 class CompileWatcher:
     """Tap the jax compile log and expose (timestamp, program) events.
 
-    Thread-safe: jax may log compiles from executor threads.  The tap is
-    installed on the ``jax`` logger at DEBUG without touching its
-    propagation or other handlers, and ``jax_log_compiles`` is enabled as
-    a side effect (harmless: the records land only on this handler unless
-    the application configured DEBUG logging itself).
+    Thread-safe: jax may log compiles from executor threads.  Enables
+    ``jax_log_compiles``, which raises the three records the tap reads
+    ("Compiling", "Finished XLA compilation", "Persistent compilation
+    cache hit") to WARNING, and adds one handler to the ``jax`` logger;
+    the logger's level is only touched when something set it ABOVE
+    WARNING, where those records would never be created.
     """
 
     def __init__(self) -> None:
@@ -69,24 +82,28 @@ class CompileWatcher:
         self._lock = threading.Lock()
         self._t0 = time.monotonic()
         self._mark: Optional[float] = None
-        # (t_monotonic, name, duration_s|None) - duration filled by the
-        # paired "Finished" record (same name, last unfinished wins)
+        # [t_monotonic, name, duration_s|None, cache_hit] - duration filled
+        # by the paired "Finished" record (same name, last unfinished wins)
         self._events: List[List] = []
+        self._dropped = 0
         jax.config.update("jax_log_compiles", True)
         self._logger = logging.getLogger("jax")
         self._prior_level = self._logger.level
-        if self._logger.level > logging.DEBUG or self._logger.level == 0:
-            # NOTSET(0) inherits root (WARNING by default): pin to DEBUG so
-            # the records reach handlers at all; the tap filters to compile
-            # messages and other handlers keep their own level gates
-            self._logger.setLevel(logging.DEBUG)
+        if self._logger.getEffectiveLevel() > logging.WARNING:
+            self._logger.setLevel(logging.WARNING)
         self._handler = _TapHandler(self)
         self._logger.addHandler(self._handler)
 
     # -- record -----------------------------------------------------------
+    def _append_locked(self, event: List) -> None:
+        self._events.append(event)
+        if len(self._events) > _MAX_EVENTS:
+            del self._events[0]
+            self._dropped += 1
+
     def _record_start(self, name: str) -> None:
         with self._lock:
-            self._events.append([time.monotonic(), name, None])
+            self._append_locked([time.monotonic(), name, None, False])
 
     def _record_finish(self, name: str, seconds: float) -> None:
         with self._lock:
@@ -96,7 +113,17 @@ class CompileWatcher:
                     return
             # "Finished" without a matched start (pre-install compile or
             # name drift): record it anyway so nothing is silently dropped
-            self._events.append([time.monotonic(), name, seconds])
+            self._append_locked([time.monotonic(), name, seconds, False])
+
+    def _record_cache_hit(self) -> None:
+        """jax logs the hit between a program's "Compiling" and "Finished"
+        records, under the MODULE name (``jit_f`` for ``jit(f)``): credit
+        the newest compile still open."""
+        with self._lock:
+            for ev in reversed(self._events):
+                if ev[2] is None:
+                    ev[3] = True
+                    return
 
     # -- query ------------------------------------------------------------
     def mark(self) -> None:
@@ -104,22 +131,37 @@ class CompileWatcher:
         with self._lock:
             self._mark = time.monotonic()
 
-    def events(self) -> List[Tuple[float, str, Optional[float]]]:
+    def events(self) -> List[Tuple[float, str, Optional[float], bool]]:
         with self._lock:
-            return [(t - self._t0, n, d) for t, n, d in self._events]
+            return [(t - self._t0, n, d, h) for t, n, d, h in self._events]
 
-    def events_since_mark(self) -> List[Tuple[float, str, Optional[float]]]:
+    def events_since_mark(self) -> List[Tuple[float, str, Optional[float], bool]]:
         with self._lock:
             if self._mark is None:
-                return [(t - self._t0, n, d) for t, n, d in self._events]
+                return [(t - self._t0, n, d, h) for t, n, d, h in self._events]
             return [
-                (t - self._mark, n, d)
-                for t, n, d in self._events
+                (t - self._mark, n, d, h)
+                for t, n, d, h in self._events
                 if t >= self._mark
             ]
 
     def count_since_mark(self) -> int:
         return len(self.events_since_mark())
+
+    def report(self) -> dict:
+        """JSON view for ``GET /healthz`` and the demo summary: every
+        compile this process made, oldest first — ``count`` includes
+        events the bound dropped."""
+        events = self.events()
+        return {
+            "count": len(events) + self._dropped,
+            "events": [
+                {"t_s": round(t, 3), "name": n,
+                 "seconds": None if d is None else round(d, 3),
+                 "cache_hit": h}
+                for t, n, d, h in events
+            ],
+        }
 
     def close(self) -> None:
         self._logger.removeHandler(self._handler)

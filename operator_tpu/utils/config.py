@@ -185,7 +185,7 @@ class OperatorConfig:
     health_port: int = 8080  # 0 = ephemeral (tests), -1 = disabled
 
     # --- serving ----------------------------------------------------------
-    model_id: str = "tinyllama-1.1b"
+    model_id: str = "qwen2.5-1.5b"
     checkpoint_dir: Optional[str] = None
     # MiniLM-class sentence encoder for semantic pattern matching (the
     # subsumed log-parser's neural scorer); unset = lexical HashingEmbedder
@@ -215,9 +215,10 @@ class OperatorConfig:
     # schedule→dispatch→commit loop over ONE ragged mixed prefill+decode
     # program — token-level admission into the running wave, per-token
     # slot/page recycling, decode-ahead pipelining and prompt-lookup
-    # speculation.  Requires paged KV, no mesh, no guided/LoRA traffic
-    # (provider falls back to wave with a loud warning).  "wave" is the
-    # explicit opt-out and still owns guided/LoRA/mesh serving.
+    # speculation.  Requires paged KV, no mesh, no LoRA adapters — with
+    # any of them build_serving_engine raises, naming the blocker; it
+    # never picks another engine on its own.  "wave" is the explicit
+    # choice and still owns guided/LoRA/mesh serving.
     sched_mode: str = "continuous"  # "continuous" | "wave"
     # max prefill tokens ONE row contributes to a step (Sarathi chunk)
     sched_chunk: int = 64

@@ -26,15 +26,11 @@ import sys
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), ".."))
 
-from operator_tpu.utils.platform import pin_cpu_if_requested  # noqa: E402
-
-pin_cpu_if_requested()
-
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
 HBM_BYTES = 16e9  # v5e chip
-SLOTS, MAX_SEQ = 8, 2048  # the bench_8b shape (scripts/tpu_experiments.sh)
+SLOTS, MAX_SEQ = 8, 2048  # the 8B bench shape
 
 
 def _size(tree) -> int:

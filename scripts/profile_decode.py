@@ -15,11 +15,6 @@ import os
 import sys
 import time
 
-sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-from _cpu_pin import pin_cpu_if_requested
-
-pin_cpu_if_requested()
-
 import jax
 import jax.numpy as jnp
 import numpy as np

@@ -58,8 +58,7 @@ def test_llama3_8b_loads_and_generates(tmp_path):
     t0 = time.time()
     writer = subprocess.run(
         [sys.executable, "-c", (
-            "import jax; jax.config.update('jax_platforms', 'cpu')\n"
-            "import dataclasses, jax.numpy as jnp\n"
+            "import dataclasses, jax, jax.numpy as jnp\n"
             "from operator_tpu.models.configs import LLAMA_3_8B\n"
             "from operator_tpu.models.llama import init_params\n"
             "from operator_tpu.models.loader import save_params\n"

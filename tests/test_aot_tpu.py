@@ -1,10 +1,13 @@
-"""AOT cross-compilation of every Pallas kernel for a real v5e target.
+"""AOT cross-compilation of every Pallas kernel and the serving step for a
+real v5e target.
 
-Mosaic lowering failures (layout/window asserts) surface at COMPILE time,
-so compiling against an abstract v5e topology on the CPU host validates
-the on-chip-crash risk without a chip (VERDICT r4 item 3 — this caught a
-real one: flash prefill's bf16 K/V head slice broke (8,128)x2 tiling).
-Skips cleanly on jax installs without the TPU compiler (plain CI wheels).
+Mosaic lowering failures (layout/window asserts, "cannot be automatically
+partitioned") surface at COMPILE time, so compiling against an abstract
+v5e topology on the CPU host finds the on-chip crash without a chip — it
+caught flash prefill's bf16 K/V head slice breaking (8,128)x2 tiling, the
+ragged kernel's head_dim-64 page DMA, and the unpartitionable paged
+kernel under ``SERVING_MESH``.  The child never opens a chip.  Skips
+cleanly on jax installs without the TPU compiler (plain CI wheels).
 """
 
 import json
@@ -14,6 +17,9 @@ import subprocess
 import sys
 
 import pytest
+
+from operator_tpu.models.configs import _REGISTRY
+from operator_tpu.utils.config import OperatorConfig
 
 REPO = pathlib.Path(__file__).resolve().parent.parent
 
@@ -30,10 +36,27 @@ def test_all_kernels_aot_compile_for_v5e():
     assert out.returncode == 0, out.stdout + out.stderr
     record = json.loads(out.stdout.strip().splitlines()[-1])
     assert record["failed"] == 0, record
-    assert all(k["ok"] for k in record["kernels"].values()), record
+    kernels = record["kernels"]
+    assert all(k["ok"] for k in kernels.values()), record
     # both production dtypes of every serving kernel must be present
     for name in (
         "paged_attention_v1_bf16", "paged_attention_v2_bf16",
         "flash_prefill_bf16", "similarity_best_window",
     ):
-        assert name in record["kernels"], record
+        assert name in kernels, record
+    # the default path's only attention: for EVERY registered config the
+    # ragged kernel either compiled at both chunk widths (verify rows and
+    # prefill chunks) or was refused by name — never anything in between
+    for name, config in _REGISTRY.items():
+        if config.head_dim % 128:
+            assert "head_dim" in kernels[f"ragged_{name}"]["refused"], record
+        else:
+            assert f"ragged_{name}_c5" in kernels, record
+            assert f"ragged_{name}_c64" in kernels, record
+    assert "ragged_window" in kernels, record
+    # the whole mixed step at the server's default shape, for the default
+    # model — which must therefore be one the kernel serves
+    assert _REGISTRY[OperatorConfig().model_id].head_dim % 128 == 0
+    assert kernels["mixed_step_default_model"]["argument_bytes"] > 1e9, record
+    # SERVING_MESH=dp=1,tp=4: the paged kernel inside a shard_map
+    assert "mesh_tp4_paged_decode" in kernels, record

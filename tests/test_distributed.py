@@ -143,7 +143,7 @@ def test_single_process_launch_is_a_noop():
     env.pop("JAX_COORDINATOR_ADDRESS", None)
     env.pop("COORDINATOR_ADDRESS", None)
     code = (
-        "import jax; jax.config.update('jax_platforms', 'cpu');\n"
+        "import jax\n"
         "from operator_tpu.parallel.mesh import initialize_distributed\n"
         "initialize_distributed()\n"
         "assert jax.process_count() == 1\n"

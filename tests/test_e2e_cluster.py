@@ -26,10 +26,6 @@ pytestmark = pytest.mark.skipif(
 
 
 def test_operator_against_real_apiserver():
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
-
     from operator_tpu.operator.app import Operator
     from operator_tpu.operator.httpapi import HttpKubeApi
     from operator_tpu.operator.storage import ANNOTATION_ANALYZED_AT

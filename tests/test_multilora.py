@@ -266,6 +266,7 @@ def test_lora_dir_loader_isolates_bad_adapters(tmp_path, params, adapters, monke
     config = OperatorConfig(
         model_id="tiny-test", allow_random_weights=True,
         max_batch_size=2, decode_block=2, lora_dir=str(lora_dir),
+        sched_mode="wave",  # adapters are the wave engine's to serve
     )
     engine, model_id = build_serving_engine(config)
     try:

@@ -10,6 +10,7 @@ from operator_tpu.models import TINY_TEST, get_config, init_params
 from operator_tpu.models.llama import forward
 from operator_tpu.parallel import (
     MeshPlan,
+    device_memory_bytes,
     make_mesh,
     make_train_step,
     mesh_summary,
@@ -35,15 +36,24 @@ def test_plan_defaults_to_dp():
 
 
 def test_plan_llama3_8b_needs_tp_on_v5e():
-    # bf16 8B ≈ 16 GB > 14 GB budget -> tp=2; kv_heads=8 divisible ✓
-    plan = plan_for(4, config=get_config("llama-3-8b"))
+    # bf16 8B ≈ 16 GB > 7/8 of a 16 GB chip -> tp=2; kv_heads=8 divisible ✓
+    plan = plan_for(4, config=get_config("llama-3-8b"), hbm_bytes=16 * 2**30)
     assert plan.tp >= 2
     assert plan.total == 4
 
 
 def test_plan_small_model_stays_dp():
-    plan = plan_for(8, config=get_config("tinyllama-1.1b"))
+    plan = plan_for(8, config=get_config("qwen2.5-1.5b"), hbm_bytes=16 * 2**30)
     assert plan.tp == 1 and plan.dp == 8
+
+
+def test_plan_from_config_needs_a_measured_memory_size():
+    # no assumed chip: sizing tp from a model takes the device's own number,
+    # and a backend that reports none (the cpu) says so
+    with pytest.raises(ValueError, match="hbm_bytes"):
+        plan_for(4, config=get_config("llama-3-8b"))
+    with pytest.raises(ValueError, match="reports no memory size"):
+        device_memory_bytes(jax.devices("cpu")[0])
 
 
 def test_plan_rejects_oversubscription():

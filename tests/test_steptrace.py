@@ -209,17 +209,21 @@ class TestFlopsModel:
         assert matmul_param_count(c) == expected == 593920
         assert flops_per_token(c) == 2.0 * expected == 1187840.0
 
-    def test_peak_table_and_env_override(self, monkeypatch):
-        monkeypatch.delenv("PEAK_TFLOPS", raising=False)
-        monkeypatch.delenv("BENCH_PEAK_TFLOPS", raising=False)
-        assert peak_tflops("bf16") == 197.0
-        assert peak_tflops("int8") == 394.0
-        assert peak_tflops("float32") == 98.5
-        assert peak_tflops("no-such-dtype") == 197.0  # bf16 fallback
+    def test_peak_table_is_keyed_by_device_kind(self, monkeypatch):
+        v5e = "TPU v5 lite"
+        assert peak_tflops(v5e, "bf16") == peak_tflops(v5e, "bfloat16") == 197.0
+        # weight-only int8 multiplies in bf16 (models/quant.py mm): judged
+        # against the bf16 row, not the chip's int8 peak
+        assert peak_tflops(v5e, "int8") == 197.0
+        # no assumed numbers: an unknown device or dtype has no peak
+        assert peak_tflops("cpu", "bf16") is None
+        assert peak_tflops("TPU v9 imaginary", "bf16") is None
+        assert peak_tflops(v5e, "float32") is None
+        # ... and no env override can invent one
         monkeypatch.setenv("PEAK_TFLOPS", "123.5")
-        assert peak_tflops("bf16") == 123.5
-        monkeypatch.setenv("PEAK_TFLOPS", "not-a-number")
-        assert peak_tflops("bf16") == 197.0  # garbage env never raises
+        monkeypatch.setenv("BENCH_PEAK_TFLOPS", "123.5")
+        assert peak_tflops(v5e, "bf16") == 197.0
+        assert peak_tflops("cpu", "bf16") is None
 
 
 class TestStepClock:
@@ -391,10 +395,11 @@ class TestEngineStepClock:
         # fractions total 1.0 by construction
         summary = generator.step_clock.summary()
         assert sum(summary["fractions"].values()) == pytest.approx(1.0, abs=0.02)
-        # the analytic flops model rode along: measured decode MFU is
-        # non-null (a CPU-smoke tiny model legitimately rounds to 0.0)
-        assert summary["decode_mfu"] is not None
+        # the analytic flops model rode along (a tiny model on the CPU
+        # legitimately rounds to 0.0) — but the CPU has no row in the
+        # peak table, so no MFU is made up for it
         assert summary["achieved_tflops"] is not None
+        assert summary["decode_mfu"] is None
         # the ONLY request on a fresh clock decoded the whole decode
         # window, so its decode_ms IS the cumulative decode wall
         assert result.decode_ms == pytest.approx(
