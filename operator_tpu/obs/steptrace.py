@@ -3,20 +3,30 @@
 A single opaque MFU number cannot say *where* a step's wall time goes.
 Both engine loops (the wave engine's ``step()`` and the
 continuous scheduler's ``Scheduler.step()``) record one
-:class:`StepRecord` per dispatched step into a bounded :class:`StepRing`,
-splitting the step's monotonic timeline into three attributed components:
+:class:`StepRecord` per COMMITTED step into a bounded :class:`StepRing`.
+A record is of an INTERVAL of the worker thread's wall clock: from the
+end of the previous commit to the end of this one (or, after an idle
+engine, from the start of the ``step()`` that found work again), so
+records never overlap and their ``wall_ms`` sum to the wall time the
+engine had work.  The interval splits three ways:
 
-- ``host_gap_ms``   — time between the previous step's commit and this
-  step's dispatch (host think-time: scheduling, admission, Python)
-- ``device_ms``     — dispatch → result ready (``block_until_ready`` on
-  the already-dispatched token array; the ONE sync the loop was about to
-  perform anyway, so the clock adds zero new host syncs — GL001-gated)
-- ``sample_xfer_ms``— the sampled-token device→host fetch
+- ``wait_ms``  — blocked in ``block_until_ready`` on the committed
+  step's token array (the ONE sync the loop was about to perform anyway,
+  so the clock adds zero new host syncs — GL001-gated): the host's slack
+- ``xfer_ms``  — the sampled-token device→host fetch
+- ``host_ms``  — the rest: what the host needed.  Its named parts are
+  ``plan_ms`` (scheduling + admission), ``pack_ms`` (packing the flat
+  token axis and enqueueing the program), ``commit_ms`` (row commits,
+  offload drains, outcomes) and ``turn_ms`` (between two ``step()``
+  calls while work was pending: the event loop's turn)
 
-Attribution fractions are computed over the SUM of the three components,
-so they always total 1.0 by construction; the analytic flops-per-token
+``host_ms + wait_ms + xfer_ms == wall_ms`` by construction, so the
+attribution fractions always total 1.0; the analytic flops-per-token
 model (serving/perf.py) turns the same records into per-step achieved
-TFLOPs and a measured, attributed decode MFU.
+TFLOPs and a measured, attributed decode MFU.  Under decode-ahead
+pipelining (depth 2) the phases inside one interval belong to two step
+numbers — the commit of step N and the plan + dispatch of step N+2's
+predecessor — the record is of the interval, not of one dispatch.
 
 The ring is host-side bookkeeping only and is never reachable from a
 compiled program; ``STEP_RING_CAPACITY`` bounds it (default 512 steps).
@@ -45,16 +55,36 @@ def _env_capacity(default: int = _DEFAULT_CAPACITY) -> int:
 
 @dataclass(frozen=True)
 class StepRecord:
-    """One engine step's attributed timeline (immutable once recorded)."""
+    """One committed step's interval of the worker thread's wall clock
+    (immutable once recorded): previous commit's end → this commit's
+    end.  ``kind`` / ``tokens`` / the work counts describe the step that
+    COMMITTED in it; at pipeline depth 2 the ``plan_ms`` / ``pack_ms``
+    inside the same interval were spent on a later step's dispatch."""
 
     seq: int
     kind: str  # "prefill" | "decode" | "mixed"
     tokens: int  # tokens processed this step (decode rows / prefill chunk)
     slots: int  # live slots at dispatch
     occupancy: float  # slots / max_slots
-    host_gap_ms: float
-    device_ms: float
-    sample_xfer_ms: float
+    wall_ms: float  # the interval: host_ms + wait_ms + xfer_ms
+    host_ms: float  # wall_ms less wait_ms and xfer_ms
+    wait_ms: float  # blocked on the device (block_until_ready)
+    xfer_ms: float  # sampled-token device->host fetch
+    #: named parts of ``host_ms`` (they need not sum to it: the rest is
+    #: loop glue between the stamps)
+    plan_ms: float = 0.0
+    pack_ms: float = 0.0
+    commit_ms: float = 0.0
+    turn_ms: float = 0.0
+    #: work counts taken where the step's arrays are packed; None on
+    #: engines that do not distinguish.  ``prefill_tokens`` of ``tokens``
+    #: were prompt tokens (the rest decode and verify tokens)
+    prefill_tokens: Optional[int] = None
+    #: KV pages ONE layer's ragged-attention call walks this step: the
+    #: sum over slots with ``q_count > 0`` of ``cdiv(kv_len, page_size)``
+    #: less the pages a sliding window skips — ``num_live - first`` of
+    #: ``ops/ragged_attention._ragged_attn_kernel``
+    kv_pages_walked: Optional[int] = None
     #: per-step achieved MFU when the ring's owner knows the model's
     #: flops/token (serving/perf.py StepClock); None on bare rings
     mfu: Optional[float] = None
@@ -71,7 +101,7 @@ class StepRecord:
 
     @property
     def total_ms(self) -> float:
-        return self.host_gap_ms + self.device_ms + self.sample_xfer_ms
+        return self.wall_ms
 
     def to_dict(self) -> dict:
         out = {
@@ -80,16 +110,14 @@ class StepRecord:
             "tokens": self.tokens,
             "slots": self.slots,
             "occupancy": round(self.occupancy, 4),
-            "host_gap_ms": round(self.host_gap_ms, 4),
-            "device_ms": round(self.device_ms, 4),
-            "sample_xfer_ms": round(self.sample_xfer_ms, 4),
         }
+        for name in _MS_FIELDS:
+            out[name] = round(getattr(self, name), 4)
         if self.mfu is not None:
             out["mfu"] = round(self.mfu, 6)
-        if self.accepted is not None:
-            out["accepted"] = self.accepted
-        if self.cached_tokens is not None:
-            out["cached_tokens"] = self.cached_tokens
+        for name in _COUNT_FIELDS:
+            if getattr(self, name) is not None:
+                out[name] = getattr(self, name)
         return out
 
     @classmethod
@@ -100,19 +128,20 @@ class StepRecord:
             tokens=int(data.get("tokens", 0)),
             slots=int(data.get("slots", 0)),
             occupancy=float(data.get("occupancy", 0.0)),
-            host_gap_ms=float(data.get("host_gap_ms", 0.0)),
-            device_ms=float(data.get("device_ms", 0.0)),
-            sample_xfer_ms=float(data.get("sample_xfer_ms", 0.0)),
             mfu=(float(data["mfu"]) if data.get("mfu") is not None else None),
-            accepted=(
-                int(data["accepted"])
-                if data.get("accepted") is not None else None
-            ),
-            cached_tokens=(
-                int(data["cached_tokens"])
-                if data.get("cached_tokens") is not None else None
-            ),
+            **{name: float(data.get(name, 0.0)) for name in _MS_FIELDS},
+            **{
+                name: (int(data[name]) if data.get(name) is not None else None)
+                for name in _COUNT_FIELDS
+            },
         )
+
+
+_MS_FIELDS = (
+    "wall_ms", "host_ms", "wait_ms", "xfer_ms",
+    "plan_ms", "pack_ms", "commit_ms", "turn_ms",
+)
+_COUNT_FIELDS = ("accepted", "cached_tokens", "prefill_tokens", "kv_pages_walked")
 
 
 class StepRing:
@@ -146,15 +175,21 @@ class StepRing:
         tokens: int,
         slots: int,
         occupancy: float,
-        host_gap_ms: float,
-        device_ms: float,
-        sample_xfer_ms: float,
+        wall_ms: float,
+        wait_ms: float = 0.0,
+        xfer_ms: float = 0.0,
         mfu: Optional[float] = None,
-        accepted: Optional[int] = None,
-        cached_tokens: Optional[int] = None,
+        **fields,
     ) -> StepRecord:
+        """Append one interval's record.  ``host_ms`` is never passed: it
+        is ``wall_ms`` less the two waits, so the three always sum to the
+        wall.  ``fields`` are the record's optional parts and counts by
+        name."""
         if kind not in STEP_KINDS:
             raise ValueError(f"unknown step kind {kind!r} (one of {STEP_KINDS})")
+        wall_ms = max(0.0, float(wall_ms))
+        wait_ms = min(max(0.0, float(wait_ms)), wall_ms)
+        xfer_ms = min(max(0.0, float(xfer_ms)), wall_ms - wait_ms)
         with self._lock:
             record = StepRecord(
                 seq=self._seq,
@@ -162,23 +197,27 @@ class StepRing:
                 tokens=int(tokens),
                 slots=int(slots),
                 occupancy=float(occupancy),
-                host_gap_ms=max(0.0, float(host_gap_ms)),
-                device_ms=max(0.0, float(device_ms)),
-                sample_xfer_ms=max(0.0, float(sample_xfer_ms)),
+                wall_ms=wall_ms,
+                host_ms=wall_ms - wait_ms - xfer_ms,
+                wait_ms=wait_ms,
+                xfer_ms=xfer_ms,
                 mfu=mfu,
-                accepted=(int(accepted) if accepted is not None else None),
-                cached_tokens=(
-                    int(cached_tokens) if cached_tokens is not None else None
-                ),
+                **fields,
             )
             self._seq += 1
             self._records.append(record)
             if len(self._records) > self.capacity:
                 del self._records[0]
                 self.evicted += 1
-            self.cum_ms[kind] += record.total_ms
+            self.cum_ms[kind] += record.wall_ms
             self.cum_tokens[kind] += record.tokens
             return record
+
+    @property
+    def next_seq(self) -> int:
+        """The ``seq`` the next appended record will get."""
+        with self._lock:
+            return self._seq
 
     def records(self, last: Optional[int] = None) -> "list[StepRecord]":
         with self._lock:
@@ -216,19 +255,19 @@ def attribution(
 ) -> dict:
     """Stall-attribution summary over a window of step records.
 
-    Fractions are shares of the summed attributed time (host_gap +
-    device + sample_xfer over all records), so they total 1.0 by
-    construction.  With a flops model, ``decode_mfu`` is the measured
-    MFU over decode-bearing steps (pure decode + mixed): tokens they
-    produced x flops/token against peak over their attributed wall.
-    Without a peak (a device serving/perf.py has no row for) the MFU
-    stays None — not measured — and only ``achieved_tflops`` is given."""
-    host_gap = sum(r.host_gap_ms for r in records)
-    device = sum(r.device_ms for r in records)
-    xfer = sum(r.sample_xfer_ms for r in records)
-    total = host_gap + device + xfer
+    Fractions are shares of the summed wall (``host`` + ``wait`` +
+    ``xfer`` over all records), so they total 1.0 by construction.
+    With a flops model, ``decode_mfu`` is the measured MFU over
+    decode-bearing steps (pure decode + mixed): tokens they produced x
+    flops/token against peak over their wall.  Without a peak (a device
+    serving/perf.py has no row for) the MFU stays None — not measured —
+    and only ``achieved_tflops`` is given."""
+    host = sum(r.host_ms for r in records)
+    wait = sum(r.wait_ms for r in records)
+    xfer = sum(r.xfer_ms for r in records)
+    total = host + wait + xfer
     decode_records = [r for r in records if r.kind in ("decode", "mixed")]
-    decode_ms = sum(r.total_ms for r in decode_records)
+    decode_ms = sum(r.wall_ms for r in decode_records)
     decode_tokens = sum(r.tokens for r in decode_records)
     # committed generated tokens: billed tokens unless the engine
     # reported a per-step accepted count (speculation / voided work)
@@ -242,9 +281,15 @@ def attribution(
         "decode_steps": sum(1 for r in records if r.kind == "decode"),
         "mixed_steps": sum(1 for r in records if r.kind == "mixed"),
         "tokens": sum(r.tokens for r in records),
-        "host_gap_ms": round(host_gap, 3),
-        "device_ms": round(device, 3),
-        "sample_xfer_ms": round(xfer, 3),
+        "wall_ms": round(total, 3),
+        "host_ms": round(host, 3),
+        "wait_ms": round(wait, 3),
+        "xfer_ms": round(xfer, 3),
+        # the host's named parts, summed (they need not add up to host_ms)
+        "host_parts_ms": {
+            name: round(sum(getattr(r, f"{name}_ms") for r in records), 3)
+            for name in ("plan", "pack", "commit", "turn")
+        },
         "accepted_tokens": accepted_tokens,
         # prompt tokens the prefix cache spared from prefill compute
         "cached_tokens": sum(r.cached_tokens or 0 for r in records),
@@ -253,9 +298,9 @@ def attribution(
             if records else None
         ),
         "fractions": {
-            "host_gap": round(host_gap / total, 4) if total else None,
-            "device": round(device / total, 4) if total else None,
-            "sample_xfer": round(xfer / total, 4) if total else None,
+            "host": round(host / total, 4) if total else None,
+            "wait": round(wait / total, 4) if total else None,
+            "xfer": round(xfer / total, 4) if total else None,
         },
         "decode_mfu": None,
         "achieved_tflops": None,
@@ -274,15 +319,21 @@ def render_steps(records: "Iterable[StepRecord]") -> str:
     --steps`` rendering; also readable when pasted from a black-box
     dump)."""
     header = (
-        f"{'seq':>5}  {'kind':<7} {'tok':>5} {'slots':>5} {'occ':>5} "
-        f"{'gap_ms':>8} {'dev_ms':>8} {'xfer_ms':>8} {'total':>8} {'mfu':>8}"
+        f"{'seq':>5}  {'kind':<7} {'tok':>5} {'pf_tok':>6} {'slots':>5} {'occ':>5} "
+        f"{'wall_ms':>8} {'host_ms':>8} {'wait_ms':>8} {'xfer_ms':>8} "
+        f"{'plan':>7} {'pack':>7} {'commit':>7} {'turn':>7} "
+        f"{'kv_pg':>6} {'mfu':>8}"
     )
     lines = [header, "-" * len(header)]
     for r in records:
         mfu = f"{r.mfu:.4f}" if r.mfu is not None else "-"
+        pages = r.kv_pages_walked if r.kv_pages_walked is not None else "-"
+        prompt = r.prefill_tokens if r.prefill_tokens is not None else "-"
         lines.append(
-            f"{r.seq:>5}  {r.kind:<7} {r.tokens:>5} {r.slots:>5} "
-            f"{r.occupancy:>5.2f} {r.host_gap_ms:>8.3f} {r.device_ms:>8.3f} "
-            f"{r.sample_xfer_ms:>8.3f} {r.total_ms:>8.3f} {mfu:>8}"
+            f"{r.seq:>5}  {r.kind:<7} {r.tokens:>5} {prompt:>6} {r.slots:>5} "
+            f"{r.occupancy:>5.2f} {r.wall_ms:>8.3f} {r.host_ms:>8.3f} "
+            f"{r.wait_ms:>8.3f} {r.xfer_ms:>8.3f} {r.plan_ms:>7.3f} "
+            f"{r.pack_ms:>7.3f} {r.commit_ms:>7.3f} {r.turn_ms:>7.3f} "
+            f"{pages:>6} {mfu:>8}"
         )
     return "\n".join(lines)

@@ -52,7 +52,7 @@ def load_steps(path: str) -> list[StepRecord]:
                 continue
             if not isinstance(data, dict):
                 continue
-            if "kind" in data and "device_ms" in data:
+            if "kind" in data and "wall_ms" in data:
                 steps.append(StepRecord.from_dict(data))
                 continue
             extra = data.get("extra")
@@ -75,12 +75,17 @@ def _print_steps(path: str) -> int:
     print(render_steps(steps))
     summary = attribution(steps)
     fractions = summary["fractions"]
-    if fractions["host_gap"] is not None:
+    if fractions["host"] is not None:
+        parts = "  ".join(
+            f"{name}={ms:.1f}" for name, ms in summary["host_parts_ms"].items()
+        )
         print(
             f"\n{summary['steps']} steps  tokens={summary['tokens']}  "
-            f"host_gap={fractions['host_gap']:.1%}  "
-            f"device={fractions['device']:.1%}  "
-            f"sample_xfer={fractions['sample_xfer']:.1%}"
+            f"wall_ms={summary['wall_ms']:.1f}  "
+            f"host={fractions['host']:.1%}  "
+            f"wait={fractions['wait']:.1%}  "
+            f"xfer={fractions['xfer']:.1%}\n"
+            f"host parts (ms): {parts}"
         )
     return 0
 

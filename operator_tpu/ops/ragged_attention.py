@@ -66,6 +66,11 @@ from ._flash_common import finalize, init_state, update_state
 
 _LANE = 128
 _NEG_INF = -1e30
+#: the kernel's stable name in lowered programs and profiler traces: what
+#: trace reductions look for (``benchmark/layer_metrics/
+#: attn_kernel_share.py`` matches ``ragged_attention``), so a refactor of
+#: the wrapper around the ``pallas_call`` cannot rename it by accident
+KERNEL_NAME = "ragged_attention_kernel"
 
 
 class UnsupportedHeadDim(ValueError):
@@ -323,6 +328,7 @@ def _ragged_attention_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, c, qh, d), jnp.float32),
         interpret=interpret,
+        name=KERNEL_NAME,
     )(page_table, kv_len, q_count, q, k_pages, v_pages)
     return out.astype(q.dtype)
 

@@ -192,7 +192,7 @@ class BatchedGenerator(AdmissionMixin, ProgramBuilderMixin):
         self.max_seq = min(max_seq or config.max_seq_len, config.max_seq_len)
         self.metrics = metrics or METRICS
         # ---- step clock (obs/steptrace.py + serving/perf.py): a bounded
-        # ring of per-step host-gap/device/sample-xfer records with the
+        # ring of per-step wall records (host / wait / xfer) with the
         # analytic flops-per-token model for the serving dtype, so every
         # decode step carries an attributed MFU (STEP_RING_CAPACITY)
         from .perf import StepClock, flops_per_token, peak_tflops
@@ -1006,16 +1006,17 @@ class BatchedGenerator(AdmissionMixin, ProgramBuilderMixin):
         jnp = self._jnp
         self.metrics.record("prefill", prefill_ms)
         self.metrics.record("prefill_batch", float(len(taken)))
-        # step clock: the wave's prefill is one phase-separated step; its
-        # compute is all "device" (the chunked path's accumulated chunk
-        # time), no per-component split is measurable post-hoc
+        # step clock: the wave's prefill is one phase-separated step.  On
+        # an idle clock the record stands alone and its wall is the
+        # prefill compute, all of it waited for; between decode rounds it
+        # closes the open interval here, of which at most ``prefill_ms``
+        # is wait (the chunked path passes accumulated chunk time, which
+        # earlier intervals already hold)
         self.step_clock.observe(
             kind="prefill",
             tokens=int(sum(int(n) for n in lengths)),
             slots=len(taken),
-            host_gap_ms=0.0,
-            device_ms=float(prefill_ms),
-            sample_xfer_ms=0.0,
+            wait_ms=float(prefill_ms),
         )
         if self.num_decoding:
             # wave-engine phase separation: this admission's prefill
@@ -1156,21 +1157,28 @@ class BatchedGenerator(AdmissionMixin, ProgramBuilderMixin):
             self._apply_guided_activation(row_aut, job.taken, first_state)
 
     # tracing ------------------------------------------------------------
-    def _annotation(self, name: str, params_list: Optional[list] = None):
-        """Host-side profiler marker around a prefill/decode region
-        (``jax.profiler.TraceAnnotation``) carrying the obs trace tags of
-        the wave, TraceMe-encoded (``name#trace=a,b#``) so an xplane
-        capture (scripts/analyze_xplane.py) joins the flight recorder's
+    def _annotation(
+        self, name: str, params_list: Optional[list] = None, **args: Any
+    ):
+        """Host-side profiler marker around a region of the decode worker
+        thread (``jax.profiler.TraceAnnotation``).  ``args`` (``step``,
+        ``kv_pages``, ...) and the obs trace tags of the wave ride as the
+        span's arguments, TraceMe-encoded (``name#step=7,trace=a|b#``):
+        a reader of the xplane capture (``benchmark/trace/``,
+        ``jax.profiler.ProfileData``) gets them back as ``event.stats``
+        under a clean name, and the tags join the flight recorder's
         per-analysis timeline.  A TraceMe costs nanoseconds while no
-        profiler session is active, so every step wears one."""
+        profiler session is active, so every phase of every step wears
+        one."""
         tags = sorted({
             p.trace_tag for p in (params_list or [])
             if p is not None and getattr(p, "trace_tag", None)
         })
         if tags:
-            name = f"{name}#trace={','.join(tags)}#"
+            # "," separates arguments, so several tags join with "|"
+            args["trace"] = "|".join(tags)
         try:
-            return self._jax.profiler.TraceAnnotation(name)
+            return self._jax.profiler.TraceAnnotation(name, **args)
         except Exception:  # noqa: BLE001 - profiler API unavailable: annotate nothing
             import contextlib
 
@@ -1216,6 +1224,16 @@ class BatchedGenerator(AdmissionMixin, ProgramBuilderMixin):
         """
         if self.num_active == 0 and not self._inflight_blocks:
             return []
+        clock = self.step_clock
+        clock.enter()
+        try:
+            return self._step_round()
+        finally:
+            clock.leave(
+                busy=self.num_active > 0 or bool(self._inflight_blocks)
+            )
+
+    def _step_round(self) -> list[tuple[int, GenerationResult]]:
         if self.fault_plan is not None:
             # chaos seam: a sleep action stalls this step (we run on the
             # decode worker, never the event loop); a raise action
@@ -1235,11 +1253,15 @@ class BatchedGenerator(AdmissionMixin, ProgramBuilderMixin):
             self.metrics.record(
                 "batch_occupancy", 100.0 * self.num_active / self.max_slots
             )
+            t_pack = self.step_clock.now()
             with self._annotation(
                 "podmortem.decode",
                 [s.params for s in self.slots if s.active],
             ):
                 self._dispatch_block()
+            self.step_clock.add(
+                "pack", (self.step_clock.now() - t_pack) * 1e3
+            )
         finished: list[tuple[int, GenerationResult]] = []
         # keep at most depth-1 blocks in flight; once nothing is active the
         # leftovers are flushed (their tokens belong to finished epochs)
@@ -1298,50 +1320,40 @@ class BatchedGenerator(AdmissionMixin, ProgramBuilderMixin):
             if slot.active
         }
         self._host_offsets[active] += block
-        # step-clock stamps: dispatch time + the host gap since the last
-        # processed block's commit travel WITH the block, because with
-        # pipeline_depth > 1 it is processed (and its record written) a
-        # later round than it was dispatched
-        t_dispatch = time.perf_counter()
-        self._inflight_blocks.append((
-            toks, snapshot,
-            (t_dispatch, self.step_clock.host_gap_ms(t_dispatch), len(snapshot)),
-        ))
+        self._inflight_blocks.append((toks, snapshot))
 
     def _process_block(
-        self, toks, snapshot, timing=None
+        self, toks, snapshot
     ) -> list[tuple[int, GenerationResult]]:
         block = self.decode_block
-        if timing is not None:
-            # resolve dispatch->ready BEFORE the fetch: the asarray below
-            # would block on the same completion event anyway, so this adds
-            # no new host sync — it only splits the wait into device time
-            # vs the sampled-token device->host transfer (GL001: this
-            # method is host loop code, never reachable from a jitted
-            # entry point — same legality as the asarray it times)
-            try:
-                toks.block_until_ready()
-            except AttributeError:  # fake arrays in tests
-                pass
-            t_ready = time.perf_counter()
+        # resolve the wait BEFORE the fetch: the asarray below would
+        # block on the same completion event anyway, so this adds no
+        # new host sync — it only splits the wait on the device from
+        # the sampled-token device->host transfer (GL001: this
+        # method is host loop code, never reachable from a jitted
+        # entry point — same legality as the asarray it times)
+        clock = self.step_clock
+        t_wait = clock.now()
+        try:
+            toks.block_until_ready()
+        except AttributeError:  # fake arrays in tests
+            pass
+        t_ready = clock.now()
         toks_np = np.asarray(toks)  # [K, B] — the ONE host sync per block
-        if timing is not None:
-            t_fetch = time.perf_counter()
-            t_dispatch, host_gap_ms, live = timing
-            self.step_clock.observe(
-                kind="decode",
-                tokens=block * live,
-                slots=live,
-                host_gap_ms=host_gap_ms,
-                # device window is dispatch -> ready; waiting began at
-                # t_ready0, but the block may have been ready long before
-                # (pipelined depth>1), in which case the wait is ~0
-                device_ms=max(0.0, (t_ready - t_dispatch) * 1e3),
-                sample_xfer_ms=max(0.0, (t_fetch - t_ready) * 1e3),
-                # the token-processing loop below runs AFTER the commit
-                # stamp, so its wall lands in the NEXT record's host gap
-                commit_t=t_fetch,
-            )
+        t_fetch = clock.now()
+        live = len(snapshot)  # the slots live when the block was dispatched
+        clock.observe(
+            kind="decode",
+            tokens=block * live,
+            slots=live,
+            # the block may have been ready long before (pipelined
+            # depth > 1), in which case the wait is ~0
+            wait_ms=(t_ready - t_wait) * 1e3,
+            xfer_ms=(t_fetch - t_ready) * 1e3,
+            # the token-processing loop below runs AFTER the commit
+            # stamp, so its wall lands in the NEXT record's host_ms
+            commit_t=t_fetch,
+        )
         finished: list[tuple[int, GenerationResult]] = []
         eos = self.tokenizer.eos_id
         for i, (epoch, before) in snapshot.items():
@@ -1996,7 +2008,7 @@ class ServingEngine:
             decode_token_s=self.generator.decode_token_estimate_s(),
             gave_up=self._gave_up,
             decode_mfu=summary.get("decode_mfu"),
-            host_gap_frac=fractions.get("host_gap"),
+            host_gap_frac=fractions.get("host"),
             occupancy=summary.get("occupancy_avg"),
             steps=summary.get("steps") or 0,
             slo_attainment=self._slo_board.attainment(),
@@ -2514,6 +2526,7 @@ class ServingEngine:
         loop = asyncio.get_running_loop()
         sched = self._sched
         assert sched is not None
+        annotate = self.generator._annotation
         # the scheduler's host queue is unbounded: cap the handoff so
         # overflow stays in THIS bounded priority queue (max_queue via
         # the low lane keeps gating external callers, and a late
@@ -2536,34 +2549,43 @@ class ServingEngine:
 
                 def _enqueue_all(requests=requests):
                     out = []
-                    for request in requests:
-                        try:
-                            out.append((request, sched.enqueue(
-                                request.prompt, request.params,
-                                submitted=request.submitted or None,
-                                priority=request.priority,
-                                resume_tokens=request.resume_tokens,
-                            ), None))
-                        except Exception as exc:  # noqa: BLE001 - per-request verdict
-                            out.append((request, None, exc))
+                    # on the worker thread, between two steps: tokenising
+                    # lands in the step record's turn_ms
+                    with annotate("podmortem.sched.enqueue", n=len(requests)):
+                        for request in requests:
+                            try:
+                                out.append((request, sched.enqueue(
+                                    request.prompt, request.params,
+                                    submitted=request.submitted or None,
+                                    priority=request.priority,
+                                    resume_tokens=request.resume_tokens,
+                                ), None))
+                            except Exception as exc:  # noqa: BLE001 - per-request verdict
+                                out.append((request, None, exc))
                     return out
-                enqueued = await loop.run_in_executor(
-                    self._executor, _enqueue_all
-                )
-                batch.clear()
-                for request, req_id, exc in enqueued:
-                    if exc is not None:
-                        self._partial_by_future.pop(request.future, None)
-                        if not request.future.done():
-                            request.future.set_exception(exc)
-                        continue
-                    self._pending[req_id] = request
-                    callback = self._partial_by_future.pop(
-                        request.future, None
+                # the hand-off as the event loop sees it: over to the
+                # worker thread, its ``podmortem.sched.enqueue``, back, and
+                # the bookkeeping here
+                with annotate("podmortem.serve.enqueue", n=len(requests)):
+                    enqueued = await loop.run_in_executor(
+                        self._executor, _enqueue_all
                     )
-                    if callback is not None:
-                        self._partial_cbs[req_id] = (callback, request.future)
-                        self._partial_sent.pop(req_id, None)
+                    batch.clear()
+                    for request, req_id, exc in enqueued:
+                        if exc is not None:
+                            self._partial_by_future.pop(request.future, None)
+                            if not request.future.done():
+                                request.future.set_exception(exc)
+                            continue
+                        self._pending[req_id] = request
+                        callback = self._partial_by_future.pop(
+                            request.future, None
+                        )
+                        if callback is not None:
+                            self._partial_cbs[req_id] = (
+                                callback, request.future,
+                            )
+                            self._partial_sent.pop(req_id, None)
             if sched.total_work:
                 # reclaim rows whose callers are gone (disconnects):
                 # per-token recycling frees their slot + pages THIS step
@@ -2587,34 +2609,44 @@ class ServingEngine:
                         self._partial_cbs.pop(req_id, None)
                         self._partial_sent.pop(req_id, None)
             if sched.total_work:
-                step_call = loop.run_in_executor(self._executor, sched.step)
-                if self._supervisor is not None:
-                    # same stall watchdog as the wave loop: one mixed
-                    # dispatch making no progress within the budget means
-                    # the device is wedged, not merely slow
-                    try:
-                        outcomes = await asyncio.wait_for(
-                            step_call, self._supervisor.stall_timeout_s
-                        )
-                    except asyncio.TimeoutError:
-                        self._stalled = True
-                        raise EngineStalled(
-                            f"mixed dispatch made no progress in "
-                            f"{self._supervisor.stall_timeout_s:.1f}s"
-                        ) from None
-                else:
-                    outcomes = await step_call
-                for outcome in outcomes:
-                    self._partial_cbs.pop(outcome.req_id, None)
-                    self._partial_sent.pop(outcome.req_id, None)
-                    request = self._pending.pop(outcome.req_id, None)
-                    if request is None or request.future.done():
-                        continue
-                    if outcome.error is not None:
-                        request.future.set_exception(outcome.error)
+                # the whole call as the event loop sees it: the worker
+                # thread's phase spans lie inside, so what this one alone
+                # covers is the hand-over to the worker and the hand-back
+                with annotate("podmortem.serve.step"):
+                    step_call = loop.run_in_executor(
+                        self._executor, sched.step
+                    )
+                    if self._supervisor is not None:
+                        # same stall watchdog as the wave loop: one mixed
+                        # dispatch making no progress within the budget
+                        # means the device is wedged, not merely slow
+                        try:
+                            outcomes = await asyncio.wait_for(
+                                step_call, self._supervisor.stall_timeout_s
+                            )
+                        except asyncio.TimeoutError:
+                            self._stalled = True
+                            raise EngineStalled(
+                                f"mixed dispatch made no progress in "
+                                f"{self._supervisor.stall_timeout_s:.1f}s"
+                            ) from None
                     else:
-                        request.future.set_result(outcome.result)
-            await asyncio.sleep(0)
+                        outcomes = await step_call
+                with annotate("podmortem.serve.outcomes", n=len(outcomes)):
+                    for outcome in outcomes:
+                        self._partial_cbs.pop(outcome.req_id, None)
+                        self._partial_sent.pop(outcome.req_id, None)
+                        request = self._pending.pop(outcome.req_id, None)
+                        if request is None or request.future.done():
+                            continue
+                        if outcome.error is not None:
+                            request.future.set_exception(outcome.error)
+                        else:
+                            request.future.set_result(outcome.result)
+            # the loop's turn: stream deliveries and whoever else is due
+            # run here, between two steps
+            with annotate("podmortem.serve.turn"):
+                await asyncio.sleep(0)
 
     async def _serve(self) -> None:
         if self._sched is not None:

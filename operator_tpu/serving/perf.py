@@ -71,13 +71,21 @@ def peak_tflops(device_kind: str, dtype: str) -> Optional[float]:
 class StepClock:
     """Per-step recorder both serving loops write through.
 
-    Owns the bounded :class:`StepRing`, stamps host-gap boundaries
-    (previous commit → next dispatch), attaches the model's analytic
-    flops/token so every record carries its achieved MFU, and feeds the
-    step histograms (``podmortem_step_duration_milliseconds`` /
-    ``podmortem_step_host_gap_milliseconds``).  All methods run on the
-    decode worker thread; reads (summary, ring) are lock-protected by
-    the ring itself."""
+    Owns the bounded :class:`StepRing` and the OPEN INTERVAL the next
+    record will close: its start (the previous commit's end, or the start
+    of the ``step()`` that found work after an idle spell — an idle
+    engine is not host time) and the host phases stamped into it so far.
+    The loop calls :meth:`enter` / :meth:`leave` around each ``step()``,
+    :meth:`add` for each timed phase, and :meth:`observe` once per
+    committed step, which closes the interval at the commit's end and
+    opens the next.  It attaches the model's analytic flops/token so
+    every record carries its achieved MFU, and feeds the step histograms
+    (``podmortem_step_duration_milliseconds`` from ``wall_ms``,
+    ``podmortem_step_host_gap_milliseconds`` from ``host_ms``).  All
+    methods run on the decode worker thread; reads (summary, ring) are
+    lock-protected by the ring itself."""
+
+    _PARTS = ("plan", "pack", "commit", "turn")
 
     def __init__(
         self,
@@ -93,16 +101,49 @@ class StepClock:
         self.peak_tflops = peak_tflops
         self.max_slots = max(1, int(max_slots))
         self.metrics = metrics
-        #: end of the previous step's commit (perf_counter); None right
-        #: after construction/reset — the first step has no host gap
-        self._last_commit: Optional[float] = None
+        #: the one clock every stamp is read from (seconds, monotonic);
+        #: an attribute so tests can inject a fake one
+        self.now = time.perf_counter
+        self._forget()
 
-    def host_gap_ms(self, dispatch_t: float) -> float:
-        """Host think-time between the previous commit and ``dispatch_t``
-        (0.0 for the first step after construction or reset)."""
-        if self._last_commit is None:
-            return 0.0
-        return max(0.0, (dispatch_t - self._last_commit) * 1e3)
+    # -- the open interval ------------------------------------------------
+    def enter(self) -> None:
+        """A ``step()`` begins.  After an idle spell the interval starts
+        here; while work was pending, the time since the last
+        :meth:`leave` was the event loop's turn (``turn_ms``)."""
+        now = self.now()
+        if self._t0 is None or not self._busy:
+            self._open(now)
+        elif self._left_t is not None:
+            self._parts["turn"] += (now - max(self._left_t, self._t0)) * 1e3
+        self._left_t = None
+
+    def leave(self, busy: bool) -> None:
+        """The ``step()`` returns; ``busy`` says whether it leaves work
+        behind (rows, queued requests or dispatches in flight)."""
+        self._left_t = self.now()
+        self._busy = bool(busy)
+
+    def add(self, part: str, ms: float) -> None:
+        """Stamp ``ms`` of a named host phase into the open interval."""
+        self._parts[part] += max(0.0, ms)
+
+    def elapsed_ms(self) -> float:
+        """Wall of the open interval so far (0.0 with none open)."""
+        return 0.0 if self._t0 is None else (self.now() - self._t0) * 1e3
+
+    def _open(self, t0: Optional[float]) -> None:
+        self._t0 = t0
+        self._parts = dict.fromkeys(self._PARTS, 0.0)
+
+    def _forget(self) -> None:
+        #: start of the open interval; None right after construction or
+        #: reset — the first record then starts at its own first stamp
+        self._open(None)
+        #: when the loop last left ``step()``, and whether work was still
+        #: pending then (if not, the time until the next call is idle)
+        self._left_t: Optional[float] = None
+        self._busy = False
 
     def observe(
         self,
@@ -110,48 +151,55 @@ class StepClock:
         kind: str,
         tokens: int,
         slots: int,
-        host_gap_ms: float,
-        device_ms: float,
-        sample_xfer_ms: float,
+        wait_ms: float,
+        xfer_ms: float = 0.0,
         commit_t: Optional[float] = None,
-        accepted: Optional[int] = None,
-        cached_tokens: Optional[int] = None,
+        **counts,
     ) -> StepRecord:
-        """Record one step and stamp its commit as the next step's
-        host-gap origin.  ``accepted`` is the step's COMMITTED generated
-        token count when it differs from the billed ``tokens``
-        (speculation verify rows, pipelined voided work); MFU stays
-        computed on billed tokens — the compute really ran.
-        ``cached_tokens`` is the prompt-token count rows admitted at this
-        step reused from the prefix cache — spared compute, so it never
-        enters ``tokens`` and MFU stays honest."""
-        total = max(0.0, host_gap_ms) + max(0.0, device_ms) + max(0.0, sample_xfer_ms)
+        """Close the open interval at ``commit_t`` (now when omitted) as
+        one step's record and open the next there.  With no interval
+        open (a step observed outside ``enter``/``leave`` on an idle
+        clock: the wave engine's admission prefill) the record stands
+        alone and its wall is the two waits.  ``counts`` are the record's
+        optional work counts (``accepted``, ``cached_tokens``,
+        ``prefill_tokens``, ``kv_pages_walked``).  MFU stays
+        computed on billed ``tokens`` — the compute really ran — over the
+        interval's wall."""
+        if commit_t is None:
+            commit_t = self.now()
+        idle = self._t0 is None or (self._left_t is not None and not self._busy)
+        if idle:
+            wall_ms = max(0.0, wait_ms) + max(0.0, xfer_ms)
+            self._open(commit_t)
+        else:
+            wall_ms = max(0.0, (commit_t - self._t0) * 1e3)
         mfu = None
         if (
             self.flops_per_token
             and self.peak_tflops
-            and total > 0
+            and wall_ms > 0
             and tokens
             and kind in ("decode", "mixed")
         ):
-            achieved = tokens * self.flops_per_token / (total / 1e3) / 1e12
+            achieved = tokens * self.flops_per_token / (wall_ms / 1e3) / 1e12
             mfu = achieved / self.peak_tflops
         record = self.ring.append(
             kind=kind,
             tokens=tokens,
             slots=slots,
             occupancy=min(1.0, slots / self.max_slots),
-            host_gap_ms=host_gap_ms,
-            device_ms=device_ms,
-            sample_xfer_ms=sample_xfer_ms,
+            wall_ms=wall_ms,
+            wait_ms=wait_ms,
+            xfer_ms=xfer_ms,
             mfu=mfu,
-            accepted=accepted,
-            cached_tokens=cached_tokens,
+            **{f"{part}_ms": ms for part, ms in self._parts.items()},
+            **counts,
         )
-        self._last_commit = commit_t if commit_t is not None else time.perf_counter()
+        self._open(commit_t)
+        self._busy = True  # a step just committed: the loop is not idle
         if self.metrics is not None:
-            self.metrics.observe("step_duration_milliseconds", total)
-            self.metrics.observe("step_host_gap_milliseconds", max(0.0, host_gap_ms))
+            self.metrics.observe("step_duration_milliseconds", record.wall_ms)
+            self.metrics.observe("step_host_gap_milliseconds", record.host_ms)
         return record
 
     @property
@@ -174,4 +222,4 @@ class StepClock:
         """Forget everything (device-state reset: the old timeline died
         with the old decode state; black-box dumps captured it first)."""
         self.ring.reset()
-        self._last_commit = None
+        self._forget()

@@ -101,7 +101,8 @@ def make_mixed_fn(generator: Any, t_budget: int, chunk: int,
         # from the carried per-slot latest-sample buffer instead of the
         # host-packed placeholder — the sampled id never visits the host
         eff_ids = jnp.where(from_prev, latest[rows], ids)
-        x = jnp.take(params["embed"], eff_ids, axis=0)[None]  # [1, T, H]
+        with jax.named_scope("embed"):
+            x = jnp.take(params["embed"], eff_ids, axis=0)[None]  # [1, T, H]
         positions = pos[None]  # [1, T]
         # flat -> per-row packing indices for the attention re-pack
         pack_idx = jnp.clip(
@@ -140,24 +141,27 @@ def make_mixed_fn(generator: Any, t_budget: int, chunk: int,
             # scatter this step's K/V into the pages FIRST — the ragged
             # kernel then reads a cache that already holds every token a
             # causal query may attend to (its own included)
-            k_pages = scanned["k"].at[page_ids, page_slots].set(
-                k[0].astype(scanned["k"].dtype)
-            )
-            v_pages = scanned["v"].at[page_ids, page_slots].set(
-                v[0].astype(scanned["v"].dtype)
-            )
-            q_pack = q[0][pack_idx]  # [B, chunk, QH, D]
-            attn_pack = ragged_paged_attention(
-                q_pack.astype(k_pages.dtype), k_pages, v_pages,
-                paged.page_table, kv_len, q_count,
-                sliding_window=config.sliding_window,
-            )
-            attn = attn_pack[rows, in_row]  # back to flat [T, QH, D]
+            with jax.named_scope("kv_write"):
+                k_pages = scanned["k"].at[page_ids, page_slots].set(
+                    k[0].astype(scanned["k"].dtype)
+                )
+                v_pages = scanned["v"].at[page_ids, page_slots].set(
+                    v[0].astype(scanned["v"].dtype)
+                )
+            with jax.named_scope("attn"):
+                q_pack = q[0][pack_idx]  # [B, chunk, QH, D]
+                attn_pack = ragged_paged_attention(
+                    q_pack.astype(k_pages.dtype), k_pages, v_pages,
+                    paged.page_table, kv_len, q_count,
+                    sliding_window=config.sliding_window,
+                )
+                attn = attn_pack[rows, in_row]  # back to flat [T, QH, D]
             x = x + proj(attn.astype(x.dtype).reshape(1, t_budget, -1), "wo")
-            mlp_in = rms_norm(x, weights["ln_mlp"], config.rms_norm_eps)
-            gate = jax.nn.silu(proj(mlp_in, "w_gate"))
-            up = proj(mlp_in, "w_up")
-            x = x + proj(gate * up, "w_down")
+            with jax.named_scope("mlp"):
+                mlp_in = rms_norm(x, weights["ln_mlp"], config.rms_norm_eps)
+                gate = jax.nn.silu(proj(mlp_in, "w_gate"))
+                up = proj(mlp_in, "w_up")
+                x = x + proj(gate * up, "w_down")
             return x, {"k": k_pages, "v": v_pages}
 
         scanned_in = {
@@ -175,17 +179,21 @@ def make_mixed_fn(generator: Any, t_budget: int, chunk: int,
             sample_start[:, None] + jnp.arange(width, dtype=jnp.int32)[None],
             0, t_budget - 1,
         )  # [B, W]
-        x_samp = x[0][samp_idx]  # [B, W, H]
-        head = (
-            params["embed"].T if config.tie_embeddings else params["lm_head"]
-        )
-        logits = jnp.einsum(
-            "bwh,hv->bwv", x_samp, head, preferred_element_type=jnp.float32
-        )
-        flat_toks, rng = generator._sample(
-            logits.reshape(b_slots * width, -1), rng,
-            jnp.repeat(temp, width), jnp.repeat(top_p, width),
-        )
+        with jax.named_scope("head"):
+            x_samp = x[0][samp_idx]  # [B, W, H]
+            head = (
+                params["embed"].T if config.tie_embeddings
+                else params["lm_head"]
+            )
+            logits = jnp.einsum(
+                "bwh,hv->bwv", x_samp, head,
+                preferred_element_type=jnp.float32,
+            )
+        with jax.named_scope("sample"):
+            flat_toks, rng = generator._sample(
+                logits.reshape(b_slots * width, -1), rng,
+                jnp.repeat(temp, width), jnp.repeat(top_p, width),
+            )
         toks = flat_toks.reshape(b_slots, width)
         if width > 1:
             # longest matching draft prefix: draft j (flat position

@@ -105,6 +105,28 @@ log = logging.getLogger(__name__)
 __all__ = ["Scheduler"]
 
 
+def _kv_walk(
+    kv_len: np.ndarray, q_count: np.ndarray, page_size: int,
+    window: Optional[int] = None,
+) -> tuple[int, int]:
+    """What ONE layer's ragged-attention call does with these per-slot
+    arrays, by the kernel's own rule (``ops/ragged_attention.py``
+    ``_ragged_attn_kernel``): a slot with ``q_count > 0`` walks pages
+    ``first .. cdiv(kv_len, page_size)``, the others none, and a sliding
+    window skips the pages wholly before the earliest position any of
+    the row's queries can see.  Returns (KV pages walked, query-key
+    pairs scored = ``q_count`` x the positions on the walked pages up to
+    ``kv_len``)."""
+    kv = kv_len.astype(np.int64)
+    count = q_count.astype(np.int64)
+    first = np.zeros_like(kv)
+    if window is not None:
+        first = np.maximum(kv - count - window + 1, 0) // page_size
+    pages = np.where(count > 0, np.maximum(-(-kv // page_size) - first, 0), 0)
+    seen = np.maximum(kv - first * page_size, 0)
+    return int(pages.sum()), int((count * seen).sum())
+
+
 @dataclasses.dataclass
 class _InFlight:
     """One dispatched-but-uncommitted step: the plan and the device-side
@@ -114,9 +136,41 @@ class _InFlight:
     plan: StepPlan
     toks: Any  # device [B, W] sampled token ids
     accept: Any  # device [B] accepted-draft counts
-    dispatch_t: float
-    started: float = 0.0
+    dispatch_t: float  # step-clock stamp: the jitted call returned
+    counts: dict  # the step record's work counts (_Packed.counts)
+    #: the ``seq`` this step's record will get (commits are in order and
+    #: each appends one record) — what its host spans carry as ``step``
+    seq: int = 0
     held_rows: int = 0
+
+
+@dataclasses.dataclass
+class _Packed:
+    """A plan packed onto the program's host-side input arrays, with the
+    work counts the step record and the dispatch span carry — counted
+    HERE, from the very ``kv_len`` / ``q_count`` the kernel is given."""
+
+    # the flat token axis, [t_budget] each
+    ids: np.ndarray
+    rows: np.ndarray
+    pos: np.ndarray
+    valid: np.ndarray
+    in_row: np.ndarray
+    from_prev: np.ndarray
+    # per slot, [slots] each
+    q_start: np.ndarray
+    q_count: np.ndarray
+    sample_start: np.ndarray
+    spec_len: np.ndarray
+    temp: np.ndarray
+    top_p: np.ndarray
+    kv_len: np.ndarray
+    #: the step record's work counts (``StepRecord`` field names)
+    counts: dict
+    #: query-key pairs the attention scores: the sum over the slots it
+    #: walks of ``q_count x (kv positions walked)`` — on the dispatch
+    #: span, for the roofline's operation count
+    qk_pairs: int
 
 
 class Scheduler:
@@ -486,7 +540,8 @@ class Scheduler:
         """Compile the one mixed program before serving (an empty wave
         drives the full trace: the program's shapes are workload-
         independent by construction)."""
-        entry = self._dispatch(StepPlan())
+        plan = StepPlan()
+        entry = self._dispatch(plan, self._pack(plan))
         np.asarray(entry.toks)  # block: precompile must finish warm
 
     # ------------------------------------------------------------------
@@ -502,9 +557,19 @@ class Scheduler:
         ``depth == 1`` degenerates to the original synchronous loop —
         the dispatch just issued commits before the call returns.  At
         ``depth >= 2`` the dispatch for step N+1 is issued BEFORE step
-        N's commit, so the host gap between commit N-1 and dispatch N+1
-        collapses to ~0: the chip always has a queued wave."""
+        N's commit, so the chip always has a queued wave and the host's
+        work hides under the device's (the step record's ``wait_ms`` is
+        what is left of that slack)."""
+        clock = self.generator.step_clock
+        clock.enter()
+        try:
+            return self._step()
+        finally:
+            clock.leave(busy=bool(self._inflight) or self.total_work > 0)
+
+    def _step(self) -> list[StepOutcome]:
         g = self.generator
+        clock = g.step_clock
         if g.fault_plan is not None:
             # chaos seam, same site as the wave engine's step so stall /
             # device-error scenarios drive both loops identically
@@ -515,18 +580,31 @@ class Scheduler:
             # terminal ShedLowValue outcomes here
             outcomes.extend(self._evicted)
             self._evicted.clear()
-        plan = self._schedule(outcomes)
+        # the record this plan's step will write: commits land in order,
+        # one record each
+        seq = clock.ring.next_seq + len(self._inflight)
+        t0 = clock.now()
+        with g._annotation("podmortem.sched.plan", step=seq):
+            plan = self._schedule(outcomes)
+        t1 = clock.now()
+        clock.add("plan", (t1 - t0) * 1e3)
         held_rows = len(self._rows)  # snapshot BEFORE commit recycles
         if self.plan_log is not None:
             self.plan_log.append(plan.trace())
         if plan.work:
-            started = time.perf_counter()
+            with g._annotation("podmortem.sched.pack", step=seq):
+                packed = self._pack(plan)
             with g._annotation(
-                "podmortem.sched_step",
+                "podmortem.sched.dispatch",
                 [row.params for row in self._rows.values()],
+                step=seq,
+                kv_pages=packed.counts["kv_pages_walked"],
+                qk_pairs=packed.qk_pairs,
+                tokens=plan.tokens_planned,
             ):
-                entry = self._dispatch(plan)
-            entry.started = started
+                entry = self._dispatch(plan, packed)
+            clock.add("pack", (entry.dispatch_t - t1) * 1e3)
+            entry.seq = seq
             entry.held_rows = held_rows
             if self._inflight:
                 self.metrics.incr("sched_pipeline_dispatch_ahead")
@@ -1083,15 +1161,10 @@ class Scheduler:
             )
         return self._fn
 
-    def _dispatch(self, plan: StepPlan) -> _InFlight:
-        """Pack the plan onto the flat token axis and ISSUE the one mixed
-        program; commits the returned cache/rng/latest handles and
-        returns the in-flight entry WITHOUT syncing — the sampled tokens
-        stay on device until ``_commit_oldest`` fetches them (the
-        pipelining point: at depth >= 2 the next plan is dispatched
-        before this fetch happens)."""
+    def _pack(self, plan: StepPlan) -> _Packed:
+        """Pack the plan onto the flat token axis (host arrays only) and
+        count the step's work from the packed arrays."""
         g = self.generator
-        jnp = g._jnp
         t, b = self.t_budget, g.max_slots
         ids = np.zeros((t,), np.int32)
         rows = np.zeros((t,), np.int32)
@@ -1106,6 +1179,7 @@ class Scheduler:
         temp = np.zeros((b,), np.float32)
         top_p = np.ones((b,), np.float32)
         kv_len = self._kv_shadow.copy()
+        prefill_tokens = 0
         for work in plan.work:
             row = self._rows[work.req_id]
             span = slice(work.start, work.start + work.count)
@@ -1128,6 +1202,7 @@ class Scheduler:
                 pos[span] = np.arange(
                     work.pos0, work.pos0 + work.count, dtype=np.int32
                 )
+                prefill_tokens += work.count
             rows[span] = work.slot
             valid[span] = True
             in_row[span] = np.arange(work.count, dtype=np.int32)
@@ -1144,6 +1219,27 @@ class Scheduler:
             kv_len[work.slot] = work.pos0 + work.count
             temp[work.slot] = row.params.temperature
             top_p[work.slot] = row.params.top_p
+        pages, pairs = _kv_walk(
+            kv_len, q_count, g.page_size, g.config.sliding_window
+        )
+        return _Packed(
+            ids=ids, rows=rows, pos=pos, valid=valid, in_row=in_row,
+            from_prev=from_prev, q_start=q_start, q_count=q_count,
+            sample_start=sample_start, spec_len=spec_len, temp=temp,
+            top_p=top_p, kv_len=kv_len,
+            counts={"prefill_tokens": prefill_tokens, "kv_pages_walked": pages},
+            qk_pairs=pairs,
+        )
+
+    def _dispatch(self, plan: StepPlan, packed: _Packed) -> _InFlight:
+        """ISSUE the one mixed program on the packed plan; commits the
+        returned cache/rng/latest handles and returns the in-flight
+        entry WITHOUT syncing — the sampled tokens stay on device until
+        ``_commit_oldest`` fetches them (the pipelining point: at depth
+        >= 2 the next plan is dispatched before this fetch happens)."""
+        g = self.generator
+        jnp = g._jnp
+        p = packed
         paged = g.paged_cache
         if self._staged_tables:
             from ...ops.paged_attention import PagedKVCache
@@ -1161,17 +1257,18 @@ class Scheduler:
             )
             self._staged_tables.clear()
         if self._latest is None:
-            self._latest = jnp.zeros((b,), jnp.int32)
-        dispatch_t = time.perf_counter()
+            self._latest = jnp.zeros((g.max_slots,), jnp.int32)
         new_paged, toks, accept, latest, rng = self._get_fn()(
             g.params, paged,
-            jnp.asarray(ids), jnp.asarray(rows), jnp.asarray(pos),
-            jnp.asarray(valid), jnp.asarray(in_row),
-            jnp.asarray(q_start), jnp.asarray(q_count), jnp.asarray(kv_len),
-            self._latest, jnp.asarray(from_prev),
-            jnp.asarray(sample_start), jnp.asarray(spec_len),
-            g._rng, jnp.asarray(temp), jnp.asarray(top_p),
+            jnp.asarray(p.ids), jnp.asarray(p.rows), jnp.asarray(p.pos),
+            jnp.asarray(p.valid), jnp.asarray(p.in_row),
+            jnp.asarray(p.q_start), jnp.asarray(p.q_count),
+            jnp.asarray(p.kv_len),
+            self._latest, jnp.asarray(p.from_prev),
+            jnp.asarray(p.sample_start), jnp.asarray(p.spec_len),
+            g._rng, jnp.asarray(p.temp), jnp.asarray(p.top_p),
         )
+        dispatch_t = g.step_clock.now()
         g.paged_cache = new_paged
         g._rng = rng
         self._latest = latest
@@ -1179,7 +1276,7 @@ class Scheduler:
         # the next plan's packing is consistent with pred_kv; a verify
         # commit re-anchors the slot from the row's authoritative state
         # when drafts were rejected
-        self._kv_shadow = kv_len
+        self._kv_shadow = p.kv_len
         # NO block/fetch here: the commit side owns the step's one host
         # sync (GL001: host loop code, not jit-reachable).  Record the
         # in-flight deltas planning reads as pred_* until commit.
@@ -1196,6 +1293,7 @@ class Scheduler:
                 row.pend_pos += work.count
         return _InFlight(
             plan=plan, toks=toks, accept=accept, dispatch_t=dispatch_t,
+            counts=packed.counts,
         )
 
     # -- commit --------------------------------------------------------
@@ -1232,7 +1330,9 @@ class Scheduler:
             reason = "degraded"
         # decode wall from the step clock's monotonic cumulative, not a
         # wall-clock delta: the SAME records /metrics and black-box dumps
-        # carry, so the span and the step timeline cannot disagree
+        # carry, so the span and the step timeline cannot disagree.  Read
+        # before this commit's record lands, as decode_cum0 was
+        # (_commit_oldest): the two offsets cancel
         decode_ms = 0.0
         if row.started:
             decode_ms = max(
@@ -1253,73 +1353,84 @@ class Scheduler:
 
     def _commit_oldest(self, outcomes: list[StepOutcome]) -> None:
         """Fetch + commit the oldest in-flight dispatch: the step's ONE
-        host sync.  Step-clock record lands BEFORE the row commits — a
-        prompt completing this step then stamps decode_cum0 with this
-        step already counted, so its decode window is exactly the steps
-        it decoded in."""
+        host sync.  The step-clock record lands at the END of the commit
+        and closes the interval that began at the previous commit's end,
+        so records tile the wall.  Rows therefore stamp ``decode_cum0``
+        (and ``_finish`` reads ``decode_cum_ms``) one record early on
+        both sides: a request's decode window is the intervals from its
+        first token's commit up to its last's, equal to last-token time
+        less first-token time to within the difference of two steps."""
         g = self.generator
+        clock = g.step_clock
         entry = self._inflight.popleft()
         plan = entry.plan
         # the sync was always here (np.asarray); block_until_ready in
-        # front only SPLITS it into device compute vs token-id transfer
+        # front only SPLITS it into device wait vs token-id transfer
         # — no new sync point (GL001: host loop code, not jit-reachable)
-        try:
-            entry.toks.block_until_ready()
-        except AttributeError:
-            pass  # already a host array (fake-jax tests)
-        t_ready = time.perf_counter()
-        toks = np.asarray(entry.toks)
-        accept = np.asarray(entry.accept)
-        if self._pending_offload:
-            # the step just paid its host sync: piggyback the offload
-            # fetches on it (device→host page copies overlap the token
-            # readback window instead of opening a new sync point)
-            self._drain_offload()
-        if self._pending_mirror:
-            self._drain_mirror()
-        fetch_t = time.perf_counter()
-        self._host_syncs += 1
-        device_ms = max(0.0, (t_ready - entry.dispatch_t) * 1e3)
-        xfer_ms = max(0.0, (fetch_t - t_ready) * 1e3)
-        if plan.decode_rows and plan.prefill_rows:
-            kind = "mixed"
-        elif plan.decode_rows:
-            kind = "decode"
-        else:
-            kind = "prefill"
-        # prospective accepted-token count so MFU attribution stays
-        # honest under speculation: a verify row lands accept+1 tokens,
-        # not the q_count it was billed for (voided rows land zero)
-        accepted = 0
-        for work in plan.work:
-            if work.req_id not in self._rows:
-                continue
-            if work.kind == "verify":
-                accepted += int(accept[work.slot]) + 1
-            elif work.kind in ("decode", "finish"):
-                accepted += 1
-        g.step_clock.observe(
+        t_wait = clock.now()
+        with g._annotation("podmortem.sched.wait", step=entry.seq):
+            try:
+                entry.toks.block_until_ready()
+            except AttributeError:
+                pass  # already a host array (fake-jax tests)
+        t_ready = clock.now()
+        with g._annotation("podmortem.sched.commit", step=entry.seq):
+            toks = np.asarray(entry.toks)
+            accept = np.asarray(entry.accept)
+            fetch_t = clock.now()
+            if self._pending_offload:
+                # the step just paid its host sync: piggyback the offload
+                # fetches on it (device→host page copies overlap the token
+                # readback window instead of opening a new sync point)
+                self._drain_offload()
+            if self._pending_mirror:
+                self._drain_mirror()
+            self._host_syncs += 1
+            if plan.decode_rows and plan.prefill_rows:
+                kind = "mixed"
+            elif plan.decode_rows:
+                kind = "decode"
+            else:
+                kind = "prefill"
+            # prospective accepted-token count so MFU attribution stays
+            # honest under speculation: a verify row lands accept+1 tokens,
+            # not the q_count it was billed for (voided rows land zero)
+            accepted = 0
+            for work in plan.work:
+                if work.req_id not in self._rows:
+                    continue
+                if work.kind == "verify":
+                    accepted += int(accept[work.slot]) + 1
+                elif work.kind in ("decode", "finish"):
+                    accepted += 1
+            # rows are charged the interval so far (their own commit, a
+            # per cent of a step, is still to come)
+            elapsed_ms = clock.elapsed_ms()
+            outcomes.extend(self._commit(plan, toks, accept, elapsed_ms))
+        commit_t = clock.now()
+        clock.add("commit", (commit_t - fetch_t) * 1e3)
+        record = clock.observe(
             kind=kind,
             tokens=plan.tokens_planned,
             slots=entry.held_rows,
-            host_gap_ms=g.step_clock.host_gap_ms(entry.dispatch_t),
-            device_ms=device_ms,
-            sample_xfer_ms=xfer_ms,
-            commit_t=fetch_t,
+            wait_ms=(t_ready - t_wait) * 1e3,
+            xfer_ms=(fetch_t - t_ready) * 1e3,
+            commit_t=commit_t,
             accepted=accepted,
             cached_tokens=(
                 plan.cached_tokens if self._kvstore is not None else None
             ),
+            **entry.counts,
         )
-        elapsed_ms = (fetch_t - entry.started) * 1e3
-        outcomes.extend(self._commit(plan, toks, accept, elapsed_ms))
         if plan.decode_rows and not plan.prefill_rows:
             # wall time per pure-decode round only: the admission
             # roofline reads p50(decode_step) as seconds-per-token
             # (decode_token_estimate_s), and a mixed step's wall includes
             # up to `chunk` prefill tokens' compute — folding that in
-            # would make deadline clamping over-truncate every admission
-            self.metrics.record("decode_step", elapsed_ms)
+            # would make deadline clamping over-truncate every admission.
+            # The record's wall: dispatch -> fetch spans two device steps
+            # at depth 2
+            self.metrics.record("decode_step", record.wall_ms)
 
     def _push_token(self, row: _Row, token: int) -> Optional[str]:
         """Append one committed token; returns the finish reason when
@@ -1342,7 +1453,7 @@ class Scheduler:
     ) -> list[StepOutcome]:
         outcomes: list[StepOutcome] = []
         g = self.generator
-        # the step's compute is attributed to its rows by token share —
+        # the step's wall is attributed to its rows by token share —
         # good enough for the prefill/decode split the spans surface
         share = elapsed_ms / max(1, plan.tokens_planned)
         for work in plan.work:

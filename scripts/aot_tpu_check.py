@@ -34,6 +34,7 @@ from __future__ import annotations
 import functools
 import json
 import os
+import re
 import sys
 from unittest import mock
 
@@ -302,7 +303,15 @@ def main() -> int:
         for name, fn, args in cases:
             try:
                 compiled = jax.jit(fn).lower(*args).compile()
-                results[name] = {"ok": True, **_memory(compiled)}
+                results[name] = {
+                    "ok": True, **_memory(compiled),
+                    # the instruction names a profiler trace of the chip
+                    # prints for the program's Pallas kernels
+                    "pallas_calls": sorted(set(re.findall(
+                        r"%(\S+) = [^\n]*custom_call_target=\"tpu_custom_call\"",
+                        compiled.as_text(),
+                    ))),
+                }
                 print(f"OK   {name}", file=sys.stderr)
             except Exception as exc:  # noqa: BLE001 - record and continue
                 failed += 1

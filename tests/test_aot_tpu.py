@@ -24,7 +24,10 @@ from operator_tpu.utils.config import OperatorConfig
 REPO = pathlib.Path(__file__).resolve().parent.parent
 
 
-def test_all_kernels_aot_compile_for_v5e():
+@pytest.fixture(scope="module")
+def record():
+    """One cross-compile of everything, in a child (the TPU compiler's
+    library belongs to one process at a time)."""
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
     out = subprocess.run(
@@ -34,7 +37,30 @@ def test_all_kernels_aot_compile_for_v5e():
     if out.returncode == 42:
         pytest.skip("this jax install has no TPU compiler")
     assert out.returncode == 0, out.stdout + out.stderr
-    record = json.loads(out.stdout.strip().splitlines()[-1])
+    return json.loads(out.stdout.strip().splitlines()[-1])
+
+
+def test_the_ragged_kernel_keeps_its_name_in_the_compiled_step(record):
+    """A chip trace names a device event after its HLO instruction; the
+    benchmark's kernel metrics find the ragged kernel by that name."""
+    import re
+
+    from operator_tpu.ops.ragged_attention import KERNEL_NAME
+
+    sys.path.insert(0, str(REPO))
+    from benchmark.layer_metrics import attn_kernel_share
+
+    calls = record["kernels"]["mixed_step_default_model"]["pallas_calls"]
+    ragged = [
+        call for call in calls
+        if re.fullmatch(re.escape(KERNEL_NAME) + r"(\.\d+)?", call)
+    ]
+    assert ragged, calls
+    assert "ragged_attention" in KERNEL_NAME
+    assert all(attn_kernel_share.PATTERN.search(call) for call in ragged)
+
+
+def test_all_kernels_aot_compile_for_v5e(record):
     assert record["failed"] == 0, record
     kernels = record["kernels"]
     assert all(k["ok"] for k in kernels.values()), record

@@ -73,9 +73,11 @@ def run(coro):
 
 
 def _decode_record(seq_tokens=4, gap=1.0, dev=2.0, xfer=1.0, kind="decode"):
+    """A record whose interval is ``gap`` of host, ``dev`` of waiting on
+    the device and ``xfer`` of token fetch."""
     return StepRecord(
         seq=0, kind=kind, tokens=seq_tokens, slots=2, occupancy=0.5,
-        host_gap_ms=gap, device_ms=dev, sample_xfer_ms=xfer,
+        wall_ms=gap + dev + xfer, host_ms=gap, wait_ms=dev, xfer_ms=xfer,
     )
 
 
@@ -89,7 +91,7 @@ class TestStepRing:
         ring = StepRing(capacity=4)
         for i in range(10):
             ring.append(kind="decode", tokens=i, slots=1, occupancy=0.25,
-                        host_gap_ms=1.0, device_ms=1.0, sample_xfer_ms=1.0)
+                        wall_ms=3.0, wait_ms=1.0, xfer_ms=1.0)
         assert len(ring) == 4
         assert ring.evicted == 6
         records = ring.records()
@@ -104,11 +106,11 @@ class TestStepRing:
         ring = StepRing(capacity=2)
         for _ in range(5):
             ring.append(kind="decode", tokens=2, slots=1, occupancy=0.25,
-                        host_gap_ms=1.0, device_ms=2.0, sample_xfer_ms=1.0)
+                        wall_ms=4.0, wait_ms=2.0, xfer_ms=1.0)
         ring.append(kind="mixed", tokens=3, slots=2, occupancy=0.5,
-                    host_gap_ms=0.0, device_ms=4.0, sample_xfer_ms=0.0)
+                    wall_ms=4.0, wait_ms=4.0, xfer_ms=0.0)
         ring.append(kind="prefill", tokens=8, slots=1, occupancy=0.25,
-                    host_gap_ms=0.0, device_ms=8.0, sample_xfer_ms=0.0)
+                    wall_ms=8.0, wait_ms=8.0, xfer_ms=0.0)
         # 5 decode steps x 4ms + 1 mixed x 4ms, prefill excluded
         assert ring.decode_cum_ms == pytest.approx(24.0)
         assert ring.cum_tokens["decode"] == 10
@@ -120,13 +122,13 @@ class TestStepRing:
         ring = StepRing(capacity=3)
         for _ in range(5):
             ring.append(kind="decode", tokens=1, slots=1, occupancy=0.25,
-                        host_gap_ms=1.0, device_ms=1.0, sample_xfer_ms=1.0)
+                        wall_ms=3.0, wait_ms=1.0, xfer_ms=1.0)
         ring.reset()
         assert len(ring) == 0
         assert ring.evicted == 0
         assert ring.decode_cum_ms == 0.0
         record = ring.append(kind="decode", tokens=1, slots=1, occupancy=0.25,
-                             host_gap_ms=0.0, device_ms=1.0, sample_xfer_ms=0.0)
+                             wall_ms=1.0, wait_ms=1.0, xfer_ms=0.0)
         assert record.seq == 0  # seq restarts with the new timeline
 
     def test_capacity_from_env(self, monkeypatch):
@@ -142,17 +144,34 @@ class TestStepRing:
         with pytest.raises(ValueError, match="unknown step kind"):
             StepRing(capacity=2).append(
                 kind="warmup", tokens=1, slots=1, occupancy=0.25,
-                host_gap_ms=0.0, device_ms=1.0, sample_xfer_ms=0.0,
+                wall_ms=1.0, wait_ms=1.0, xfer_ms=0.0,
             )
 
     def test_record_dict_roundtrip(self):
         record = StepRecord(
             seq=3, kind="mixed", tokens=5, slots=2, occupancy=0.5,
-            host_gap_ms=1.25, device_ms=2.5, sample_xfer_ms=0.25, mfu=0.125,
+            wall_ms=4.0, host_ms=1.25, wait_ms=2.5, xfer_ms=0.25, mfu=0.125,
         )
         parsed = StepRecord.from_dict(record.to_dict())
         assert parsed == record
         assert StepRecord.from_dict({}).kind == "decode"  # tolerant default
+
+    def test_record_dict_roundtrip_carries_every_new_field(self):
+        record = StepRecord(
+            seq=9, kind="mixed", tokens=70, slots=3, occupancy=0.75,
+            wall_ms=12.5, host_ms=2.0, wait_ms=10.0, xfer_ms=0.5,
+            plan_ms=0.5, pack_ms=0.75, commit_ms=0.25, turn_ms=0.125,
+            prefill_tokens=64, kv_pages_walked=11,
+            accepted=3, cached_tokens=16,
+        )
+        raw = record.to_dict()
+        assert json.loads(json.dumps(raw)) == raw  # plain JSON
+        assert StepRecord.from_dict(raw) == record
+        assert record.total_ms == record.wall_ms == 12.5
+        # a record of an engine that does not count leaves the keys out
+        bare = _decode_record().to_dict()
+        assert "kv_pages_walked" not in bare and "prefill_tokens" not in bare
+        assert StepRecord.from_dict(bare).kv_pages_walked is None
 
 
 # ---------------------------------------------------------------------------
@@ -178,7 +197,7 @@ class TestAttribution:
     def test_empty_window_degrades_to_none(self):
         out = attribution([])
         assert out["steps"] == 0
-        assert out["fractions"]["host_gap"] is None
+        assert out["fractions"]["host"] is None
         assert out["decode_mfu"] is None
         assert out["occupancy_avg"] is None
 
@@ -226,42 +245,122 @@ class TestFlopsModel:
         assert peak_tflops("cpu", "bf16") is None
 
 
+class _FakeClock:
+    """Seconds that move only when told to (``StepClock.now``)."""
+
+    def __init__(self, t=100.0):
+        self.t = t
+
+    def __call__(self):
+        return self.t
+
+    def advance(self, ms):
+        self.t += ms / 1e3
+
+
+def _fake_clock(**kw):
+    clock = StepClock(capacity=8, max_slots=kw.pop("max_slots", 1), **kw)
+    clock.now = _FakeClock()
+    return clock
+
+
+def _one_step(clock, *, host=1.0, wait=2.0, xfer=1.0, kind="decode",
+              tokens=1, slots=1, busy=True, **counts):
+    """One ``step()`` as the loops drive the clock: ``host`` ms of
+    planning, then ``wait`` and ``xfer``, commit, leave."""
+    clock.enter()
+    clock.now.advance(host)
+    clock.add("plan", host)
+    clock.now.advance(wait + xfer)
+    record = clock.observe(kind=kind, tokens=tokens, slots=slots,
+                           wait_ms=wait, xfer_ms=xfer, **counts)
+    clock.leave(busy=busy)
+    return record
+
+
 class TestStepClock:
     def test_mfu_on_decode_records_only(self):
-        clock = StepClock(capacity=8, flops_per_token=1000.0,
-                          peak_tflops=1.0, max_slots=4)
-        prefill = clock.observe(kind="prefill", tokens=16, slots=1,
-                                host_gap_ms=0.0, device_ms=10.0,
-                                sample_xfer_ms=0.0)
+        clock = _fake_clock(flops_per_token=1000.0, peak_tflops=1.0,
+                            max_slots=4)
+        prefill = _one_step(clock, host=0.0, wait=10.0, xfer=0.0,
+                            kind="prefill", tokens=16)
         assert prefill.mfu is None
-        decode = clock.observe(kind="decode", tokens=4, slots=2,
-                               host_gap_ms=1.0, device_ms=2.0,
-                               sample_xfer_ms=1.0)
+        decode = _one_step(clock, host=1.0, wait=2.0, xfer=1.0, tokens=4,
+                           slots=2)
+        assert decode.wall_ms == pytest.approx(4.0)
         assert decode.mfu == pytest.approx(1e-6)
         assert decode.occupancy == pytest.approx(0.5)
         summary = clock.summary()
         assert summary["decode_mfu"] == pytest.approx(1e-6)
 
-    def test_host_gap_measured_from_previous_commit(self):
-        clock = StepClock(capacity=8, max_slots=1)
-        assert clock.host_gap_ms(123.0) == 0.0  # first step: no gap yet
-        clock.observe(kind="decode", tokens=1, slots=1, host_gap_ms=0.0,
-                      device_ms=1.0, sample_xfer_ms=0.0, commit_t=10.0)
-        assert clock.host_gap_ms(10.005) == pytest.approx(5.0)
+    def test_interval_runs_from_the_previous_commit(self):
+        clock = _fake_clock()
+        first = _one_step(clock, host=1.0, wait=2.0, xfer=0.5)
+        # the first interval starts at its own step()'s start
+        assert first.wall_ms == pytest.approx(3.5)
+        assert (first.host_ms, first.plan_ms, first.turn_ms) == (
+            pytest.approx(1.0), pytest.approx(1.0), 0.0,
+        )
+        clock.now.advance(5.0)  # the event loop's turn, work pending
+        second = _one_step(clock, host=1.0, wait=2.0, xfer=0.5)
+        assert second.turn_ms == pytest.approx(5.0)
+        assert second.host_ms == pytest.approx(6.0)  # turn + plan
+        assert second.wall_ms == pytest.approx(8.5)
         clock.reset()
-        assert clock.host_gap_ms(10.010) == 0.0  # reset forgets the commit
+        third = _one_step(clock, host=1.0, wait=1.0, xfer=0.0)
+        assert third.wall_ms == pytest.approx(2.0)  # reset forgets the commit
+        assert third.seq == 0
+
+    def test_an_idle_engine_adds_nothing_to_host_ms(self):
+        clock = _fake_clock()
+        _one_step(clock, busy=False)  # the last request finished here
+        clock.now.advance(60_000.0)  # a minute with nothing to do
+        record = _one_step(clock, host=1.0, wait=2.0, xfer=1.0)
+        assert record.wall_ms == pytest.approx(4.0)
+        assert record.host_ms == pytest.approx(1.0)
+        assert record.turn_ms == 0.0
+        assert clock.ring.cum_ms["decode"] == pytest.approx(8.0)
+
+    @pytest.mark.parametrize("wait,xfer", [(2.0, 1.0), (0.0, 0.0), (50.0, 9.0)])
+    def test_host_wait_and_xfer_sum_to_the_wall(self, wait, xfer):
+        clock = _fake_clock()
+        for _ in range(3):
+            clock.now.advance(0.75)
+            record = _one_step(clock, host=1.25, wait=wait, xfer=xfer)
+            assert record.host_ms + record.wait_ms + record.xfer_ms == (
+                pytest.approx(record.wall_ms)
+            )
+            assert record.host_ms >= 0 and record.wait_ms >= 0
+        # ... and the ring never lets the two waits exceed the wall
+        odd = clock.ring.append(kind="decode", tokens=1, slots=1,
+                                occupancy=1.0, wall_ms=1.0, wait_ms=5.0,
+                                xfer_ms=5.0)
+        assert (odd.host_ms, odd.wait_ms, odd.xfer_ms) == (0.0, 1.0, 0.0)
+
+    def test_a_step_observed_on_an_idle_clock_stands_alone(self):
+        """The wave engine's admission prefill runs between ``step()``
+        calls: on an idle clock its record's wall is its own wait."""
+        clock = _fake_clock()
+        _one_step(clock, busy=False)
+        clock.now.advance(1000.0)
+        alone = clock.observe(kind="prefill", tokens=8, slots=1, wait_ms=7.0)
+        assert alone.wall_ms == alone.wait_ms == pytest.approx(7.0)
+        clock.now.advance(2.0)
+        record = _one_step(clock, host=1.0, wait=1.0, xfer=0.0)
+        assert record.turn_ms == pytest.approx(2.0)
+        assert record.wall_ms == pytest.approx(4.0)
 
     def test_feeds_step_histograms(self):
         metrics = MetricsRegistry()
-        clock = StepClock(capacity=8, max_slots=1, metrics=metrics)
+        clock = _fake_clock(metrics=metrics)
         for _ in range(3):
-            clock.observe(kind="decode", tokens=1, slots=1, host_gap_ms=2.0,
-                          device_ms=3.0, sample_xfer_ms=1.0)
+            _one_step(clock, host=2.0, wait=3.0, xfer=1.0)
         duration = metrics.histogram("step_duration_milliseconds")
         gap = metrics.histogram("step_host_gap_milliseconds")
         assert duration is not None and duration.count == 3
-        assert duration.sum == pytest.approx(18.0)
+        assert duration.sum == pytest.approx(18.0)  # the walls
         assert gap is not None and gap.count == 3
+        assert gap.sum == pytest.approx(6.0)  # the host's part of them
 
 
 # ---------------------------------------------------------------------------
@@ -312,14 +411,17 @@ class TestStepView:
         table = render_steps([
             _decode_record(),
             StepRecord(seq=1, kind="prefill", tokens=16, slots=1,
-                       occupancy=0.25, host_gap_ms=0.0, device_ms=9.0,
-                       sample_xfer_ms=0.0),
+                       occupancy=0.25, wall_ms=9.0, host_ms=0.0, wait_ms=9.0,
+                       xfer_ms=0.0, kv_pages_walked=7, prefill_tokens=16),
         ])
         lines = table.splitlines()
         assert lines[0].split() == [
-            "seq", "kind", "tok", "slots", "occ",
-            "gap_ms", "dev_ms", "xfer_ms", "total", "mfu",
+            "seq", "kind", "tok", "pf_tok", "slots", "occ",
+            "wall_ms", "host_ms", "wait_ms", "xfer_ms",
+            "plan", "pack", "commit", "turn", "kv_pg", "mfu",
         ]
+        assert lines[3].split()[-2] == "7" and lines[2].split()[-2] == "-"
+        assert lines[3].split()[3] == "16" and lines[2].split()[3] == "-"
         assert len(lines) == 4  # header + rule + 2 rows
         assert "prefill" in lines[3]
 
@@ -331,8 +433,8 @@ class TestStepView:
         blackbox = {"recordedAt": 1.0, "reason": "stall",
                     "extra": {"steps": [
                         StepRecord(seq=1, kind="mixed", tokens=2, slots=2,
-                                   occupancy=0.5, host_gap_ms=1.0,
-                                   device_ms=1.0, sample_xfer_ms=0.0).to_dict()
+                                   occupancy=0.5, wall_ms=2.0, host_ms=1.0,
+                                   wait_ms=1.0, xfer_ms=0.0).to_dict()
                     ]}}
         journal.write_text(
             json.dumps(raw) + "\n"
@@ -344,7 +446,7 @@ class TestStepView:
         out = capsys.readouterr().out
         assert "kind" in out and "mixed" in out
         assert "2 steps" in out
-        assert "host_gap=" in out
+        assert "host=" in out and "wait=" in out and "host parts (ms)" in out
 
     def test_view_steps_cli_empty(self, tmp_path, capsys):
         from operator_tpu.obs import view
@@ -456,6 +558,302 @@ class TestEngineStepClock:
         histograms = generator.metrics.snapshot()["histograms"]
         assert histograms["queue_wait_milliseconds"]["count"] >= 3
         assert histograms["step_duration_milliseconds"]["count"] == len(records)
+
+
+# ---------------------------------------------------------------------------
+# the continuous scheduler on an injected clock and a fake device
+# ---------------------------------------------------------------------------
+
+STEP_S = 0.100  # the fake device's time for one mixed program
+TICK_S = 0.0002  # every read of the clock costs the host this much
+TURN_S = 0.003  # the event loop's turn between two step() calls
+
+
+class _TickingClock:
+    """The injected ``StepClock.now``: time moves by ``TICK_S`` at every
+    read (host work), and otherwise only when the test or the fake
+    device says so."""
+
+    def __init__(self):
+        self.t = 50.0
+
+    def __call__(self):
+        self.t += TICK_S
+        return self.t
+
+
+class _DeviceHandle:
+    """Stands in for the device array of sampled tokens: ready at
+    ``ready_at``, so blocking on it moves the clock there."""
+
+    def __init__(self, tokens, ready_at, clock):
+        self._tokens, self._ready_at, self._clock = tokens, ready_at, clock
+
+    def block_until_ready(self):
+        self._clock.t = max(self._clock.t, self._ready_at)
+        return self
+
+    def __array__(self, dtype=None, copy=None):
+        import numpy as np
+
+        return np.asarray(self._tokens, dtype=dtype)
+
+
+def _drive_on_fake_device(params, depth, requests, **sched_kw):
+    """Run ``requests`` ([(prompt, max_tokens)]) to the end through the
+    real mixed program, whose results a fake device hands back
+    ``STEP_S`` after the later of their dispatch and the previous
+    program's end.  Returns (generator, elapsed seconds with work,
+    {req_id: (first token t, last token t, result)})."""
+    generator = make_generator(params, **sched_kw.pop("generator_kw", {}))
+    sched = Scheduler(generator, chunk=16, token_budget=32,
+                      pipeline_depth=depth, **sched_kw)
+    sched.precompile()
+    clock = _TickingClock()
+    generator.step_clock.now = clock
+    real = sched._get_fn()
+    device = {"free_at": 0.0}
+
+    def on_fake_device(*args):
+        new_paged, toks, accept, latest, rng = real(*args)
+        device["free_at"] = max(device["free_at"], clock.t) + STEP_S
+        handle = _DeviceHandle(toks, device["free_at"], clock)
+        return new_paged, handle, accept, latest, rng
+
+    sched._fn = on_fake_device
+    first, seen = {}, {}
+    sched.partial_hook = lambda req_id, ids: first.setdefault(req_id, clock.t)
+    ids = [
+        sched.enqueue(prompt, SamplingParams(
+            max_tokens=n, temperature=0.0, stop_on_eos=False))
+        for prompt, n in requests
+    ]
+    started = clock.t
+    for _ in range(400):
+        for outcome in sched.step():
+            assert outcome.error is None
+            seen[outcome.req_id] = (
+                first.get(outcome.req_id, clock.t), clock.t, outcome.result,
+            )
+        if len(seen) == len(ids):
+            break
+        clock.t += TURN_S
+    assert len(seen) == len(ids)
+    return generator, clock.t - started, seen, sched
+
+
+_FAKE_DEVICE_REQUESTS = [
+    ("pod crashed with exit code 137", 12),
+    ("a longer second prompt that takes two chunks of prefill", 9),
+    ("three", 14),
+]
+
+
+class TestSchedulerOnAnHonestClock:
+    @pytest.mark.parametrize("depth", [1, 2])
+    def test_walls_tile_the_elapsed_time(self, params, depth):
+        generator, elapsed_s, _, _ = _drive_on_fake_device(
+            params, depth, _FAKE_DEVICE_REQUESTS
+        )
+        records = generator.step_clock.ring.records()
+        assert len(records) > 10
+        # no interval overlaps another and none is missing: the walls sum
+        # to the time the engine had work (to within the reads of the
+        # clock around the first and the last step() call)
+        assert sum(r.wall_ms for r in records) == pytest.approx(
+            elapsed_s * 1e3, abs=5 * TICK_S * 1e3
+        )
+        ring = generator.step_clock.ring
+        assert sum(ring.cum_ms.values()) == pytest.approx(
+            sum(r.wall_ms for r in records)
+        )
+        # the device sets the pace: a step's wall is its program's time,
+        # at either depth, plus what the host adds when nothing overlaps
+        steady = records[3:-3]
+        host_serial = TURN_S + 30 * TICK_S
+        for r in steady:
+            if depth == 2:
+                assert r.wall_ms == pytest.approx(STEP_S * 1e3, abs=1.0)
+            else:
+                assert STEP_S * 1e3 <= r.wall_ms <= (STEP_S + host_serial) * 1e3
+
+    @pytest.mark.parametrize("depth", [1, 2])
+    def test_every_record_splits_its_wall_three_ways(self, params, depth):
+        generator, _, _, _ = _drive_on_fake_device(
+            params, depth, _FAKE_DEVICE_REQUESTS
+        )
+        for r in generator.step_clock.ring.records():
+            assert r.host_ms + r.wait_ms + r.xfer_ms == pytest.approx(r.wall_ms)
+            parts = r.plan_ms + r.pack_ms + r.commit_ms + r.turn_ms
+            assert 0 < parts <= r.host_ms + 1e-9
+            assert 0 <= r.prefill_tokens <= r.tokens
+        steady = generator.step_clock.ring.records()[3:-3]
+        # between two steps the loop took its turn while work was pending
+        assert all(
+            r.turn_ms == pytest.approx(TURN_S * 1e3, abs=3 * TICK_S * 1e3)
+            for r in steady
+        )
+        if depth == 2:
+            # the host hides under the device: most of the wall is slack
+            assert all(r.wait_ms > 0.8 * r.wall_ms for r in steady)
+
+    @pytest.mark.parametrize("depth", [1, 2])
+    def test_decode_ms_is_last_token_less_first(self, params, depth):
+        """At depth 2 the old stamps (dispatch -> ready, which spans two
+        device steps) made this about twice the truth."""
+        generator, _, seen, _ = _drive_on_fake_device(
+            params, depth, _FAKE_DEVICE_REQUESTS
+        )
+        one_step_ms = (STEP_S + TURN_S) * 1e3 + 5.0
+        for first_t, last_t, result in seen.values():
+            truth_ms = (last_t - first_t) * 1e3
+            assert truth_ms > 5 * STEP_S * 1e3
+            assert result.decode_ms == pytest.approx(truth_ms, abs=one_step_ms)
+
+    def test_admission_reads_the_steps_wall_as_seconds_per_token(self, params):
+        """``decode_step`` clamps deadlines (admission.deadline_policy);
+        at depth 2 it used to read dispatch -> fetch, two device steps."""
+        generator, _, _, _ = _drive_on_fake_device(
+            params, 2, [("pod crashed with exit code 137", 24)]
+        )
+        per_token_s = generator.decode_token_estimate_s()
+        assert per_token_s == pytest.approx(STEP_S, rel=0.05)
+        now = generator._clock()
+        clamped, outcome = generator.deadline_policy(
+            SamplingParams(max_tokens=64, deadline=now + 20.5 * STEP_S), now=now
+        )
+        assert outcome == "truncated"
+        assert clamped.max_tokens in (19, 20)  # was ~10 at two steps a token
+
+    def test_step_numbers_on_the_spans_are_the_records(self, params, monkeypatch):
+        """plan/pack/dispatch spans carry the seq of the record their step
+        will write, wait/commit the seq of the one being written."""
+        spans = []
+        real_annotation = BatchedGenerator._annotation
+
+        def spy(self, name, params_list=None, **args):
+            spans.append((name, dict(args)))
+            return real_annotation(self, name, params_list, **args)
+
+        monkeypatch.setattr(BatchedGenerator, "_annotation", spy)
+        generator, _, _, _ = _drive_on_fake_device(
+            params, 2, _FAKE_DEVICE_REQUESTS
+        )
+        records = {r.seq: r for r in generator.step_clock.ring.records()}
+        names = {name for name, _ in spans}
+        assert names == {
+            "podmortem.sched.plan", "podmortem.sched.pack",
+            "podmortem.sched.dispatch", "podmortem.sched.wait",
+            "podmortem.sched.commit",
+        }
+        dispatched = [a for name, a in spans if name == "podmortem.sched.dispatch"]
+        assert [a["step"] for a in dispatched] == sorted(records)
+        for args in dispatched:
+            record = records[args["step"]]
+            assert args["kv_pages"] == record.kv_pages_walked
+            assert args["qk_pairs"] >= args["tokens"] == record.tokens
+        committed = [a["step"] for name, a in spans if name == "podmortem.sched.commit"]
+        assert committed == sorted(records)
+
+
+def _walk_by_the_references_rule(kv_len, q_count, page_size, window=None):
+    """Pages and query-key pairs by ``ragged_attention_reference``'s own
+    mask, written out position by position: a page is walked when any
+    live query of the row may attend to a position on it."""
+    import numpy as np
+
+    pages = pairs = 0
+    for kv, count in zip(kv_len.tolist(), q_count.tolist()):
+        if count <= 0:
+            continue  # rows without queries produce garbage nobody reads
+        kv_pos = np.arange(max(kv, 1))[None, :]
+        q_pos = (kv - count + np.arange(count))[:, None]
+        mask = (kv_pos <= q_pos) & (kv_pos < kv)
+        if window is not None:
+            mask &= kv_pos > q_pos - window
+        touched = np.unique(np.nonzero(mask.any(axis=0))[0] // page_size)
+        pages += len(touched)
+        # the kernel scores every query against every position of the
+        # pages it walks, up to kv_len
+        first = touched.min() * page_size if len(touched) else 0
+        pairs += count * (kv - first)
+    return pages, pairs
+
+
+class TestKvPagesWalked:
+    @pytest.mark.parametrize("window", [None, 24, 100])
+    def test_count_matches_the_references_rule(self, window):
+        import numpy as np
+
+        from operator_tpu.serving.sched.scheduler import _kv_walk
+
+        #            decode  chunk  whole-prompt  verify  unscheduled  empty  page-edge
+        kv_len = np.array([130, 48, 16, 77, 200, 0, 64], np.int32)
+        q_count = np.array([1, 16, 16, 5, 0, 0, 1], np.int32)
+        got = _kv_walk(kv_len, q_count, 16, window)
+        assert got == _walk_by_the_references_rule(kv_len, q_count, 16, window)
+        if window is None:
+            # 9 + 3 + 1 + 5 + 0 + 0 + 4 pages; the unscheduled row's 13 not walked
+            assert got == (22, 130 + 16 * 48 + 16 * 16 + 5 * 77 + 64)
+
+    @pytest.mark.parametrize("window", [None, 24])
+    def test_records_count_what_the_program_was_given(
+        self, params, window, monkeypatch
+    ):
+        """Every step: the record's ``kv_pages_walked`` and the dispatch
+        span's ``qk_pairs`` against a count over the very ``kv_len`` /
+        ``q_count`` the mixed program got."""
+        import dataclasses
+
+        import numpy as np
+
+        span_pairs = {}
+        real_annotation = BatchedGenerator._annotation
+
+        def spy_annotation(self, name, params_list=None, **args):
+            if name == "podmortem.sched.dispatch":
+                span_pairs[args["step"]] = args["qk_pairs"]
+            return real_annotation(self, name, params_list, **args)
+
+        monkeypatch.setattr(BatchedGenerator, "_annotation", spy_annotation)
+
+        config = dataclasses.replace(TINY_TEST, sliding_window=window)
+        generator = BatchedGenerator(
+            params, config, ByteTokenizer(), paged=True, max_slots=4,
+            max_seq=128, page_size=16, cache_dtype=jnp.float32,
+            metrics=MetricsRegistry(),
+        )
+        sched = Scheduler(generator, chunk=16, token_budget=32, pipeline_depth=2)
+        real = sched._get_fn()
+        given = []
+
+        def spy(*args):
+            given.append((np.asarray(args[9]), np.asarray(args[8])))  # kv_len, q_count
+            return real(*args)
+
+        sched._fn = spy
+        sampling = SamplingParams(max_tokens=20, temperature=0.0, stop_on_eos=False)
+        for prompt in ("pod crashed with exit code 137 after the node ran out "
+                       "of memory and the kubelet evicted it", "short", "oom"):
+            sched.enqueue(prompt, sampling)
+        finished = 0
+        for _ in range(200):
+            finished += len(sched.step())
+            if finished == 3:
+                break
+        assert finished == 3
+        records = generator.step_clock.ring.records()
+        assert len(records) == len(given)
+        kinds = set()
+        for record, (kv_len, q_count) in zip(records, given):
+            pages, pairs = _walk_by_the_references_rule(kv_len, q_count, 16, window)
+            assert (record.kv_pages_walked, span_pairs[record.seq]) == (pages, pairs)
+            assert record.tokens == int(q_count.sum())
+            kinds.add(record.kind)
+            kinds.update(
+                "unscheduled" for kv, c in zip(kv_len, q_count) if kv > 0 and c == 0
+            )
+        assert {"decode", "mixed"} <= kinds
 
 
 class TestChaosReplayStepRecords:
