@@ -189,12 +189,14 @@ def kernels_leg(interpret: bool) -> dict:
     width = 1 + OperatorConfig().spec_lookup_k
     report["ragged"] = {
         # one wave of every phase the scheduler packs together: decode rows,
-        # a whole-prompt prefill, a mid-prompt chunk, a verify row, and an
-        # inactive slot (q_count 0 walks zero pages)
+        # a whole-prompt prefill, a mid-prompt chunk, rows on either side
+        # of the small query tile's edge (8 and 17 queries), and inactive
+        # slots between and after the live ones (q_count 0 runs nothing
+        # and moves no block)
         "mixed_rows_c64": ragged_case(
             "mixed", *geometry, chunk=64,
-            kv_len=[1, 200, 64, 448, 333, 0],
-            q_count=[1, 1, 64, 64, 17, 0],
+            kv_len=[1, 200, 0, 64, 448, 333, 300, 0],
+            q_count=[1, 1, 0, 64, 64, 17, 8, 0],
         ),
         f"verify_rows_c{width}": ragged_case(
             "verify", *geometry, chunk=width,

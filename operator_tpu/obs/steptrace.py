@@ -85,6 +85,11 @@ class StepRecord:
     #: less the pages a sliding window skips — ``num_live - first`` of
     #: ``ops/ragged_attention._ragged_attn_kernel``
     kv_pages_walked: Optional[int] = None
+    #: query-tile rows ONE layer's ragged-attention call computes this
+    #: step: the sum over slots with ``q_count > 0`` of the tile the
+    #: kernel chooses for them (``ops/ragged_attention.query_tile_rows``);
+    #: ``tokens`` over it is how full the tiles were
+    q_tile_rows: Optional[int] = None
     #: per-step achieved MFU when the ring's owner knows the model's
     #: flops/token (serving/perf.py StepClock); None on bare rings
     mfu: Optional[float] = None
@@ -141,7 +146,10 @@ _MS_FIELDS = (
     "wall_ms", "host_ms", "wait_ms", "xfer_ms",
     "plan_ms", "pack_ms", "commit_ms", "turn_ms",
 )
-_COUNT_FIELDS = ("accepted", "cached_tokens", "prefill_tokens", "kv_pages_walked")
+_COUNT_FIELDS = (
+    "accepted", "cached_tokens", "prefill_tokens", "kv_pages_walked",
+    "q_tile_rows",
+)
 
 
 class StepRing:
@@ -322,18 +330,19 @@ def render_steps(records: "Iterable[StepRecord]") -> str:
         f"{'seq':>5}  {'kind':<7} {'tok':>5} {'pf_tok':>6} {'slots':>5} {'occ':>5} "
         f"{'wall_ms':>8} {'host_ms':>8} {'wait_ms':>8} {'xfer_ms':>8} "
         f"{'plan':>7} {'pack':>7} {'commit':>7} {'turn':>7} "
-        f"{'kv_pg':>6} {'mfu':>8}"
+        f"{'kv_pg':>6} {'q_fill':>6} {'mfu':>8}"
     )
     lines = [header, "-" * len(header)]
     for r in records:
         mfu = f"{r.mfu:.4f}" if r.mfu is not None else "-"
         pages = r.kv_pages_walked if r.kv_pages_walked is not None else "-"
         prompt = r.prefill_tokens if r.prefill_tokens is not None else "-"
+        fill = f"{r.tokens / r.q_tile_rows:.3f}" if r.q_tile_rows else "-"
         lines.append(
             f"{r.seq:>5}  {r.kind:<7} {r.tokens:>5} {prompt:>6} {r.slots:>5} "
             f"{r.occupancy:>5.2f} {r.wall_ms:>8.3f} {r.host_ms:>8.3f} "
             f"{r.wait_ms:>8.3f} {r.xfer_ms:>8.3f} {r.plan_ms:>7.3f} "
             f"{r.pack_ms:>7.3f} {r.commit_ms:>7.3f} {r.turn_ms:>7.3f} "
-            f"{pages:>6} {mfu:>8}"
+            f"{pages:>6} {fill:>6} {mfu:>8}"
         )
     return "\n".join(lines)

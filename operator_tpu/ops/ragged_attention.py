@@ -45,6 +45,29 @@ move from HBM) and keeps a flash-attention running (max, sum, acc) per
 (query row, head) in VMEM.  The dense reference is the oracle for parity
 tests and the CPU path.
 
+**The kernel pays by the work.**  One grid step serves one row, and what
+it computes follows that row's ``q_count``, not the compiled chunk ``C``:
+
+- *the query tile*: a row with at most ``SMALL_TILE`` queries — a decode
+  row, a verify row of ``1 + spec_lookup_k`` — works its first
+  ``min(SMALL_TILE, C)`` query tokens, any other row all ``C``.  Flash
+  state, score block, mask, ``exp`` and both products have the tile's
+  rows (:func:`query_tile_rows` is the rule, and what the scheduler's
+  ``q_tile_rows`` counts).  Both rungs are branches of the one kernel;
+- *the idle rule*: a row with ``q_count == 0`` (an empty slot, or a live
+  row the step's token budget left out) runs nothing — no state, no page,
+  no finalise — and moves no block: its grid step's q / out index is the
+  last live row's, and the pipeline copies a block only when the index
+  changes.  Its output is whatever the buffer held;
+- *the output's dtype* is the query's: float32 inside, one cast at the
+  finalise.  Scores multiply the pool's dtype (bf16 values are exact
+  products in the float32 accumulator); probabilities, running max, sum
+  and accumulator stay float32.
+
+So output rows past a row's tile, and every row of an idle slot, are
+never written: a caller gathers the ``q_count`` live rows and nothing
+else (``sched/mixed.py`` masks its padding tokens).
+
 The page DMA moves one ``[page_size, KH, D]`` page per copy, and Mosaic
 requires the minor dimension of a DMA slice to fill the 128-lane tile, so
 the kernel serves ``head_dim`` in multiples of 128 only.  There is no
@@ -61,6 +84,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ._flash_common import finalize, init_state, update_state
 
@@ -71,6 +95,18 @@ _NEG_INF = -1e30
 #: attn_kernel_share.py`` matches ``ragged_attention``), so a refactor of
 #: the wrapper around the ``pallas_call`` cannot rename it by accident
 KERNEL_NAME = "ragged_attention_kernel"
+#: the query tile of a row with few queries: a decode row (1) and a
+#: speculation-verify row (1 + spec_lookup_k = 5) fit it; a row with more
+#: takes the whole chunk
+SMALL_TILE = 8
+
+
+def query_tile_rows(q_count: np.ndarray, chunk: int) -> np.ndarray:
+    """The query tile the kernel works for each slot (0 for a slot it
+    skips), by the rule ``_ragged_attn_kernel`` branches on.  On the
+    host: the scheduler counts its ``q_tile_rows`` with it."""
+    small = min(SMALL_TILE, chunk)
+    return np.where(q_count > small, chunk, np.where(q_count > 0, small, 0))
 
 
 class UnsupportedHeadDim(ValueError):
@@ -145,53 +181,49 @@ def _ragged_attn_kernel(
     pt_ref,  # [B, pages_per_seq] int32 (SMEM)
     len_ref,  # [B] int32 kv_len (SMEM)
     cnt_ref,  # [B] int32 q_count (SMEM)
+    blk_ref,  # [B] int32: the q / out block each grid step holds
     # blocks
     q_ref,  # [1, C, QH, D] (VMEM)
     k_hbm,  # [num_pages, page_size, KH, D] (stays in HBM)
     v_hbm,
-    out_ref,  # [1, C, QH, D] f32
+    out_ref,  # [1, C, QH, D] in q's dtype
     # scratch
     k_buf,  # [2, page_size, KH, D] VMEM double buffer
     v_buf,
-    sem,  # DMA semaphores [2, 2]
-    m_scratch,  # [C*QH, LANE] f32 running max
-    l_scratch,  # [C*QH, LANE] f32 running denominator
-    acc_scratch,  # [C*QH, D] f32
+    sem,  # DMA semaphores [2, 2]: page slot x (k, v)
     *,
-    c: int,
+    tiles: tuple[int, ...],
     kv_heads: int,
     q_per_kv: int,
     page_size: int,
     scale: float,
     window: Optional[int] = None,
 ):
-    """One grid step per batch row; the row's q chunk rides a BlockSpec
-    while its live KV pages stream through a manual double-buffered DMA
-    walk (the ``ops/paged_attention.py`` v2 design).  Flash-state rows
-    are laid out head-major — row ``h*C*G + i*G + j`` is query token
-    ``i`` of q head ``h*G + j`` — so the per-kv-head GQA dots write
-    contiguous slabs; the finalize transposes back to [C, QH, D]."""
+    """One grid step per batch row; a row with ``q_count == 0`` runs
+    nothing.  A live row's query tile — the smallest of ``tiles`` (query
+    tokens, ascending) that holds its ``q_count`` — sets the rows of
+    everything computed:
+    flash state, scores, mask, ``exp`` and both products.  Its live KV
+    pages stream through a manual double-buffered DMA walk (the
+    ``ops/paged_attention.py`` v2 design).  Flash-state rows are laid
+    out head-major — row ``h*T*G + i*G + j`` is query token ``i`` of q
+    head ``h*G + j`` at tile ``T`` — so the per-kv-head GQA dots write
+    contiguous slabs; the finalize transposes back to [T, QH, D] and
+    casts to the output's dtype once."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    del blk_ref  # the index maps' business
     b = pl.program_id(0)
     seq_len = len_ref[b]
     count = cnt_ref[b]
     q_base = seq_len - count  # absolute position of q row 0
-    # rows with no work this step (count == 0: inactive, or live but
-    # unscheduled under a saturated token budget) walk ZERO pages — their
-    # output is garbage by contract, so the DMAs and matmuls would be
-    # pure waste exactly when the step is already compute-bound
-    num_live = jnp.where(count > 0, pl.cdiv(seq_len, page_size), 0)
+    num_live = pl.cdiv(seq_len, page_size)
     first = 0
     if window is not None:
         # earliest kv ANY live q row can see: q_base - window + 1
         first = jnp.maximum(q_base - window + 1, 0) // page_size
-
-    slab = c * q_per_kv  # flash rows per kv head (token-major within)
-    total = kv_heads * slab
-
-    init_state(m_scratch, l_scratch, acc_scratch)
+    head_dim = q_ref.shape[-1]
 
     def dma(slot, j):
         return (
@@ -203,76 +235,107 @@ def _ragged_attn_kernel(
             ),
         )
 
-    @pl.when(num_live > first)
-    def _prologue():
-        for copy in dma(first % 2, first):
-            copy.start()
+    def rung(tile: int):
+        """The whole of a live row's work at a static query tile."""
+        slab = tile * q_per_kv  # flash rows per kv head (token-major within)
+        total = kv_heads * slab
 
-    q = q_ref[0].astype(jnp.float32)  # [C, QH, D]
-    # flash rows: kv-head slabs stacked, token-major inside each — row
-    # h*slab + i*G + j is query token i of q head h*G + j.  Its q
-    # position depends only on the token index within the slab.
-    row_iota = jax.lax.broadcasted_iota(jnp.int32, (total, page_size), 0)
-    q_pos = q_base + (row_iota % slab) // q_per_kv
+        def walk(m_scratch, l_scratch, acc_scratch):
+            @pl.when(num_live > first)
+            def _prologue():
+                for copy in dma(first % 2, first):
+                    copy.start()
 
-    def body(j, _):
-        slot = j % 2
-
-        @pl.when(j + 1 < num_live)
-        def _prefetch_next():
-            for copy in dma((j + 1) % 2, j + 1):
-                copy.start()
-
-        for copy in dma(slot, j):
-            copy.wait()
-
-        k = k_buf[slot]  # [page, KH, D]
-        v = v_buf[slot]
-        kv_pos = j * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (total, page_size), 1
-        )
-
-        # scores for every slab against this page, stacked [total, page]
-        parts = []
-        for h in range(kv_heads):
-            q_h = q[:, h * q_per_kv : (h + 1) * q_per_kv, :].reshape(slab, -1)
-            k_h = k[:, h, :].astype(jnp.float32)  # [page, D]
-            parts.append(
-                jax.lax.dot_general(
-                    q_h, k_h, (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                )
+            init_state(m_scratch, l_scratch, acc_scratch)
+            # flash rows: kv-head slabs stacked, token-major inside each —
+            # row h*slab + i*G + j is query token i of q head h*G + j.  Its
+            # q position depends only on the token index within the slab.
+            row_iota = jax.lax.broadcasted_iota(
+                jnp.int32, (total, page_size), 0
             )
-        s = jnp.concatenate(parts, axis=0) * scale
-        mask = (kv_pos <= q_pos) & (kv_pos < seq_len)
-        if window is not None:
-            mask = mask & (kv_pos > q_pos - window)
-        s = jnp.where(mask, s, _NEG_INF)
+            q_pos = q_base + (row_iota % slab) // q_per_kv
+            q = q_ref[0, :tile].astype(jnp.float32)  # [tile, QH, D]
+            # the score product's operands keep the pool's dtype (bf16
+            # values multiply exactly into the f32 accumulator); the
+            # [tile, G, D] -> [slab, D] regroup runs on f32 tiles
+            q_slabs = [
+                q[:, h * q_per_kv : (h + 1) * q_per_kv, :]
+                .reshape(slab, head_dim).astype(k_buf.dtype)
+                for h in range(kv_heads)
+            ]
 
-        def values(p):
-            outs = []
-            for h in range(kv_heads):
-                p_h = p[h * slab : (h + 1) * slab]
-                v_h = v[:, h, :].astype(jnp.float32)
-                outs.append(
-                    jax.lax.dot_general(
-                        p_h, v_h, (((1,), (0,)), ((), ())),
-                        preferred_element_type=jnp.float32,
-                    )
+            def body(j, _):
+                slot = j % 2
+
+                @pl.when(j + 1 < num_live)
+                def _prefetch_next():
+                    for copy in dma((j + 1) % 2, j + 1):
+                        copy.start()
+
+                for copy in dma(slot, j):
+                    copy.wait()
+
+                k = k_buf[slot]  # [page, KH, D]
+                v = v_buf[slot]
+                kv_pos = j * page_size + jax.lax.broadcasted_iota(
+                    jnp.int32, (total, page_size), 1
                 )
-            return jnp.concatenate(outs, axis=0)
 
-        update_state(m_scratch, l_scratch, acc_scratch, s, values)
-        return 0
+                # scores for every slab against this page, [total, page]
+                s = jnp.concatenate(
+                    [
+                        jax.lax.dot_general(
+                            q_slabs[h], k[:, h, :], (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32,
+                        )
+                        for h in range(kv_heads)
+                    ],
+                    axis=0,
+                ) * scale
+                mask = (kv_pos <= q_pos) & (kv_pos < seq_len)
+                if window is not None:
+                    mask = mask & (kv_pos > q_pos - window)
+                s = jnp.where(mask, s, _NEG_INF)
 
-    jax.lax.fori_loop(first, num_live, body, 0)
-    out = finalize(l_scratch, acc_scratch)  # [KH*C*G, D]
-    # slab h holds [C, G, D]; write it into the head band of [C, QH, D]
-    for h in range(kv_heads):
-        out_ref[0, :, h * q_per_kv : (h + 1) * q_per_kv, :] = (
-            out[h * slab : (h + 1) * slab].reshape(c, q_per_kv, -1)
-            .astype(out_ref.dtype)
+                def values(p):
+                    return jnp.concatenate(
+                        [
+                            jax.lax.dot_general(
+                                p[h * slab : (h + 1) * slab],
+                                v[:, h, :].astype(jnp.float32),
+                                (((1,), (0,)), ((), ())),
+                                preferred_element_type=jnp.float32,
+                            )
+                            for h in range(kv_heads)
+                        ],
+                        axis=0,
+                    )
+
+                update_state(m_scratch, l_scratch, acc_scratch, s, values)
+                return 0
+
+            jax.lax.fori_loop(first, num_live, body, 0)
+            out = finalize(l_scratch, acc_scratch)  # [KH*tile*G, D]
+            # slab h holds [tile, G, D]: the head band of [tile, QH, D]
+            for h in range(kv_heads):
+                out_ref[0, :tile, h * q_per_kv : (h + 1) * q_per_kv, :] = (
+                    out[h * slab : (h + 1) * slab]
+                    .reshape(tile, q_per_kv, head_dim).astype(out_ref.dtype)
+                )
+
+        pl.run_scoped(
+            walk,
+            pltpu.VMEM((total, _LANE), jnp.float32),  # running max
+            pltpu.VMEM((total, _LANE), jnp.float32),  # running denominator
+            pltpu.VMEM((total, head_dim), jnp.float32),
         )
+
+    # the smallest tile that holds the row's queries; none for count == 0
+    below = 0
+    # graftlint: disable=GL002 reason=tiles is a static tuple of ints, bound with functools.partial before the pallas_call
+    for tile in tiles:
+        pl.when((count > below) & (count <= tile))(functools.partial(rung, tile))
+        below = tile
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "sliding_window"))
@@ -292,45 +355,47 @@ def _ragged_attention_pallas(
 
     b, c, qh, d = q.shape
     _, page_size, kh, _ = k_pages.shape
-    scale = d**-0.5
-    rows = c * qh  # total flash rows (all kv-head slabs stacked)
 
     kernel = functools.partial(
         _ragged_attn_kernel,
-        c=c,
+        tiles=tuple(sorted({min(SMALL_TILE, c), c})),
         kv_heads=kh,
         q_per_kv=qh // kh,
         page_size=page_size,
-        scale=scale,
+        scale=d**-0.5,
         window=sliding_window,
     )
+    # an idle slot's grid step holds the block of the last live slot
+    # before it (of the first live slot, ahead of it): the pipeline moves
+    # a block only when its index changes, so an idle slot fetches no
+    # queries and writes no output back
+    live = q_count > 0
+    slots = jnp.arange(b, dtype=jnp.int32)
+    held = jax.lax.cummax(jnp.where(live, slots, -1))
+    block = jnp.where(held < 0, jnp.argmax(live).astype(jnp.int32), held)
+
+    def row_block(i, pt, ln, cn, blk):
+        return (blk[i], 0, 0, 0)
+
     any_space = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(b,),
-        in_specs=[
-            pl.BlockSpec((1, c, qh, d), lambda b, pt, ln, cn: (b, 0, 0, 0)),
-            any_space,
-            any_space,
-        ],
-        out_specs=pl.BlockSpec((1, c, qh, d), lambda b, pt, ln, cn: (b, 0, 0, 0)),
+        in_specs=[pl.BlockSpec((1, c, qh, d), row_block), any_space, any_space],
+        out_specs=pl.BlockSpec((1, c, qh, d), row_block),
         scratch_shapes=[
             pltpu.VMEM((2, page_size, kh, d), k_pages.dtype),
             pltpu.VMEM((2, page_size, kh, d), v_pages.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
-            pltpu.VMEM((rows, _LANE), jnp.float32),
-            pltpu.VMEM((rows, _LANE), jnp.float32),
-            pltpu.VMEM((rows, d), jnp.float32),
         ],
     )
-    out = pl.pallas_call(
+    return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, c, qh, d), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
         name=KERNEL_NAME,
-    )(page_table, kv_len, q_count, q, k_pages, v_pages)
-    return out.astype(q.dtype)
+    )(page_table, kv_len, q_count, block, q, k_pages, v_pages)
 
 
 def ragged_paged_attention(

@@ -162,7 +162,7 @@ class StepClock:
         clock: the wave engine's admission prefill) the record stands
         alone and its wall is the two waits.  ``counts`` are the record's
         optional work counts (``accepted``, ``cached_tokens``,
-        ``prefill_tokens``, ``kv_pages_walked``).  MFU stays
+        ``prefill_tokens``, ``kv_pages_walked``, ``q_tile_rows``).  MFU stays
         computed on billed ``tokens`` — the compute really ran — over the
         interval's wall."""
         if commit_t is None:

@@ -10,8 +10,11 @@ phases.  Attention is the only op that needs row structure: the flat
 q tokens are re-packed per row into ``[B, chunk]`` and handed to the
 ragged paged-attention kernel (``ops/ragged_attention.py``), whose
 causal mask makes a decode row the ``q_count == 1`` special case of a
-prefill chunk.  KV for the step is scattered into the paged cache
-BEFORE attention, so the kernel is a pure page read.
+prefill chunk and whose work follows each row's ``q_count``: a slot the
+step gives no token costs it nothing, and what it leaves unwritten there
+is masked off when the rows are gathered back.  KV for the step is
+scattered into the paged cache BEFORE attention, so the kernel is a pure
+page read.
 
 Exactly ONE program compiles per engine (static ``t_budget`` / ``chunk``
 / ``max_slots``): there is no bucket grid to warm, no per-shape compile
@@ -21,9 +24,12 @@ approximate for the wave engine, the mixed program has by construction.
 Unsupported here (the wave engine keeps them): guided decoding and LoRA
 adapters are refused at submit (serving/engine.py + Scheduler.enqueue);
 mesh sharding makes build_serving_engine fall back to wave mode; and
-shared-prefix KV reuse simply does not apply — every prompt prefills in
-full, so provider.py skips prefix priming in continuous mode rather
-than holding pages the program would never read.
+the wave engine's primed shared prefix has no path here, so provider.py
+skips that priming in continuous mode.  Prefix reuse is the scheduler's
+block-hash cache instead (serving/kvstore.py): a hit maps the cached
+pages into the row's table and its first chunk starts at ``cached_len``
+— to this program just a row whose ``kv_len`` runs ahead of its
+``q_count``.
 """
 
 from __future__ import annotations
@@ -155,7 +161,12 @@ def make_mixed_fn(generator: Any, t_budget: int, chunk: int,
                     paged.page_table, kv_len, q_count,
                     sliding_window=config.sliding_window,
                 )
-                attn = attn_pack[rows, in_row]  # back to flat [T, QH, D]
+                # back to flat [T, QH, D].  The kernel leaves what it was
+                # not asked for unwritten (idle slots, rows past q_count):
+                # a padding token would gather whatever the buffer held
+                attn = jnp.where(
+                    valid[:, None, None], attn_pack[rows, in_row], 0
+                )
             x = x + proj(attn.astype(x.dtype).reshape(1, t_budget, -1), "wo")
             with jax.named_scope("mlp"):
                 mlp_in = rms_norm(x, weights["ln_mlp"], config.rms_norm_eps)

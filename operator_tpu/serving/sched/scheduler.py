@@ -601,6 +601,7 @@ class Scheduler:
                 kv_pages=packed.counts["kv_pages_walked"],
                 qk_pairs=packed.qk_pairs,
                 tokens=plan.tokens_planned,
+                q_tile_rows=packed.counts["q_tile_rows"],
             ):
                 entry = self._dispatch(plan, packed)
             clock.add("pack", (entry.dispatch_t - t1) * 1e3)
@@ -1219,6 +1220,8 @@ class Scheduler:
             kv_len[work.slot] = work.pos0 + work.count
             temp[work.slot] = row.params.temperature
             top_p[work.slot] = row.params.top_p
+        from ...ops.ragged_attention import query_tile_rows
+
         pages, pairs = _kv_walk(
             kv_len, q_count, g.page_size, g.config.sliding_window
         )
@@ -1227,7 +1230,13 @@ class Scheduler:
             from_prev=from_prev, q_start=q_start, q_count=q_count,
             sample_start=sample_start, spec_len=spec_len, temp=temp,
             top_p=top_p, kv_len=kv_len,
-            counts={"prefill_tokens": prefill_tokens, "kv_pages_walked": pages},
+            counts={
+                "prefill_tokens": prefill_tokens, "kv_pages_walked": pages,
+                # the query-tile rows one layer's kernel call works, by
+                # the kernel's own rule: nothing for a slot without
+                # queries, the small tile or the whole chunk for the rest
+                "q_tile_rows": int(query_tile_rows(q_count, self.chunk).sum()),
+            },
             qk_pairs=pairs,
         )
 

@@ -11,7 +11,8 @@ CPU host finds them before chip time is spent.  Covered:
 - the ragged mixed-phase kernel — the continuous scheduler's ONLY
   attention on a TPU — at the ``(QH, KH, D)`` of every registered model
   config, bf16, page 64, chunk widths 5 (verify) and 64 (prefill), plus a
-  sliding-window case.  A config the kernel cannot serve must be REFUSED
+  sliding-window case, and alone at the benchmark's two cells (12/2 heads
+  x 128 slots, 28/4 x 32): both rungs of its query tile.  A config the kernel cannot serve must be REFUSED
   by ``require_ragged_kernel_support`` (a named error at engine build),
   never silently routed elsewhere — the check asserts which of the two
   happens for each config;
@@ -283,6 +284,17 @@ def main() -> int:
             cases.append(
                 (f"ragged_{name}_c{chunk}", fn, ragged_args(*geometry, chunk))
             )
+    # the kernel alone at the benchmark's two cells (BENCHMARK.json: heads
+    # x slots, bf16 pool, page 64, chunk 64): both rungs of the query tile
+    # at 6 and at 7 queries a kv head, which the 4-row cases above lower
+    # too, at the slot counts the cells run
+    for tag, heads, kv_heads, slots in (
+        ("qwen2.5-1.5b", 12, 2, 128), ("qwen2.5-7b", 28, 4, 32),
+    ):
+        cases.append((
+            f"ragged_cell_{tag}_b{slots}", _ragged_attention_pallas,
+            ragged_args(heads, kv_heads, 128, _CHUNK, rows=slots),
+        ))
     # a window that actually bites inside max_seq (Mistral's 4096 is wider
     # than the serving cap, so its first-page term folds to zero above)
     cases.append((

@@ -80,6 +80,10 @@ def test_all_kernels_aot_compile_for_v5e(record):
             assert f"ragged_{name}_c5" in kernels, record
             assert f"ragged_{name}_c64" in kernels, record
     assert "ragged_window" in kernels, record
+    # the kernel alone at the benchmark's two cells: a rung of the query
+    # tile that cannot lower at 7B's 7 queries a kv head is found here
+    assert "ragged_cell_qwen2.5-1.5b_b128" in kernels, record
+    assert "ragged_cell_qwen2.5-7b_b32" in kernels, record
     # the whole mixed step at the server's default shape, for the default
     # model — which must therefore be one the kernel serves
     assert _REGISTRY[OperatorConfig().model_id].head_dim % 128 == 0

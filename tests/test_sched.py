@@ -106,6 +106,80 @@ class TestRaggedKernel:
                 rtol=2e-5, atol=2e-5,
             )
 
+    #: chunk 16 over the small tile of 8: both rungs, with q_count at
+    #: their edges (0, 1, 5, small, small + 1, chunk) and idle slots
+    #: between the live ones
+    _EDGES = dict(
+        b=8, c=16, q_count=[0, 1, 0, 5, 8, 0, 9, 16],
+        kv_len=[12, 33, 0, 21, 8, 40, 30, 48],
+    )
+
+    @pytest.mark.parametrize("case", [
+        dict(id="edges-4x2", qh=4, kh=2, **_EDGES),
+        # group sizes that are no power of two: 6 and 7 queries a kv head
+        dict(id="edges-6x1", qh=6, kh=1, **_EDGES),
+        dict(id="edges-7x1", qh=7, kh=1, **_EDGES),
+        dict(id="all-idle", qh=4, kh=2, b=4, c=16, q_count=[0] * 4,
+             kv_len=[9, 0, 17, 3]),
+        dict(id="bf16-pool", qh=6, kh=1, dtype=jnp.bfloat16, **_EDGES),
+        # the window bites on small-tile rows (decode and verify) and on
+        # a chunk, several pages back
+        dict(id="window-small-tile", qh=4, kh=2, b=4, c=16, window=7,
+             q_count=[1, 5, 0, 16], kv_len=[41, 30, 12, 37]),
+    ], ids=lambda case: case["id"])
+    def test_parity_by_rung(self, case):
+        """The kernel against the reference where what it works follows
+        ``q_count``: no slot's output depends on the tile it was given,
+        and a slot without queries leaves the others alone."""
+        from operator_tpu.ops.ragged_attention import SMALL_TILE, query_tile_rows
+
+        dtype = case.get("dtype", jnp.float32)
+        rng = np.random.default_rng(len(case["id"]))
+        q, k, v, table = self._setup(
+            rng, b=case["b"], c=case["c"], qh=case["qh"], kh=case["kh"]
+        )
+        q, k, v = (x.astype(dtype) for x in (q, k, v))
+        kv_len = jnp.asarray(case["kv_len"], jnp.int32)
+        q_count = jnp.asarray(case["q_count"], jnp.int32)
+        tiles = set(query_tile_rows(np.asarray(q_count), case["c"]).tolist())
+        assert tiles <= {0, SMALL_TILE, case["c"]}
+        if case["id"].startswith("edges"):
+            assert tiles == {0, SMALL_TILE, case["c"]}
+        window = case.get("window")
+        got = _ragged_attention_pallas(
+            q, k, v, table, kv_len, q_count, interpret=True,
+            sliding_window=window,
+        )
+        assert got.shape == q.shape and got.dtype == dtype
+        want = ragged_attention_reference(
+            *(x.astype(jnp.float32) for x in (q, k, v)), table, kv_len,
+            q_count, sliding_window=window,
+        )
+        tol = 2e-5 if dtype == jnp.float32 else 2e-2
+        for row, n in enumerate(case["q_count"]):
+            np.testing.assert_allclose(
+                np.asarray(got[row, :n], np.float32), np.asarray(want[row, :n]),
+                rtol=tol, atol=tol,
+            )
+
+    def test_both_rungs_serve_the_same_queries_alike(self):
+        """One row's last five queries, once as the tail of a 9-query row
+        (the chunk's tile) and once as a 5-query row padded out to the
+        chunk (the small tile), over the same pages: equal outputs."""
+        rng = np.random.default_rng(7)
+        q, k, v, table = self._setup(rng, b=2, c=16)
+        table = jnp.stack([table[0], table[0]])  # both slots read one row's pages
+        q = q.at[1, :5].set(q[0, 4:9])
+        kv_len = jnp.asarray([29, 29], jnp.int32)
+        q_count = jnp.asarray([9, 5], jnp.int32)
+        got = _ragged_attention_pallas(
+            q, k, v, table, kv_len, q_count, interpret=True
+        )
+        np.testing.assert_allclose(
+            np.asarray(got[1, :5]), np.asarray(got[0, 4:9]), rtol=2e-6, atol=2e-6
+        )
+        self._check(q, k, v, table, kv_len, q_count)
+
     def test_prefill_only_rows(self):
         rng = np.random.default_rng(0)
         q, k, v, table = self._setup(rng)

@@ -161,7 +161,7 @@ class TestStepRing:
             seq=9, kind="mixed", tokens=70, slots=3, occupancy=0.75,
             wall_ms=12.5, host_ms=2.0, wait_ms=10.0, xfer_ms=0.5,
             plan_ms=0.5, pack_ms=0.75, commit_ms=0.25, turn_ms=0.125,
-            prefill_tokens=64, kv_pages_walked=11,
+            prefill_tokens=64, kv_pages_walked=11, q_tile_rows=80,
             accepted=3, cached_tokens=16,
         )
         raw = record.to_dict()
@@ -172,6 +172,8 @@ class TestStepRing:
         bare = _decode_record().to_dict()
         assert "kv_pages_walked" not in bare and "prefill_tokens" not in bare
         assert StepRecord.from_dict(bare).kv_pages_walked is None
+        assert "q_tile_rows" not in bare
+        assert StepRecord.from_dict(bare).q_tile_rows is None
 
 
 # ---------------------------------------------------------------------------
@@ -412,15 +414,18 @@ class TestStepView:
             _decode_record(),
             StepRecord(seq=1, kind="prefill", tokens=16, slots=1,
                        occupancy=0.25, wall_ms=9.0, host_ms=0.0, wait_ms=9.0,
-                       xfer_ms=0.0, kv_pages_walked=7, prefill_tokens=16),
+                       xfer_ms=0.0, kv_pages_walked=7, prefill_tokens=16,
+                       q_tile_rows=64),
         ])
         lines = table.splitlines()
         assert lines[0].split() == [
             "seq", "kind", "tok", "pf_tok", "slots", "occ",
             "wall_ms", "host_ms", "wait_ms", "xfer_ms",
-            "plan", "pack", "commit", "turn", "kv_pg", "mfu",
+            "plan", "pack", "commit", "turn", "kv_pg", "q_fill", "mfu",
         ]
-        assert lines[3].split()[-2] == "7" and lines[2].split()[-2] == "-"
+        assert lines[3].split()[-3] == "7" and lines[2].split()[-3] == "-"
+        # q_fill = tokens / q_tile_rows: 16 of the chunk's 64 rows
+        assert lines[3].split()[-2] == "0.250" and lines[2].split()[-2] == "-"
         assert lines[3].split()[3] == "16" and lines[2].split()[3] == "-"
         assert len(lines) == 4  # header + rule + 2 rows
         assert "prefill" in lines[3]
@@ -752,6 +757,7 @@ class TestSchedulerOnAnHonestClock:
             record = records[args["step"]]
             assert args["kv_pages"] == record.kv_pages_walked
             assert args["qk_pairs"] >= args["tokens"] == record.tokens
+            assert args["q_tile_rows"] == record.q_tile_rows >= record.tokens
         committed = [a["step"] for name, a in spans if name == "podmortem.sched.commit"]
         assert committed == sorted(records)
 
@@ -800,19 +806,23 @@ class TestKvPagesWalked:
     def test_records_count_what_the_program_was_given(
         self, params, window, monkeypatch
     ):
-        """Every step: the record's ``kv_pages_walked`` and the dispatch
-        span's ``qk_pairs`` against a count over the very ``kv_len`` /
+        """Every step: the record's ``kv_pages_walked`` and
+        ``q_tile_rows`` and the dispatch span's ``qk_pairs`` and
+        ``q_tile_rows`` against a count over the very ``kv_len`` /
         ``q_count`` the mixed program got."""
         import dataclasses
 
         import numpy as np
 
-        span_pairs = {}
+        from operator_tpu.ops.ragged_attention import query_tile_rows
+
+        span_pairs, span_rows = {}, {}
         real_annotation = BatchedGenerator._annotation
 
         def spy_annotation(self, name, params_list=None, **args):
             if name == "podmortem.sched.dispatch":
                 span_pairs[args["step"]] = args["qk_pairs"]
+                span_rows[args["step"]] = args["q_tile_rows"]
             return real_annotation(self, name, params_list, **args)
 
         monkeypatch.setattr(BatchedGenerator, "_annotation", spy_annotation)
@@ -844,16 +854,52 @@ class TestKvPagesWalked:
         assert finished == 3
         records = generator.step_clock.ring.records()
         assert len(records) == len(given)
-        kinds = set()
+        kinds, tiles = set(), set()
         for record, (kv_len, q_count) in zip(records, given):
             pages, pairs = _walk_by_the_references_rule(kv_len, q_count, 16, window)
             assert (record.kv_pages_walked, span_pairs[record.seq]) == (pages, pairs)
             assert record.tokens == int(q_count.sum())
+            rows = query_tile_rows(q_count, 16)
+            assert record.q_tile_rows == span_rows[record.seq] == int(rows.sum())
+            tiles.update(rows.tolist())
             kinds.add(record.kind)
             kinds.update(
                 "unscheduled" for kv, c in zip(kv_len, q_count) if kv > 0 and c == 0
             )
         assert {"decode", "mixed"} <= kinds
+        assert tiles == {0, 8, 16}  # idle slots, decode rows, prompt chunks
+        last = records[-1]
+        assert f"{last.tokens / last.q_tile_rows:.3f}" in (
+            render_steps(records).splitlines()[-1].split()
+        )
+
+
+class TestQTileRows:
+    """``q_tile_rows``: the query-tile rows one layer's kernel call works,
+    by the rule the kernel branches on (``ops/ragged_attention.py``)."""
+
+    @pytest.mark.parametrize("name, q_count, chunk, rows", [
+        # 3 decode rows and a verify row, each on the small tile of 8
+        ("decode-only", [1, 1, 0, 1, 5, 0], 64, 4 * 8),
+        # the small tile's edge, one past it, a whole chunk, two idle
+        ("mixed", [1, 8, 9, 64, 0, 0], 64, 8 + 8 + 64 + 64),
+        ("empty", [0, 0, 0, 0], 64, 0),
+        # a chunk no larger than the small tile has one rung: the chunk
+        ("tiny-chunk", [1, 4, 0], 4, 4 + 4),
+    ])
+    def test_count_is_the_kernels_rule(self, name, q_count, chunk, rows):
+        import numpy as np
+
+        from operator_tpu.ops.ragged_attention import SMALL_TILE, query_tile_rows
+
+        assert SMALL_TILE == 8
+        counts = np.asarray(q_count, np.int32)
+        assert int(query_tile_rows(counts, chunk).sum()) == rows
+        # slot by slot: nothing, the small tile, or the chunk
+        small = min(SMALL_TILE, chunk)
+        assert query_tile_rows(counts, chunk).tolist() == [
+            0 if c == 0 else small if c <= small else chunk for c in q_count
+        ]
 
 
 class TestChaosReplayStepRecords:
