@@ -108,7 +108,7 @@ class Handle:
     def engine_resets(self) -> int:
         return int(self._generator.metrics.counter("supervisor_restart"))
 
-    # -- parameters (for the float32 reference) --------------------------
+    # -- parameters (for the weight floor and the reference's adapter) ---
     def param_bytes(self) -> int:
         import jax
 
@@ -117,60 +117,24 @@ class Handle:
             for leaf in jax.tree_util.tree_leaves(self._generator.params)
         )
 
-    def reference_weights(self) -> "ReferenceWeights":
-        return ReferenceWeights(self._generator.params)
+    def parameters(self) -> Any:
+        """The program's parameter tree as the engine holds it, for
+        ``tools/weights_check.py`` alone: what decides ``correct`` never
+        reads it (the reference makes weights of its own)."""
+        return self._generator.params
 
     async def close(self) -> None:
+        """Close the engine and free its device state, the weights and the
+        KV pool: the reference, which runs next, needs the room."""
+        import jax
+
         await self.engine.close()
-
-
-class ReferenceWeights:
-    """The engine's own parameters, one layer at a time, as float32.
-
-    The reference (``benchmark/reference/decoder_f32.py``) knows nothing of
-    the program's parameter layout; this class does: layer matrices are
-    stacked on axis 0 as ``[layer, in, out]``, int8 groups are
-    ``{"q": int8 [in, out], "s": float [out]}`` (``models/quant.py``), the
-    embedding is ``[vocab, hidden]`` and the head ``[hidden, vocab]`` or
-    tied."""
-
-    _MATRICES = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
-    _VECTORS = ("ln_attn", "ln_mlp", "bq", "bk", "bv")
-
-    def __init__(self, params: Any) -> None:
-        self._params = params
-
-    @property
-    def embed(self) -> Any:
-        return self._params["embed"]
-
-    @property
-    def ln_final(self) -> Any:
-        return self._params["ln_final"]
-
-    @property
-    def head(self) -> Optional[Any]:
-        """``[hidden, vocab]``, or None where the head is the embedding."""
-        return self._params.get("lm_head")
-
-    def layer(self, index: int) -> dict:
-        import jax.numpy as jnp
-
-        layers = self._params["layers"]
-        out = {}
-        for name in self._MATRICES:
-            leaf = layers[name]
-            if isinstance(leaf, dict):
-                out[name] = (
-                    leaf["q"][index].astype(jnp.float32)
-                    * leaf["s"][index].astype(jnp.float32)[None, :]
-                )
-            else:
-                out[name] = leaf[index].astype(jnp.float32)
-        for name in self._VECTORS:
-            if name in layers:
-                out[name] = layers[name][index].astype(jnp.float32)
-        return out
+        g = self._generator
+        state = (g.params, getattr(g, "paged_cache", None), getattr(g, "cache", None))
+        g.params = g.paged_cache = g.cache = None
+        for leaf in jax.tree_util.tree_leaves(state):
+            if isinstance(leaf, jax.Array) and not leaf.is_deleted():
+                leaf.delete()
 
 
 def operator_config(engine_map: dict) -> Any:

@@ -1,5 +1,7 @@
-"""One cell, once: set-up, the correctness probe, warm-up, the measured
-window, and the reduction to the contract's last line.
+"""One cell, once: set-up, warm-up, the measured window, the reduction to
+the contract's last line and, once the engine is closed and freed, the
+reference over a sample of what the window served, which decides
+``correct``.
 """
 
 from __future__ import annotations
@@ -18,9 +20,6 @@ from typing import Any, Optional
 from . import loops, stats
 from .manifest import Manifest, load_json
 
-#: greedy tokens decoded per probe prompt, and how many prompts
-PROBE_TOKENS = 16
-PROBE_PROMPTS = 2
 #: requests a closed-loop client has to draw from before it wraps
 POOL_PER_CLIENT = 64
 #: seconds of the window a traced run traces, from its middle
@@ -67,6 +66,17 @@ class Spec:
         the seed then makes only the prompts' words."""
         return self.traffic.get("structure_seed", seed)
 
+    def greedy(self) -> tuple:
+        """``(every, sampling)`` of the mix's ``greedy`` group: every
+        ``every``-th request (open loop) or client (closed loop) is sent at
+        that sampling, and what it is served is what the reference reads.
+        A mix without the group has no such request, and its cells can
+        never read ``correct: true``."""
+        group = self.traffic.get("greedy")
+        if not group:
+            return 0, None
+        return int(group["every"]), dict(group["sampling"])
+
     def prompts(self, seed: Any, at_s: list) -> list:
         params = self.traffic["prompts"]
         if "structure_seed" in self.traffic:
@@ -91,62 +101,134 @@ class Run:
 # -- set-up ---------------------------------------------------------------
 
 
-async def probe(spec: Spec, handle: Any, seed: int) -> tuple[bool, list]:
-    """Correctness (a): greedy tokens through the engine against the
-    float32 reference on the engine's own parameters.  Returns the verdict
-    and the prompts used (the warm-up re-asks one)."""
-    from benchmark.reference import decoder_f32
+class SetupClock:
+    """Seconds of each part of set-up, in the order they ran: a part ends
+    where ``lap`` names it, and the next begins there."""
 
-    arch = spec.config["architecture"]
-    prompts = spec.prompts(f"probe:{seed}", [0.0] * PROBE_PROMPTS)
-    meter = loops.TokenMeter()
-    weights = handle.reference_weights()
-    gaps: list = []
-    ok = True
-    for i, prompt in enumerate(prompts):
-        req = loops.Request(index=i, prompt=prompt, max_tokens=PROBE_TOKENS)
-        req.due_t = time.perf_counter()
-        await loops.send(
-            handle, req, {"temperature": 0.0, "top_p": 1.0, "stop_on_eos": False},
-            meter, keep_ids=True,
+    def __init__(self, started: float) -> None:
+        self.parts: dict = {}
+        self._at = started
+
+    def lap(self, name: str, now: Optional[float] = None) -> None:
+        now = time.perf_counter() if now is None else now
+        self.parts[name] = self.parts.get(name, 0.0) + now - self._at
+        self._at = now
+
+
+def probe_group(spec: Spec) -> tuple:
+    """``(reference module, its weights module, the probe group)`` of the
+    cell's configuration file.  A file that names no reference is an
+    error: what decides ``correct`` is never a default."""
+    name = spec.config.get("reference")
+    if not name:
+        raise ValueError(
+            f"configuration {spec.cell['config']!r} names no reference: its file "
+            'needs "reference": "<module under <path>/reference/>" and a "probe" '
+            "group (benchmark/README.md, 'A reference')"
         )
-        if req.error is not None or len(req.token_ids or []) != PROBE_TOKENS:
-            log("probe request failed:", req.error, req.token_ids)
-            ok = False
-            continue
-        prompt_ids = handle.prompt_ids(prompt, PROBE_TOKENS)
-        gaps.extend(decoder_f32.greedy_gaps(weights, arch, prompt_ids, req.token_ids))
-    worst = max(gaps) if gaps else math.inf
-    ok = ok and worst <= decoder_f32.LOGIT_TOLERANCE
+    group = spec.config.get("probe")
+    if not isinstance(group, dict) or not {"requests", "limit"} <= set(group):
+        raise ValueError(
+            f"configuration {spec.cell['config']!r} needs a \"probe\" group with "
+            "requests and limit (and why, origin, readings)"
+        )
+    reference = spec.manifest.module("reference", name)
+    own = spec.manifest.module("reference", reference.WEIGHTS)
+    return reference, own, group
+
+
+def judge(gaps: list, group: dict) -> dict:
+    """The numbers compared, each beside its limit: the largest gap
+    against ``limit`` and, where the group has a ``soft`` rule, that
+    percentile of the gaps against its limit.  Nothing where no sequence
+    came back: the count of missing requests then says so."""
+    flat = [g for row in gaps for g in row]
+    if not flat:
+        return {}
+    compared = {"served_gap_max": {"value": max(flat), "limit": float(group["limit"])}}
+    soft = group.get("soft")
+    if soft:
+        compared[f"served_gap_p{soft['percentile']:g}"] = {
+            "value": stats.percentile(flat, float(soft["percentile"])),
+            "limit": float(soft["limit"]),
+        }
+    return compared
+
+
+def served_sample(spec: Spec, handle: Any, window: loops.Window, seed: int) -> list:
+    """Correctness (a), its first half, while the engine lives: a sample,
+    drawn from the seed, of the greedy requests that the window finished,
+    the longest among them, as ``[(prompt ids, served ids), ...]``."""
+    _, _, group = probe_group(spec)
+    done = [
+        r for r in window.sent
+        if r.sampling is not None and r.finished and r.token_ids
+        and window.t0 <= r.last_t <= window.t1
+    ]
+    done.sort(key=lambda r: (-(r.prompt_tokens + len(r.token_ids)), r.index))
+    count = int(group["requests"])
+    rest = done[1:]
+    random.Random(f"served:{seed}").shuffle(rest)
+    sample = sorted(done[:1] + rest[:count - 1], key=lambda r: r.index)
     log(
-        f"probe: {len(gaps)} greedy tokens, largest gap to the reference's "
-        f"maximum {worst:.4f} (tolerance {decoder_f32.LOGIT_TOLERANCE}), "
-        f"{sum(1 for g in gaps if g > 0)} not the reference's own choice"
+        f"served sample: {len(sample)} of the {len(done)} greedy requests the window "
+        f"finished, {sum(len(r.token_ids) for r in sample)} served tokens"
     )
-    return ok, prompts
+    return [(handle.prompt_ids(r.prompt, r.max_tokens), r.token_ids) for r in sample]
 
 
-async def warm_up(spec: Spec, handle: Any, probe_prompts: list) -> None:
-    """Admit 1, 2, 3, ... rows at once: the scheduler's page-table update
-    compiles once per number of rows staged in a step.  Then re-ask a probe
-    prompt, so that the prefix-hit path has run too."""
+def compare_served(spec: Spec, sequences: list, clock: Optional[SetupClock] = None) -> dict:
+    """Correctness (a), its second half, once the window has closed, the
+    memory peak has been read and the program's state is freed: the
+    configuration's own reference, on weights of its own, teacher-forced
+    over each sampled prompt with its served tokens; each number beside
+    its limit."""
+    reference, own, group = probe_group(spec)
+    clock = clock or SetupClock(time.perf_counter())
+    gaps = []
+    if sequences:
+        weights = own.make(spec.config)
+        clock.lap("reference_weights")
+        gaps = reference.greedy_gaps(spec.config, weights, sequences)
+        clock.lap("reference_forward")
+    compared = judge(gaps, group)
+    compared["served_requests_missing"] = {
+        "value": max(0, int(group["requests"]) - len(sequences)), "limit": 0,
+    }
+    flat = [g for row in gaps for g in row]
+    log(
+        f"served tokens against the reference: {len(flat)} of {len(sequences)} requests, "
+        f"largest gap to the reference's maximum {max(flat, default=math.nan):.4f} (limit "
+        f"{group['limit']}: {group.get('origin', 'no origin given')}), "
+        f"{sum(1 for g in flat if g > 0)} not the reference's own choice"
+    )
+    return compared
+
+
+async def warm_up(spec: Spec, handle: Any, seed: int) -> None:
+    """Two prompts of the mix, one of them greedy, then one of them again,
+    so that long prompts, both samplings and the prefix-hit path have run;
+    then admit 1, 2, 3, ... rows at once: the scheduler's page-table update
+    compiles once per number of rows staged in a step."""
     warm = spec.traffic.get("warmup", {})
-    rows = int(warm.get("rows_at_once", 8))
-    rows = min(rows, handle.slots)
+    rows = min(int(warm.get("rows_at_once", 8)), handle.slots)
     sampling = dict(spec.traffic["sampling"])
     meter = loops.TokenMeter()
+    _, greedy = spec.greedy()
+    first = [
+        loops.Request(index=i, prompt=p, max_tokens=8, sampling=greedy if i == 0 else None)
+        for i, p in enumerate(spec.prompts(f"warm-up:{seed}", [0.0, 0.0]))
+    ]
     line = "warm-up row {k}.{j}: status: container app terminated exit code 137 reason=OOMKilled"
-    for k in range(1, rows + 1):
-        batch = [
-            loops.Request(index=j, prompt=line.format(k=k, j=j), max_tokens=2)
-            for j in range(k)
-        ]
+    batches = [first, [dataclasses.replace(first[1], max_tokens=2)]] + [
+        [loops.Request(index=j, prompt=line.format(k=k, j=j), max_tokens=2) for j in range(k)]
+        for k in range(1, rows + 1)
+    ]
+    for batch in batches:
         await asyncio.gather(*(loops.send(handle, r, sampling, meter) for r in batch))
         errors = [r.error for r in batch if r.error]
         if errors:
             raise RuntimeError(f"warm-up request failed: {errors[0]}")
-    again = loops.Request(index=0, prompt=probe_prompts[0], max_tokens=2)
-    await loops.send(handle, again, sampling, meter)
 
 
 # -- the window -----------------------------------------------------------
@@ -162,8 +244,12 @@ def build_open(spec: Spec, seed: int, seconds: float, rate: Optional[float] = No
     prompts = spec.prompts(seed, due)
     rng = random.Random(f"max_tokens:{shape}")
     max_tokens = stats.draw_ints(rng, spec.traffic["max_tokens"], len(due))
+    every, greedy = spec.greedy()
     return [
-        loops.Request(index=i, prompt=p, due_t=t, max_tokens=m)
+        loops.Request(
+            index=i, prompt=p, due_t=t, max_tokens=m,
+            sampling=greedy if every and i % every == 0 else None,
+        )
         for i, (t, p, m) in enumerate(zip(due, prompts, max_tokens))
     ]
 
@@ -179,10 +265,12 @@ def build_closed(spec: Spec, seed: int, slots: int) -> list:
         stats.draw_ints(rng, spec.traffic["max_tokens"], clients)
         for _ in range(POOL_PER_CLIENT - 1)
     ]
+    every, greedy = spec.greedy()
     return [
         [
             loops.Request(
-                index=0, prompt=prompts[c * POOL_PER_CLIENT + k], max_tokens=rounds[k][c]
+                index=0, prompt=prompts[c * POOL_PER_CLIENT + k], max_tokens=rounds[k][c],
+                sampling=greedy if every and c % every == 0 else None,
             )
             for k in range(POOL_PER_CLIENT)
         ]
@@ -193,15 +281,19 @@ def build_closed(spec: Spec, seed: int, slots: int) -> list:
 async def measure(
     spec: Spec, handle: Any, seed: int, seconds: float,
     trace_dir: Optional[str] = None, rate: Optional[float] = None,
+    clock: Optional[SetupClock] = None,
 ) -> loops.Window:
     sampling = dict(spec.traffic["sampling"])
+    clock = clock or SetupClock(time.perf_counter())
     if spec.traffic["loop"] == "open":
+        requests = build_open(spec, seed, seconds, rate)
+        clock.lap("traffic")
         return await loops.open_loop(
-            handle, build_open(spec, seed, seconds, rate), seconds, sampling,
-            trace_dir, TRACE_SLICE_S,
+            handle, requests, seconds, sampling, trace_dir, TRACE_SLICE_S,
         )
     if spec.traffic["loop"] == "closed":
         pools = build_closed(spec, seed, handle.slots)
+        clock.lap("traffic")
         # the first prompts of all the clients are prefilled before the window
         ramp_s = max(
             float(spec.traffic.get("ramp_s", 0.0)),
@@ -312,6 +404,17 @@ def describe(window: loops.Window) -> dict:
     return out
 
 
+def save_setup(manifest: Manifest, workload: str, seed: int, setup_s: float, parts: dict) -> None:
+    """The seconds of each part of the run, to the log and under ``out/``
+    (no metric: ``setup_s`` is the sum of those up to ``ramp``, and the one
+    that is judged; ``window`` and what follows it are in none)."""
+    log("set-up parts (s):", json.dumps({k: round(v, 3) for k, v in parts.items()}))
+    out_dir = os.path.join(manifest.paths[0], "out", "setup")
+    os.makedirs(out_dir, exist_ok=True)
+    with open(os.path.join(out_dir, f"{workload}.seed{seed}.json"), "w") as f:
+        json.dump({"setup_s": setup_s, "parts": parts}, f)
+
+
 def save_requests(manifest: Manifest, workload: str, seed: int, window: loops.Window) -> None:
     """The window's request records, under ``out/``: what another statistic
     would have read, without another run."""
@@ -339,7 +442,9 @@ async def run_cell(
     spec = Spec.load(manifest, workload)
     entry = manifest.module("entries", spec.config.get("entry", "engine"))
     configure_jax()
+    clock = SetupClock(started)
     handle = entry.build(spec.config)
+    closed = False
     try:
         device = device_info()
         if device["count"] < int(spec.cell["chips"]):
@@ -348,15 +453,18 @@ async def run_cell(
             )
         peaks = load_peaks(manifest, device)
         log(f"engine built in {time.perf_counter() - started:.1f}s on", device)
-        probe_ok, probe_prompts = await probe(spec, handle, seed)
-        await warm_up(spec, handle, probe_prompts)
+        clock.lap("engine_build")
+        await warm_up(spec, handle, seed)
+        clock.lap("warm_up")
         trace_dir = None
         if trace:
             trace_dir = os.path.join(manifest.paths[0], "out", "trace", workload)
             os.makedirs(trace_dir, exist_ok=True)
         log(f"warm after {time.perf_counter() - started:.1f}s; measuring {seconds}s")
-        window = await measure(spec, handle, seed, seconds, trace_dir)
+        window = await measure(spec, handle, seed, seconds, trace_dir, clock=clock)
         setup_s = window.t0 - started  # a closed loop's ramp is set-up too
+        clock.lap("ramp", window.t0)
+        clock.lap("window")  # the drain included; from here on, in no metric
         steps = handle.step_records(window.first_step, window.end_step)
         in_window_ok, problems = window_correct(handle, window)
         for problem in problems[:10]:
@@ -368,7 +476,7 @@ async def run_cell(
         log("end to end:", json.dumps(numbers))
         save_requests(manifest, workload, seed, window)
         line = {
-            "correct": bool(probe_ok and in_window_ok),
+            "correct": False,  # decided last, below
             "attempted": len(window.attempted),
             "failed": sum(1 for r in window.attempted if r.failed),
             "metrics": {},
@@ -381,25 +489,40 @@ async def run_cell(
                     line["metrics"][metric["name"]] = {
                         "value": value, "unit": metric["unit"],
                     }
-            return line
-        run = Run(spec, handle, window, steps, device, peaks)
-        if window.trace_dir and device["platform"] == "tpu":
-            from benchmark.trace import reduce as trace_reduce
+        else:
+            run = Run(spec, handle, window, steps, device, peaks)
+            if window.trace_dir and device["platform"] == "tpu":
+                from benchmark.trace import reduce as trace_reduce
 
-            run.trace = trace_reduce.reduce_dir(window.trace_dir)
-            device["busy_s"] = run.trace["busy_s"]
-            device["window_s"] = run.trace["window_s"]
-            line["breakdown"] = {
-                "device_ops": run.trace["device_ops"][:10],
-                "idle_gaps": run.trace["idle_gaps"][:10],
-            }
-        for metric in manifest.metrics_for("per_layer", workload):
-            reader = manifest.module("layer_metrics", metric["name"])
-            value = reader.read(run)
-            if value is not None and math.isfinite(value):
-                line["metrics"][metric["name"]] = {
-                    "value": value, "unit": metric["unit"],
+                run.trace = trace_reduce.reduce_dir(window.trace_dir)
+                device["busy_s"] = run.trace["busy_s"]
+                device["window_s"] = run.trace["window_s"]
+                line["breakdown"] = {
+                    "device_ops": run.trace["device_ops"][:10],
+                    "idle_gaps": run.trace["idle_gaps"][:10],
                 }
+            for metric in manifest.metrics_for("per_layer", workload):
+                reader = manifest.module("layer_metrics", metric["name"])
+                value = reader.read(run)
+                if value is not None and math.isfinite(value):
+                    line["metrics"][metric["name"]] = {
+                        "value": value, "unit": metric["unit"],
+                    }
+        # the reference comes last: the window has closed, the memory peak has
+        # been read, and the program's state is freed before its weights are made
+        sequences = served_sample(spec, handle, window, seed)
+        await handle.close()
+        closed = True
+        clock.lap("reduce_and_close")
+        compared = compare_served(spec, sequences, clock)
+        save_setup(manifest, workload, seed, setup_s, clock.parts)
+        compared["window_requests_wrong"] = {"value": len(problems), "limit": 0}
+        line["correct"] = bool(
+            in_window_ok
+            and all(entry["value"] <= entry["limit"] for entry in compared.values())
+        )
+        line["compared"] = compared  # last, each number beside its limit
         return line
     finally:
-        await handle.close()
+        if not closed:
+            await handle.close()

@@ -46,7 +46,10 @@ class Request:
     finish_reason: Optional[str] = None
     queue_wait_ms: Optional[float] = None
     ids_in_vocab: Optional[bool] = None
-    token_ids: Optional[list] = None  # kept only where asked (the probe)
+    #: this request's own sampling, where it is not the mix's: a greedy
+    #: request (``Spec.greedy``), whose served ids are kept for the reference
+    sampling: Optional[dict] = None
+    token_ids: Optional[list] = None  # served ids, kept for greedy requests only
 
     @property
     def ttft_ms(self) -> Optional[float]:
@@ -72,13 +75,11 @@ class TokenMeter:
         self.delivered = 0
 
 
-async def send(
-    handle: Any, req: Request, sampling: dict, meter: TokenMeter,
-    keep_ids: bool = False,
-) -> None:
-    """Send one request and fill in its record; never raises except
-    cancellation."""
+async def send(handle: Any, req: Request, sampling: dict, meter: TokenMeter) -> None:
+    """Send one request, at its own sampling where it has one, and fill in
+    its record; never raises except cancellation."""
     eos = handle.eos_id
+    keep_ids = req.sampling is not None
 
     def on_partial(ids: list) -> None:
         now = time.perf_counter()
@@ -91,10 +92,14 @@ async def send(
             meter.delivered += new
             req.tokens = len(ids)
             req.last_t = now
+            if keep_ids:
+                req.token_ids = list(ids)  # as streamed: EOS ids included
 
     req.sent_t = time.perf_counter()
     try:
-        result = await handle.generate(req.prompt, req.max_tokens, sampling, on_partial)
+        result = await handle.generate(
+            req.prompt, req.max_tokens, req.sampling or sampling, on_partial
+        )
     except asyncio.CancelledError:
         raise
     except Exception as exc:  # noqa: BLE001 - a refused or failed request is a result
@@ -115,7 +120,9 @@ async def send(
     req.finish_reason = result.finish_reason
     req.queue_wait_ms = float(result.queue_wait_ms)
     req.ids_in_vocab = all(0 <= t < handle.vocab_size for t in result.token_ids)
-    if keep_ids:
+    if keep_ids and len(result.token_ids) == req.max_tokens:
+        # the answer as the user got it; where the program filtered an EOS id
+        # out of it, the ids streamed so far stand, whose positions are known
         req.token_ids = list(result.token_ids)
 
 
@@ -132,6 +139,7 @@ class Window:
     compiles: list
     trace_dir: Optional[str] = None  # the profiler's log directory, traced runs
     pool: list = dataclasses.field(default_factory=list)  # Handle.pool_pages() samples, traced runs
+    sent: list = dataclasses.field(default_factory=list)  # every request sent, a closed loop's ramp included
 
     @property
     def seconds(self) -> float:
@@ -190,8 +198,8 @@ def _start_tracer(
 
 async def _close_window(
     handle: Any, tasks: list, tracer: list, t0: float, t1: float,
-    attempted: list, delivered: int, first_step: int, trace_dir: Optional[str],
-    pool: list,
+    attempted: list, sent: list, delivered: int, first_step: int,
+    trace_dir: Optional[str], pool: list,
 ) -> Window:
     """Read the counters at the window's end, stop the pool sampler,
     drain, cancel, and wait for the tracer."""
@@ -203,7 +211,7 @@ async def _close_window(
     if tracer:
         await tracer[0]
     return Window(
-        t0, t1, attempted, delivered, first_step, end_step, compiles, trace_dir, pool
+        t0, t1, attempted, delivered, first_step, end_step, compiles, trace_dir, pool, sent
     )
 
 
@@ -242,7 +250,7 @@ async def open_loop(
                 tasks.append(asyncio.create_task(send(handle, req, sampling, meter)))
         t1 = await _sleep_until(t0 + seconds)
     return await _close_window(
-        handle, tasks, tracer, t0, t1, requests, meter.delivered, first_step,
+        handle, tasks, tracer, t0, t1, requests, requests, meter.delivered, first_step,
         trace_dir, pool_samples,
     )
 
@@ -280,6 +288,6 @@ async def closed_loop(
         t1 = await _sleep_until(t0 + seconds)
     attempted = [r for r in sent if t0 <= r.due_t < t1]
     return await _close_window(
-        handle, tasks, tracer, t0, t1, attempted, meter.delivered - delivered0,
+        handle, tasks, tracer, t0, t1, attempted, sent, meter.delivered - delivered0,
         first_step, trace_dir, pool_samples,
     )

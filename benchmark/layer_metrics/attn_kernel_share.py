@@ -1,7 +1,9 @@
-"""Device time of the ragged paged-attention kernel over device busy time,
-from the trace.  ``PATTERN`` matches the kernel's events under the names
-the trace prints today (the ``pallas_call`` has no stable ``name`` yet;
-giving it one is on the list for the tracing issue)."""
+"""Device time of the attention kernels over device busy time, from the
+trace.  The convention (``benchmark/README.md``): an attention kernel's
+``pallas_call`` is given a ``name`` that ends in ``attention_kernel`` (the
+program's is ``ragged_attention_kernel``, ``ops/ragged_attention.py
+KERNEL_NAME``), so a later attention kernel is counted by naming it so and
+any other Pallas kernel (a grouped expert product, say) is not."""
 
 import re
 
@@ -11,7 +13,7 @@ LAYER = "kernels"
 MOVES = "token_gap_mean_ms"
 SOURCE = "device_trace"
 
-PATTERN = re.compile(r"ragged_attention|pallas", re.IGNORECASE)
+PATTERN = re.compile(r"attention_kernel", re.IGNORECASE)
 
 
 def read(run):

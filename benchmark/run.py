@@ -54,6 +54,11 @@ def main(argv: "list[str] | None" = None) -> int:
     line = asyncio.run(run_cell(
         manifest, args.workload, args.seed, args.seconds, bool(args.trace), _STARTED
     ))
+    for name, entry in line["compared"].items():  # the last lines on stderr
+        print(
+            f"[benchmark] compared {name}: {entry['value']} (limit {entry['limit']})",
+            file=sys.stderr,
+        )
     sys.stderr.flush()
     print(json.dumps(line), flush=True)
     return 0

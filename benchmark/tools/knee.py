@@ -73,8 +73,7 @@ async def sweep(manifest, workload: str, seed: int, seconds: float, rates: list)
     handle = entry.build(spec.config)
     out = []
     try:
-        _, probe_prompts = await cell.probe(spec, handle, seed)
-        await cell.warm_up(spec, handle, probe_prompts)
+        await cell.warm_up(spec, handle, seed)
         cell.log(f"set-up {time.perf_counter() - _STARTED:.1f}s")
         for i, rate in enumerate(rates):
             window = await cell.measure(spec, handle, seed + i, seconds, rate=rate)

@@ -128,8 +128,7 @@ async def run(manifest, workload: str, seed: int, seconds: float) -> dict:
     cell.configure_jax()
     handle = Tap(entry.build(spec.config))
     try:
-        _, probe_prompts = await cell.probe(spec, handle, seed)
-        await cell.warm_up(spec, handle, probe_prompts)
+        await cell.warm_up(spec, handle, seed)
         cell.log(f"set-up {time.perf_counter() - _STARTED:.1f}s")
         window = await cell.measure(spec, handle, seed, seconds)
         steps = handle.step_records(window.first_step, window.end_step)

@@ -26,9 +26,22 @@ from benchmark.harness import loops, stats  # noqa: E402
 from benchmark.harness.manifest import NAME, UNIT, Manifest, load_json  # noqa: E402
 from benchmark.trace import reduce as trace_reduce  # noqa: E402
 
-MANIFESTS = ("BENCHMARK.json", "tests/benchmark/rehearsal.json")
+MANIFESTS = (
+    "BENCHMARK.json", "tests/benchmark/rehearsal.json",
+    "tests/benchmark/rehearsal-reference.json",
+)
 #: keys `reduced` may never name (the contract: no width is ever cut)
 WIDTH_WORDS = ("hidden", "intermediate", "latent", "state", "proj", "head_dim", "expand")
+#: and: a key that ends so, or the number of experts a token is sent to
+WIDTH_ENDINGS = ("_dim", "_rank", "_per_tok", "_per_token")
+
+
+def is_width(key):
+    """A count of layers is depth (``num_hidden_layers``), whatever words
+    its name holds; everything else that names a width word is a width."""
+    if key.endswith("_layers"):
+        return False
+    return any(word in key for word in WIDTH_WORDS) or key.endswith(WIDTH_ENDINGS)
 
 
 @pytest.fixture(scope="module")
@@ -91,7 +104,43 @@ def test_engine_keys_are_operator_config_fields(manifest):
         entry.operator_config({"no_such_knob": 1})
 
 
+def reference_modules(manifest, config):
+    """``(reference, its weights module)`` as the harness finds them for a file."""
+    reference = manifest.module("reference", config["reference"])
+    return reference, manifest.module("reference", reference.WEIGHTS)
+
+
+def cut_problems(item, config):
+    """What is wrong with how a configuration states its cut.  Every key in
+    ``reduced`` is a key of ``architecture`` (the size held, which is what
+    the program is built with), has its published value beside it in a
+    ``published`` group, names no width, and the file states the
+    deployment the share is of.  With ``reduced`` empty nothing is asked."""
+    problems = []
+    if sorted(item["reduced"]) != sorted(config.get("reduced", [])):
+        problems.append("the manifest's `reduced` is not the file's")
+    published = config.get("published", {})
+    for key in item["reduced"]:
+        if is_width(key):
+            problems.append(f"{key} is a width: no width is ever cut")
+        if key not in config["architecture"]:
+            problems.append(f"{key} is reduced but `architecture` does not hold its size")
+        if key not in published:
+            problems.append(f"{key} is reduced but `published` does not give the source's value")
+        elif published[key] == config["architecture"].get(key):
+            problems.append(f"{key} is listed as reduced but equals the published value")
+    if item["reduced"] and len(config.get("deployment", "")) < 20:
+        problems.append("a cut configuration states the deployment its share is of")
+    if set(published) - set(item["reduced"]):
+        problems.append(f"`published` gives {sorted(set(published) - set(item['reduced']))}, not in `reduced`")
+    return problems
+
+
 def test_configuration_files_match_the_program_and_cut_no_width(manifest):
+    """Every configuration through its own table: which ``architecture``
+    keys are held to which attribute of the program's model configuration
+    is the adapter's ``PROGRAM_CONFIG``, beside the reference the file
+    names.  Every key of ``architecture`` is held to something."""
     from operator_tpu.models import get_config
 
     used = {cell["config"] for cell in manifest.doc["workloads"]}
@@ -101,20 +150,125 @@ def test_configuration_files_match_the_program_and_cut_no_width(manifest):
         assert item["name"] in used
         assert any(item["file"].startswith(p + "/") for p in manifest.doc["paths"])
         assert 1 <= len(item["source"]) <= 200 and 1 <= len(item["why"]) <= 200
-        assert not [k for k in item["reduced"] if any(w in k for w in WIDTH_WORDS)]
         config = manifest.config(item["name"])
+        assert cut_problems(item, config) == [], item["name"]
+        _, adapter = reference_modules(manifest, config)
         arch, program = config["architecture"], get_config(config["model_id"])
-        assert (
-            arch["num_hidden_layers"], arch["hidden_size"], arch["intermediate_size"],
-            arch["num_attention_heads"], arch["num_key_value_heads"], arch["head_dim"],
-            arch["vocab_size"], arch["tie_word_embeddings"], arch["attention_bias"],
-            arch["rope_theta"], arch["rms_norm_eps"],
-        ) == (
-            program.num_layers, program.hidden_size, program.intermediate_size,
-            program.num_heads, program.num_kv_heads, program.head_dim,
-            program.vocab_size, program.tie_embeddings, program.attention_bias,
-            program.rope_theta, program.rms_norm_eps,
-        )
+        assert set(arch) == set(adapter.PROGRAM_CONFIG), item["name"]
+        for key, attribute in adapter.PROGRAM_CONFIG.items():
+            assert arch[key] == getattr(program, attribute), (item["name"], key)
+
+
+CUT = {
+    "architecture": {"num_hidden_layers": 5, "n_routed_experts": 16, "vocab_size": 19200,
+                     "hidden_size": 7680},
+    "reduced": ["num_hidden_layers", "n_routed_experts", "vocab_size"],
+    "published": {"num_hidden_layers": 61, "n_routed_experts": 256, "vocab_size": 153600},
+    "deployment": "one of 16 chips that share each layer: experts 16 ways, attention whole",
+}
+
+
+@pytest.mark.parametrize(
+    "change, problem",
+    [
+        ({}, None),
+        ({"reduced": ["num_hidden_layers", "n_routed_experts", "vocab_size", "hidden_size"],
+          "published": {**CUT["published"], "hidden_size": 15360}}, "is a width"),
+        ({"reduced": CUT["reduced"] + ["num_experts_per_tok"],
+          "published": {**CUT["published"], "num_experts_per_tok": 8}}, "is a width"),
+        ({"reduced": CUT["reduced"] + ["kv_lora_rank"],
+          "published": {**CUT["published"], "kv_lora_rank": 512}}, "is a width"),
+        ({"published": {"num_hidden_layers": 61, "vocab_size": 153600}}, "does not give the source's value"),
+        ({"published": {**CUT["published"], "vocab_size": 19200}}, "equals the published value"),
+        ({"architecture": {"num_hidden_layers": 5, "vocab_size": 19200}}, "does not hold its size"),
+        ({"deployment": "none"}, "states the deployment"),
+        ({"reduced": ["num_hidden_layers", "n_routed_experts"]}, "not in `reduced`"),
+    ],
+)
+def test_a_cut_states_the_size_held_and_the_published_size(change, problem):
+    config = {**CUT, **change}
+    item = {"reduced": list(config["reduced"])}
+    problems = cut_problems(item, config)
+    if problem is None:
+        assert problems == []
+    else:
+        assert any(problem in p for p in problems), problems
+    # the manifest's list and the file's are one list
+    assert cut_problems({"reduced": []}, CUT)[0].startswith("the manifest's `reduced`")
+
+
+def test_every_configuration_names_its_reference_and_its_probe(manifest):
+    for item in manifest.doc["configs"]:
+        config = manifest.config(item["name"])
+        reference, own = reference_modules(manifest, config)
+        assert callable(reference.greedy_gaps) and callable(own.make) and callable(own.adapt)
+        # the recipe the reference makes its own weights from, and nothing of the program's
+        assert {"seed", "init", "dtype", "bits"} <= set(config["weights"]), item["name"]
+        probe = config["probe"]
+        assert probe["requests"] >= 4  # served greedy requests compared, the longest among them
+        assert 0 < probe["limit"] < 1.0  # far under what an order-one fault reads: 2 to 5
+        for key in ("origin", "why"):
+            assert len(probe[key]) > 20 and "TODO" not in probe[key], (item["name"], key)
+        assert {"sound", "control", "measured_by"} <= set(probe["readings"]), item["name"]
+        if "soft" in probe:
+            assert probe["soft"]["limit"] < probe["limit"] and 50 <= probe["soft"]["percentile"] < 100
+
+
+def test_a_configuration_that_names_no_reference_is_an_error(in_root):
+    from benchmark.harness import cell
+
+    rehearsal = Manifest(os.path.join(ROOT, "tests/benchmark/rehearsal.json"))
+    spec = cell.Spec.load(rehearsal, "tiny-test.decode")
+    nameless = {k: v for k, v in spec.config.items() if k != "reference"}
+    with pytest.raises(ValueError, match="names no reference"):
+        cell.probe_group(dataclasses.replace(spec, config=nameless))
+    unsized = {k: v for k, v in spec.config.items() if k != "probe"}
+    with pytest.raises(ValueError, match="needs a \"probe\" group"):
+        cell.probe_group(dataclasses.replace(spec, config=unsized))
+    with pytest.raises(FileNotFoundError, match="no reference/nowhere.py"):
+        cell.probe_group(dataclasses.replace(spec, config={**spec.config, "reference": "nowhere"}))
+    reference, own, group = cell.probe_group(spec)
+    assert (reference.__name__, own.__name__) == (
+        "benchmark.reference.decoder_f32", "benchmark.reference.decoder_f32_weights",
+    )
+    assert group is spec.config["probe"]
+
+
+def test_the_harness_imports_no_reference_by_name(in_root):
+    for folder in ("benchmark/harness", "benchmark/entries"):
+        for name in sorted(os.listdir(os.path.join(ROOT, folder))):
+            if name.endswith(".py"):
+                with open(os.path.join(ROOT, folder, name), encoding="utf-8") as f:
+                    text = f.read()
+                assert "decoder_f32" not in text, (folder, name)
+                assert "PROBE_PROMPTS" not in text and "LOGIT_TOLERANCE" not in text
+                # what decides `correct` reads no weight the program made
+                if folder == "benchmark/harness":
+                    assert ".parameters(" not in text and ".adapt(" not in text, name
+    with open(os.path.join(ROOT, "benchmark/run.py"), encoding="utf-8") as f:
+        assert "decoder_f32" not in f.read()
+
+
+@pytest.mark.parametrize(
+    "gaps, group, names, ok",
+    [
+        ([[0.0, 0.01], [0.04]], {"limit": 0.05}, ["served_gap_max"], True),
+        ([[0.0, 0.01], [0.06]], {"limit": 0.05}, ["served_gap_max"], False),
+        ([[0.0] * 99 + [0.3]], {"limit": 0.5, "soft": {"percentile": 95, "limit": 0.1}},
+         ["served_gap_max", "served_gap_p95"], True),
+        ([[0.2] * 10 + [0.0] * 90], {"limit": 0.5, "soft": {"percentile": 95, "limit": 0.1}},
+         ["served_gap_max", "served_gap_p95"], False),
+        ([], {"limit": 0.05}, [], True),  # nothing came back: the failed count says so
+    ],
+)
+def test_the_probe_judges_each_number_against_its_own_limit(gaps, group, names, ok):
+    from benchmark.harness import cell
+
+    compared = cell.judge(gaps, group)
+    assert list(compared) == names
+    assert all(entry["value"] <= entry["limit"] for entry in compared.values()) is ok
+    for entry in compared.values():
+        assert set(entry) == {"value", "limit"}
 
 
 def test_names_units_and_text_use_the_permitted_characters(manifest):
@@ -463,60 +617,358 @@ def test_an_engine_reset_is_incorrect():
     assert cell.window_correct(Handle(), window)[0] is False
 
 
+# -- the greedy requests of a mix, and the sample the reference reads ---------
+
+
+def test_every_mix_sends_greedy_requests_through_the_sampler(manifest):
+    """What decides ``correct`` is read from requests of the window itself,
+    so every mix names which of them are greedy: at the sampler's floor
+    temperature with a top-p that keeps the first candidate alone, the
+    argmax through the same sampler as every other row (``temperature
+    <= 0`` would switch on the host's prompt-lookup drafting, a path no
+    other row of the mix takes)."""
+    from benchmark.harness import cell
+
+    for item in manifest.doc["workloads"]:
+        spec = cell.Spec.load(manifest, item["name"])
+        every, sampling = spec.greedy()
+        assert 2 <= every <= 16, item["name"]
+        assert 0 < sampling["temperature"] <= 1e-4 and 0 < sampling["top_p"] <= 1e-6
+        assert sampling["stop_on_eos"] is spec.traffic["sampling"]["stop_on_eos"]
+        if spec.traffic["loop"] == "open":
+            requests = cell.build_open(spec, 5, 20.0)
+            marked = [r.index for r in requests if r.sampling is not None]
+            assert marked == list(range(0, len(requests), every))
+        else:
+            pools = cell.build_closed(spec, 5, 16)
+            for c, pool in enumerate(pools):
+                assert all((r.sampling is not None) == (c % every == 0) for r in pool)
+        assert requests[0].sampling == sampling if spec.traffic["loop"] == "open" else True
+
+
+def _served(index, finished_at, prompt_tokens, ids, sampling={"temperature": 1e-4}, done=True):  # noqa: B006
+    return loops.Request(
+        index=index, prompt=f"prompt {index}", max_tokens=len(ids), finished=done,
+        last_t=finished_at, first_t=0.1, prompt_tokens=prompt_tokens, token_ids=ids,
+        sampling=sampling,
+    )
+
+
+def test_the_served_sample_is_of_the_windows_own_greedy_requests(in_root):
+    """Drawn from the seed, the longest among them, and only requests that
+    the window finished: not one that finished in the ramp or the drain,
+    not one that was sampled, not one still running."""
+    from benchmark.harness import cell
+
+    class Handle:
+        def prompt_ids(self, prompt, max_tokens):
+            return [int(prompt.split()[1])] * 3
+
+    spec = cell.Spec.load(Manifest(os.path.join(ROOT, "tests/benchmark/rehearsal.json")),
+                          "tiny-test.decode")
+    assert spec.config["probe"]["requests"] == 4
+    sent = [_served(i, 10.0 + i, 20 + i, [i] * (5 + i)) for i in range(8)]
+    sent += [
+        _served(8, 9.0, 90, [8] * 50),  # finished in the ramp
+        _served(9, 61.5, 90, [9] * 50),  # finished in the drain
+        _served(10, 30.0, 90, [10] * 50, sampling=None),  # sampled, ids not kept
+        _served(11, 30.0, 90, [11] * 50, done=False),  # still running at the close
+    ]
+    window = loops.Window(10.0, 61.0, sent[:4], 0, 0, 0, [], sent=sent)
+    first = cell.served_sample(spec, Handle(), window, 7)
+    assert len(first) == 4 and all(served[0] < 8 for _, served in first)
+    assert ([7] * 3, [7] * 12) in first  # the longest
+    assert first == cell.served_sample(spec, Handle(), window, 7)  # the same seed, the same sample
+    others = {tuple(s[0] for _, s in cell.served_sample(spec, Handle(), window, k)) for k in range(12)}
+    assert len(others) > 1 and all(7 in picked for picked in others)
+    # fewer finished than asked: the run says so, and cannot read correct
+    window = loops.Window(10.0, 61.0, sent[:2], 0, 0, 0, [], sent=sent[:2])
+    compared = cell.compare_served(spec, [])
+    assert compared["served_requests_missing"] == {"value": 4, "limit": 0}
+    assert list(compared) == ["served_requests_missing"]
+
+
+@pytest.mark.parametrize(
+    "result_ids, streamed, kept",
+    [
+        ([5, 6, 7, 8], [5, 6, 7], [5, 6, 7, 8]),  # whole: the answer as the user got it
+        ([5, 7, 8], [5, 2, 7], [5, 2, 7]),  # an EOS id filtered out: the ids as streamed
+    ],
+)
+def test_a_greedy_requests_served_ids_keep_their_positions(result_ids, streamed, kept):
+    import asyncio
+    import types
+
+    class Handle:
+        eos_id, vocab_size = 2, 100
+
+        async def generate(self, prompt, max_tokens, sampling, on_partial=None):
+            assert sampling == {"temperature": 1e-4}
+            for k in range(1, len(streamed) + 1):
+                on_partial(streamed[:k])
+            return types.SimpleNamespace(
+                token_ids=result_ids, completion_tokens=len(result_ids), prompt_tokens=3,
+                finish_reason="length", queue_wait_ms=0.0,
+            )
+
+    req = loops.Request(index=0, prompt="p", max_tokens=4, sampling={"temperature": 1e-4})
+    asyncio.run(loops.send(Handle(), req, {"temperature": 0.3}, loops.TokenMeter()))
+    assert req.finished and req.token_ids == kept
+    plain = loops.Request(index=0, prompt="p", max_tokens=4)
+
+    class Sampled(Handle):
+        async def generate(self, prompt, max_tokens, sampling, on_partial=None):
+            assert sampling == {"temperature": 0.3}  # the mix's own
+            return await Handle.generate(self, prompt, max_tokens, {"temperature": 1e-4}, on_partial)
+
+    asyncio.run(loops.send(Sampled(), plain, {"temperature": 0.3}, loops.TokenMeter()))
+    assert plain.finished and plain.token_ids is None  # kept for greedy requests only
+
+
 # -- the float32 reference against the program's forward pass ----------------
 
 
-@pytest.mark.parametrize("bias, tied", [(False, False), (True, False), (True, True)])
-def test_reference_matches_the_programs_forward(bias, tied):
+def tiny_model(bias=False, tied=False, seed=3):
+    """The tiny preset's quantised parameters, its configuration document
+    as a file would state it, and the program's ``ModelConfig``."""
     import jax
     import jax.numpy as jnp
 
     from operator_tpu.models import get_config
-    from operator_tpu.models.llama import forward, init_params
+    from operator_tpu.models.llama import init_params
     from operator_tpu.models.quant import quantize_params
-
-    from benchmark.entries.engine import ReferenceWeights
-    from benchmark.reference import decoder_f32
 
     config = dataclasses.replace(
         get_config("tiny-test"), attention_bias=bias, tie_embeddings=tied
     )
-    params = init_params(config, jax.random.PRNGKey(3), dtype=jnp.float32)
+    params = init_params(config, jax.random.PRNGKey(seed), dtype=jnp.float32)
     if bias:  # the seeded init leaves biases zero: a dropped one must show
-        keys = jax.random.split(jax.random.PRNGKey(4), 3)
+        keys = jax.random.split(jax.random.PRNGKey(seed + 1), 3)
         for key, name in zip(keys, ("bq", "bk", "bv")):
             shape = params["layers"][name].shape
             params["layers"][name] = 0.5 * jax.random.normal(key, shape, jnp.float32)
     params = quantize_params(params, config)
-    arch = {
+    doc = {"architecture": {
         "num_hidden_layers": config.num_layers, "hidden_size": config.hidden_size,
         "num_attention_heads": config.num_heads,
         "num_key_value_heads": config.num_kv_heads, "head_dim": config.head_dim,
         "vocab_size": config.vocab_size, "tie_word_embeddings": tied,
         "rope_theta": config.rope_theta, "rms_norm_eps": config.rms_norm_eps,
-    }
-    ids = [int(t) for t in jax.random.randint(jax.random.PRNGKey(5), (37,), 0, 512)]
-    weights = ReferenceWeights(params)
-    ours = decoder_f32.logits(weights, arch, decoder_f32.hidden_states(weights, arch, ids))
+    }}
+    return params, doc, config
+
+
+def program_logits(params, config, ids):
+    import jax.numpy as jnp
+
+    from operator_tpu.models.llama import forward
+
     theirs, _ = forward(
         params, config, jnp.asarray([ids], jnp.int32),
         jnp.arange(len(ids), dtype=jnp.int32)[None],
     )
+    return theirs[0]
+
+
+def tiny_limit():
+    return load_json(os.path.join(ROOT, "tests/benchmark/configs/tiny-test.json"))["probe"]["limit"]
+
+
+def tiny_doc():
+    """The tiny configuration's file: sizes, the weights' recipe, the limits."""
+    return load_json(os.path.join(ROOT, "tests/benchmark/configs/tiny-test.json"))
+
+
+def reference_logits(decoder, weights, arch, ids):
+    """Full logits of one sequence, in the test's own arithmetic: the
+    reference's hidden states through the head in numpy float64."""
+    import numpy as np
+
+    hidden = decoder.hidden_states(weights, arch, [ids])
+    assert hidden.shape[:2] == (1, 256)  # one sequence, padded
+    head = weights.embed.T if weights.head is None else weights.head
+    return np.asarray(hidden[0, :len(ids)], np.float64) @ np.asarray(head, np.float64)
+
+
+@pytest.mark.parametrize("bias, tied", [(False, False), (True, False), (True, True)])
+def test_reference_matches_the_programs_forward(bias, tied):
+    """The reference's arithmetic against the program's forward pass on one
+    tree (mapped by ``adapt``, layout only; biases set, which no init
+    makes).  What a run compares is on weights of the reference's own: the
+    next test."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmark.reference import decoder_f32, decoder_f32_weights
+
+    params, doc, config = tiny_model(bias, tied)
+    arch = doc["architecture"]
+    ids = [int(t) for t in jax.random.randint(jax.random.PRNGKey(5), (37,), 0, 512)]
+    weights = decoder_f32_weights.adapt(params, doc)
+    ours = reference_logits(decoder_f32, weights, arch, ids)
+    theirs = np.asarray(program_logits(params, config, ids))
     assert ours.shape == (37, 512)
-    assert float(jnp.max(jnp.abs(ours - theirs[0]))) < 2e-4
+    assert float(np.max(np.abs(ours - theirs))) < 2e-4
     # teacher-forced on the program's own greedy choices, every gap is ~0
-    chosen = [int(t) for t in jnp.argmax(theirs[0, 20:36], axis=-1)]
-    gaps = decoder_f32.greedy_gaps(weights, arch, ids[:21], ids[21:37])
+    chosen = [int(t) for t in jnp.argmax(theirs[20:36], axis=-1)]
+    (gaps,) = decoder_f32.greedy_gaps(doc, weights, [(ids[:21], ids[21:37])])
     assert len(gaps) == 16 and all(g >= 0 for g in gaps)
-    assert max(decoder_f32.greedy_gaps(weights, arch, ids[:21], chosen[:1])) < 1e-3
-    if bias:  # without the biases the logits move by far more than the tolerance
+    assert gaps == pytest.approx(
+        [float(ours[20 + j].max() - ours[20 + j, ids[21 + j]]) for j in range(16)], abs=1e-4
+    )
+    (first,) = decoder_f32.greedy_gaps(doc, weights, [(ids[:21], chosen[:1])])
+    assert max(first) < 1e-3
+    # all the sampled sequences at once, of unequal lengths: each reads what it
+    # reads alone (padding and the other rows are invisible to it)
+    together = decoder_f32.greedy_gaps(
+        doc, weights, [(ids[:21], ids[21:37]), (ids[:9], ids[9:12]), (ids[:21], chosen[:1])]
+    )
+    assert [len(row) for row in together] == [16, 3, 1]
+    assert together[0] == pytest.approx(gaps, abs=1e-4)
+    assert together[2] == pytest.approx(first, abs=1e-4)
+    if bias:  # without the biases the logits move by far more than the limit
         for name in ("bq", "bk", "bv"):
             params["layers"][name] = jnp.zeros_like(params["layers"][name])
-        dropped = decoder_f32.logits(
-            ReferenceWeights(params), arch,
-            decoder_f32.hidden_states(ReferenceWeights(params), arch, ids),
+        dropped = reference_logits(
+            decoder_f32, decoder_f32_weights.adapt(params, doc), arch, ids
         )
-        assert float(jnp.max(jnp.abs(dropped - ours))) > decoder_f32.LOGIT_TOLERANCE
+        assert float(np.max(np.abs(dropped - ours))) > tiny_limit()
+
+
+def differing_leaves(mine, theirs):
+    """Names of the leaves of two ``decoder_f32_weights.Weights`` that are
+    not equal bit for bit."""
+    import numpy as np
+
+    flat = lambda w: {  # noqa: E731
+        **{k: v for k, v in w.leaves.items() if k != "layers"},
+        **{
+            f"{name}.{part}": value
+            for name, leaf in w.layers.items()
+            for part, value in (leaf.items() if isinstance(leaf, dict) else [("", leaf)])
+        },
+    }
+    mine, theirs = flat(mine), flat(theirs)
+    assert set(mine) == set(theirs)
+    return sorted(
+        name for name in mine
+        if np.asarray(mine[name]).dtype != np.asarray(theirs[name]).dtype
+        or not np.array_equal(np.asarray(mine[name]), np.asarray(theirs[name]))
+    )
+
+
+def test_the_references_own_weights_are_the_programs_bit_for_bit():
+    """``decoder_f32_weights.make`` reads a recipe and nothing of the
+    program, and what it makes is the program's seeded init to the bit
+    (``tools/weights_check.py`` says the same on the chip at both sizes);
+    a program that quantised with another scale would differ in every
+    ``q``, which is what a reference fed the program's own ``q`` and ``s``
+    could never see."""
+    import jax
+    import jax.numpy as jnp
+
+    from benchmark.reference import decoder_f32_weights
+    from operator_tpu.models import get_config
+    from operator_tpu.models.quant import init_params_quantized
+
+    doc = tiny_doc()
+    with open(decoder_f32_weights.__file__, encoding="utf-8") as f:
+        assert "operator_tpu" not in f.read().replace("``operator_tpu", "")
+    mine = decoder_f32_weights.make(doc)
+    program = init_params_quantized(
+        get_config("tiny-test"), jax.random.PRNGKey(doc["weights"]["seed"]), dtype=jnp.bfloat16
+    )
+    assert differing_leaves(mine, decoder_f32_weights.adapt(program, doc)) == []
+    # a wrong scale in the program (126 levels in place of 127) is another model
+    wrong = dict(program["layers"])
+    w32 = wrong["wq"]["q"].astype(jnp.float32) * wrong["wq"]["s"][:, None, :]
+    scale = jnp.max(jnp.abs(w32), axis=-2) / 126.0
+    wrong["wq"] = {"q": jnp.round(w32 / scale[:, None, :]).astype(jnp.int8), "s": scale}
+    assert differing_leaves(
+        mine, decoder_f32_weights.adapt({**program, "layers": wrong}, doc)
+    ) == ["wq.q", "wq.s"]
+
+
+def test_the_rehearsals_own_reference_agrees_and_the_wrong_one_does_not(in_root):
+    """``tests/benchmark/reference/``: numpy float64, written apart, found by
+    the name in a configuration file, on weights it makes itself; the
+    interleaved rotary is another model."""
+    import numpy as np
+
+    rehearsal = Manifest(os.path.join(ROOT, "tests/benchmark/rehearsal-reference.json"))
+    config = rehearsal.config("tiny-own-reference")
+    own, weights_module = reference_modules(rehearsal, config)
+    wrong, _ = reference_modules(rehearsal, rehearsal.config("tiny-wrong-reference"))
+    for module in (own, wrong, weights_module):
+        assert module.__file__.startswith(os.path.join(ROOT, "tests/benchmark/reference/"))
+    params, doc, program = tiny_model(bias=True)
+    ids = [int(t) for t in np.random.default_rng(5).integers(0, 512, 37)]
+    theirs = np.asarray(program_logits(params, program, ids))
+    weights = weights_module.adapt(params, doc)
+    assert np.max(np.abs(own.forward(doc["architecture"], weights, ids) - theirs)) < 2e-4
+    sequences = [(ids[:21], [int(theirs[20].argmax())])]  # the program's own choice
+    assert max(own.greedy_gaps(doc, weights, sequences)[0]) < 1e-3
+    # the same gaps as the benchmark's reference reads, on arbitrary tokens
+    from benchmark.reference import decoder_f32, decoder_f32_weights
+
+    arbitrary = [(ids[:21], ids[21:37])]
+    assert own.greedy_gaps(doc, weights, arbitrary)[0] == pytest.approx(
+        decoder_f32.greedy_gaps(doc, decoder_f32_weights.adapt(params, doc), arbitrary)[0],
+        abs=1e-3,
+    )
+    # and on the weights each makes for itself from the file's recipe: two
+    # readers of one recipe, written apart, hold the same model
+    assert own.greedy_gaps(config, weights_module.make(config), arbitrary)[0] == pytest.approx(
+        decoder_f32.greedy_gaps(config, decoder_f32_weights.make(config), arbitrary)[0],
+        abs=5e-3,  # float64 against float32, on gaps of 3 to 6
+    )
+    assert max(wrong.greedy_gaps(doc, weights, arbitrary)[0]) != pytest.approx(
+        max(own.greedy_gaps(doc, weights, arbitrary)[0]), abs=0.1
+    )
+    worst = max(
+        wrong.greedy_gaps(doc, weights, [(ids[:k], [int(theirs[k - 1].argmax())])])[0][0]
+        for k in range(8, 37)
+    )
+    assert worst > 3 * tiny_limit()  # the program's own choices, under the wrong rotary
+
+
+@pytest.mark.parametrize("seed", [1, 2, 3])
+def test_the_control_comes_out_not_correct(seed):
+    """The control (``decoder_f32.control_gaps``: the recipe's init at int4
+    in place of int8, the reference put in the program's place) at a size a
+    test can hold, through the harness's own comparison (``cell.judge``
+    under the tiny configuration's own limits): not correct, where the
+    int8 model's own choices are."""
+    import numpy as np
+
+    from benchmark.harness import cell
+    from benchmark.reference import decoder_f32, decoder_f32_weights
+
+    doc = tiny_doc()
+    weights = decoder_f32_weights.make(doc)
+    rng = np.random.default_rng(seed)
+    prompts = [[int(t) for t in rng.integers(0, 512, 40 + 5 * k)] for k in range(4)]
+    forced = [(ids[:-16], ids[-16:]) for ids in prompts]  # 16 scored positions each
+
+    def verdict(gaps):
+        compared = cell.judge(gaps, doc["probe"])
+        assert compared
+        return all(entry["value"] <= entry["limit"] for entry in compared.values())
+
+    lowered = decoder_f32.control_gaps(doc, weights, forced)
+    assert [len(row) for row in lowered] == [16] * 4 and min(map(min, lowered)) >= 0.0
+    assert verdict(lowered) is False
+    assert sum(1 for row in lowered for g in row if g > 0) >= 16  # int4 moves a quarter of the choices
+    # the int8 model's own first choices at the same positions read nothing
+    rows, padded = decoder_f32._rows(weights, doc["architecture"], forced)
+    _, first, _ = decoder_f32.head_reduce(
+        weights, doc["architecture"], rows, np.zeros(rows.shape[0], np.int32)
+    )
+    own_choice = [
+        (ids[:-16], [int(first[i * padded + len(ids) - 17])]) for i, ids in enumerate(prompts)
+    ]
+    assert verdict(decoder_f32.greedy_gaps(doc, weights, own_choice)) is True
 
 
 # -- the trace reducer -------------------------------------------------------
@@ -640,13 +1092,20 @@ def test_reducer_reproduces_the_recorded_chip_trace(in_root):
     )
     assert out["idle_gaps"] == [["no host span", pytest.approx(0.000165777, rel=1e-4)]]
     assert out["programs"] == []  # recorded before the reducer read the programs' line
-    # the kernel's share, as layer_metrics/attn_kernel_share.py reads it
+    # the kernel's share as layer_metrics/attn_kernel_share.py reads it, under
+    # the name the kernel had that day; the reader's pattern is for the name it
+    # has had since PR 25 and finds nothing to read here: no metric, never a 0
     from benchmark.layer_metrics import attn_kernel_share
 
     class Traced:
         trace = out
 
-    assert attn_kernel_share.read(Traced()) == pytest.approx(0.5228, abs=1e-3)
+    kernel_s = sum(
+        seconds for name, seconds in out["op_self_s"].items()
+        if "_ragged_attention_pallas" in name
+    )
+    assert kernel_s / sum(out["op_self_s"].values()) == pytest.approx(0.5228, abs=1e-3)
+    assert attn_kernel_share.read(Traced()) is None
 
 
 def test_short_names_keep_the_instruction_and_its_result_type():
@@ -676,21 +1135,24 @@ def test_reducer_without_the_window_span_uses_first_to_last_event():
 
 # -- rehearsal: the command itself, on the CPU -------------------------------
 
-CONTRACT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+CONTRACT_KEYS = {"correct", "attempted", "failed", "metrics", "device", "compared"}
+SETUP_PARTS = ["engine_build", "warm_up", "traffic", "ramp"]
+#: after the window, and in no metric: closing the engine, then the reference
+AFTER_PARTS = ["window", "reduce_and_close", "reference_weights", "reference_forward"]
 DEVICE_ONLY = {
     "device_idle_share", "attn_kernel_share", "step_weight_floor_share",
     "step_device_ms", "peak_hbm_gb",
 }
 
 
-def _run(workload, trace, platform="cpu", seconds="3"):
+def _run(workload, trace, platform="cpu", seconds="3", manifest="tests/benchmark/rehearsal.json"):
     env = {k: v for k, v in os.environ.items() if k != "OPERATOR_TPU_PLATFORM"}
     env["OPERATOR_TPU_MODEL"] = "qwen2.5-7b"  # must be scrubbed, or this would not fit
     env["BENCH_MODEL"] = "qwen2.5-7b"
     if platform:
         env["OPERATOR_TPU_PLATFORM"] = platform
     return subprocess.run(
-        [sys.executable, "benchmark/run.py", "--manifest", "tests/benchmark/rehearsal.json",
+        [sys.executable, "benchmark/run.py", "--manifest", manifest,
          "--workload", workload, "--seed", "11", "--seconds", seconds,
          "--trace", str(trace)],
         cwd=ROOT, env=env, capture_output=True, text=True, timeout=300,
@@ -710,6 +1172,7 @@ def rehearsals():
     for key, proc in runs.items():
         assert proc.returncode == 0, proc.stderr[-2000:]
         lines[key] = json.loads(proc.stdout.strip().splitlines()[-1])
+        lines[key + ":stderr"] = proc.stderr
     return lines
 
 
@@ -717,7 +1180,18 @@ def rehearsals():
 def test_rehearsal_prints_the_contracts_last_line(rehearsals, kind):
     line = rehearsals[kind]
     assert set(line) == CONTRACT_KEYS  # no breakdown: nothing ran on a device
+    assert list(line)[-1] == "compared"  # each number compared, beside its limit, last
     assert line["correct"] is True and line["failed"] == 0 and line["attempted"] > 0
+    compared = line["compared"]
+    assert list(compared) == [
+        "served_gap_max", "served_requests_missing", "window_requests_wrong",
+    ]
+    assert all(entry["value"] <= entry["limit"] for entry in compared.values())
+    assert compared["served_gap_max"]["limit"] == tiny_limit()
+    # and as the last lines on standard error
+    tail = rehearsals[kind + ":stderr"].strip().splitlines()[-len(compared):]
+    assert [t.split()[2].rstrip(":") for t in tail] == list(compared)
+    assert all(t.startswith("[benchmark] compared ") and "(limit " in t for t in tail)
     assert set(line["device"]) == {"platform", "kind", "count", "memory_peak_bytes"}
     assert line["device"]["platform"] == "cpu"
     for metric in line["metrics"].values():
@@ -739,12 +1213,81 @@ def test_rehearsal_reports_each_cells_own_metrics(rehearsals):
     # a CPU run writes nothing under a device metric's name
     assert not traced & DEVICE_ONLY
     assert not traced & set(rehearsals["open"]["metrics"])
-    # the closed loop's per-layer list is the decode cells' own, less the device's
-    real = Manifest(os.path.join(ROOT, "BENCHMARK.json"))
-    cells = {m["name"] for m in real.metrics_for("per_layer", "qwen2.5-7b-int8.decode")}
+    # the closed loop's per-layer list is the rehearsal's own for that cell,
+    # less the device's: the real manifest may grow without this test
+    own = Manifest(os.path.join(ROOT, "tests/benchmark/rehearsal.json"))
+    cells = {m["name"] for m in own.metrics_for("per_layer", "tiny-test.decode")}
     assert set(rehearsals["traced_closed"]["metrics"]) == cells - DEVICE_ONLY
     pool = rehearsals["traced_closed"]["metrics"]
     assert 0 < pool["kv_pool_rows_share"]["value"] <= pool["kv_pool_fill_share"]["value"] <= 1
+
+
+@pytest.mark.parametrize("kind, cell", [("open", "tiny-test.storm"), ("closed", "tiny-test.decode")])
+def test_rehearsal_logs_the_seconds_of_each_part_of_set_up(rehearsals, kind, cell):
+    saved = load_json(os.path.join(ROOT, f"benchmark/out/setup/{cell}.seed11.json"))
+    assert list(saved["parts"]) == SETUP_PARTS + AFTER_PARTS
+    assert all(seconds >= 0 for seconds in saved["parts"].values())
+    # the reference runs after the window: its seconds are in no metric
+    assert sum(saved["parts"][k] for k in SETUP_PARTS) == pytest.approx(saved["setup_s"], abs=1e-6)
+    assert "[benchmark] set-up parts (s): {" in rehearsals[kind + ":stderr"]
+    if kind == "closed":  # the mix's ramp of 0.5 s is set-up, and is named
+        assert saved["parts"]["ramp"] == pytest.approx(0.5, abs=0.2)
+    # the comparison's log line says where its limit comes from
+    origin = load_json(os.path.join(ROOT, "tests/benchmark/configs/tiny-test.json"))["probe"]["origin"]
+    assert f"(limit {tiny_limit()}: {origin})" in rehearsals[kind + ":stderr"]
+
+
+REFERENCE_MANIFEST = "tests/benchmark/rehearsal-reference.json"
+
+
+@pytest.fixture(scope="module")
+def reference_rehearsals():
+    """A configuration of its own reference, one of a wrong reference and
+    one on a broken entry, each through the command."""
+    lines = {}
+    for config in ("tiny-own-reference", "tiny-wrong-reference", "tiny-token-altered"):
+        proc = _run(config + ".decode", 0, manifest=REFERENCE_MANIFEST)
+        assert proc.returncode == 0, proc.stderr[-2000:]
+        lines[config] = json.loads(proc.stdout.strip().splitlines()[-1])
+    return lines
+
+
+@pytest.mark.parametrize(
+    "config, correct",
+    [("tiny-own-reference", True), ("tiny-wrong-reference", False), ("tiny-token-altered", False)],
+)
+def test_the_reference_a_configuration_names_decides_correct(reference_rehearsals, config, correct):
+    line = reference_rehearsals[config]
+    assert line["correct"] is correct
+    assert line["failed"] == 0 and line["attempted"] > 0  # the run itself is whole
+    gap = line["compared"]["served_gap_max"]
+    assert (gap["value"] <= gap["limit"]) is correct
+    if not correct:  # an order-one fault, not a near miss
+        assert gap["value"] > 5 * gap["limit"]
+        assert line["compared"]["window_requests_wrong"]["value"] == 0
+
+
+def test_the_second_rehearsal_adds_files_only_under_the_tests(in_root):
+    """Every file the reference rehearsal resolves that the first rehearsal
+    does not is under ``tests/benchmark/``: nothing under ``benchmark/``
+    was edited or added for it."""
+    mine = Manifest(os.path.join(ROOT, REFERENCE_MANIFEST))
+    added = []
+    for item in mine.doc["configs"]:
+        config = mine.config(item["name"])
+        reference, weights_module = reference_modules(mine, config)
+        entry = mine.module("entries", config["entry"])
+        added += [os.path.join(ROOT, item["file"])]
+        added += [m.__file__ for m in (reference, weights_module, entry)]
+    shared = {
+        os.path.join(ROOT, "benchmark/entries/engine.py"),
+        os.path.join(ROOT, "benchmark/reference/decoder_f32.py"),
+        os.path.join(ROOT, "benchmark/reference/decoder_f32_weights.py"),
+    }
+    new = set(added) - shared
+    assert len(new) == 3 + 3 + 1  # configurations; reference, wrong reference, their weights; entry
+    assert all(path.startswith(os.path.join(ROOT, "tests/benchmark/")) for path in new)
+    assert os.path.join(ROOT, "tests/benchmark/reference/numpy_f64.py") in new
 
 
 def test_without_a_named_backend_the_command_fails_and_prints_no_line():
