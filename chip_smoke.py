@@ -261,6 +261,52 @@ def kernels_leg(interpret: bool) -> dict:
     )
     report["flash_prefill"] = close("flash_prefill", got[valid], want[valid])
 
+    # the state-space scan (a model with recurrent state, ops/ssm_scan.py)
+    # at the Falcon-H1 geometry: idle slots between live ones, decode rows,
+    # a full chunk, a part chunk, fresh and carried states; everything is
+    # float32 inside, so kernel and token-by-token reference differ by the
+    # order of a sum alone
+    from operator_tpu.ops.ssm_scan import _ssm_scan_pallas, ssm_scan_reference
+
+    falcon = get_config("falcon-h1-34b-6l")
+    s_heads, s_dim, s_state, s_groups = (
+        (falcon.mamba_n_heads, falcon.mamba_d_head, falcon.mamba_d_state,
+         falcon.mamba_n_groups) if not interpret else (4, 16, 16, 2)
+    )
+    counts = np.asarray([1, 0, 64, 1, 0, 0, 5, 1], np.int32)
+    starts = np.concatenate([[0], np.cumsum(counts)[:-1]]).astype(np.int32)
+    tokens = 128
+    sk = jax.random.split(jax.random.PRNGKey(13), 6)
+    sx = jax.random.normal(sk[0], (tokens, s_heads, s_dim), jnp.bfloat16)
+    sdt = jax.nn.softplus(jax.random.normal(sk[1], (tokens, s_heads)) - 3.0)
+    sa = -jnp.exp(jax.random.uniform(sk[2], (s_heads,), minval=0.0, maxval=2.7))
+    sb = jax.random.normal(sk[3], (tokens, s_groups, s_state), jnp.bfloat16)
+    sc = jax.random.normal(sk[4], (tokens, s_groups, s_state), jnp.bfloat16)
+    state = jax.random.normal(sk[5], (2, len(counts), s_heads, s_state, s_dim))
+    fresh = jnp.asarray([0, 1, 1, 0, 0, 1, 0, 1], bool)
+    scan_args = (sx, sdt, sa, sb, sc, state, jnp.int32(1), jnp.asarray(starts),
+                 jnp.asarray(counts), fresh)
+    want_y, want_state = jax.jit(ssm_scan_reference, static_argnames=("chunk",))(
+        *scan_args, chunk=64
+    )
+    got_y, got_state = _ssm_scan_pallas(
+        *scan_args, interpret=interpret,
+        heads_per_block=2 if interpret else 0,
+    )
+    live = np.zeros(tokens, bool)
+    for start, count in zip(starts, counts):
+        live[start:start + count] = True
+    idle = counts == 0
+    check(
+        bool(np.array_equal(np.asarray(got_state)[:, idle], np.asarray(state)[:, idle]))
+        and bool(np.array_equal(np.asarray(got_state)[0], np.asarray(state)[0])),
+        "ssm_scan: an idle slot's state, or another layer's, was touched",
+    )
+    report["ssm_scan"] = {
+        "y": close("ssm_scan y", np.asarray(got_y)[live], np.asarray(want_y)[live]),
+        "state": close("ssm_scan state", np.asarray(got_state), np.asarray(want_state)),
+    }
+
     # similarity: the semantic matcher's shape (1000 windows x 300 patterns)
     # and incident recall's (one query row x a handful of incidents)
     def unit(key, shape):

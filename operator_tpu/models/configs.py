@@ -9,7 +9,7 @@ theta.  Sizes are from the public model cards / HF config.json files.
 from __future__ import annotations
 
 from dataclasses import dataclass, replace
-from typing import Optional
+from typing import ClassVar, Optional
 
 
 @dataclass(frozen=True)
@@ -42,12 +42,96 @@ class ModelConfig:
     rope_scaling: Optional[RopeScaling] = None  # Llama-3.1+ long context
     attention_bias: bool = False  # Qwen2-style bias on the q/k/v projections
 
+    #: which module of this package builds and runs the model
+    #: (``models.family_of``): init, layer matrices, the mixed step's layer
+    family: ClassVar[str] = "llama"
+    #: a recurrent state per slot beside the KV pages: prefix hits and
+    #: draft roll-backs cannot restore it, so the serving path switches
+    #: both off for such a model (serving/provider.py)
+    recurrent_state: ClassVar[bool] = False
+
     @property
     def q_per_kv(self) -> int:
         return self.num_heads // self.num_kv_heads
 
     def __post_init__(self) -> None:
         assert self.num_heads % self.num_kv_heads == 0, "heads must divide evenly into kv groups"
+
+
+@dataclass(frozen=True)
+class FalconH1Config(ModelConfig):
+    """Falcon-H1: in every layer a Mamba-2 mixer in parallel with
+    grouped-query attention, then a gated MLP; muP multipliers on nearly
+    every branch (``models/falcon_h1.py`` has the equations).  A sibling
+    of ``ModelConfig`` and not more fields on it: the Llama family's
+    configurations, their fingerprints and every ``dataclasses.asdict`` of
+    them stay what they were, and what reads only the shared sizes
+    (heads, head_dim, vocabulary, depth) reads them from either.  Field
+    names follow the published ``config.json`` where it has the key."""
+
+    mamba_d_ssm: int = 0
+    mamba_n_heads: int = 0
+    mamba_d_head: int = 0
+    mamba_d_state: int = 0
+    mamba_n_groups: int = 1
+    mamba_d_conv: int = 4
+    mamba_expand: int = 2
+    mamba_conv_bias: bool = True
+    mamba_proj_bias: bool = False
+    mamba_rms_norm: bool = True
+    mamba_norm_before_gate: bool = False
+    mlp_bias: bool = False
+    projectors_bias: bool = False
+    hidden_act: str = "silu"
+    embedding_multiplier: float = 1.0
+    lm_head_multiplier: float = 1.0
+    attention_in_multiplier: float = 1.0
+    attention_out_multiplier: float = 1.0
+    key_multiplier: float = 1.0
+    ssm_in_multiplier: float = 1.0
+    ssm_out_multiplier: float = 1.0
+    #: gate and down-projection multipliers
+    mlp_multipliers: tuple = (1.0, 1.0)
+    #: over the in-projection's segments z | x | B | C | dt
+    ssm_multipliers: tuple = (1.0, 1.0, 1.0, 1.0, 1.0)
+
+    family: ClassVar[str] = "falcon_h1"
+    recurrent_state: ClassVar[bool] = True
+
+    @property
+    def mamba_conv_dim(self) -> int:
+        """Channels the causal convolution runs over: x | B | C."""
+        return self.mamba_d_ssm + 2 * self.mamba_n_groups * self.mamba_d_state
+
+    @property
+    def mamba_in_dim(self) -> int:
+        """The in-projection's width: z | x | B | C | dt."""
+        return self.mamba_d_ssm + self.mamba_conv_dim + self.mamba_n_heads
+
+    @property
+    def mlp_multipliers_list(self) -> list:
+        """As JSON has it: a configuration file's ``architecture`` group
+        is held to this (and to ``ssm_multipliers_list``)."""
+        return list(self.mlp_multipliers)
+
+    @property
+    def ssm_multipliers_list(self) -> list:
+        return list(self.ssm_multipliers)
+
+    def __post_init__(self) -> None:
+        super().__post_init__()
+        assert self.mamba_d_ssm == self.mamba_n_heads * self.mamba_d_head
+        assert self.mamba_n_heads % self.mamba_n_groups == 0
+        assert len(self.mlp_multipliers) == 2 and len(self.ssm_multipliers) == 5
+        # the flags are held so that a configuration's file can be checked
+        # against them; the layer (models/falcon_h1.py) is written for
+        # these values and a config that states another is refused here
+        assert (
+            self.mamba_conv_bias and self.mamba_rms_norm and self.hidden_act == "silu"
+            and not (self.mamba_proj_bias or self.mamba_norm_before_gate
+                     or self.mlp_bias or self.projectors_bias or self.attention_bias)
+            and self.mamba_expand * self.hidden_size >= self.mamba_d_ssm
+        ), f"{self.name}: a Falcon-H1 variant the layer body does not implement"
 
 
 TINYLLAMA_1_1B = ModelConfig(
@@ -184,6 +268,71 @@ TINY_TEST = ModelConfig(
     max_seq_len=256,
 )
 
+# Falcon-H1-34B-Instruct as published (tiiuae/Falcon-H1-34B-Instruct,
+# config.json): 72 identical layers.  ``falcon-h1-34b-6l`` is the same
+# model cut in depth only, one pipeline stage's six layers with the
+# embedding and the head (benchmark/configs/falcon-h1-34b-int8.json)
+FALCON_H1_34B = FalconH1Config(
+    name="falcon-h1-34b",
+    vocab_size=261120,
+    hidden_size=5120,
+    intermediate_size=21504,
+    num_layers=72,
+    num_heads=20,
+    num_kv_heads=4,
+    head_dim=128,
+    rope_theta=1e11,
+    rms_norm_eps=1e-5,
+    max_seq_len=16384,  # serving cap; the model supports 256k
+    mamba_d_ssm=4096,
+    mamba_n_heads=32,
+    mamba_d_head=128,
+    mamba_d_state=256,
+    mamba_n_groups=2,
+    mamba_d_conv=4,
+    embedding_multiplier=5.656854249492381,
+    lm_head_multiplier=0.0078125,
+    attention_in_multiplier=1.0,
+    attention_out_multiplier=0.0375,
+    key_multiplier=0.011048543456039804,
+    ssm_in_multiplier=0.25,
+    ssm_out_multiplier=0.08838834764831845,
+    mlp_multipliers=(0.1767766952966369, 0.011160714285714284),
+    ssm_multipliers=(
+        0.3535533905932738, 0.25, 0.1767766952966369, 0.5, 0.3535533905932738,
+    ),
+)
+FALCON_H1_34B_6L = replace(FALCON_H1_34B, name="falcon-h1-34b-6l", num_layers=6)
+
+#: the family's small config for tests: every multiplier != 1
+TINY_FALCON_H1 = FalconH1Config(
+    name="tiny-falcon-h1",
+    vocab_size=512,
+    hidden_size=128,
+    intermediate_size=352,
+    num_layers=3,
+    num_heads=8,
+    num_kv_heads=2,
+    head_dim=16,
+    rope_theta=10_000.0,
+    max_seq_len=256,
+    mamba_d_ssm=64,
+    mamba_n_heads=4,
+    mamba_d_head=16,
+    mamba_d_state=16,
+    mamba_n_groups=2,
+    mamba_d_conv=4,
+    embedding_multiplier=2.5,
+    lm_head_multiplier=0.25,
+    attention_in_multiplier=0.9,
+    attention_out_multiplier=0.6,
+    key_multiplier=0.7,
+    ssm_in_multiplier=0.8,
+    ssm_out_multiplier=0.5,
+    mlp_multipliers=(0.75, 0.4),
+    ssm_multipliers=(0.9, 0.8, 0.7, 1.2, 0.6),
+)
+
 _REGISTRY = {
     cfg.name: cfg
     for cfg in (
@@ -196,6 +345,9 @@ _REGISTRY = {
         QWEN2_5_7B,
         QWEN2_5_1_5B,
         TINY_TEST,
+        FALCON_H1_34B,
+        FALCON_H1_34B_6L,
+        TINY_FALCON_H1,
     )
 }
 

@@ -37,7 +37,7 @@ from ..ops.flash_prefill import (
     flash_prefill_supported,
 )
 from .configs import ModelConfig
-from .quant import mm
+from .quant import QUANTIZED_LAYER_MATRICES, mm
 
 Params = dict[str, Any]
 
@@ -48,6 +48,11 @@ _PROJ_BIAS = {"wq": "bq", "wk": "bk", "wv": "bv"}
 # --------------------------------------------------------------------------
 # parameter init
 # --------------------------------------------------------------------------
+
+
+#: the layer matrices, in the order the init key-split follows
+#: (``models.family_of(config).LAYER_MATRICES``: what int8 quantises)
+LAYER_MATRICES = QUANTIZED_LAYER_MATRICES
 
 
 def layer_matrix_shapes(config: ModelConfig) -> dict[str, tuple[int, int, int]]:
@@ -516,6 +521,38 @@ def forward(
     head = params["embed"].T if config.tie_embeddings else params["lm_head"]
     logits = jnp.einsum("bth,hv->btv", x, head, preferred_element_type=jnp.float32)
     return logits, new_cache
+
+
+def mixed_layer(config: ModelConfig, step: Any):
+    """The continuous scheduler's layer body for this family
+    (``serving/sched/mixed.py``): projections with the optional q/k/v
+    bias, the step's shared attention (``step.attend``), the gated MLP.
+    The carry is ``(x, None)``: no state beside the KV pages."""
+
+    def layer_step(carry, scanned):
+        x, recurrent = carry
+        weights = scanned["w"]
+        attn_in = rms_norm(x, weights["ln_attn"], config.rms_norm_eps)
+
+        def proj(h_in, name):
+            y = mm(h_in, weights[name])
+            bias = _PROJ_BIAS.get(name)
+            if bias is not None and bias in weights:
+                y = y + weights[bias].astype(y.dtype)
+            return y
+
+        attn, pages = step.attend(
+            proj(attn_in, "wq"), proj(attn_in, "wk"), proj(attn_in, "wv"), scanned
+        )
+        x = x + proj(attn, "wo")
+        with jax.named_scope("mlp"):
+            mlp_in = rms_norm(x, weights["ln_mlp"], config.rms_norm_eps)
+            gate = jax.nn.silu(proj(mlp_in, "w_gate"))
+            up = proj(mlp_in, "w_up")
+            x = x + proj(gate * up, "w_down")
+        return (x, recurrent), pages
+
+    return layer_step
 
 
 def decode_step(

@@ -317,8 +317,15 @@ def load_params(
     would peak at float tree + int8 tree, an OOM for 8B-class checkpoints
     on a 16 GB chip.
     """
-    from .quant import QUANTIZED_LAYER_MATRICES, quantize_matrix
+    from .quant import quantize_matrix, quantized_layer_matrices
 
+    if config.family != "llama":
+        raise NotImplementedError(
+            f"no checkpoint converter for the {config.family!r} family "
+            f"(model {config.name!r}): convert_hf_state_dict maps Llama-family "
+            "tensor names only; serve it with ALLOW_RANDOM_WEIGHTS=true"
+        )
+    quantized = quantized_layer_matrices(config)
     state = iter_safetensors(checkpoint_dir)
     quantize_jit = jax.jit(quantize_matrix) if quantize else None
 
@@ -328,7 +335,7 @@ def load_params(
     def put(name: str, array: np.ndarray) -> Any:
         value = jnp.asarray(array, dtype)
         sharding = shardings.get(name) if shardings else None
-        if quantize and name in QUANTIZED_LAYER_MATRICES:
+        if quantize and name in quantized:
             out = quantize_jit(value)
             # block so XLA frees the bf16 group before the next one arrives
             out = jax.block_until_ready(out)

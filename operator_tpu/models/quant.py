@@ -39,8 +39,16 @@ from .configs import ModelConfig
 
 Params = dict[str, Any]
 
-#: layer matrices that get quantized (stored [n_layers, in, out])
+#: the Llama family's layer matrices (stored [n_layers, in, out]); what a
+#: given model quantises is its family's list: ``quantized_layer_matrices``
 QUANTIZED_LAYER_MATRICES = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
+
+
+def quantized_layer_matrices(config: ModelConfig) -> tuple:
+    """The layer matrices ``config``'s family holds int8 a column."""
+    from . import family_of
+
+    return tuple(family_of(config).LAYER_MATRICES)
 
 
 def is_quantized(params: Params) -> bool:
@@ -79,7 +87,7 @@ def quantize_matrix(w: jax.Array) -> dict[str, jax.Array]:
 def quantize_params(params: Params, config: ModelConfig) -> Params:
     """Quantize the layer matrices of a loaded/initialised param tree."""
     layers = dict(params["layers"])
-    for name in QUANTIZED_LAYER_MATRICES:
+    for name in quantized_layer_matrices(config):
         layers[name] = quantize_matrix(layers[name])
     return {**params, "layers": layers}
 
@@ -119,7 +127,8 @@ def init_params_quantized(
     boundaries, so bit-exactness is not promised) — tests/test_quant.py
     pins the tolerance.
     """
-    from .llama import dense_init, init_params
+    from . import family_of
+    from .llama import dense_init
 
     h = config.hidden_size
     # dense-init and quantize are SEPARATE jits on purpose: fused, XLA elides
@@ -135,7 +144,7 @@ def init_params_quantized(
         # block per matrix so the bf16 transient frees before the next one
         return jax.block_until_ready(quantize(init_dense(key, shape=shape)))
 
-    return init_params(
+    return family_of(config).init_params(
         config, key, dtype, layer_matrix_init=init_quantized_matrix
     )
 

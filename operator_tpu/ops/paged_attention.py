@@ -48,10 +48,30 @@ class PagedKVCache:
     v_pages: jax.Array
     page_table: jax.Array  # [batch, pages_per_seq] int32
     lengths: jax.Array  # [batch] int32
+    #: recurrent state beside the pages, for a model that has it
+    #: (``config.recurrent_state``; ops/ssm_scan.py): per layer and SLOT,
+    #: not per page -- allocated, donated, reset and freed with the pool,
+    #: because it is part of the one cache object.  None for the others:
+    #: an absent child of the pytree, so their programs see no change
+    ssm_state: Optional[jax.Array] = None  # [layers, batch, heads, d_state, head_dim] f32
+    conv_state: Optional[jax.Array] = None  # [layers, batch, d_conv - 1, conv_dim]
 
     @property
     def page_size(self) -> int:
         return self.k_pages.shape[2]
+
+    @staticmethod
+    def recurrent_shapes(config, batch_size: int) -> Optional[tuple]:
+        """``(ssm_state shape, conv_state shape)`` for ``config``, or None
+        for a model without recurrent state."""
+        if not getattr(config, "recurrent_state", False):
+            return None
+        n = config.num_layers
+        return (
+            (n, batch_size, config.mamba_n_heads, config.mamba_d_state,
+             config.mamba_d_head),
+            (n, batch_size, config.mamba_d_conv - 1, config.mamba_conv_dim),
+        )
 
     @classmethod
     def create(
@@ -64,19 +84,28 @@ class PagedKVCache:
         batch_size: int,
         pages_per_seq: int,
         dtype: jnp.dtype = jnp.bfloat16,
+        recurrent: Optional[tuple] = None,
     ) -> "PagedKVCache":
+        """``recurrent`` is :meth:`recurrent_shapes`' pair: the state is
+        float32 (a sum over every token the row has seen), the conv tail
+        the activations' ``dtype``."""
         shape = (num_layers, num_pages, page_size, kv_heads, head_dim)
         return cls(
             k_pages=jnp.zeros(shape, dtype),
             v_pages=jnp.zeros(shape, dtype),
             page_table=jnp.zeros((batch_size, pages_per_seq), jnp.int32),
             lengths=jnp.zeros((batch_size,), jnp.int32),
+            ssm_state=None if recurrent is None else jnp.zeros(recurrent[0], jnp.float32),
+            conv_state=None if recurrent is None else jnp.zeros(recurrent[1], dtype),
         )
 
 
 jax.tree_util.register_pytree_node(
     PagedKVCache,
-    lambda c: ((c.k_pages, c.v_pages, c.page_table, c.lengths), None),
+    lambda c: (
+        (c.k_pages, c.v_pages, c.page_table, c.lengths, c.ssm_state, c.conv_state),
+        None,
+    ),
     lambda _, ch: PagedKVCache(*ch),
 )
 

@@ -140,6 +140,11 @@ def generator_fingerprint(
         model = dataclasses.asdict(config)
     except TypeError:
         model = {k: v for k, v in vars(config).items() if not k.startswith("_")}
+    family = getattr(config, "family", "llama")
+    if family != "llama":
+        # the family is a class attribute, not a field: two families that
+        # shared every field value would otherwise share a fingerprint
+        model["family"] = family
     mesh_desc = None
     if mesh is not None:
         first = next(iter(mesh.devices.flat))

@@ -904,6 +904,12 @@ class BatchedGenerator(AdmissionMixin, ProgramBuilderMixin):
                     self.page_size, self.config.num_kv_heads,
                     self.config.head_dim, self.max_slots, self.pages_per_seq,
                     dtype=self.cache_dtype,
+                    # a model with recurrent state keeps it in the SAME
+                    # cache object, so whatever donates, resets or frees
+                    # the pool covers it
+                    recurrent=PagedKVCache.recurrent_shapes(
+                        self.config, self.max_slots
+                    ),
                 ),
                 "paged",
             )
@@ -2032,6 +2038,22 @@ class ServingEngine:
             ),
         )
 
+    def serving_features(self) -> dict:
+        """Which engine runs and what it really does, as opposed to what
+        the configuration asked for (``GET /healthz`` ``features``): the
+        scheduler switches speculation and the prefix cache off for a
+        model with recurrent state and says why here."""
+        model = self.generator.config
+        sched = self._sched
+        return {
+            "schedMode": "continuous" if sched is not None else "wave",
+            "modelFamily": getattr(model, "family", "llama"),
+            "recurrentState": bool(getattr(model, "recurrent_state", False)),
+            "specDecode": sched is not None and sched.spec_k > 0,
+            "kvPrefixCache": sched is not None and sched._kvstore is not None,
+            "switchedOff": dict(sched.switched_off) if sched is not None else {},
+        }
+
     def device_memory(self) -> list:
         """Per local device, what its runtime says about memory
         (``memory_stats()``: bytes in use now, the peak, the limit) —
@@ -2358,9 +2380,15 @@ class ServingEngine:
         ):
             # the mixed-phase program has no guided/LoRA path yet: refuse
             # at SUBMIT (to this caller) rather than inside the serve loop
+            model = self.generator.config
             raise ValueError(
                 "guided decoding and LoRA adapters are not supported in "
                 "continuous scheduler mode (sched_mode=continuous)"
+                + (
+                    f", the only mode that serves model {model.name!r} "
+                    f"({model.family} family: a recurrent state per slot)"
+                    if getattr(model, "recurrent_state", False) else ""
+                )
             )
         if resume_tokens and self._sched is None:
             raise ValueError(

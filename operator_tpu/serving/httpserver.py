@@ -442,6 +442,9 @@ class CompletionServer:
                 # on; this is what each local device's runtime says it holds
                 "deviceMemory": self.engine.device_memory(),
                 "load": load.to_dict(),
+                # what really runs (speculation and the prefix cache are
+                # switched off for a model with recurrent state)
+                "features": self.engine.serving_features(),
                 # every XLA compile this process made, with persistent-
                 # cache hits marked: a compile after warm-up is a latency
                 # outlier somebody should be able to see from outside

@@ -39,17 +39,19 @@ _MATMUL_DTYPE = {"bf16": "bfloat16", "bfloat16": "bfloat16", "int8": "bfloat16"}
 
 def matmul_param_count(config: Any) -> int:
     """Weights that participate in a matmul during one token's forward
-    pass, analytically from the config (attention projections + MLP per
-    layer, plus the LM head — which multiplies even when tied to the
-    embedding).  Norm scales and the embedding GATHER move no MACs, so
-    they are excluded; ``param_count(params)`` counts them and is the
-    storage number, not the compute number."""
-    h = config.hidden_size
-    q = config.num_heads * config.head_dim
-    kv = config.num_kv_heads * config.head_dim
-    attn = h * q + 2 * h * kv + q * h  # wq, wk, wv, wo
-    mlp = 3 * h * config.intermediate_size  # gate, up, down
-    return config.num_layers * (attn + mlp) + h * config.vocab_size
+    pass: the layer matrices the model's own family names
+    (``models.family_of(config).layer_matrix_shapes``: attention
+    projections and MLP, and a state-space mixer's in- and out-projection
+    where the family has one), plus the LM head — which multiplies even
+    when tied to the embedding.  Norm scales, convolution taps and the
+    embedding GATHER move no matmul MACs, so they are excluded;
+    ``param_count(params)`` counts them and is the storage number, not
+    the compute number."""
+    from ..models import family_of
+
+    shapes = family_of(config).layer_matrix_shapes(config)
+    layers = sum(n * rows * cols for n, rows, cols in shapes.values())
+    return layers + config.hidden_size * config.vocab_size
 
 
 def flops_per_token(config: Any, dtype: str = "bf16") -> float:
@@ -162,7 +164,8 @@ class StepClock:
         clock: the wave engine's admission prefill) the record stands
         alone and its wall is the two waits.  ``counts`` are the record's
         optional work counts (``accepted``, ``cached_tokens``,
-        ``prefill_tokens``, ``kv_pages_walked``, ``q_tile_rows``).  MFU stays
+        ``prefill_tokens``, ``kv_pages_walked``, ``q_tile_rows``,
+        ``state_rows``).  MFU stays
         computed on billed ``tokens`` — the compute really ran — over the
         interval's wall."""
         if commit_t is None:

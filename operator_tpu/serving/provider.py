@@ -365,6 +365,26 @@ def build_serving_engine(
             f"unknown sched_mode {config.sched_mode!r}: expected "
             "'wave' or 'continuous'"
         )
+    if model_config.recurrent_state and (
+        config.sched_mode != "continuous" or mesh is not None or lora_adapters
+    ):
+        # a recurrent state per slot lives only in the continuous path's
+        # cache (ops/paged_attention.PagedKVCache.ssm_state): the wave
+        # engine's programs, a sharded pool and the LoRA path know nothing
+        # of it, and serving without it would be wrong, not slow
+        asked = ", ".join(
+            text for on, text in (
+                (config.sched_mode != "continuous", f"sched_mode={config.sched_mode!r}"),
+                (mesh is not None, f"serving_mesh={config.serving_mesh!r}"),
+                (bool(lora_adapters), "lora_dir adapters"),
+            ) if on
+        )
+        raise ValueError(
+            f"model {model_id!r} ({model_config.family} family) keeps a "
+            f"recurrent state per slot, which only the unsharded continuous "
+            f"scheduler serves (sched_mode=continuous, no serving_mesh, no "
+            f"LoRA); this configuration asks for {asked}"
+        )
     if config.sched_mode == "continuous":
         blockers = [
             reason for blocked, reason in (
@@ -410,10 +430,16 @@ def build_serving_engine(
                 pipeline_depth=config.pipeline_depth,
                 prefill_chunk=prefill_chunk,
                 sched_pipeline_depth=config.sched_pipeline_depth,
+                # what the scheduler will really run: it switches both
+                # off for a model with recurrent state (sched/scheduler.py)
                 spec_width=1 + (
-                    config.spec_lookup_k if config.spec_decode else 0
+                    config.spec_lookup_k
+                    if config.spec_decode and not model_config.recurrent_state
+                    else 0
                 ),
-                kv_prefix_cache=config.kv_prefix_cache,
+                kv_prefix_cache=(
+                    config.kv_prefix_cache and not model_config.recurrent_state
+                ),
                 lora_names=sorted(lora_adapters) if lora_adapters else (),
             ))
         except Exception:  # noqa: BLE001 - cache is an optimisation only
@@ -452,7 +478,11 @@ def build_serving_engine(
                 model_config, jax.random.PRNGKey(0), dtype=jnp.bfloat16
             )
         else:
-            params = init_params(model_config, jax.random.PRNGKey(0), dtype=jnp.bfloat16)
+            from ..models import family_of
+
+            params = family_of(model_config).init_params(
+                model_config, jax.random.PRNGKey(0), dtype=jnp.bfloat16
+            )
     else:
         # refusing keeps random-weight noise out of pod annotations: the
         # pipeline catches the ProviderError and stores the pattern-only

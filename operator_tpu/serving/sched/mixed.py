@@ -34,17 +34,33 @@ pages into the row's table and its first chunk starts at ``cached_len``
 
 from __future__ import annotations
 
-from typing import Any
+import dataclasses
+from typing import Any, Callable
 
-from ...models.llama import (
-    _PROJ_BIAS,
-    apply_rope,
-    rms_norm,
-    rope_frequencies,
-)
-from ...models.quant import mm
+from ...models import family_of
+from ...models.llama import apply_rope, rms_norm, rope_frequencies
 
-__all__ = ["make_mixed_fn"]
+__all__ = ["StepView", "make_mixed_fn"]
+
+
+@dataclasses.dataclass
+class StepView:
+    """What a family's layer body (``models.family_of(config).mixed_layer``)
+    is given of one dispatch: the flat tokens' packing and ``attend``, the
+    attention every family shares."""
+
+    t_budget: int
+    chunk: int
+    rows: Any  # [T] owning slot per flat token
+    in_row: Any  # [T] index within the row's tokens of this step
+    pos: Any  # [T] absolute positions
+    valid: Any  # [T] live mask
+    q_start: Any  # [S] flat offset of the slot's first token
+    q_count: Any  # [S] the slot's tokens this step
+    #: ``attend(q, k, v, scanned) -> (attn [1, T, QH * D], {"k", "v"})``:
+    #: RoPE, the step's K/V into the layer's pages (``scanned["k"]``,
+    #: ``scanned["v"]``), the ragged kernel, the rows back on the flat axis
+    attend: Callable
 
 
 def make_mixed_fn(generator: Any, t_budget: int, chunk: int,
@@ -95,11 +111,13 @@ def make_mixed_fn(generator: Any, t_budget: int, chunk: int,
     inv_freq = rope_frequencies(config)
     lax = jax.lax
     width = max(1, int(spec_width))
+    # muP models scale the embedding and the logits (models/falcon_h1.py)
+    embedding_multiplier = float(getattr(config, "embedding_multiplier", 1.0))
+    lm_head_multiplier = float(getattr(config, "lm_head_multiplier", 1.0))
 
     def mixed_fn(params, paged, ids, rows, pos, valid, in_row,
                  q_start, q_count, kv_len, latest, from_prev,
                  sample_start, spec_len, rng, temp, top_p):
-        from ...ops.paged_attention import PagedKVCache
         from ...ops.ragged_attention import ragged_paged_attention
 
         page_size = paged.page_size
@@ -109,6 +127,8 @@ def make_mixed_fn(generator: Any, t_budget: int, chunk: int,
         eff_ids = jnp.where(from_prev, latest[rows], ids)
         with jax.named_scope("embed"):
             x = jnp.take(params["embed"], eff_ids, axis=0)[None]  # [1, T, H]
+            if embedding_multiplier != 1.0:
+                x = (x.astype(jnp.float32) * embedding_multiplier).astype(x.dtype)
         positions = pos[None]  # [1, T]
         # flat -> per-row packing indices for the attention re-pack
         pack_idx = jnp.clip(
@@ -121,27 +141,10 @@ def make_mixed_fn(generator: Any, t_budget: int, chunk: int,
         )
         page_slots = jnp.where(valid, pos % page_size, 0)
 
-        def layer_step(carry, scanned):
-            x = carry
-            weights = scanned["w"]
-            attn_in = rms_norm(x, weights["ln_attn"], config.rms_norm_eps)
-
-            def proj(h_in, name):
-                y = mm(h_in, weights[name])
-                bias = _PROJ_BIAS.get(name)
-                if bias is not None and bias in weights:
-                    y = y + weights[bias].astype(y.dtype)
-                return y
-
-            q = proj(attn_in, "wq").reshape(
-                1, t_budget, config.num_heads, config.head_dim
-            )
-            k = proj(attn_in, "wk").reshape(
-                1, t_budget, config.num_kv_heads, config.head_dim
-            )
-            v = proj(attn_in, "wv").reshape(
-                1, t_budget, config.num_kv_heads, config.head_dim
-            )
+        def attend(q, k, v, scanned):
+            q = q.reshape(1, t_budget, config.num_heads, config.head_dim)
+            k = k.reshape(1, t_budget, config.num_kv_heads, config.head_dim)
+            v = v.reshape(1, t_budget, config.num_kv_heads, config.head_dim)
             q = apply_rope(q, positions, inv_freq)
             k = apply_rope(k, positions, inv_freq)
             # scatter this step's K/V into the pages FIRST — the ragged
@@ -167,18 +170,28 @@ def make_mixed_fn(generator: Any, t_budget: int, chunk: int,
                 attn = jnp.where(
                     valid[:, None, None], attn_pack[rows, in_row], 0
                 )
-            x = x + proj(attn.astype(x.dtype).reshape(1, t_budget, -1), "wo")
-            with jax.named_scope("mlp"):
-                mlp_in = rms_norm(x, weights["ln_mlp"], config.rms_norm_eps)
-                gate = jax.nn.silu(proj(mlp_in, "w_gate"))
-                up = proj(mlp_in, "w_up")
-                x = x + proj(gate * up, "w_down")
-            return x, {"k": k_pages, "v": v_pages}
+            return (
+                attn.astype(x.dtype).reshape(1, t_budget, -1),
+                {"k": k_pages, "v": v_pages},
+            )
 
+        # what differs by family is the layer body; the model's config
+        # selects it, no option does
+        layer_step = family_of(config).mixed_layer(config, StepView(
+            t_budget=t_budget, chunk=chunk, rows=rows, in_row=in_row, pos=pos,
+            valid=valid, q_start=q_start, q_count=q_count, attend=attend,
+        ))
         scanned_in = {
             "w": params["layers"], "k": paged.k_pages, "v": paged.v_pages,
         }
-        x, pages_out = lax.scan(layer_step, x, scanned_in)
+        # a model with recurrent state carries its pools WHOLE through
+        # the layer loop (updated in place, ops/ssm_scan.py); the others
+        # carry nothing beside the residual stream
+        recurrent = None
+        if paged.ssm_state is not None:
+            recurrent = {"ssm": paged.ssm_state, "conv": paged.conv_state}
+            scanned_in["layer"] = jnp.arange(config.num_layers, dtype=jnp.int32)
+        (x, recurrent), pages_out = lax.scan(layer_step, (x, recurrent), scanned_in)
 
         x = rms_norm(x, params["ln_final"], config.rms_norm_eps)
         # only each slot's sampled positions need logit rows: gather them
@@ -200,6 +213,8 @@ def make_mixed_fn(generator: Any, t_budget: int, chunk: int,
                 "bwh,hv->bwv", x_samp, head,
                 preferred_element_type=jnp.float32,
             )
+            if lm_head_multiplier != 1.0:
+                logits = logits * lm_head_multiplier
         with jax.named_scope("sample"):
             flat_toks, rng = generator._sample(
                 logits.reshape(b_slots * width, -1), rng,
@@ -236,10 +251,14 @@ def make_mixed_fn(generator: Any, t_budget: int, chunk: int,
             toks, jnp.clip(accept, 0, width - 1)[:, None], axis=1
         )[:, 0]
         latest_out = jnp.where(q_count > 0, fresh, latest)
-        new_paged = PagedKVCache(
-            k_pages=pages_out["k"], v_pages=pages_out["v"],
-            page_table=paged.page_table, lengths=new_lengths,
+        new_paged = dataclasses.replace(
+            paged, k_pages=pages_out["k"], v_pages=pages_out["v"],
+            lengths=new_lengths,
         )
+        if recurrent is not None:
+            new_paged = dataclasses.replace(
+                new_paged, ssm_state=recurrent["ssm"], conv_state=recurrent["conv"],
+            )
         return new_paged, toks, accept, latest_out, rng
 
     assert b_slots <= t_budget, (b_slots, t_budget)

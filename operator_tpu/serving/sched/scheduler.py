@@ -209,6 +209,32 @@ class Scheduler:
                 "the continuous scheduler does not support mesh sharding yet"
             )
         self.generator = generator
+        #: what this model cannot have, switched off HERE whatever the
+        #: configuration asked for, with the reason (``/healthz``
+        #: ``features``): a recurrent state per slot can be neither
+        #: restored by a prefix hit (the pages map, the recurrence does
+        #: not) nor rolled back past a rejected draft
+        self.switched_off: dict[str, str] = {}
+        model = generator.config
+        self._recurrent = bool(getattr(model, "recurrent_state", False))
+        if self._recurrent:
+            if spec_decode:
+                self.switched_off["spec_decode"] = (
+                    "a rejected draft shrinks the KV lengths but cannot roll "
+                    "a recurrent state back"
+                )
+                spec_decode = False
+            if kvstore is not None:
+                self.switched_off["kv_prefix_cache"] = (
+                    "a prefix hit maps KV pages but cannot restore a "
+                    "recurrent state"
+                )
+                kvstore = None
+            for feature, why in self.switched_off.items():
+                log.warning(
+                    "%s is OFF for model %r (%s family): %s",
+                    feature, model.name, model.family, why,
+                )
         self.chunk = max(1, min(chunk, generator.max_seq))
         self.t_budget = token_budget or max(self.chunk, generator.max_slots)
         if self.t_budget < generator.max_slots:
@@ -602,6 +628,10 @@ class Scheduler:
                 qk_pairs=packed.qk_pairs,
                 tokens=plan.tokens_planned,
                 q_tile_rows=packed.counts["q_tile_rows"],
+                **(
+                    {"state_rows": packed.counts["state_rows"]}
+                    if self._recurrent else {}  # no such argument without the state
+                ),
             ):
                 entry = self._dispatch(plan, packed)
             clock.add("pack", (entry.dispatch_t - t1) * 1e3)
@@ -1236,6 +1266,11 @@ class Scheduler:
                 # the kernel's own rule: nothing for a slot without
                 # queries, the small tile or the whole chunk for the rest
                 "q_tile_rows": int(query_tile_rows(q_count, self.chunk).sum()),
+                # slots whose recurrent state a layer's scan call reads
+                # and rewrites (the others it skips); no such state, no count
+                "state_rows": (
+                    int((q_count > 0).sum()) if self._recurrent else None
+                ),
             },
             qk_pairs=pairs,
         )
@@ -1251,18 +1286,14 @@ class Scheduler:
         p = packed
         paged = g.paged_cache
         if self._staged_tables:
-            from ...ops.paged_attention import PagedKVCache
-
             idx = jnp.asarray(
                 [slot for slot, _ in self._staged_tables], jnp.int32
             )
             tables = jnp.asarray(
                 np.stack([tab for _, tab in self._staged_tables]), jnp.int32
             )
-            paged = PagedKVCache(
-                k_pages=paged.k_pages, v_pages=paged.v_pages,
-                page_table=paged.page_table.at[idx].set(tables),
-                lengths=paged.lengths,
+            paged = dataclasses.replace(
+                paged, page_table=paged.page_table.at[idx].set(tables)
             )
             self._staged_tables.clear()
         if self._latest is None:

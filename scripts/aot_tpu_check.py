@@ -17,7 +17,13 @@ CPU host finds them before chip time is spent.  Covered:
   never silently routed elsewhere — the check asserts which of the two
   happens for each config;
 - the whole mixed step (``serving/sched/mixed.py``) for the default model
-  at the server's default shape, int8 weights, with XLA's memory analysis;
+  at the server's default shape, int8 weights, with XLA's memory analysis,
+  lowered as the scheduler calls it (the jitted step itself, so that the
+  cache's donation counts);
+- the state-space scan kernel (``ops/ssm_scan.py``) alone and the whole
+  mixed step of a model with recurrent state, at the benchmark cell's
+  shape (``falcon-h1-34b-6l``, 128 slots, 1,536 pages): the memory
+  analysis shows the 3.2 GB state pool aliased, held once;
 - the sharded wave decode step over the 4-device topology (``tp=4``): the
   program ``SERVING_MESH=dp=1,tp=4`` runs, whose paged-attention kernel
   must sit inside a ``shard_map``.
@@ -60,6 +66,7 @@ def _memory(compiled) -> dict:
         "argument_bytes": int(mem.argument_size_in_bytes),
         "output_bytes": int(mem.output_size_in_bytes),
         "temp_bytes": int(mem.temp_size_in_bytes),
+        "alias_bytes": int(mem.alias_size_in_bytes),
     }
 
 
@@ -78,9 +85,12 @@ def _abstract_params(config, sharding_for):
     )
 
 
-def _mixed_step_case(topo_device):
-    """(fn, args) for the continuous scheduler's one program at the
-    server's default shape for the default model."""
+def _mixed_step_case(topo_device, model_id=None, slots=_SLOTS, t_budget=None,
+                     kv_pages=None, spec_width=_SPEC_WIDTH):
+    """(fn, args) for the continuous scheduler's one program: by default
+    at the server's default shape for the default model, else at a
+    benchmark cell's (its slots, token budget, pool pages and sampled
+    width)."""
     from jax.sharding import SingleDeviceSharding
 
     from operator_tpu.models import get_config
@@ -89,7 +99,7 @@ def _mixed_step_case(topo_device):
     from operator_tpu.serving.sched.mixed import make_mixed_fn
     from operator_tpu.utils.config import OperatorConfig
 
-    config = get_config(OperatorConfig().model_id)
+    config = get_config(model_id or OperatorConfig().model_id)
     sharding = SingleDeviceSharding(topo_device)
 
     def shaped(shape, dtype):
@@ -99,26 +109,29 @@ def _mixed_step_case(topo_device):
         """The four attributes ``make_mixed_fn`` reads off a generator."""
 
         _jax, _jnp = jax, jnp
-        max_slots = _SLOTS
+        max_slots = slots
         sample_top_k = ProgramBuilderMixin.SAMPLE_TOP_K
 
     generator = _Shapes()
     generator.config = config
     pages_per_seq = _MAX_SEQ // _PAGE
-    num_pages = _SLOTS * pages_per_seq + 1
+    num_pages = kv_pages or slots * pages_per_seq + 1
     pool = (num_pages, _PAGE, config.num_kv_heads, config.head_dim)
+    recurrent = PagedKVCache.recurrent_shapes(config, slots)
     paged = PagedKVCache(
         k_pages=shaped((config.num_layers, *pool), jnp.bfloat16),
         v_pages=shaped((config.num_layers, *pool), jnp.bfloat16),
-        page_table=shaped((_SLOTS, pages_per_seq), jnp.int32),
-        lengths=shaped((_SLOTS,), jnp.int32),
+        page_table=shaped((slots, pages_per_seq), jnp.int32),
+        lengths=shaped((slots,), jnp.int32),
+        ssm_state=None if recurrent is None else shaped(recurrent[0], jnp.float32),
+        conv_state=None if recurrent is None else shaped(recurrent[1], jnp.bfloat16),
     )
-    t = max(_CHUNK, _SLOTS)
+    t = t_budget or max(_CHUNK, slots)
     params = _abstract_params(
         config, lambda tree: jax.tree_util.tree_map(lambda _: sharding, tree)
     )
     flat_i, flat_b = shaped((t,), jnp.int32), shaped((t,), jnp.bool_)
-    slot_i, slot_f = shaped((_SLOTS,), jnp.int32), shaped((_SLOTS,), jnp.float32)
+    slot_i, slot_f = shaped((slots,), jnp.int32), shaped((slots,), jnp.float32)
     args = (
         params, paged,
         flat_i, flat_i, flat_i, flat_b, flat_i,  # ids rows pos valid in_row
@@ -126,7 +139,7 @@ def _mixed_step_case(topo_device):
         slot_i, slot_i,  # sample_start spec_len
         shaped((2,), jnp.uint32), slot_f, slot_f,  # rng temp top_p
     )
-    return make_mixed_fn(generator, t, _CHUNK, spec_width=_SPEC_WIDTH), args
+    return make_mixed_fn(generator, t, _CHUNK, spec_width=spec_width), args
 
 
 def _mesh_decode_case(topo_devices):
@@ -199,6 +212,7 @@ def main() -> int:
     from operator_tpu.models.configs import _REGISTRY
     from operator_tpu.ops.flash_prefill import _flash_prefill_pallas
     from operator_tpu.ops.paged_attention import (
+        PagedKVCache,
         _paged_attention_pallas,
         _paged_attention_pallas_v2,
     )
@@ -303,18 +317,46 @@ def main() -> int:
         ragged_args(32, 8, 128, _CHUNK),
     ))
 
+    # the state-space scan kernel alone at the benchmark's cell: 32 heads x
+    # 128 x 256 float32 a slot and layer, 128 slots, 256 flat tokens
+    from operator_tpu.ops.ssm_scan import _ssm_scan_pallas
+
+    falcon = _REGISTRY["falcon-h1-34b-6l"]
+    f_slots, f_tokens = 128, 256
+    state_shape, _ = PagedKVCache.recurrent_shapes(falcon, f_slots)
+    cases.append((
+        "ssm_scan_cell_falcon-h1-34b_b128", _ssm_scan_pallas,
+        (
+            shaped((f_tokens, falcon.mamba_n_heads, falcon.mamba_d_head), jnp.bfloat16),
+            shaped((f_tokens, falcon.mamba_n_heads), jnp.float32),
+            shaped((falcon.mamba_n_heads,), jnp.float32),
+            shaped((f_tokens, falcon.mamba_n_groups, falcon.mamba_d_state), jnp.bfloat16),
+            shaped((f_tokens, falcon.mamba_n_groups, falcon.mamba_d_state), jnp.bfloat16),
+            shaped(state_shape, jnp.float32), shaped((), jnp.int32),
+            shaped((f_slots,), jnp.int32), shaped((f_slots,), jnp.int32),
+            shaped((f_slots,), jnp.bool_),
+        ),
+    ))
+
     # whole programs: the dispatchers must pick the kernels although the
     # HOST backend is the CPU — the compile target is the TPU topology
     from operator_tpu.ops import _dispatch
 
     with mock.patch.object(_dispatch, "on_tpu", lambda: True):
         cases.append(("mixed_step_default_model", *_mixed_step_case(topo.devices[0])))
+        cases.append(("mixed_step_falcon-h1-34b-6l_b128", *_mixed_step_case(
+            topo.devices[0], "falcon-h1-34b-6l", slots=f_slots, t_budget=f_tokens,
+            kv_pages=1536, spec_width=1,
+        )))
         if len(topo.devices) >= 4:
             cases.append(("mesh_tp4_paged_decode", *_mesh_decode_case(topo.devices[:4])))
 
         for name, fn, args in cases:
             try:
-                compiled = jax.jit(fn).lower(*args).compile()
+                # a jitted step is lowered as it is called: a second
+                # jax.jit around it would drop its donation
+                lowered = fn.lower(*args) if hasattr(fn, "lower") else jax.jit(fn).lower(*args)
+                compiled = lowered.compile()
                 results[name] = {
                     "ok": True, **_memory(compiled),
                     # the instruction names a profiler trace of the chip

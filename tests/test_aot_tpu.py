@@ -90,3 +90,25 @@ def test_all_kernels_aot_compile_for_v5e(record):
     assert kernels["mixed_step_default_model"]["argument_bytes"] > 1e9, record
     # SERVING_MESH=dp=1,tp=4: the paged kernel inside a shard_map
     assert "mesh_tp4_paged_decode" in kernels, record
+
+
+def test_the_recurrent_models_step_compiles_with_its_state_pool_held_once(record):
+    """The benchmark cell of a model with recurrent state: the scan kernel
+    alone and the whole mixed step at 128 slots.  The 3.2 GB state pool is
+    donated, carried through the layer loop and aliased by the kernel, so
+    it is an argument that comes back as an output and never a temporary:
+    what is not aliased of the outputs is a few vectors, and the
+    temporaries hold no second pool of that size."""
+    from operator_tpu.ops.ssm_scan import KERNEL_NAME
+
+    kernels = record["kernels"]
+    assert kernels["ssm_scan_cell_falcon-h1-34b_b128"]["ok"], record
+    step = kernels["mixed_step_falcon-h1-34b-6l_b128"]
+    assert any(call.startswith(KERNEL_NAME) for call in step["pallas_calls"]), step
+    assert any(call.startswith("ragged_attention_kernel") for call in step["pallas_calls"])
+    state_bytes = 6 * 128 * 32 * 256 * 128 * 4
+    assert step["alias_bytes"] > state_bytes  # the state and the KV pool, in place
+    assert step["output_bytes"] - step["alias_bytes"] < 1e6
+    assert step["temp_bytes"] < 0.75 * state_bytes, step
+    total = step["argument_bytes"] + step["output_bytes"] - step["alias_bytes"] + step["temp_bytes"]
+    assert total < 15.75 * 2**30  # fits the chip

@@ -415,14 +415,16 @@ class TestStepView:
             StepRecord(seq=1, kind="prefill", tokens=16, slots=1,
                        occupancy=0.25, wall_ms=9.0, host_ms=0.0, wait_ms=9.0,
                        xfer_ms=0.0, kv_pages_walked=7, prefill_tokens=16,
-                       q_tile_rows=64),
+                       q_tile_rows=64, state_rows=1),
         ])
         lines = table.splitlines()
         assert lines[0].split() == [
             "seq", "kind", "tok", "pf_tok", "slots", "occ",
             "wall_ms", "host_ms", "wait_ms", "xfer_ms",
-            "plan", "pack", "commit", "turn", "kv_pg", "q_fill", "mfu",
+            "plan", "pack", "commit", "turn", "st_rows", "kv_pg", "q_fill", "mfu",
         ]
+        # slots whose recurrent state the step touched; "-" without such state
+        assert lines[3].split()[-4] == "1" and lines[2].split()[-4] == "-"
         assert lines[3].split()[-3] == "7" and lines[2].split()[-3] == "-"
         # q_fill = tokens / q_tile_rows: 16 of the chunk's 64 rows
         assert lines[3].split()[-2] == "0.250" and lines[2].split()[-2] == "-"
