@@ -134,13 +134,16 @@ def kernels_leg(interpret: bool) -> dict:
         )
 
     page, pages_per_seq = 64, 8  # 512-token rows: several pages per walk
+    layers = 3  # a stacked pool, every layer's pages different
 
     def ragged_case(name, heads, kv_heads, head_dim, chunk, kv_len, q_count,
-                    window=None):
-        """One kernel-vs-reference comparison over rows of mixed phases."""
+                    layer, window=None):
+        """One kernel-vs-reference comparison over rows of mixed phases,
+        at one layer of the stacked pool."""
         rows = len(kv_len)
         keys = jax.random.split(jax.random.PRNGKey(len(name)), 3)
-        pool = (rows * pages_per_seq + 1, page, kv_heads, head_dim)
+        pool = (layers, rows * pages_per_seq + 1, page, kv_heads, head_dim)
+        layer = jnp.int32(layer)
         q = jax.random.normal(keys[0], (rows, chunk, heads, head_dim), jnp.bfloat16)
         k_pages = jax.random.normal(keys[1], pool, jnp.bfloat16)
         v_pages = jax.random.normal(keys[2], pool, jnp.bfloat16)
@@ -151,13 +154,13 @@ def kernels_leg(interpret: bool) -> dict:
         kv = jnp.asarray(kv_len, jnp.int32)
         count = jnp.asarray(q_count, jnp.int32)
         got = _ragged_attention_pallas(
-            q, k_pages, v_pages, table, kv, count,
+            q, k_pages, v_pages, table, kv, count, layer,
             interpret=interpret, sliding_window=window,
         )
         with jax.default_matmul_precision("highest"):
             want = ragged_attention_reference(
                 q.astype(jnp.float32), k_pages.astype(jnp.float32),
-                v_pages.astype(jnp.float32), table, kv, count,
+                v_pages.astype(jnp.float32), table, kv, count, layer,
                 sliding_window=window,
             )
         # rows past q_count (and whole inactive rows) are garbage by
@@ -196,11 +199,12 @@ def kernels_leg(interpret: bool) -> dict:
         "mixed_rows_c64": ragged_case(
             "mixed", *geometry, chunk=64,
             kv_len=[1, 200, 0, 64, 448, 333, 300, 0],
-            q_count=[1, 1, 0, 64, 64, 17, 8, 0],
+            q_count=[1, 1, 0, 64, 64, 17, 8, 0], layer=1,
         ),
         f"verify_rows_c{width}": ragged_case(
             "verify", *geometry, chunk=width,
             kv_len=[130, 5, 512, 0], q_count=[width, width, 3, 0],
+            layer=layers - 1,
         ),
         # Mistral's geometry with a window that bites inside 512 tokens
         # (its published 4096 never does below the serving cap)
@@ -208,7 +212,7 @@ def kernels_leg(interpret: bool) -> dict:
             "window", mistral.num_heads if not interpret else 4,
             mistral.num_kv_heads if not interpret else 2, mistral.head_dim,
             chunk=64, kv_len=[500, 130, 64, 0], q_count=[1, 64, 64, 0],
-            window=100,
+            layer=0, window=100,
         ),
     }
 

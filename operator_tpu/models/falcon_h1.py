@@ -303,11 +303,12 @@ def mixed_layer(config: FalconH1Config, step: Any) -> Callable:
     ``sched/mixed.py``'s view of one dispatch: the flat tokens' packing
     (``rows``, ``in_row``, ``pos``, ``valid``, ``q_start``, ``q_count``),
     ``chunk`` and ``attend``, the shared attention (KV write, ragged
-    kernel, gather back).  The carry is ``(x, recurrent)`` with
-    ``recurrent = {"ssm": [L, S, H, N, P] f32, "conv": [L, S, d_conv - 1,
-    conv_dim]}``, the WHOLE pools: the scan kernel updates the layer's
-    state in place and the conv tail is one small row update, so nothing
-    slices a layer's state out of the pool or writes one back."""
+    kernel, gather back).  The carry is ``(x, pools, recurrent)`` with
+    ``pools`` the KV pools (``step.attend`` writes and reads them at the
+    layer) and ``recurrent = {"ssm": [L, S, H, N, P] f32, "conv": [L, S,
+    d_conv - 1, conv_dim]}``, all WHOLE: the scan kernel updates the
+    layer's state in place and the conv tail is one small row update, so
+    nothing slices a layer out of a pool or writes one back."""
     from ..ops.ssm_scan import ssm_scan
     from .llama import rms_norm
 
@@ -347,7 +348,7 @@ def mixed_layer(config: FalconH1Config, step: Any) -> Callable:
         return out[None], jnp.where((at >= tail)[..., None], from_flat, from_tail)
 
     def layer_step(carry, scanned):
-        x, recurrent = carry
+        x, pools, recurrent = carry
         weights, layer = scanned["w"], scanned["layer"]
         u = rms_norm(x, weights["ln_attn"], config.rms_norm_eps)
         with jax.named_scope("ssm_conv"):
@@ -369,10 +370,10 @@ def mixed_layer(config: FalconH1Config, step: Any) -> Callable:
         q = mm(a_in, weights["wq"])
         k = scale(mm(a_in, weights["wk"]), config.key_multiplier)
         v = mm(a_in, weights["wv"])
-        attn, pages = step.attend(q, k, v, scanned)
+        attn, pools = step.attend(q, k, v, pools, layer)
         x = residual(config, x, mixed, mm(attn, weights["wo"]))
         with jax.named_scope("mlp"):
             x = x + mlp(config, weights, rms_norm(x, weights["ln_mlp"], config.rms_norm_eps))
-        return (x, {"ssm": ssm_pool, "conv": conv_pool}), pages
+        return (x, pools, {"ssm": ssm_pool, "conv": conv_pool}), None
 
     return layer_step

@@ -527,10 +527,12 @@ def mixed_layer(config: ModelConfig, step: Any):
     """The continuous scheduler's layer body for this family
     (``serving/sched/mixed.py``): projections with the optional q/k/v
     bias, the step's shared attention (``step.attend``), the gated MLP.
-    The carry is ``(x, None)``: no state beside the KV pages."""
+    The carry is ``(x, pools, None)``: the residual stream and the whole
+    KV pools, which ``step.attend`` writes and reads at ``scanned["layer"]``;
+    no recurrent state."""
 
     def layer_step(carry, scanned):
-        x, recurrent = carry
+        x, pools, recurrent = carry
         weights = scanned["w"]
         attn_in = rms_norm(x, weights["ln_attn"], config.rms_norm_eps)
 
@@ -541,8 +543,9 @@ def mixed_layer(config: ModelConfig, step: Any):
                 y = y + weights[bias].astype(y.dtype)
             return y
 
-        attn, pages = step.attend(
-            proj(attn_in, "wq"), proj(attn_in, "wk"), proj(attn_in, "wv"), scanned
+        attn, pools = step.attend(
+            proj(attn_in, "wq"), proj(attn_in, "wk"), proj(attn_in, "wv"),
+            pools, scanned["layer"],
         )
         x = x + proj(attn, "wo")
         with jax.named_scope("mlp"):
@@ -550,7 +553,7 @@ def mixed_layer(config: ModelConfig, step: Any):
             gate = jax.nn.silu(proj(mlp_in, "w_gate"))
             up = proj(mlp_in, "w_up")
             x = x + proj(gate * up, "w_down")
-        return (x, recurrent), pages
+        return (x, pools, recurrent), None
 
     return layer_step
 

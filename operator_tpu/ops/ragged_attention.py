@@ -16,12 +16,18 @@ kernel is a pure read over the page pool):
 
     q         [B, C, QH, D]  this step's query tokens, row-padded past
                              ``q_count[b]`` (padding rows are ignored)
-    k_pages   [num_pages, page_size, KH, D]  (single layer)
+    k_pages   [L, num_pages, page_size, KH, D]  the WHOLE stacked pool
     v_pages   likewise
     page_table [B, pages_per_seq] int32
     kv_len    [B] int32  valid tokens in the row's pages INCLUDING this
                          step's writes
     q_count   [B] int32  live query rows this step (0 = inactive row)
+    layer     [] int32   which layer's pages this call reads
+
+The pool goes in whole and the layer is one more scalar-prefetched value:
+a page is fetched from ``(layer, page_table[b, j])``, so the layer loop
+(``sched/mixed.py``) can carry the pool and nothing slices a layer out of
+it.  One layer's pages are all a call ever touches.
 
 Query token ``i`` of row ``b`` sits at absolute position
 ``kv_len[b] - q_count[b] + i`` and attends causally over positions
@@ -132,11 +138,12 @@ def require_ragged_kernel_support(config) -> None:
 
 def ragged_attention_reference(
     q: jax.Array,  # [B, C, QH, D]
-    k_pages: jax.Array,  # [num_pages, page_size, KH, D]
+    k_pages: jax.Array,  # [L, num_pages, page_size, KH, D]
     v_pages: jax.Array,
     page_table: jax.Array,  # [B, pages_per_seq]
     kv_len: jax.Array,  # [B]
     q_count: jax.Array,  # [B]
+    layer: jax.Array,  # [] int32
     sliding_window: Optional[int] = None,
 ) -> jax.Array:
     """Gather-then-attend oracle.  Returns [B, C, QH, D] in q.dtype.
@@ -145,13 +152,13 @@ def ragged_attention_reference(
     callers gather only the valid rows, exactly as the kernel's
     flash-state finalize leaves NaN in fully-masked rows."""
     b, c, qh, d = q.shape
-    kh = k_pages.shape[2]
+    kh = k_pages.shape[3]
     g = qh // kh
-    page_size = k_pages.shape[1]
+    page_size = k_pages.shape[2]
     max_seq = page_table.shape[1] * page_size
 
-    k = k_pages[page_table].reshape(b, max_seq, kh, d)
-    v = v_pages[page_table].reshape(b, max_seq, kh, d)
+    k = k_pages[layer, page_table].reshape(b, max_seq, kh, d)
+    v = v_pages[layer, page_table].reshape(b, max_seq, kh, d)
 
     q_grouped = q.reshape(b, c, kh, g, d)
     scores = jnp.einsum(
@@ -182,9 +189,10 @@ def _ragged_attn_kernel(
     len_ref,  # [B] int32 kv_len (SMEM)
     cnt_ref,  # [B] int32 q_count (SMEM)
     blk_ref,  # [B] int32: the q / out block each grid step holds
+    layer_ref,  # [1] int32: the layer whose pages are read
     # blocks
     q_ref,  # [1, C, QH, D] (VMEM)
-    k_hbm,  # [num_pages, page_size, KH, D] (stays in HBM)
+    k_hbm,  # [L, num_pages, page_size, KH, D] (stays in HBM)
     v_hbm,
     out_ref,  # [1, C, QH, D] in q's dtype
     # scratch
@@ -224,14 +232,15 @@ def _ragged_attn_kernel(
         # earliest kv ANY live q row can see: q_base - window + 1
         first = jnp.maximum(q_base - window + 1, 0) // page_size
     head_dim = q_ref.shape[-1]
+    layer = layer_ref[0]
 
     def dma(slot, j):
         return (
             pltpu.make_async_copy(
-                k_hbm.at[pt_ref[b, j]], k_buf.at[slot], sem.at[slot, 0]
+                k_hbm.at[layer, pt_ref[b, j]], k_buf.at[slot], sem.at[slot, 0]
             ),
             pltpu.make_async_copy(
-                v_hbm.at[pt_ref[b, j]], v_buf.at[slot], sem.at[slot, 1]
+                v_hbm.at[layer, pt_ref[b, j]], v_buf.at[slot], sem.at[slot, 1]
             ),
         )
 
@@ -346,6 +355,7 @@ def _ragged_attention_pallas(
     page_table: jax.Array,
     kv_len: jax.Array,
     q_count: jax.Array,
+    layer: jax.Array,
     *,
     interpret: bool = False,
     sliding_window: Optional[int] = None,
@@ -354,7 +364,7 @@ def _ragged_attention_pallas(
     from jax.experimental.pallas import tpu as pltpu
 
     b, c, qh, d = q.shape
-    _, page_size, kh, _ = k_pages.shape
+    _, _, page_size, kh, _ = k_pages.shape
 
     kernel = functools.partial(
         _ragged_attn_kernel,
@@ -374,12 +384,12 @@ def _ragged_attention_pallas(
     held = jax.lax.cummax(jnp.where(live, slots, -1))
     block = jnp.where(held < 0, jnp.argmax(live).astype(jnp.int32), held)
 
-    def row_block(i, pt, ln, cn, blk):
+    def row_block(i, pt, ln, cn, blk, layer):
         return (blk[i], 0, 0, 0)
 
     any_space = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
+        num_scalar_prefetch=5,
         grid=(b,),
         in_specs=[pl.BlockSpec((1, c, qh, d), row_block), any_space, any_space],
         out_specs=pl.BlockSpec((1, c, qh, d), row_block),
@@ -395,7 +405,10 @@ def _ragged_attention_pallas(
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
         name=KERNEL_NAME,
-    )(page_table, kv_len, q_count, block, q, k_pages, v_pages)
+    )(
+        page_table, kv_len, q_count, block,
+        jnp.reshape(layer, (1,)).astype(jnp.int32), q, k_pages, v_pages,
+    )
 
 
 def ragged_paged_attention(
@@ -405,6 +418,7 @@ def ragged_paged_attention(
     page_table: jax.Array,
     kv_len: jax.Array,
     q_count: jax.Array,
+    layer: jax.Array,
     sliding_window: Optional[int] = None,
 ) -> jax.Array:
     """Dispatch: Pallas kernel on TPU, dense reference elsewhere."""
@@ -412,10 +426,10 @@ def ragged_paged_attention(
 
     if on_tpu():
         return _ragged_attention_pallas(
-            q, k_pages, v_pages, page_table, kv_len, q_count,
+            q, k_pages, v_pages, page_table, kv_len, q_count, layer,
             sliding_window=sliding_window,
         )
     return ragged_attention_reference(
-        q, k_pages, v_pages, page_table, kv_len, q_count,
+        q, k_pages, v_pages, page_table, kv_len, q_count, layer,
         sliding_window=sliding_window,
     )

@@ -16,6 +16,13 @@ is masked off when the rows are gathered back.  KV for the step is
 scattered into the paged cache BEFORE attention, so the kernel is a pure
 page read.
 
+The stacked KV pools ``[L, pages, page, KH, D]`` ride the layer loop's
+CARRY, whole, beside the residual stream (and a recurrent model's state
+pools): a layer scatters its step's rows at ``(layer, page, slot)`` and
+the kernel fetches its pages from ``(layer, page)``, so the donated pool
+is updated in place and held once — no layer's slice is cut out, none is
+stacked back, and no step copies the pool.
+
 Exactly ONE program compiles per engine (static ``t_budget`` / ``chunk``
 / ``max_slots``): there is no bucket grid to warm, no per-shape compile
 to hit mid-run — the property the warmup-grid machinery exists to
@@ -47,7 +54,10 @@ __all__ = ["StepView", "make_mixed_fn"]
 class StepView:
     """What a family's layer body (``models.family_of(config).mixed_layer``)
     is given of one dispatch: the flat tokens' packing and ``attend``, the
-    attention every family shares."""
+    attention every family shares.  The body is a ``lax.scan`` step over
+    ``{"w": the layer's weights, "layer": its index}`` whose carry is
+    ``(x, pools, recurrent)``: the residual stream, the KV pools (through
+    ``attend``) and the family's recurrent pools, or ``None``."""
 
     t_budget: int
     chunk: int
@@ -57,9 +67,12 @@ class StepView:
     valid: Any  # [T] live mask
     q_start: Any  # [S] flat offset of the slot's first token
     q_count: Any  # [S] the slot's tokens this step
-    #: ``attend(q, k, v, scanned) -> (attn [1, T, QH * D], {"k", "v"})``:
-    #: RoPE, the step's K/V into the layer's pages (``scanned["k"]``,
-    #: ``scanned["v"]``), the ragged kernel, the rows back on the flat axis
+    #: ``attend(q, k, v, pools, layer) -> (attn [1, T, QH * D], pools)``
+    #: with ``pools = {"k", "v"}`` the WHOLE stacked KV pools
+    #: ``[L, pages, page, KH, D]`` off the layer loop's carry: RoPE, the
+    #: step's K/V into ``layer``'s pages in place, the ragged kernel over
+    #: that layer, the rows back on the flat axis.  The pools it returns
+    #: go back into the carry
     attend: Callable
 
 
@@ -141,27 +154,28 @@ def make_mixed_fn(generator: Any, t_budget: int, chunk: int,
         )
         page_slots = jnp.where(valid, pos % page_size, 0)
 
-        def attend(q, k, v, scanned):
+        def attend(q, k, v, pools, layer):
             q = q.reshape(1, t_budget, config.num_heads, config.head_dim)
             k = k.reshape(1, t_budget, config.num_kv_heads, config.head_dim)
             v = v.reshape(1, t_budget, config.num_kv_heads, config.head_dim)
             q = apply_rope(q, positions, inv_freq)
             k = apply_rope(k, positions, inv_freq)
-            # scatter this step's K/V into the pages FIRST — the ragged
-            # kernel then reads a cache that already holds every token a
-            # causal query may attend to (its own included)
+            # scatter this step's K/V into the layer's pages FIRST — the
+            # ragged kernel then reads a cache that already holds every
+            # token a causal query may attend to (its own included).  The
+            # pools are the loop's carry: the rows land in place
             with jax.named_scope("kv_write"):
-                k_pages = scanned["k"].at[page_ids, page_slots].set(
-                    k[0].astype(scanned["k"].dtype)
+                k_pages = pools["k"].at[layer, page_ids, page_slots].set(
+                    k[0].astype(pools["k"].dtype)
                 )
-                v_pages = scanned["v"].at[page_ids, page_slots].set(
-                    v[0].astype(scanned["v"].dtype)
+                v_pages = pools["v"].at[layer, page_ids, page_slots].set(
+                    v[0].astype(pools["v"].dtype)
                 )
             with jax.named_scope("attn"):
                 q_pack = q[0][pack_idx]  # [B, chunk, QH, D]
                 attn_pack = ragged_paged_attention(
                     q_pack.astype(k_pages.dtype), k_pages, v_pages,
-                    paged.page_table, kv_len, q_count,
+                    paged.page_table, kv_len, q_count, layer,
                     sliding_window=config.sliding_window,
                 )
                 # back to flat [T, QH, D].  The kernel leaves what it was
@@ -181,17 +195,22 @@ def make_mixed_fn(generator: Any, t_budget: int, chunk: int,
             t_budget=t_budget, chunk=chunk, rows=rows, in_row=in_row, pos=pos,
             valid=valid, q_start=q_start, q_count=q_count, attend=attend,
         ))
-        scanned_in = {
-            "w": params["layers"], "k": paged.k_pages, "v": paged.v_pages,
-        }
-        # a model with recurrent state carries its pools WHOLE through
-        # the layer loop (updated in place, ops/ssm_scan.py); the others
-        # carry nothing beside the residual stream
+        # the pools ride the layer loop's carry WHOLE, beside the residual
+        # stream: the KV pools every model has, and the recurrent pools of
+        # a model with state-space layers (updated in place,
+        # ops/ssm_scan.py).  A layer gets its weights and its index;
+        # nothing slices a layer's pages out of a pool or stacks them back
+        pools = {"k": paged.k_pages, "v": paged.v_pages}
         recurrent = None
         if paged.ssm_state is not None:
             recurrent = {"ssm": paged.ssm_state, "conv": paged.conv_state}
-            scanned_in["layer"] = jnp.arange(config.num_layers, dtype=jnp.int32)
-        (x, recurrent), pages_out = lax.scan(layer_step, (x, recurrent), scanned_in)
+        (x, pools, recurrent), _ = lax.scan(
+            layer_step, (x, pools, recurrent),
+            {
+                "w": params["layers"],
+                "layer": jnp.arange(config.num_layers, dtype=jnp.int32),
+            },
+        )
 
         x = rms_norm(x, params["ln_final"], config.rms_norm_eps)
         # only each slot's sampled positions need logit rows: gather them
@@ -252,7 +271,7 @@ def make_mixed_fn(generator: Any, t_budget: int, chunk: int,
         )[:, 0]
         latest_out = jnp.where(q_count > 0, fresh, latest)
         new_paged = dataclasses.replace(
-            paged, k_pages=pages_out["k"], v_pages=pages_out["v"],
+            paged, k_pages=pools["k"], v_pages=pools["v"],
             lengths=new_lengths,
         )
         if recurrent is not None:

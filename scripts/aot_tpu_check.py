@@ -17,13 +17,19 @@ CPU host finds them before chip time is spent.  Covered:
   never silently routed elsewhere — the check asserts which of the two
   happens for each config;
 - the whole mixed step (``serving/sched/mixed.py``) for the default model
-  at the server's default shape, int8 weights, with XLA's memory analysis,
-  lowered as the scheduler calls it (the jitted step itself, so that the
-  cache's donation counts);
+  at the server's default shape and at the 1.5B benchmark cell's (128
+  slots, 3,456 pages, 256 tokens a step), int8 weights, with XLA's memory
+  analysis, lowered as the scheduler calls it (the jitted step itself, so
+  that the cache's donation counts);
 - the state-space scan kernel (``ops/ssm_scan.py``) alone and the whole
   mixed step of a model with recurrent state, at the benchmark cell's
   shape (``falcon-h1-34b-6l``, 128 slots, 1,536 pages): the memory
   analysis shows the 3.2 GB state pool aliased, held once;
+- for each whole mixed step, ``kv_pool``: the stacked KV pools' bytes and
+  the names of the optimised HLO's instructions that MOVE a pool — a
+  ``copy``, ``dynamic-slice`` or ``dynamic-update-slice`` (or a fusion that
+  holds one) whose result has the pool's shape or one layer's slice of it.  The pools ride the layer loop's carry and are written in place:
+  the list is empty;
 - the sharded wave decode step over the 4-device topology (``tp=4``): the
   program ``SERVING_MESH=dp=1,tp=4`` runs, whose paged-attention kernel
   must sit inside a ``shard_map``.
@@ -40,6 +46,7 @@ from __future__ import annotations
 
 import functools
 import json
+import math
 import os
 import re
 import sys
@@ -70,6 +77,39 @@ def _memory(compiled) -> dict:
     }
 
 
+#: what moves a buffer whole: the opcodes a pool's instruction must not be
+_MOVES = ("copy", "dynamic-slice", "dynamic-update-slice")
+
+
+def _pool_moves(hlo: str, pool_shape: tuple) -> list[str]:
+    """Names, as a trace of the chip would print them, of the optimised
+    HLO's instructions that are a ``copy``, ``dynamic-slice`` or
+    ``dynamic-update-slice`` whose result has the stacked pool's shape or
+    one layer's slice of it (with or without the leading 1) — or a fusion
+    that holds one."""
+    shapes = {
+        "bf16[" + ",".join(str(d) for d in shape) + "]"
+        for shape in (pool_shape, pool_shape[1:], (1, *pool_shape[1:]))
+    }
+    header = re.compile(r"(?:ENTRY )?%?(\S+) \(.*\{$")
+    instruction = re.compile(r"\s*(?:ROOT )?%?(\S+) = (\w+\[[\d,]*\])\S* ([\w-]+)\(")
+    fusion_of = dict(  # fused computation -> the fusion instruction that calls it
+        (called, name) for name, called in re.findall(
+            r"^\s*(?:ROOT )?%?(\S+) = [^\n]* fusion\([^\n]*calls=%?([\w.-]+)", hlo, re.M,
+        )
+    )
+    found, computation = set(), None
+    for line in hlo.splitlines():
+        opened = header.match(line)
+        if opened:
+            computation = opened.group(1)
+            continue
+        parsed = instruction.match(line)
+        if parsed and parsed.group(2) in shapes and parsed.group(3) in _MOVES:
+            found.add(fusion_of.get(computation, parsed.group(1)))
+    return sorted(found)
+
+
 def _abstract_params(config, sharding_for):
     """The int8 serving tree as ShapeDtypeStructs (``jax.eval_shape``: no
     weight is ever allocated)."""
@@ -87,10 +127,10 @@ def _abstract_params(config, sharding_for):
 
 def _mixed_step_case(topo_device, model_id=None, slots=_SLOTS, t_budget=None,
                      kv_pages=None, spec_width=_SPEC_WIDTH):
-    """(fn, args) for the continuous scheduler's one program: by default
-    at the server's default shape for the default model, else at a
-    benchmark cell's (its slots, token budget, pool pages and sampled
-    width)."""
+    """(fn, args, stacked KV pool shape) for the continuous scheduler's
+    one program: by default at the server's default shape for the default
+    model, else at a benchmark cell's (its slots, token budget, pool pages
+    and sampled width)."""
     from jax.sharding import SingleDeviceSharding
 
     from operator_tpu.models import get_config
@@ -139,7 +179,10 @@ def _mixed_step_case(topo_device, model_id=None, slots=_SLOTS, t_budget=None,
         slot_i, slot_i,  # sample_start spec_len
         shaped((2,), jnp.uint32), slot_f, slot_f,  # rng temp top_p
     )
-    return make_mixed_fn(generator, t, _CHUNK, spec_width=spec_width), args
+    return (
+        make_mixed_fn(generator, t, _CHUNK, spec_width=spec_width), args,
+        (config.num_layers, *pool),
+    )
 
 
 def _mesh_decode_case(topo_devices):
@@ -245,7 +288,7 @@ def main() -> int:
 
     def ragged_args(heads, kv_heads, head_dim, chunk, rows=4):
         pages = _MAX_SEQ // _PAGE
-        pool = (rows * pages + 1, _PAGE, kv_heads, head_dim)
+        pool = (2, rows * pages + 1, _PAGE, kv_heads, head_dim)  # two layers
         return (
             shaped((rows, chunk, heads, head_dim), jnp.bfloat16),
             shaped(pool, jnp.bfloat16),
@@ -253,6 +296,7 @@ def main() -> int:
             shaped((rows, pages), jnp.int32),
             shaped((rows,), jnp.int32),
             shaped((rows,), jnp.int32),
+            shaped((), jnp.int32),
         )
 
     cases = [
@@ -343,11 +387,20 @@ def main() -> int:
     from operator_tpu.ops import _dispatch
 
     with mock.patch.object(_dispatch, "on_tpu", lambda: True):
-        cases.append(("mixed_step_default_model", *_mixed_step_case(topo.devices[0])))
-        cases.append(("mixed_step_falcon-h1-34b-6l_b128", *_mixed_step_case(
-            topo.devices[0], "falcon-h1-34b-6l", slots=f_slots, t_budget=f_tokens,
-            kv_pages=1536, spec_width=1,
-        )))
+        pools = {}  # whole mixed steps: the stacked KV pool's shape
+        for name, cell in (
+            ("mixed_step_default_model", {}),
+            # the 1.5B cells (BENCHMARK.json: storm and decode)
+            ("mixed_step_qwen2.5-1.5b_b128", dict(
+                model_id="qwen2.5-1.5b", slots=128, t_budget=256, kv_pages=3456,
+            )),
+            ("mixed_step_falcon-h1-34b-6l_b128", dict(
+                model_id="falcon-h1-34b-6l", slots=f_slots, t_budget=f_tokens,
+                kv_pages=1536, spec_width=1,
+            )),
+        ):
+            fn, args, pools[name] = _mixed_step_case(topo.devices[0], **cell)
+            cases.append((name, fn, args))
         if len(topo.devices) >= 4:
             cases.append(("mesh_tp4_paged_decode", *_mesh_decode_case(topo.devices[:4])))
 
@@ -366,6 +419,13 @@ def main() -> int:
                         compiled.as_text(),
                     ))),
                 }
+                if name in pools:
+                    shape = pools[name]
+                    results[name]["kv_pool"] = {
+                        "shape": list(shape),
+                        "bytes": 2 * 2 * math.prod(shape),  # K and V, bf16
+                        "moved_by": _pool_moves(compiled.as_text(), shape),
+                    }
                 print(f"OK   {name}", file=sys.stderr)
             except Exception as exc:  # noqa: BLE001 - record and continue
                 failed += 1

@@ -109,6 +109,70 @@ def test_the_recurrent_models_step_compiles_with_its_state_pool_held_once(record
     state_bytes = 6 * 128 * 32 * 256 * 128 * 4
     assert step["alias_bytes"] > state_bytes  # the state and the KV pool, in place
     assert step["output_bytes"] - step["alias_bytes"] < 1e6
-    assert step["temp_bytes"] < 0.75 * state_bytes, step
+    # 137 MB as found: no pool of either kind (the smallest, one KV pool,
+    # is 604 MB) is held a second time while the step runs
+    assert step["temp_bytes"] < 200e6, step
     total = step["argument_bytes"] + step["output_bytes"] - step["alias_bytes"] + step["temp_bytes"]
     assert total < 15.75 * 2**30  # fits the chip
+
+
+@pytest.mark.parametrize("case", [
+    "mixed_step_default_model",
+    "mixed_step_qwen2.5-1.5b_b128",
+    "mixed_step_falcon-h1-34b-6l_b128",
+])
+def test_the_kv_pool_is_held_once_and_never_copied(record, case):
+    """The stacked KV pools ride the layer loop's carry and are written in
+    place (``serving/sched/mixed.py``): in the step's optimised HLO nothing
+    copies a pool, slices a layer out of one or stacks a layer back; the
+    donated pools come back aliased; and the temporaries could not hold a
+    second copy of even one of them."""
+    step = record["kernels"][case]
+    pool = step["kv_pool"]
+    assert pool["moved_by"] == [], pool
+    assert step["temp_bytes"] < pool["bytes"] / 2, step
+    assert step["alias_bytes"] >= pool["bytes"], step
+
+
+#: the parent's way through the layer loop, cut from the optimised HLO of
+#: its 7B step: a layer sliced out of the pool and stacked back (two
+#: fusions), a whole-pool copy, and beside them what must NOT be named:
+#: the in-place scatter and an instruction of another shape
+_OLD_WAY_HLO = """\
+%fused_computation.23.clone.clone (param_0.985: bf16[28,800,64,4,128], param_1.1206: s32[]) -> bf16[800,64,4,128] {
+  %param_0.985 = bf16[28,800,64,4,128]{4,3,2,1,0:T(4,128)(2,1)} parameter(0)
+  %dynamic_slice.202 = bf16[1,800,64,4,128]{4,3,2,1,0:T(4,128)(2,1)} dynamic-slice(%param_0.985, %param_1.1206), dynamic_slice_sizes={1,800,64,4,128}
+  ROOT %bitcast.196 = bf16[800,64,4,128]{3,2,1,0:T(4,128)(2,1)S(1)} bitcast(%dynamic_slice.202)
+}
+
+%fused_computation.21.clone.clone (param_0.987: bf16[28,800,64,4,128], param_1.1208: s32[], param_2.1037: bf16[800,64,4,128]) -> bf16[28,800,64,4,128] {
+  %param_0.987 = bf16[28,800,64,4,128]{4,3,2,1,0:T(4,128)(2,1)} parameter(0)
+  ROOT %dynamic_update_slice.16 = bf16[28,800,64,4,128]{4,3,2,1,0:T(4,128)(2,1)} dynamic-update-slice(%param_0.987, %bitcast.197, %param_1.1208)
+}
+
+%fused_computation.8.clone (param_0.915: bf16[28,800,64,4,128], param_1.1131: s32[64]) -> bf16[28,800,64,4,128] {
+  ROOT %scatter.21 = bf16[28,800,64,4,128]{4,3,2,1,0:T(4,128)(2,1)} scatter(%param_0.915, %custom-call.30, %transpose.190), to_apply=%region
+}
+
+ENTRY %main.48 (paged_0_.1: bf16[28,800,64,4,128]) -> bf16[28,800,64,4,128] {
+  %dynamic-slice_bitcast_fusion.5 = bf16[800,64,4,128]{3,2,1,0:T(4,128)(2,1)S(1)} fusion(%get-tuple-element.1102, %get-tuple-element.1059), kind=kLoop, calls=%fused_computation.23.clone.clone, metadata={op_name="squeeze"}
+  %bitcast_dynamic-update-slice_fusion.5 = bf16[28,800,64,4,128]{4,3,2,1,0:T(4,128)(2,1)} fusion(%get-tuple-element.1062, %fusion.234), kind=kLoop, calls=%fused_computation.21.clone.clone
+  %fusion.221 = bf16[28,800,64,4,128]{4,3,2,1,0:T(4,128)(2,1)} fusion(%get-tuple-element.1002, %bitcast.217), kind=kCustom, calls=%fused_computation.8.clone
+  %copy.7 = bf16[64,3584]{1,0:T(8,128)(2,1)} copy(%fusion.3)
+  ROOT %copy.99 = bf16[28,800,64,4,128]{4,3,2,1,0:T(4,128)(2,1)} copy(%get-tuple-element.1135)
+}
+"""
+
+
+def test_the_pool_scan_names_the_old_ways_slice_stack_back_and_copy():
+    """What ``kv_pool.moved_by`` is read from: on the parent's HLO the scan
+    names the slice, the stack-back and the copy as a chip trace would, and
+    neither the in-place scatter nor a copy of another shape."""
+    sys.path.insert(0, str(REPO / "scripts"))
+    import aot_tpu_check
+
+    assert aot_tpu_check._pool_moves(_OLD_WAY_HLO, (28, 800, 64, 4, 128)) == [
+        "bitcast_dynamic-update-slice_fusion.5", "copy.99",
+        "dynamic-slice_bitcast_fusion.5",
+    ]
+    assert aot_tpu_check._pool_moves(_OLD_WAY_HLO, (28, 3456, 64, 2, 128)) == []

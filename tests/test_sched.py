@@ -73,13 +73,16 @@ def assert_no_leaks(generator):
 
 
 class TestRaggedKernel:
+    #: the stacked pool's layers, every one with contents of its own
+    LAYERS = 3
+
     def _setup(self, rng, b=4, c=8, qh=4, kh=2, d=16, ps=8, pps=6):
         num_pages = b * pps + 1
         k_pages = jnp.asarray(
-            rng.normal(size=(num_pages, ps, kh, d)), jnp.float32
+            rng.normal(size=(self.LAYERS, num_pages, ps, kh, d)), jnp.float32
         )
         v_pages = jnp.asarray(
-            rng.normal(size=(num_pages, ps, kh, d)), jnp.float32
+            rng.normal(size=(self.LAYERS, num_pages, ps, kh, d)), jnp.float32
         )
         table = np.zeros((b, pps), np.int32)
         free = list(range(1, num_pages))
@@ -89,12 +92,15 @@ class TestRaggedKernel:
         q = jnp.asarray(rng.normal(size=(b, c, qh, d)), jnp.float32)
         return q, k_pages, v_pages, jnp.asarray(table)
 
-    def _check(self, q, k_pages, v_pages, table, kv_len, q_count, window=None):
+    def _check(self, q, k_pages, v_pages, table, kv_len, q_count, window=None,
+               layer=1):
+        layer = jnp.int32(layer)
         ref = ragged_attention_reference(
-            q, k_pages, v_pages, table, kv_len, q_count, sliding_window=window
+            q, k_pages, v_pages, table, kv_len, q_count, layer,
+            sliding_window=window,
         )
         got = _ragged_attention_pallas(
-            q, k_pages, v_pages, table, kv_len, q_count,
+            q, k_pages, v_pages, table, kv_len, q_count, layer,
             interpret=True, sliding_window=window,
         )
         for row in range(q.shape[0]):
@@ -126,6 +132,11 @@ class TestRaggedKernel:
         # a chunk, several pages back
         dict(id="window-small-tile", qh=4, kh=2, b=4, c=16, window=7,
              q_count=[1, 5, 0, 16], kv_len=[41, 30, 12, 37]),
+        # the first, a middle and the last layer of the stacked pool: a
+        # kernel that ignored ``layer`` would read another layer's pages
+        dict(id="layer-first", qh=4, kh=2, layer=0, **_EDGES),
+        dict(id="layer-middle", qh=4, kh=2, layer=1, **_EDGES),
+        dict(id="layer-last", qh=4, kh=2, layer=2, **_EDGES),
     ], ids=lambda case: case["id"])
     def test_parity_by_rung(self, case):
         """The kernel against the reference where what it works follows
@@ -146,21 +157,42 @@ class TestRaggedKernel:
         if case["id"].startswith("edges"):
             assert tiles == {0, SMALL_TILE, case["c"]}
         window = case.get("window")
+        layer = case.get("layer", self.LAYERS - 1)
         got = _ragged_attention_pallas(
-            q, k, v, table, kv_len, q_count, interpret=True,
+            q, k, v, table, kv_len, q_count, jnp.int32(layer), interpret=True,
             sliding_window=window,
         )
         assert got.shape == q.shape and got.dtype == dtype
+        # the oracle is handed the one layer alone, as a pool of one: it
+        # cannot read another layer's pages whatever it does with ``layer``
         want = ragged_attention_reference(
-            *(x.astype(jnp.float32) for x in (q, k, v)), table, kv_len,
-            q_count, sliding_window=window,
+            q.astype(jnp.float32),
+            *(x[layer][None].astype(jnp.float32) for x in (k, v)),
+            table, kv_len, q_count, jnp.int32(0), sliding_window=window,
         )
         tol = 2e-5 if dtype == jnp.float32 else 2e-2
+        others = [
+            ragged_attention_reference(
+                *(x.astype(jnp.float32) for x in (q, k, v)), table, kv_len,
+                q_count, jnp.int32(other), sliding_window=window,
+            )
+            for other in range(self.LAYERS)
+        ]
         for row, n in enumerate(case["q_count"]):
             np.testing.assert_allclose(
                 np.asarray(got[row, :n], np.float32), np.asarray(want[row, :n]),
                 rtol=tol, atol=tol,
             )
+            # the reference indexes the same layer, and no other layer's
+            # pages would have given this answer
+            np.testing.assert_array_equal(
+                np.asarray(others[layer][row, :n]), np.asarray(want[row, :n])
+            )
+            for other in set(range(self.LAYERS)) - {layer}:
+                if n:
+                    assert np.abs(
+                        np.asarray(others[other][row, :n] - want[row, :n])
+                    ).max() > 1e-2, (row, other)
 
     def test_both_rungs_serve_the_same_queries_alike(self):
         """One row's last five queries, once as the tail of a 9-query row
@@ -173,7 +205,7 @@ class TestRaggedKernel:
         kv_len = jnp.asarray([29, 29], jnp.int32)
         q_count = jnp.asarray([9, 5], jnp.int32)
         got = _ragged_attention_pallas(
-            q, k, v, table, kv_len, q_count, interpret=True
+            q, k, v, table, kv_len, q_count, jnp.int32(1), interpret=True
         )
         np.testing.assert_allclose(
             np.asarray(got[1, :5]), np.asarray(got[0, 4:9]), rtol=2e-6, atol=2e-6
@@ -222,11 +254,177 @@ class TestRaggedKernel:
         q, k, v, table = self._setup(rng)
         kv_len = jnp.asarray([17, 30, 9, 2], jnp.int32)
         q_count = jnp.asarray([1, 1, 1, 1], jnp.int32)
-        ragged = ragged_attention_reference(q, k, v, table, kv_len, q_count)
-        decode = paged_attention_reference(q[:, 0], k, v, table, kv_len)
+        ragged = ragged_attention_reference(
+            q, k, v, table, kv_len, q_count, jnp.int32(2)
+        )
+        decode = paged_attention_reference(q[:, 0], k[2], v[2], table, kv_len)
         np.testing.assert_allclose(
             np.asarray(ragged[:, 0]), np.asarray(decode), rtol=2e-5, atol=2e-5
         )
+
+
+# ---------------------------------------------------------------------------
+# the mixed step: the pools on the layer loop's carry, written in place
+# ---------------------------------------------------------------------------
+
+
+def step_built_the_old_way(generator, params, paged, step):
+    """One mixed step as it was built before the pools rode the carry: a
+    loop over the layers in the test, each cutting its pages out of the
+    pool, scattering the step's K/V into that slice, attending over the
+    slice alone and stacking it back.  Returns the greedy tokens, the
+    pools and every layer's K/V as written (after RoPE)."""
+    from operator_tpu.models import family_of
+    from operator_tpu.models.llama import apply_rope, rms_norm, rope_frequencies
+    from operator_tpu.serving.sched.mixed import StepView
+
+    config, inv_freq = generator.config, rope_frequencies(generator.config)
+    t, chunk, page = step["ids"].shape[0], step["chunk"], paged.page_size
+    rows, pos, valid = step["rows"], step["pos"], step["valid"]
+    pack_idx = jnp.clip(
+        step["q_start"][:, None] + jnp.arange(chunk)[None], 0, t - 1
+    )
+    page_ids = jnp.where(valid, paged.page_table[rows, pos // page], 0)
+    page_slots = jnp.where(valid, pos % page, 0)
+    written = []
+
+    def attend(q, k, v, pools, layer):
+        q = apply_rope(q.reshape(1, t, config.num_heads, -1), pos[None], inv_freq)
+        k = apply_rope(k.reshape(1, t, config.num_kv_heads, -1), pos[None], inv_freq)
+        v = v.reshape(1, t, config.num_kv_heads, -1)
+        written.append((np.asarray(k[0]), np.asarray(v[0])))
+        k_layer = pools["k"][layer].at[page_ids, page_slots].set(k[0])
+        v_layer = pools["v"][layer].at[page_ids, page_slots].set(v[0])
+        attn_pack = ragged_attention_reference(
+            q[0][pack_idx], k_layer[None], v_layer[None], paged.page_table,
+            step["kv_len"], step["q_count"], jnp.int32(0),
+        )
+        attn = jnp.where(valid[:, None, None], attn_pack[rows, step["in_row"]], 0)
+        return attn.reshape(1, t, -1), {
+            "k": pools["k"].at[layer].set(k_layer),
+            "v": pools["v"].at[layer].set(v_layer),
+        }
+
+    layer_step = family_of(config).mixed_layer(config, StepView(
+        t_budget=t, chunk=chunk, rows=rows, in_row=step["in_row"], pos=pos,
+        valid=valid, q_start=step["q_start"], q_count=step["q_count"],
+        attend=attend,
+    ))
+    x = jnp.take(params["embed"], step["ids"], axis=0)[None]
+    x = x * getattr(config, "embedding_multiplier", 1.0)
+    recurrent = None
+    if paged.ssm_state is not None:
+        recurrent = {"ssm": paged.ssm_state, "conv": paged.conv_state}
+    carry = (x, {"k": paged.k_pages, "v": paged.v_pages}, recurrent)
+    for layer in range(config.num_layers):
+        carry, _ = layer_step(carry, {
+            "w": jax.tree_util.tree_map(lambda leaf: leaf[layer], params["layers"]),
+            "layer": jnp.int32(layer),
+        })
+    x, pools, _ = carry
+    x = rms_norm(x, params["ln_final"], config.rms_norm_eps)
+    head = params["embed"].T if config.tie_embeddings else params["lm_head"]
+    last = x[0][step["q_start"] + step["q_count"] - 1]
+    return np.asarray(jnp.argmax(last @ head, axis=-1)), pools, written
+
+
+class TestMixedStepPool:
+    @pytest.mark.parametrize("name", ["tiny-test", "tiny-falcon-h1"])
+    def test_a_step_writes_each_layers_rows_in_place_and_nothing_else(self, name):
+        """One step of a three-layer model over a pool full of noise: a
+        decode row, a prefill chunk across a page boundary, an idle slot,
+        a fresh prompt and six padding tokens.  Afterwards every layer
+        holds ITS K/V at the step's ``(page, slot)``s, every other entry
+        of both pools is the bit it was (the trash page excepted), and the
+        tokens are those of the step built the old way."""
+        from operator_tpu.models import family_of, get_config
+        from operator_tpu.serving.sched.mixed import make_mixed_fn
+
+        config = get_config(name)
+        weights = family_of(config).init_params(
+            config, jax.random.PRNGKey(0), dtype=jnp.float32
+        )
+        generator = BatchedGenerator(
+            weights, config, ByteTokenizer(), paged=True, max_slots=4,
+            max_seq=128, page_size=16, cache_dtype=jnp.float32,
+            metrics=MetricsRegistry(),
+        )
+        rng = np.random.default_rng(3)
+        paged = generator.paged_cache
+        noise = {
+            field.name: jnp.asarray(
+                rng.normal(size=getattr(paged, field.name).shape), jnp.float32
+            )
+            for field in dataclasses.fields(paged)
+            if field.name.endswith(("_pages", "_state"))
+            and getattr(paged, field.name) is not None
+        }
+        pages_per_seq = paged.page_table.shape[1]
+        paged = dataclasses.replace(
+            paged, **noise,
+            page_table=1 + jnp.arange(4 * pages_per_seq, dtype=jnp.int32).reshape(4, -1),
+            lengths=jnp.asarray([20, 13, 9, 0], jnp.int32),
+        )
+        assert paged.k_pages.shape[:2] == (3, 4 * pages_per_seq + 1)
+        t_budget, chunk = 16, 8
+        q_count = np.asarray([1, 6, 0, 3], np.int32)  # decode, chunk, idle, fresh
+        first = np.asarray([20, 13, 0, 0], np.int32)  # 13..18 crosses a page
+        q_start = np.asarray([0, 1, 0, 7], np.int32)
+        live = int(q_count.sum())
+        rows = np.zeros(t_budget, np.int32)
+        rows[:live] = np.repeat(np.arange(4), q_count)
+        in_row = np.zeros(t_budget, np.int32)
+        in_row[:live] = np.concatenate([np.arange(n) for n in q_count])
+        valid = np.arange(t_budget) < live
+        step = {
+            "chunk": chunk,
+            "ids": jnp.asarray(rng.integers(0, config.vocab_size, t_budget), jnp.int32),
+            "rows": jnp.asarray(rows), "in_row": jnp.asarray(in_row),
+            "pos": jnp.asarray(np.where(valid, first[rows] + in_row, 0), jnp.int32),
+            "valid": jnp.asarray(valid),
+            "q_start": jnp.asarray(q_start), "q_count": jnp.asarray(q_count),
+            "kv_len": jnp.asarray(np.where(q_count > 0, first + q_count, [0, 0, 9, 0]), jnp.int32),
+        }
+        # the step donates the cache: what is compared is copied out first
+        before = {key: np.asarray(getattr(paged, key)) for key in ("k_pages", "v_pages")}
+        table = np.asarray(paged.page_table)
+        want_toks, want_pools, written = step_built_the_old_way(
+            generator, weights, paged, step
+        )
+        zeros = jnp.zeros((4,), jnp.int32)
+        new_paged, toks, _, _, _ = make_mixed_fn(generator, t_budget, chunk)(
+            weights, paged, step["ids"], step["rows"], step["pos"], step["valid"],
+            step["in_row"], step["q_start"], step["q_count"], step["kv_len"],
+            zeros, jnp.zeros((t_budget,), bool),
+            step["q_start"] + step["q_count"] - 1, zeros,
+            jax.random.PRNGKey(0), jnp.zeros((4,), jnp.float32),
+            jnp.ones((4,), jnp.float32),
+        )
+        scheduled = q_count > 0
+        assert np.asarray(toks)[scheduled, 0].tolist() == want_toks[scheduled].tolist()
+        page_of = table[rows, np.asarray(step["pos"]) // 16][:live]
+        slot_of = (np.asarray(step["pos"]) % 16)[:live]
+        assert len(set(page_of.tolist())) == 4  # four pages written, none the trash page
+        for which, key in enumerate(("k_pages", "v_pages")):
+            got = np.asarray(getattr(new_paged, key))
+            for layer in range(config.num_layers):
+                np.testing.assert_allclose(
+                    got[layer, page_of, slot_of], written[layer][which][:live],
+                    rtol=1e-5, atol=1e-5,
+                )
+                if layer:  # and not the layer before's
+                    assert np.abs(
+                        got[layer, page_of, slot_of] - written[layer - 1][which][:live]
+                    ).max() > 1e-2
+            untouched = np.ones(got.shape[:3], bool)
+            untouched[:, page_of, slot_of] = False
+            untouched[:, 0] = False  # the trash page takes the padding tokens
+            np.testing.assert_array_equal(got[untouched], before[key][untouched])
+            assert untouched.sum() == got[..., 0, 0].size - 3 * (live + 16)
+            np.testing.assert_allclose(
+                got[:, 1:], np.asarray(want_pools[key[0]])[:, 1:], rtol=1e-5, atol=1e-5
+            )
+        assert np.asarray(new_paged.lengths).tolist() == np.asarray(step["kv_len"]).tolist()
 
 
 # ---------------------------------------------------------------------------
