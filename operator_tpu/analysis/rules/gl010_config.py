@@ -71,8 +71,8 @@ def _config_fields(tree: ast.Module) -> dict[str, tuple[str, int]]:
 
 def _code_read_envs(root: Path) -> set[str]:
     """Every env var name the code reads by string literal — the package
-    plus the root-level entry points (bench.py) and scripts/, which read
-    BENCH_* / CI knobs the README documents."""
+    plus the root-level entry points (chip_smoke.py) and scripts/, which
+    read knobs the README documents."""
     paths: list[Path] = sorted(root.glob("*.py"))
     for sub in ("operator_tpu", "scripts"):
         if (root / sub).is_dir():

@@ -7,8 +7,7 @@ at build time (the ``utils/faultinject.py`` discipline), so the same
 (spec, seed) replays byte-identically; ``driver.py`` fires them open-loop
 (arrivals keep coming when the system falls behind — that is the point);
 ``storm.py`` assembles the in-process operator→router→serving stack the
-storm drives, shared by ``bench.py`` and the CI smoke
-(``python -m operator_tpu.loadgen``).
+storm drives (the CI smoke, ``python -m operator_tpu.loadgen``).
 """
 
 from __future__ import annotations

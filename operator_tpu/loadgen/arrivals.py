@@ -7,7 +7,7 @@ time from one ``random.Random(seed)`` (the ``utils/faultinject.py``
 ``bernoulli`` discipline: no draw during the run, so two materialisations
 of the same (spec, seed) are byte-identical regardless of scheduling,
 wall-clock, or how far the system fell behind).  ``fingerprint()`` hashes
-the materialised schedule; the bench and the CI smoke assert two-replay
+the materialised schedule; the tests and the CI smoke assert two-replay
 equality on it.
 
 Time-varying rates (storm bursts, diurnal ramps) use Lewis-Shedler
@@ -159,7 +159,7 @@ class ArrivalProcess:
     def fingerprint(self) -> str:
         """sha256 over the spec + the materialised schedule — equal
         fingerprints mean byte-identical replays (the two-replay gate
-        bench.py and the CI smoke assert)."""
+        the CI smoke asserts)."""
         basis = {
             "spec": self.spec.to_dict(),
             "seed": self.seed,

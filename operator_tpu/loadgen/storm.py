@@ -7,14 +7,9 @@ ProviderRegistry whose ``storm`` backend dispatches through a real
 :class:`~..router.core.EngineRouter` over in-process replicas — so a
 storm exercises admission, affinity routing, load-feedback shedding,
 failover, deadline clamping, and the ledger's journaling together, not a
-mocked subset.  Replicas come in two flavours:
-
-- :class:`SyntheticReplica` — deterministic engine-less service times
-  with a bounded concurrency gate, so the CPU-only CI smoke shows REAL
-  queueing collapse under overload without JAX;
-- :class:`EngineReplica` — wraps a live ``ServingEngine`` (bench.py's
-  open-loop sweep), mapping SLO class to admission priority and the
-  residual budget to a ``SamplingParams.deadline``.
+mocked subset.  A replica is a :class:`SyntheticReplica`: deterministic
+engine-less service times with a bounded concurrency gate, so the
+CPU-only CI smoke shows REAL queueing collapse under overload without JAX.
 
 Every storm submit is one ``pipeline.process_pod_failure`` call on a pod
 carrying a ``podmortem.io/slo-class`` annotation; the ledger admits at
@@ -68,7 +63,6 @@ from .arrivals import ArrivalEvent, ArrivalProcess, ArrivalSpec
 from .driver import run_open_loop
 
 __all__ = [
-    "EngineReplica",
     "InProcessServingBackend",
     "StormStack",
     "SyntheticReplica",
@@ -78,9 +72,6 @@ __all__ = [
 
 #: pod annotation the pipeline reads the SLO class from
 SLO_CLASS_ANNOTATION = "podmortem.io/slo-class"
-
-#: SLO class -> scheduler admission priority (EDF orders within a class)
-CLASS_PRIORITY = {"interactive": 10, "standard": 5, "batch": 0}
 
 #: recall-hot arrivals repeat these EXACT log bodies, so incident-memory
 #: fingerprints collide (recall hits) and router affinity keeps them on
@@ -238,73 +229,6 @@ class SyntheticReplica:
         )
 
 
-class EngineReplica:
-    """A live ``ServingEngine`` behind the storm router (bench.py's
-    open-loop sweep uses one per engine).  Imports serving lazily so the
-    loadgen package stays importable on JAX-less boxes."""
-
-    def __init__(self, replica_id: str, engine: Any, *, max_tokens: int = 48) -> None:
-        self.id = replica_id
-        self.engine = engine
-        self.max_tokens = max_tokens
-
-    def load(self) -> ReplicaLoad:
-        return self.engine.load_report()
-
-    async def serve(
-        self,
-        request: AnalysisRequest,
-        budget_s: Optional[float],
-        degrade_frac: float = 1.0,
-        phase: str = "full",
-    ) -> AIResponse:
-        from ..serving.types import SamplingParams
-
-        logs = ""
-        slo_class = getattr(request, "slo_class", None)
-        if request.failure_data is not None:
-            logs = request.failure_data.logs or ""
-            slo_class = slo_class or getattr(
-                request.failure_data, "slo_class", None
-            )
-        prompt = f"Explain this pod failure:\n{logs[:2048]}\nRoot cause:"
-        deadline = (
-            self.engine.generator._clock() + budget_s
-            if budget_s is not None
-            else None
-        )
-        max_tokens = self.max_tokens
-        if phase == "prefill":
-            # disaggregated prefill leg: run the full prompt for exactly
-            # one token — the decode leg picks up over the fabric
-            max_tokens = 1
-        if degrade_frac < 1.0:
-            max_tokens = max(1, int(max_tokens * degrade_frac))
-        params = SamplingParams(
-            max_tokens=max_tokens,
-            temperature=0.0,
-            deadline=deadline,
-            slo_class=slo_class,
-            degraded=degrade_frac < 1.0,
-            recall_p=getattr(request, "recall_p", 0.0),
-        )
-        priority = CLASS_PRIORITY.get(slo_class or "", 5)
-        result = await self.engine.generate(prompt, params, priority=priority)
-        return AIResponse(
-            explanation=result.text,
-            provider_id="storm",
-            model_id="tpu-native",
-            completion_tokens=result.completion_tokens,
-            deadline_outcome=(
-                "deadline-exceeded" if result.finish_reason == "deadline"
-                and not result.completion_tokens else
-                "truncated" if result.finish_reason == "deadline"
-                else "degraded" if result.finish_reason == "degraded"
-                else "completed" if budget_s is not None else None
-            ),
-        )
-
-
 # --------------------------------------------------------------------------
 # the routed backend
 # --------------------------------------------------------------------------
@@ -322,7 +246,7 @@ class InProcessServingBackend:
 
     def __init__(
         self,
-        replicas: "list[SyntheticReplica | EngineReplica]",
+        replicas: "list[SyntheticReplica]",
         *,
         metrics: Optional[MetricsRegistry] = None,
         shed_pressure: int = 8,
@@ -350,7 +274,7 @@ class InProcessServingBackend:
     # -- elastic membership (docs/SCALING.md): the discovery loop mutates
     # the serving plane mid-storm through these, without restart --------
     def add_replica(
-        self, replica: "SyntheticReplica | EngineReplica"
+        self, replica: "SyntheticReplica"
     ) -> None:
         self.replicas[replica.id] = replica
         self.router.add(Replica(id=replica.id, url=f"inproc://{replica.id}"))
@@ -545,7 +469,7 @@ class StormStack:
 
 async def build_storm_stack(
     *,
-    replicas: "Optional[list[SyntheticReplica | EngineReplica]]" = None,
+    replicas: "Optional[list[SyntheticReplica]]" = None,
     config: Optional[OperatorConfig] = None,
     metrics: Optional[MetricsRegistry] = None,
     ledger_path: Optional[str] = None,
@@ -627,8 +551,8 @@ async def run_storm(
     drain_s: float = 30.0,
 ) -> dict:
     """Drive one storm open-loop and fold the ledger's verdict into the
-    driver's offered/achieved accounting — the record bench.py publishes
-    as ``open_loop`` and the CI smoke asserts on."""
+    driver's offered/achieved accounting — the record the CI smoke
+    asserts on."""
     report = await run_open_loop(
         stack.submit, process,
         time_scale=stack.time_scale, drain_s=drain_s,

@@ -10,13 +10,10 @@ can never select a program that was not compiled before readiness flipped.
 
 Mixed into :class:`serving.engine.BatchedGenerator`.
 
-With ``sched_mode=continuous`` (serving/sched/, docs/SERVING.md) wave
-FORMATION moves behind the scheduler: admission becomes token-level per
-step and the batched-prefill dispatch below is not used.  The POLICY
-stays here — the scheduler calls :meth:`deadline_policy` and
-:meth:`_truncate_prompt`, and shares the budget/page formulas
-(``types.prompt_budget`` / ``types.pages_needed``) — so the two modes
-cannot diverge on what gets admitted, clamped, or refused.
+The POLICY both engines share — :meth:`Runtime.deadline_policy`, tail
+truncation, the budget/page formulas (``types.prompt_budget`` /
+``types.pages_needed``) — lives in serving/runtime.py and serving/types.py;
+what is here is the wave engine's alone.
 """
 
 from __future__ import annotations
@@ -43,101 +40,6 @@ log = logging.getLogger(__name__)
 
 class AdmissionMixin:
     """Wave formation + the warmup grid (see module doc)."""
-
-    # ------------------------------------------------------------------
-    # deadline budget (utils/deadline.py): admission is the enforcement
-    # point for the decode leg — the one stage whose cost is predictable
-    # up front (max_tokens x per-token step time)
-    # ------------------------------------------------------------------
-
-    def decode_token_estimate_s(self) -> float:
-        """Expected seconds per decoded token: the MEASURED p50 of the
-        decode_step stage once any block has run, else the constructor's
-        roofline estimate (``roofline_token_s``).  0.0 = unknown — the
-        policy then only rejects already-expired requests (it will not
-        clamp on a guess it doesn't have)."""
-        stats = self.metrics.stage("decode_step")
-        if stats.count:
-            return stats.p50_ms / 1e3
-        return self.roofline_token_s or 0.0
-
-    def deadline_policy(
-        self,
-        params: SamplingParams,
-        *,
-        now: "float | None" = None,
-        pressure: "float | None" = None,
-    ) -> "tuple[SamplingParams, str]":
-        """(possibly clamped params, outcome) for one request's budget.
-
-        Outcomes: ``"ok"`` (fits, untouched), ``"truncated"``
-        (``max_tokens`` clamped to the roofline fit, ``deadline_clamped``
-        set so the finish reason reads "deadline"), ``"degraded"``
-        (overload ladder scaled ``max_tokens`` down — degrade-before-
-        reject, router/value.py), ``"shed"`` (the ladder dropped the
-        request outright: lowest value under storm, class unprotected),
-        ``"rejected"`` (the residue cannot fit even one token).  Requests
-        without a deadline pass the deadline leg untouched but can still
-        be degraded or shed under pressure.
-
-        ``pressure`` is the caller's load signal (queued + running rows):
-        when an ``overload_policy`` is wired (serving mixins default to
-        None) the ladder may truncate analysis depth BEFORE the deadline
-        math, so the clamp sees the already-reduced ask."""
-        policy = getattr(self, "overload_policy", None)
-        degraded = False
-        if (
-            policy is not None
-            and pressure is not None
-            and not params.degraded
-        ):
-            residual = None
-            if params.deadline is not None:
-                residual = params.deadline - (
-                    self._clock() if now is None else now
-                )
-            value = policy.model.value(
-                slo_class=params.slo_class,
-                residual_s=residual,
-                recall_p=params.recall_p,
-            )
-            verdict = policy.decide(
-                value, pressure, site="admission",
-                request_id=params.trace_tag or "",
-            )
-            if verdict.action == "shed":
-                return params, "shed"
-            if verdict.action == "degrade":
-                params = dataclasses.replace(
-                    params,
-                    max_tokens=max(
-                        1,
-                        int(params.max_tokens * verdict.degrade_tokens_frac),
-                    ),
-                    degraded=True,
-                )
-                degraded = True
-        ok = "degraded" if degraded else "ok"
-        if params.deadline is None:
-            return params, ok
-        now = self._clock() if now is None else now
-        remaining = params.deadline - now
-        if remaining <= 0.0:
-            return params, "rejected"
-        per_token = self.decode_token_estimate_s()
-        if per_token <= 0.0:
-            return params, ok
-        fit = int(remaining / per_token)
-        if fit < 1:
-            return params, "rejected"
-        if fit < params.max_tokens:
-            return (
-                dataclasses.replace(
-                    params, max_tokens=fit, deadline_clamped=True
-                ),
-                "truncated",
-            )
-        return params, ok
 
     def _deadline_clamp_wave(
         self, params_list: "Sequence[SamplingParams]"
@@ -175,13 +77,7 @@ class AdmissionMixin:
             + decode
         )
 
-    def precompile_grid(
-        self,
-        level: str = "serving",
-        *,
-        workload_prompts: "Sequence[str] | None" = None,
-        workload_params: "SamplingParams | None" = None,
-    ) -> dict:
+    def precompile_grid(self, level: str = "serving") -> dict:
         """Compile every program the admission policy can select BEFORE
         serving: a mid-run XLA compile is an SLO violation, not noise (the
         100/min CPU soak's 5.9 s p99 was exactly three first-encounter
@@ -202,14 +98,6 @@ class AdmissionMixin:
             already off-loop (ensure_guided).
           - ``"full"``: additionally the guided variants of the whole grid
             and the guided decode block.
-
-        ``workload_prompts`` (with ``workload_params``, e.g. the bench
-        harness whose prompt set is known up front) restricts the length
-        buckets to exactly those the given prompts produce under the REAL
-        encode/truncate/prefix pipeline — every wave SIZE stays covered
-        (open-loop arrivals form all of them) but chip time is not spent
-        compiling length buckets the workload cannot hit.  The bucket
-        derivation lives here, next to the admission math it must mirror.
 
         Every wave runs through the REAL admission path (`_admit_tokens`),
         so bucket selection, page granting, shared-prefix detection, and
@@ -253,41 +141,6 @@ class AdmissionMixin:
             i: t_buckets(self.max_seq - 1 - len(ptoks))
             for i, ptoks in enumerate(prefixes)
         }
-        if workload_prompts is not None:
-            # restrict to the buckets THIS workload's prompts produce,
-            # derived through the real encode/truncate/prefix pipeline so
-            # it can never desync from admission
-            if workload_params is None:
-                raise ValueError(
-                    "workload_prompts requires workload_params: the "
-                    "truncation budget (max_tokens) decides the buckets"
-                )
-            probe = workload_params
-            budget = self.max_seq - max(
-                1, min(probe.max_tokens, self.max_seq // 2)
-            )
-            plain_set: set = set()
-            prefix_sets: dict = {i: set() for i in range(len(prefixes))}
-            for prompt in workload_prompts:
-                toks = self._truncate_prompt(
-                    self.tokenizer.encode(prompt), budget
-                )
-                for i, ptoks in enumerate(prefixes):
-                    if (
-                        len(toks) - 1 >= len(ptoks)
-                        and toks[: len(ptoks)] == ptoks
-                    ):
-                        prefix_sets[i].add(
-                            _bucket(len(toks) - len(ptoks), 64, self.max_seq)
-                        )
-                # EVERY prompt's full-length plain bucket is admissible,
-                # prefix-sharer or not: sharing is per-wave all-or-nothing,
-                # so a mixed wave (sharer + non-sharer) takes the PLAIN
-                # program at the longest row's full length
-                plain_set.add(_bucket(len(toks), 64, self.max_seq))
-            plain_ts = sorted(plain_set)
-            prefix_ts = {i: sorted(v) for i, v in prefix_sets.items()}
-
         guided_variants = [False] + ([True] if level == "full" else [])
         base = dict(max_tokens=1, stop_on_eos=False)
         waves: list[tuple[list, SamplingParams]] = []
@@ -664,7 +517,8 @@ class AdmissionMixin:
         return result
 
     def _truncate_prompt(self, ids: list, budget: int) -> list:
-        """Fit ``ids`` into ``budget`` tokens.
+        """Fit ``ids`` into ``budget`` tokens (over ``Runtime``'s plain
+        tail truncation, for an engine with registered prefixes).
 
         Failure evidence concentrates at the TAIL; instructions sit at
         the HEAD — when the prompt starts with the cached prefix, drop

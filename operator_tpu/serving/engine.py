@@ -18,19 +18,26 @@ Here generation runs on the local TPU with **continuous batching**:
 - **Per-slot sampling params**: temperature / top-p ride in ``[B]`` arrays,
   so requests with different AIProvider configs share one batch.
 
-Two layers: :class:`BatchedGenerator` is the synchronous JAX core (jitted
-prefill / decode-step / sampler); :class:`ServingEngine` is the asyncio
-front the operator talks to (queue, admission, futures).  The split keeps
-the JAX code testable without an event loop.
+Layers: :class:`serving.runtime.Runtime` is the device state one chip
+holds (weights, page pool and allocator, slot table, RNG, step clock,
+the shared admission policy) and owns no way of forming a step;
+:class:`BatchedGenerator` is the WAVE engine on top of it, the
+synchronous JAX core (jitted prefill / decode-step programs);
+:class:`ServingEngine` is the asyncio front the operator talks to
+(queue, admission, futures), over either the wave engine or the
+continuous scheduler (serving/sched/), which stands on a bare
+``Runtime`` and on nothing in this module.  The split keeps the JAX
+code testable without an event loop.
 
-Module layout (round-5 split; this module remains the public import
-surface): program construction lives in :mod:`.programs`
-(ProgramBuilderMixin — every jitted XLA program), admission policy in
-:mod:`.admission` (AdmissionMixin — wave formation, truncation, prefix
-decision, page grants, warmup grid), shared dataclasses in :mod:`.types`.
-This module keeps the STATE and the loops: slot/cache/page lifecycle,
-decode stepping + pipelining, guided-automaton registry, chunked-prefill
-job advancement, and the async engine.
+Module layout: program construction lives in :mod:`.programs`
+(ProgramBuilderMixin — every jitted XLA program of the wave engine),
+wave admission in :mod:`.admission` (AdmissionMixin — wave formation,
+head-and-tail truncation, prefix decision, page grants, warmup grid),
+the sampler in :mod:`.sampler`, shared dataclasses in :mod:`.types`.
+This module keeps the wave engine's STATE and loops: its contiguous
+cache and per-slot vectors, decode stepping + pipelining,
+guided-automaton registry, registered prefixes, chunked-prefill job
+advancement, and the async engine.
 
 Grown-in serving subsystems (each opt-in or zero-cost when unused):
 multi-step decode blocks + decode-ahead pipelining; sharded TP/DP serving
@@ -61,6 +68,7 @@ from ..obs import span as obs_span
 from ..utils.timing import METRICS, MetricsRegistry
 from .admission import AdmissionMixin
 from .programs import ProgramBuilderMixin
+from .runtime import Runtime, params_dtype_name
 
 # re-exported types: the public import surface predates the round-5 module
 # split (every consumer does `from operator_tpu.serving.engine import ...`)
@@ -76,23 +84,6 @@ from .types import (  # noqa: F401
 )
 
 log = logging.getLogger(__name__)
-
-
-def _params_dtype_name(params: Any) -> str:
-    """Dtype label for the AOT-cache fingerprint: int8-quantized param
-    trees carry scale leaves, so detect via models.quant, else report the
-    first leaf's dtype."""
-    from ..models.quant import is_quantized
-
-    if is_quantized(params):
-        return "int8"
-    try:
-        import jax
-
-        leaf = jax.tree_util.tree_leaves(params)[0]
-        return str(leaf.dtype)
-    except Exception:  # noqa: BLE001 - fingerprint label only
-        return "?"
 
 
 class EngineStalled(RuntimeError):
@@ -149,8 +140,9 @@ class _Request:
     queue_wait_ms: float = 0.0
 
 
-class BatchedGenerator(AdmissionMixin, ProgramBuilderMixin):
-    """Slot-based generation over one shared KV cache (single host thread).
+class BatchedGenerator(AdmissionMixin, ProgramBuilderMixin, Runtime):
+    """The wave engine: slot-based generation over one shared KV cache
+    (single host thread), on the device state of a :class:`Runtime`.
 
     Not thread-safe by design: the ServingEngine serialises all calls on
     one worker; the TPU itself is the serial resource.
@@ -182,45 +174,105 @@ class BatchedGenerator(AdmissionMixin, ProgramBuilderMixin):
         step_ring_capacity: Optional[int] = None,
     ) -> None:
         import jax
-        import jax.numpy as jnp
 
-        self._jax = jax
-        self._jnp = jnp
-        self.config = config
-        self.tokenizer = tokenizer
-        self.max_slots = max_slots
-        self.max_seq = min(max_seq or config.max_seq_len, config.max_seq_len)
-        self.metrics = metrics or METRICS
-        # ---- step clock (obs/steptrace.py + serving/perf.py): a bounded
-        # ring of per-step wall records (host / wait / xfer) with the
-        # analytic flops-per-token model for the serving dtype, so every
-        # decode step carries an attributed MFU (STEP_RING_CAPACITY)
-        from .perf import StepClock, flops_per_token, peak_tflops
+        # ---- what the runtime's allocation reads of the wave engine: the
+        # cache layout (paged pool or contiguous rows) and the mesh
+        self.paged = paged
+        self.cache = None
+        # ---- sharded serving (BASELINE configs 3/5): params TP on heads /
+        # MLP columns, slots DP over the batch axis; one jitted program per
+        # mesh — XLA inserts the tp psums and dp scatter collectives
+        self.mesh = mesh
+        if mesh is not None:
+            from ..models.quant import is_quantized
 
-        _serving_dtype = _params_dtype_name(params)
-        self.step_clock = StepClock(
-            capacity=step_ring_capacity,
-            flops_per_token=flops_per_token(config, _serving_dtype),
-            peak_tflops=peak_tflops(
-                jax.devices()[0].device_kind, _serving_dtype
-            ),
-            max_slots=max_slots,
-            metrics=self.metrics,
+            self._init_shardings(
+                mesh, config, max_slots, quantized=is_quantized(params)
+            )
+            params = jax.tree_util.tree_map(
+                jax.device_put, params, self._param_shardings
+            )
+        else:
+            self._shardings = None
+
+        # ---- multi-LoRA serving: adapters stacked [n_layers, n_adapters+1,
+        # ...] with the all-zeros base at index 0; every request picks its
+        # adapter per slot inside ONE compiled program (models/llama.py
+        # _lora_path).  Passed as ARGUMENTS to the jitted fns — closure
+        # capture would embed tens of MB as program constants.
+        self.lora_alpha = lora_alpha
+        if lora_adapters:
+            from ..parallel.lora import stack_adapters, zero_lora
+
+            names = sorted(lora_adapters)
+            first = lora_adapters[names[0]]
+            first_a = first[next(iter(first))]["a"]
+            zero = zero_lora(
+                config, rank=first_a.shape[-1], targets=tuple(first),
+                dtype=first_a.dtype,
+            )
+            self.lora = stack_adapters([zero] + [lora_adapters[n] for n in names])
+            self._adapter_ids: dict[Optional[str], int] = {
+                None: 0, **{n: i + 1 for i, n in enumerate(names)}
+            }
+        else:
+            self.lora = None
+            self._adapter_ids = {None: 0}
+
+        # ``aot_cache`` is a prebuilt AotCache (provider overlap path), a
+        # directory path (this generator fingerprints its own cache), or
+        # None = off
+        if aot_cache is not None:
+            from .aotcache import AotCache, generator_fingerprint
+
+            if not isinstance(aot_cache, AotCache):
+                try:
+                    aot_cache = AotCache(
+                        str(aot_cache),
+                        generator_fingerprint(
+                            config=config,
+                            weight_dtype=params_dtype_name(params),
+                            max_slots=max_slots,
+                            max_seq=max_seq,
+                            cache_dtype=cache_dtype,
+                            paged=paged,
+                            page_size=page_size,
+                            kv_pages=kv_pages,
+                            mesh=mesh,
+                            decode_block=decode_block,
+                            sample_top_k=sample_top_k,
+                            pipeline_depth=pipeline_depth,
+                            prefill_chunk=prefill_chunk,
+                            lora_names=[n for n in self._adapter_ids if n],
+                        ),
+                        metrics=metrics or METRICS,
+                    )
+                except Exception:  # noqa: BLE001 - cache is an optimisation only
+                    log.warning(
+                        "AOT executable cache disabled: fingerprint "
+                        "construction failed", exc_info=True,
+                    )
+                    aot_cache = None
+
+        # ---- shared-prefix KV cache (add_shared_prefix): each registered
+        # prompt prefix is prefilled ONCE into generator-owned pages;
+        # admitted prompts that start with one reference those pages
+        # read-only and prefill only their suffix.  Registry entries:
+        # {"text", "tokens", "pages"} in registration order (the default
+        # template first, then custom AIProvider promptTemplates).
+        # Initialised unconditionally: reset() and the compat properties
+        # read it in contiguous (non-paged) mode too, where it stays empty
+        self._prefixes: list[dict] = []
+        self._prefix_fns: dict[tuple, Any] = {}  # (n_pad, t_sfx, shared, guided)
+
+        super().__init__(
+            params, config, tokenizer,
+            max_slots=max_slots, max_seq=max_seq, cache_dtype=cache_dtype,
+            metrics=metrics, seed=seed, page_size=page_size,
+            kv_pages=kv_pages, sample_top_k=sample_top_k,
+            roofline_token_s=roofline_token_s, aot_cache=aot_cache,
+            step_ring_capacity=step_ring_capacity,
         )
-        # deadline budgets (admission.deadline_policy): per-token decode
-        # estimate before any block has been measured; the clock is an
-        # attribute so chaos tests can inject a fake one
-        self.roofline_token_s = roofline_token_s
-        self._clock = time.monotonic
-        #: value-aware overload ladder (router/value.py OverloadPolicy):
-        #: when wired, admission.deadline_policy degrades/sheds by value
-        #: under pressure; None = pre-overload-control semantics
-        self.overload_policy = None
-        #: opt-in chaos seam (utils/faultinject.py): consulted per step()
-        #: round — stalls and simulated device errors for recovery tests
-        self.fault_plan = None
-        cache_dtype = cache_dtype or jnp.bfloat16
-        self.cache_dtype = cache_dtype
         # decode in blocks of K steps per host round-trip (lax.scan): one
         # dispatch + one token fetch per K tokens hides host latency for
         # K-1 of every K steps.  Finished slots may decode up to K-1 junk
@@ -230,7 +282,6 @@ class BatchedGenerator(AdmissionMixin, ProgramBuilderMixin):
         # (adds up to K-1 steps of queueing to p50, microseconds-to-ms).
         assert decode_block >= 1
         self.decode_block = decode_block
-        self.sample_top_k = sample_top_k or self.SAMPLE_TOP_K
         # decode-ahead: blocks in flight before the host fetches tokens
         # (see step()); 1 = synchronous, 2 = one block of lookahead
         assert pipeline_depth >= 1
@@ -281,159 +332,41 @@ class BatchedGenerator(AdmissionMixin, ProgramBuilderMixin):
         self.guided_state = None                    # device [B] DFA states
         self._decode_fn_guided = None
 
-        # ---- multi-LoRA serving: adapters stacked [n_layers, n_adapters+1,
-        # ...] with the all-zeros base at index 0; every request picks its
-        # adapter per slot inside ONE compiled program (models/llama.py
-        # _lora_path).  Passed as ARGUMENTS to the jitted fns — closure
-        # capture would embed tens of MB as program constants.
-        self.lora_alpha = lora_alpha
-        if lora_adapters:
-            from ..parallel.lora import stack_adapters, zero_lora
-
-            names = sorted(lora_adapters)
-            first = lora_adapters[names[0]]
-            first_a = first[next(iter(first))]["a"]
-            zero = zero_lora(
-                config, rank=first_a.shape[-1], targets=tuple(first),
-                dtype=first_a.dtype,
-            )
-            self.lora = stack_adapters([zero] + [lora_adapters[n] for n in names])
-            self._adapter_ids: dict[Optional[str], int] = {
-                None: 0, **{n: i + 1 for i, n in enumerate(names)}
-            }
-        else:
-            self.lora = None
-            self._adapter_ids = {None: 0}
-
-        # ---- sharded serving (BASELINE configs 3/5): params TP on heads /
-        # MLP columns, slots DP over the batch axis; one jitted program per
-        # mesh — XLA inserts the tp psums and dp scatter collectives
-        self.mesh = mesh
+        # ---- the decode block, one jitted program per engine; every
+        # construction site routes through _aot_wrap
+        body = self._decode_block_paged if paged else self._decode_block
         if mesh is not None:
-            from ..models.quant import is_quantized
+            s = self._shardings
+            from jax.sharding import NamedSharding, PartitionSpec as P
 
-            self._init_shardings(mesh, quantized=is_quantized(params))
-            params = self._jax.tree_util.tree_map(
-                jax.device_put, params, self._param_shardings
+            block_tokens = NamedSharding(mesh, P(None, ("dp", "fsdp")))
+            if paged:
+                in_shardings = (
+                    self._param_shardings, s["paged"], s["tokens"],
+                    s["repl"], s["batch"], s["batch"], s["batch"],
+                    s["repl"], s["batch"],  # stacked lora (small), idx
+                )
+                out_shardings = (s["paged"], block_tokens, s["tokens"], s["repl"])
+            else:
+                in_shardings = (
+                    self._param_shardings, s["cache"], s["tokens"],
+                    s["batch"], s["repl"], s["batch"], s["batch"], s["batch"],
+                    s["repl"], s["batch"],  # stacked lora (small), idx
+                )
+                out_shardings = (
+                    s["cache"], block_tokens, s["tokens"], s["batch"], s["repl"]
+                )
+            decode = jax.jit(
+                body, in_shardings=in_shardings, out_shardings=out_shardings,
+                donate_argnums=(1,),  # cache / page pool: update in place, no copy
             )
         else:
-            self._shardings = None
-        self.params = params
-
-        self.paged = paged
-        self.page_size = page_size
-
-        # ---- persisted AOT executables (serving/aotcache.py): every
-        # serving-program construction site below routes through _aot_wrap,
-        # so a warm boot (or a supervised restart) deserializes executables
-        # instead of recompiling.  ``aot_cache`` is a directory path (the
-        # generator builds + fingerprints its own cache), a prebuilt
-        # AotCache (provider overlap path), or None = off.
-        self._aot = None
-        if aot_cache is not None:
-            from .aotcache import AotCache, generator_fingerprint
-
-            if isinstance(aot_cache, AotCache):
-                self._aot = aot_cache
-                self._aot.metrics = self.metrics
-            else:
-                try:
-                    payload = generator_fingerprint(
-                        config=config,
-                        weight_dtype=_params_dtype_name(params),
-                        max_slots=max_slots,
-                        max_seq=max_seq,
-                        cache_dtype=cache_dtype,
-                        paged=paged,
-                        page_size=page_size,
-                        kv_pages=kv_pages,
-                        mesh=mesh,
-                        decode_block=decode_block,
-                        sample_top_k=sample_top_k,
-                        pipeline_depth=pipeline_depth,
-                        prefill_chunk=prefill_chunk,
-                        lora_names=[n for n in self._adapter_ids if n],
-                    )
-                    self._aot = AotCache(
-                        str(aot_cache), payload, metrics=self.metrics
-                    )
-                except Exception:  # noqa: BLE001 - cache is an optimisation only
-                    log.warning(
-                        "AOT executable cache disabled: fingerprint "
-                        "construction failed", exc_info=True,
-                    )
-
-        # ---- shared-prefix KV cache (add_shared_prefix): each registered
-        # prompt prefix is prefilled ONCE into generator-owned pages;
-        # admitted prompts that start with one reference those pages
-        # read-only and prefill only their suffix.  Registry entries:
-        # {"text", "tokens", "pages"} in registration order (the default
-        # template first, then custom AIProvider promptTemplates).
-        # Initialised unconditionally: reset() and the compat properties
-        # read it in contiguous (non-paged) mode too, where it stays empty
-        self._prefixes: list[dict] = []
-        self._prefix_fns: dict[tuple, Any] = {}  # (n_pad, t_sfx, shared, guided)
-        if paged:
-            from ..ops.paged_attention import PagedKVCache
-
-            self.pages_per_seq = -(-self.max_seq // page_size)
-            # default: worst case + trash page (configure kv_pages smaller to
-            # oversubscribe HBM — admission then backpressures on the free
-            # list instead of reserving max_seq per slot up front)
-            num_pages = kv_pages or (max_slots * self.pages_per_seq + 1)
-            self.allocator = PageAllocator(num_pages)
-            self.cache = None
-            self._alloc_decode_state()
-            if mesh is not None:
-                s = self._shardings
-                from jax.sharding import NamedSharding, PartitionSpec as P
-
-                block_tokens = NamedSharding(mesh, P(None, ("dp", "fsdp")))
-                self._decode_fn = self._aot_wrap("decode", jax.jit(
-                    self._decode_block_paged,
-                    in_shardings=(
-                        self._param_shardings, s["paged"], s["tokens"],
-                        s["repl"], s["batch"], s["batch"], s["batch"],
-                        s["repl"], s["batch"],  # stacked lora (small), idx
-                    ),
-                    out_shardings=(s["paged"], block_tokens, s["tokens"], s["repl"]),
-                    donate_argnums=(1,),  # page pool: update in place, no copy
-                ))
-            else:
-                self._decode_fn = self._aot_wrap(
-                    "decode",
-                    jax.jit(self._decode_block_paged, donate_argnums=(1,)),
-                )
-        else:
-            self._alloc_decode_state()
-            if mesh is not None:
-                s = self._shardings
-                from jax.sharding import NamedSharding, PartitionSpec as P
-
-                block_tokens = NamedSharding(mesh, P(None, ("dp", "fsdp")))
-                self._decode_fn = self._aot_wrap("decode", jax.jit(
-                    self._decode_block,
-                    in_shardings=(
-                        self._param_shardings, s["cache"], s["tokens"],
-                        s["batch"], s["repl"], s["batch"], s["batch"], s["batch"],
-                        s["repl"], s["batch"],  # stacked lora (small), idx
-                    ),
-                    out_shardings=(
-                        s["cache"], block_tokens, s["tokens"], s["batch"], s["repl"]
-                    ),
-                    donate_argnums=(1,),  # KV cache: update in place, no copy
-                ))
-            else:
-                self._decode_fn = self._aot_wrap(
-                    "decode",
-                    jax.jit(self._decode_block, donate_argnums=(1,)),
-                )
-        self.slots: list[_Slot] = [_Slot() for _ in range(max_slots)]
+            decode = jax.jit(body, donate_argnums=(1,))
+        self._decode_fn = self._aot_wrap("decode", decode)
         # per-slot generation counter: an in-flight decode block carries the
         # epoch it was dispatched under, so tokens from a block dispatched
         # before a slot was recycled are never credited to the new sequence
         self._slot_epoch = [0] * max_slots
-        self._rng = jax.random.PRNGKey(seed)
         # host shadow of per-slot token counts (BOTH cache layouts): the
         # decode loop must never fetch offsets from the device — at the 8B
         # target the per-step host budget is ~10ms and a blocking read eats it
@@ -444,36 +377,27 @@ class BatchedGenerator(AdmissionMixin, ProgramBuilderMixin):
 
         self._prefill_fns: dict[tuple, Any] = {}  # (n_pad, t_pad, guided)
 
-    def _aot_wrap(self, name: str, fn: Any) -> Any:
-        """Route one serving program through the AOT executable cache.
-
-        Identity when the cache is off — every construction site stays a
-        plain ``jax.jit`` callable then, so the wrapping is zero-cost in
-        the default configuration."""
-        if self._aot is None:
-            return fn
-        from .aotcache import CachedProgram
-
-        return CachedProgram(self._aot, name, fn)
-
-    def _init_shardings(self, mesh: Any, *, quantized: bool = False) -> None:
+    def _init_shardings(
+        self, mesh: Any, config: ModelConfig, max_slots: int, *,
+        quantized: bool = False,
+    ) -> None:
         """Validate the mesh against the model and build the sharding table."""
+        import jax
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         from ..parallel.mesh import kv_cache_spec, paged_cache_specs, param_shardings
 
-        jax = self._jax
         sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
         tp = sizes.get("tp", 1)
         dp_total = sizes.get("dp", 1) * sizes.get("fsdp", 1)
-        if self.config.num_kv_heads % tp or self.config.num_heads % tp:
+        if config.num_kv_heads % tp or config.num_heads % tp:
             raise ValueError(
-                f"tp={tp} must divide kv_heads={self.config.num_kv_heads} "
-                f"and heads={self.config.num_heads}"
+                f"tp={tp} must divide kv_heads={config.num_kv_heads} "
+                f"and heads={config.num_heads}"
             )
-        if self.max_slots % dp_total:
+        if max_slots % dp_total:
             raise ValueError(
-                f"max_slots={self.max_slots} must be a multiple of "
+                f"max_slots={max_slots} must be a multiple of "
                 f"dp*fsdp={dp_total} (slots shard over the data axes)"
             )
         self._dp_total = dp_total
@@ -481,7 +405,7 @@ class BatchedGenerator(AdmissionMixin, ProgramBuilderMixin):
         def ns(spec):
             return NamedSharding(mesh, spec)
 
-        self._param_shardings = param_shardings(mesh, self.config, quantized=quantized)
+        self._param_shardings = param_shardings(mesh, config, quantized=quantized)
         self._shardings = {
             "repl": ns(P()),
             "batch": ns(P(("dp", "fsdp"))),          # [B] per-slot vectors
@@ -877,44 +801,23 @@ class BatchedGenerator(AdmissionMixin, ProgramBuilderMixin):
         """Registered LoRA adapter names (multi-LoRA serving)."""
         return sorted(name for name in self._adapter_ids if name is not None)
 
+    def _place(self, create: Any, name: str) -> Any:
+        # on a mesh the state is allocated IN its sharded layout: created
+        # whole and then placed, the pool sits on device 0 first — next to
+        # the still-unsharded parameters, that was an out-of-memory at 7B
+        # on four chips (chip run, PR 21)
+        if self.mesh is not None:
+            create = self._jax.jit(create, out_shardings=self._shardings[name])
+        return create()
+
     def _alloc_decode_state(self) -> None:
-        """Fresh zeroed decode state: KV cache / page pool (+ mesh
-        placement) and the per-slot device vectors.  Used at construction
-        and by :meth:`reset` — one code path, so post-recovery state can
-        never diverge from fresh-start state."""
+        """The runtime's page pool, or this engine's contiguous cache in
+        its place, and the per-slot device vectors of the decode block."""
         jnp = self._jnp
-
-        def place(create, name):
-            # on a mesh the state is allocated IN its sharded layout:
-            # created whole and then placed, the pool sits on device 0
-            # first — next to the still-unsharded parameters, that was an
-            # out-of-memory at 7B on four chips (chip run, PR 21)
-            if self.mesh is not None:
-                create = self._jax.jit(
-                    create, out_shardings=self._shardings[name]
-                )
-            return create()
-
         if self.paged:
-            from ..ops.paged_attention import PagedKVCache
-
-            self.paged_cache = place(
-                lambda: PagedKVCache.create(
-                    self.config.num_layers, self.allocator.num_pages,
-                    self.page_size, self.config.num_kv_heads,
-                    self.config.head_dim, self.max_slots, self.pages_per_seq,
-                    dtype=self.cache_dtype,
-                    # a model with recurrent state keeps it in the SAME
-                    # cache object, so whatever donates, resets or frees
-                    # the pool covers it
-                    recurrent=PagedKVCache.recurrent_shapes(
-                        self.config, self.max_slots
-                    ),
-                ),
-                "paged",
-            )
+            super()._alloc_decode_state()
         else:
-            self.cache = place(
+            self.cache = self._place(
                 lambda: KVCache.create(
                     self.config, self.max_slots, self.max_seq,
                     dtype=self.cache_dtype,
@@ -940,51 +843,39 @@ class BatchedGenerator(AdmissionMixin, ProgramBuilderMixin):
         return False
 
     def reset(self) -> None:
-        """Drop every sequence and rebuild the device decode state.
-
-        The recovery path after a device error mid-step: donated
-        buffers (KV cache / page pool) may be invalid, so fresh zeroed
-        caches are allocated, all pages freed, and every slot emptied —
-        the WEIGHTS are reused (never donated, still resident).  In-flight
-        generations are lost; their futures were already failed by the
-        ServingEngine before it calls this.
-        """
+        """:meth:`Runtime.reset`, and with it everything the wave engine
+        keeps beside the device state: in-flight blocks, the chunked
+        prefill job, the guided tables, and the registered prefixes, which
+        are primed again into the fresh pool."""
         self._inflight_blocks.clear()
         self._prefill_job = None
         self._reserved.clear()
-        # the step timeline died with the device state (black-box dumps
-        # captured the tail first — _dump_blackbox runs before reset)
-        self.step_clock.reset()
         self._guided_tables = None
         self._guided_index = {}
         self._guided_aut_np[:] = 0
         self.guided_aut = None
         self.guided_state = None
         prefix_texts = [p["text"] for p in self._prefixes]
-        if self.paged:
-            self.allocator = PageAllocator(self.allocator.num_pages)
-            self._prefixes = []
-            self._prefix_fns.clear()
-        self._alloc_decode_state()
+        self._prefixes = []
+        self._prefix_fns.clear()
+        super().reset()
         for i in range(self.max_slots):
             self._slot_epoch[i] += 1  # orphan any in-flight device tokens
-            self.slots[i] = _Slot()
         self._host_offsets[:] = 0
         self._sampling_cache = None
-        if self.paged and prefix_texts:
-            # the page pool was rebuilt: re-prime every registered prefix
-            # so post-recovery admissions keep their fast path.  Guarded: a
-            # failed re-prime must not fail the RECOVERY — serving without
-            # the optimisation beats staying down (_try_recover treats a
-            # reset() exception as fatal)
-            for text in prefix_texts:
-                try:
-                    self.add_shared_prefix(text)
-                except Exception:  # noqa: BLE001
-                    log.warning(
-                        "shared-prefix re-prime failed after reset; serving "
-                        "without it", exc_info=True,
-                    )
+        # the page pool was rebuilt: re-prime every registered prefix so
+        # post-recovery admissions keep their fast path.  Guarded: a
+        # failed re-prime must not fail the RECOVERY — serving without
+        # the optimisation beats staying down (_try_recover treats a
+        # reset() exception as fatal)
+        for text in prefix_texts:
+            try:
+                self.add_shared_prefix(text)
+            except Exception:  # noqa: BLE001
+                log.warning(
+                    "shared-prefix re-prime failed after reset; serving "
+                    "without it", exc_info=True,
+                )
 
     def free_slots(self) -> list[int]:
         return [
@@ -1028,7 +919,7 @@ class BatchedGenerator(AdmissionMixin, ProgramBuilderMixin):
             # wave-engine phase separation: this admission's prefill
             # compute ran while decode slots sat idle — the stall the
             # continuous scheduler (serving/sched/) exists to remove;
-            # recorded so bench.py can put a number on the difference
+            # recorded so the difference has a number
             self.metrics.record("decode_stall", prefill_ms)
 
         # paged mode tracks positions in _host_offsets + paged_cache.lengths
@@ -1162,34 +1053,6 @@ class BatchedGenerator(AdmissionMixin, ProgramBuilderMixin):
         if guided:
             self._apply_guided_activation(row_aut, job.taken, first_state)
 
-    # tracing ------------------------------------------------------------
-    def _annotation(
-        self, name: str, params_list: Optional[list] = None, **args: Any
-    ):
-        """Host-side profiler marker around a region of the decode worker
-        thread (``jax.profiler.TraceAnnotation``).  ``args`` (``step``,
-        ``kv_pages``, ...) and the obs trace tags of the wave ride as the
-        span's arguments, TraceMe-encoded (``name#step=7,trace=a|b#``):
-        a reader of the xplane capture (``benchmark/trace/``,
-        ``jax.profiler.ProfileData``) gets them back as ``event.stats``
-        under a clean name, and the tags join the flight recorder's
-        per-analysis timeline.  A TraceMe costs nanoseconds while no
-        profiler session is active, so every phase of every step wears
-        one."""
-        tags = sorted({
-            p.trace_tag for p in (params_list or [])
-            if p is not None and getattr(p, "trace_tag", None)
-        })
-        if tags:
-            # "," separates arguments, so several tags join with "|"
-            args["trace"] = "|".join(tags)
-        try:
-            return self._jax.profiler.TraceAnnotation(name, **args)
-        except Exception:  # noqa: BLE001 - profiler API unavailable: annotate nothing
-            import contextlib
-
-            return contextlib.nullcontext()
-
     def _sampling_tensors(self):
         """(active_np, temp_dev, top_p_dev, active_dev), rebuilt only when
         the slot set changes (admit/finish) — not every decode step."""
@@ -1255,7 +1118,7 @@ class BatchedGenerator(AdmissionMixin, ProgramBuilderMixin):
         if self.num_decoding:
             # HELD slots (decoding + chunk-prefill reserved) over
             # capacity — the same definition the continuous scheduler's
-            # sched_occupancy uses, so bench.py compares like with like
+            # sched_occupancy uses, so the two compare like with like
             self.metrics.record(
                 "batch_occupancy", 100.0 * self.num_active / self.max_slots
             )
@@ -1473,7 +1336,7 @@ class BatchedGenerator(AdmissionMixin, ProgramBuilderMixin):
         attribute the p50 budget between prefill, decode and host work)."""
         return self._jax.profiler.trace(log_dir)
 
-    # convenience for tests / bench -------------------------------------
+    # convenience for tests ---------------------------------------------
     def generate(self, prompt: str, params: Optional[SamplingParams] = None) -> GenerationResult:
         """Synchronous single-prompt generation (drains the whole batch)."""
         sampling = params or SamplingParams()
@@ -1494,7 +1357,7 @@ class ServingEngine:
 
     def __init__(
         self,
-        generator: BatchedGenerator,
+        generator: Runtime,  # the wave BatchedGenerator, or a bare Runtime under a scheduler
         *,
         admission_wait_s: float = 0.004,
         max_queue: int = 1024,
@@ -1557,7 +1420,8 @@ class ServingEngine:
         # single-flight dedup for guided-automaton builds (ensure_guided)
         self._guided_builds: dict[tuple, asyncio.Future] = {}
         self._loop: Optional[asyncio.AbstractEventLoop] = None
-        generator.partial_hook = self._on_partial_from_worker
+        if scheduler is None:
+            generator.partial_hook = self._on_partial_from_worker
         self._stalled_avail: Optional[int] = None  # pages free at last stall
         self._task: Optional[asyncio.Task] = None
         self._closed = False
@@ -2262,10 +2126,30 @@ class ServingEngine:
         on the decode worker: safe while serving — registration only
         allocates pages and updates the cache functionally.  Programs for
         the new prefix's buckets compile in-band on their first waves
-        (restart to fold them into the warmup grid)."""
+        (restart to fold them into the warmup grid).
+
+        Under the continuous scheduler nothing is registered (0): the
+        mixed program reads no registered prefix, and the block-hash
+        store already reuses any template's preamble."""
+        if self._sched is not None:
+            return 0
         loop = asyncio.get_running_loop()
         return await loop.run_in_executor(
             self._executor, lambda: self.generator.add_shared_prefix(text)
+        )
+
+    def _refused_by_scheduler(self) -> ValueError:
+        """What a guided or LoRA request is told under the continuous
+        scheduler, whose mixed program has neither path."""
+        model = self.generator.config
+        return ValueError(
+            "guided decoding and LoRA adapters are not supported in "
+            "continuous scheduler mode (sched_mode=continuous)"
+            + (
+                f", the only mode that serves model {model.name!r} "
+                f"({model.family} family: a recurrent state per slot)"
+                if getattr(model, "recurrent_state", False) else ""
+            )
         )
 
     async def ensure_guided(self, spec: tuple) -> None:
@@ -2285,6 +2169,8 @@ class ServingEngine:
         occupying one executor thread each.  The single entry point for
         both submit (generate) and HTTP validate paths, so build
         scheduling can never diverge between them."""
+        if self._sched is not None:
+            raise self._refused_by_scheduler()
         if self.generator._automaton_cached(spec):
             return
         build = self._guided_builds.get(spec)
@@ -2358,43 +2244,34 @@ class ServingEngine:
                 await self._try_recover()
             if self._error is not None:
                 raise RuntimeError("serving engine loop died") from self._error
-        # reject unknown adapters at SUBMIT time: a bad name surfacing as a
-        # ValueError inside the serve loop's admit would fail the whole
-        # co-batched wave and kill the loop — one misconfigured AIProvider CR
-        # must never take down serving for everyone
-        adapter = (params.adapter if params is not None else None)
-        if adapter is not None and adapter not in getattr(
-            self.generator, "_adapter_ids", {}
-        ):
-            raise ValueError(
-                f"unknown LoRA adapter {adapter!r}; registered: "
-                f"{getattr(self.generator, 'adapter_names', [])}"
-            )
         if params is not None and params.guided_choice is not None \
                 and params.guided_regex is not None:
             raise ValueError("guided_choice and guided_regex are mutually exclusive")
-        if self._sched is not None and params is not None and (
-            params.guided_choice is not None
-            or params.guided_regex is not None
-            or params.adapter is not None
-        ):
-            # the mixed-phase program has no guided/LoRA path yet: refuse
-            # at SUBMIT (to this caller) rather than inside the serve loop
-            model = self.generator.config
-            raise ValueError(
-                "guided decoding and LoRA adapters are not supported in "
-                "continuous scheduler mode (sched_mode=continuous)"
-                + (
-                    f", the only mode that serves model {model.name!r} "
-                    f"({model.family} family: a recurrent state per slot)"
-                    if getattr(model, "recurrent_state", False) else ""
+        if self._sched is not None:
+            if params is not None and (
+                params.guided_choice is not None
+                or params.guided_regex is not None
+                or params.adapter is not None
+            ):
+                # refused at SUBMIT (to this caller) rather than inside
+                # the serve loop
+                raise self._refused_by_scheduler()
+        else:
+            # reject unknown adapters at SUBMIT time: a bad name surfacing
+            # as a ValueError inside the serve loop's admit would fail the
+            # whole co-batched wave and kill the loop — one misconfigured
+            # AIProvider CR must never take down serving for everyone
+            adapter = (params.adapter if params is not None else None)
+            if adapter is not None and adapter not in self.generator._adapter_ids:
+                raise ValueError(
+                    f"unknown LoRA adapter {adapter!r}; registered: "
+                    f"{self.generator.adapter_names}"
                 )
-            )
-        if resume_tokens and self._sched is None:
-            raise ValueError(
-                "token-level streaming resume requires the continuous "
-                "scheduler (sched_mode=continuous)"
-            )
+            if resume_tokens:
+                raise ValueError(
+                    "token-level streaming resume requires the continuous "
+                    "scheduler (sched_mode=continuous)"
+                )
         if params is not None and params.deadline is not None:
             # fail-fast at submit: a budget that cannot fit ONE decoded
             # token must not consume a queue slot, a prefill, or KV pages.
@@ -2407,11 +2284,12 @@ class ServingEngine:
                     "deadline budget cannot fit any decoded output "
                     f"(remaining {max(0.0, params.deadline - self.generator._clock()):.3f}s)"
                 )
-        guided_spec = self.generator._guided_spec(params)
-        if guided_spec is not None:
-            # builds+caches the automaton; raises ValueError here (to THIS
-            # caller) on bad specs or unsupported engine configs
-            await self.ensure_guided(guided_spec)
+        if self._sched is None:
+            guided_spec = self.generator._guided_spec(params)
+            if guided_spec is not None:
+                # builds+caches the automaton; raises ValueError here (to
+                # THIS caller) on bad specs or unsupported engine configs
+                await self.ensure_guided(guided_spec)
         if self.fabric is not None and self._sched is not None:
             # fleet KV fabric: pull the prompt's missing prefix blocks
             # from a peer's host pool BEFORE admission so the scheduler's

@@ -213,8 +213,7 @@ class StepClock:
 
     def summary(self, last: Optional[int] = None) -> dict:
         """Stall-attribution summary (+ measured decode MFU) over the
-        ring's current window — what /healthz, /fleet and bench.py's
-        ``step_attribution`` block all read."""
+        ring's current window — what /healthz and /fleet read."""
         return attribution(
             self.ring.records(last),
             flops_per_token=self.flops_per_token,

@@ -1,12 +1,13 @@
 """Program construction: every jitted XLA program the generator runs.
 
 The compile layer split out of serving/engine.py (VERDICT r4 item 8): the
-decode step/block variants (plain/paged x unguided/guided), the sampler,
-the prefill-bucket factories (plain, paged, shared-prefix suffix), and the
+decode step/block variants (plain/paged x unguided/guided), the
+prefill-bucket factories (plain, paged, shared-prefix suffix), and the
 chunked-prefill chunk/finish programs.  Pure construction — program CACHES
 (_prefill_fns/_prefix_fns/_chunk_fns/_finish_fns) and all mutable state
 stay on the generator; these methods close over `self` only for static
-configuration (config, mesh, shardings, sampler knobs).
+configuration (config, mesh, shardings) and the runtime's sampler
+(``self.sample``, serving/sampler.py).
 
 Mixed into :class:`serving.engine.BatchedGenerator`.
 """
@@ -26,23 +27,9 @@ class ProgramBuilderMixin:
     """Builders for the generator's compiled programs (see module doc)."""
 
     #: unroll the K-step decode block into straight-line XLA instead of a
-    #: lax.scan: a scan CARRIES the whole KV cache/page pool, and XLA's
-    #: loop handling may double-buffer (copy) the carry every iteration —
-    #: unrolled, updates chain without loop plumbing.  Experiment knob
-    #: (ROADMAP D2); compile time grows ~K-fold.
+    #: lax.scan whose carry holds the whole KV cache/page pool.  Experiment
+    #: knob (ROADMAP D2); compile time grows ~K-fold.
     DECODE_UNROLL = os.environ.get("OPERATOR_TPU_DECODE_UNROLL", "0") == "1"
-
-    #: nucleus-sampling candidate-set size (constructor: ``sample_top_k``).
-    #: A full-vocab ``top_k`` is a 32k-128k element sort on the TPU vector
-    #: units EVERY decode step, so sampling is truncated to the top-k
-    #: candidates FIRST and the top-p cutoff computed within them — i.e.
-    #: the served distribution is top-k AND top-p composed, the standard
-    #: serving trade.  At this system's temperatures (0.3 default,
-    #: aiprovider-crd.yaml:56-58) the top-64 hold ~all the nucleus mass; at
-    #: temperatures ~1+ the truncation measurably narrows diversity vs true
-    #: nucleus sampling — raise sample_top_k (e.g. 256) if that matters
-    #: more than decode latency.
-    SAMPLE_TOP_K = 64
 
     def _decode_step(self, params, cache, tokens, offsets, rng, temp, top_p, active,
                      lora=None, lora_idx=None,
@@ -58,7 +45,7 @@ class ProgramBuilderMixin:
         if gtables is not None:
             row = gtables[gaut, gstate]
             last = jnp.where(row >= 0, last, -jnp.inf)
-        next_tokens, rng = self._sample(last, rng, temp, top_p)
+        next_tokens, rng = self.sample(last, rng, temp, top_p)
         # inactive slots keep decoding garbage into their own slot space;
         # offsets only advance for active ones so their state is untouched
         offsets = jnp.where(active, offsets + 1, offsets)
@@ -87,7 +74,7 @@ class ProgramBuilderMixin:
         if gtables is not None:
             row = gtables[gaut, gstate]  # [B, vocab] allowed-transition rows
             logits = jnp.where(row >= 0, logits, -jnp.inf)
-        next_tokens, rng = self._sample(logits, rng, temp, top_p)
+        next_tokens, rng = self.sample(logits, rng, temp, top_p)
         lengths = jnp.where(active, new_paged.lengths, paged.lengths)
         new_paged = PagedKVCache(
             k_pages=new_paged.k_pages, v_pages=new_paged.v_pages,
@@ -271,30 +258,6 @@ class ProgramBuilderMixin:
             )
         return self._decode_fn_guided
 
-    def _sample(self, logits, rng, temp, top_p):
-        """Temperature + truncated-nucleus sampling; temp<=0 means greedy.
-
-        [B, V] logits -> [B] token ids.  top-p filtering runs inside the
-        top-``sample_top_k`` candidates (renormalised by categorical), not
-        the full vocab — see SAMPLE_TOP_K above for the semantics trade.
-        """
-        jax, jnp = self._jax, self._jnp
-        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-
-        safe_temp = jnp.maximum(temp, 1e-4)[:, None]
-        scaled = logits.astype(jnp.float32) / safe_temp
-        k = min(self.sample_top_k, logits.shape[-1])
-        top_logits, top_idx = jax.lax.top_k(scaled, k)
-        probs = jax.nn.softmax(top_logits, axis=-1)
-        cumulative = jnp.cumsum(probs, axis=-1) - probs  # exclusive prefix
-        keep = cumulative < top_p[:, None]  # first token always kept
-        filtered = jnp.where(keep, top_logits, -jnp.inf)
-        rng, sub = jax.random.split(rng)
-        choice = jax.random.categorical(sub, filtered, axis=-1)
-        sampled = jnp.take_along_axis(top_idx, choice[:, None], axis=-1)[:, 0]
-        picked = jnp.where(temp <= 0.0, greedy, sampled.astype(jnp.int32))
-        return picked, rng
-
     def _prefill_shardings(self, n_pad: int):
         """(row, vec) shardings for a prefill bucket.  dp-aware admission
         (_admit_batch) always pads the bucket to a multiple of dp*fsdp, so
@@ -344,7 +307,7 @@ class ProgramBuilderMixin:
             if guided:
                 row = gtables[gaut, jnp.zeros_like(gaut)]  # DFA start state
                 last = jnp.where(row >= 0, last, -jnp.inf)
-            first_tokens, rng = self._sample(last, rng, temp, top_p)
+            first_tokens, rng = self.sample(last, rng, temp, top_p)
             if guided:
                 first_state = jnp.take_along_axis(
                     row, first_tokens[:, None], axis=1
@@ -401,7 +364,7 @@ class ProgramBuilderMixin:
             if guided:
                 row = gtables[gaut, jnp.zeros_like(gaut)]  # DFA start state
                 last = jnp.where(row >= 0, last, -jnp.inf)
-            first_tokens, rng = self._sample(last, rng, temp, top_p)
+            first_tokens, rng = self.sample(last, rng, temp, top_p)
             new_paged = PagedKVCache(
                 k_pages=k_pages, v_pages=v_pages,
                 page_table=paged.page_table, lengths=paged.lengths,
@@ -492,7 +455,7 @@ class ProgramBuilderMixin:
             if guided:
                 row = gtables[gaut, jnp.zeros_like(gaut)]
                 last = jnp.where(row >= 0, last, -jnp.inf)
-            first_tokens, rng = self._sample(last, rng, temp, top_p)
+            first_tokens, rng = self.sample(last, rng, temp, top_p)
             new_paged = PagedKVCache(
                 k_pages=k_pages, v_pages=v_pages,
                 page_table=paged.page_table, lengths=paged.lengths,
@@ -580,7 +543,7 @@ class ProgramBuilderMixin:
             if guided:
                 row = gtables[gaut, jnp.zeros_like(gaut)]
                 last_logits = jnp.where(row >= 0, last_logits, -jnp.inf)
-            first_tokens, rng = self._sample(last_logits, rng, temp, top_p)
+            first_tokens, rng = self.sample(last_logits, rng, temp, top_p)
             if guided:
                 first_state = jnp.take_along_axis(
                     row, first_tokens[:, None], axis=1

@@ -498,37 +498,28 @@ def build_serving_engine(
         # idempotent: the checkpoint branch already preloaded during the
         # weight stream; the random-init branches reach it only here
         aot.preload()
-    generator = BatchedGenerator(
-        params,
-        model_config,
-        tokenizer,
+    # what both engines stand on (serving/runtime.py): one chip's device
+    # state, built from the same settings whichever engine forms the steps
+    runtime_args = dict(
         max_slots=max_slots,
         max_seq=max_seq,
-        paged=config.kv_cache_mode == "paged",
         page_size=config.kv_page_size,
         kv_pages=config.kv_pages or None,
-        mesh=mesh,
-        decode_block=config.decode_block,
-        pipeline_depth=config.pipeline_depth,
         sample_top_k=config.sample_top_k,
-        lora_adapters=lora_adapters,
-        lora_alpha=config.lora_alpha,
-        prefill_chunk=prefill_chunk,
         aot_cache=aot,
         step_ring_capacity=config.step_ring_capacity,
     )
-    # Decided BEFORE prefix priming: the scheduler prefills every prompt in
-    # full, so priming would only hold KV pages hostage for the process
-    # lifetime.
     scheduler = None
     if config.sched_mode == "continuous":
+        from .runtime import Runtime
         from .sched import Scheduler
 
-        # automatic block-hash prefix caching (serving/kvstore.py):
-        # the continuous scheduler's generalisation of the wave
-        # engine's registered-shared-prefix — any cached prompt
-        # prefix is reused, with an optional host-RAM offload tier
-        # for evicted blocks (ops/kv_transfer.py)
+        # a Runtime and a Scheduler, and no wave engine: no decode block,
+        # prefill grid, guided tables, LoRA stack or registered prefix
+        generator = Runtime(params, model_config, tokenizer, **runtime_args)
+        # automatic block-hash prefix caching (serving/kvstore.py): any
+        # cached prompt prefix is reused, with an optional host-RAM
+        # offload tier for evicted blocks (ops/kv_transfer.py)
         kvstore = None
         if config.kv_prefix_cache:
             from .kvstore import PrefixKVStore
@@ -561,9 +552,8 @@ def build_serving_engine(
                 and kvstore.host_pool is not None
             ),
         )
-    # loud, unambiguous mode line: fleet operators grep for it when a
-    # rollout flips scheduling behaviour
-    if scheduler is not None:
+        # loud, unambiguous mode line: fleet operators grep for it when a
+        # rollout flips scheduling behaviour
         log.info(
             "serving mode: CONTINUOUS scheduler (pipeline_depth=%d "
             "spec_decode=%s spec_lookup_k=%d kv_prefix_cache=%s "
@@ -572,25 +562,36 @@ def build_serving_engine(
             scheduler._kvstore is not None, config.kv_host_pool_mb,
         )
     else:
+        generator = BatchedGenerator(
+            params,
+            model_config,
+            tokenizer,
+            paged=config.kv_cache_mode == "paged",
+            mesh=mesh,
+            decode_block=config.decode_block,
+            pipeline_depth=config.pipeline_depth,
+            lora_adapters=lora_adapters,
+            lora_alpha=config.lora_alpha,
+            prefill_chunk=prefill_chunk,
+            **runtime_args,
+        )
         log.info(
             "serving mode: WAVE engine (sched_mode=%s)", config.sched_mode
         )
-    if config.prefix_cache and generator.paged and scheduler is None:
-        # the default template's static preamble is shared by every
-        # explanation request: cache its KV once so each admission
-        # prefills only its variable remainder.  CRs with a custom
-        # promptTemplate simply fall back to full prefill (the engine
-        # compares TOKENS per wave; a non-matching wave costs nothing).
-        # Skipped in continuous mode: the mixed program has no prefix
-        # path, and the primed pages would shrink the pool for nothing.
-        from .prompts import DEFAULT_TEMPLATE, template_preamble
+        if config.prefix_cache and generator.paged:
+            # the default template's static preamble is shared by every
+            # explanation request: cache its KV once so each admission
+            # prefills only its variable remainder.  CRs with a custom
+            # promptTemplate simply fall back to full prefill (the engine
+            # compares TOKENS per wave; a non-matching wave costs nothing).
+            from .prompts import DEFAULT_TEMPLATE, template_preamble
 
-        static_preamble = template_preamble(DEFAULT_TEMPLATE)
-        try:
-            generator.set_shared_prefix(static_preamble)
-        except Exception:  # noqa: BLE001 - an optimisation must never block startup
-            log.warning("shared-prefix priming failed; serving without it",
-                        exc_info=True)
+            static_preamble = template_preamble(DEFAULT_TEMPLATE)
+            try:
+                generator.set_shared_prefix(static_preamble)
+            except Exception:  # noqa: BLE001 - an optimisation must never block startup
+                log.warning("shared-prefix priming failed; serving without it",
+                            exc_info=True)
     # supervised by default in production wiring (docs/ROBUSTNESS.md): a
     # stalled or errored decode loop resets the engine and requeues
     # in-flight requests once with their residual deadlines.  Direct
@@ -609,7 +610,7 @@ def build_serving_engine(
     engine.compile_watch = compile_watch
     # fleet KV fabric + disaggregation role (operator_tpu/fabric/,
     # docs/FABRIC.md).  The fetcher starts with a private empty index;
-    # two feeders exist: in-process fleets (loadgen storm, bench, tests)
+    # two feeders exist: in-process fleets (loadgen storm, tests)
     # point it at the router's health.kv_index, which the existing
     # /healthz poll keeps fresh, while a standalone replica (the k8s
     # Deployment) runs the KV_FABRIC_PEERS poller — without one of the

@@ -30,8 +30,8 @@ class PromptLookupDraft:
     context's tail n-gram (longest ``ngram`` first, down to 1) and
     returns up to ``k`` continuation tokens.  An empty return means "no
     draft": the scheduler falls back to a plain one-token decode row for
-    that step, so a miss costs nothing but this scan (measured and
-    reported as ``draft_overhead_ms`` by bench.py).
+    that step, so a miss costs nothing but this scan (measured:
+    ``Scheduler.stats()`` ``draft_overhead_ms``).
     """
 
     def __init__(self, max_ngram: int = 3, min_ngram: int = 1) -> None:

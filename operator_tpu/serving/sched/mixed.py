@@ -28,15 +28,14 @@ Exactly ONE program compiles per engine (static ``t_budget`` / ``chunk``
 to hit mid-run — the property the warmup-grid machinery exists to
 approximate for the wave engine, the mixed program has by construction.
 
-Unsupported here (the wave engine keeps them): guided decoding and LoRA
-adapters are refused at submit (serving/engine.py + Scheduler.enqueue);
-mesh sharding makes build_serving_engine fall back to wave mode; and
-the wave engine's primed shared prefix has no path here, so provider.py
-skips that priming in continuous mode.  Prefix reuse is the scheduler's
-block-hash cache instead (serving/kvstore.py): a hit maps the cached
-pages into the row's table and its first chunk starts at ``cached_len``
-— to this program just a row whose ``kv_len`` runs ahead of its
-``q_count``.
+Unsupported here: guided decoding and LoRA adapters are refused at
+submit (``ServingEngine.generate`` + ``Scheduler.enqueue``), and a
+serving mesh with ``sched_mode=continuous`` is a start-up error
+(``build_serving_engine``): the program has no sharded path.  Prefix
+reuse is the scheduler's block-hash cache (serving/kvstore.py): a hit
+maps the cached pages into the row's table and its first chunk starts at
+``cached_len`` — to this program just a row whose ``kv_len`` runs ahead
+of its ``q_count``.
 """
 
 from __future__ import annotations
@@ -76,9 +75,10 @@ class StepView:
     attend: Callable
 
 
-def make_mixed_fn(generator: Any, t_budget: int, chunk: int,
+def make_mixed_fn(runtime: Any, t_budget: int, chunk: int,
                   spec_width: int = 1):
-    """Compile the mixed-step program for ``generator`` (paged, no mesh).
+    """Compile the mixed-step program for ``runtime`` (serving/runtime.py:
+    its ``config``, ``max_slots`` and ``sample``).
 
     Signature of the returned jitted function::
 
@@ -118,9 +118,9 @@ def make_mixed_fn(generator: Any, t_budget: int, chunk: int,
     next dispatch's chaining (passthrough when the slot sat this step
     out).
     """
-    jax, jnp = generator._jax, generator._jnp
-    config = generator.config
-    b_slots = generator.max_slots
+    jax, jnp = runtime._jax, runtime._jnp
+    config = runtime.config
+    b_slots = runtime.max_slots
     inv_freq = rope_frequencies(config)
     lax = jax.lax
     width = max(1, int(spec_width))
@@ -235,7 +235,7 @@ def make_mixed_fn(generator: Any, t_budget: int, chunk: int,
             if lm_head_multiplier != 1.0:
                 logits = logits * lm_head_multiplier
         with jax.named_scope("sample"):
-            flat_toks, rng = generator._sample(
+            flat_toks, rng = runtime.sample(
                 logits.reshape(b_slots * width, -1), rng,
                 jnp.repeat(temp, width), jnp.repeat(top_p, width),
             )

@@ -1,8 +1,9 @@
 """The continuous-batching scheduler: schedule → dispatch → commit.
 
-Replaces the wave engine's implicit phase machinery (batched prefill
-dispatches + fixed decode blocks, serving/engine.py) with an explicit
-per-step loop over ONE ragged mixed-phase program:
+An explicit per-step loop over ONE ragged mixed-phase program, in place
+of the wave engine's implicit phase machinery (batched prefill dispatches
++ fixed decode blocks), standing on the device state of a
+``serving/runtime.py`` ``Runtime``:
 
 - **schedule** (:meth:`Scheduler._schedule`) — form this step's ragged
   wave: every decode row contributes its next token (or a prompt-lookup
@@ -87,6 +88,7 @@ from typing import Any, Optional
 
 import numpy as np
 
+from ..runtime import Runtime
 from ..types import (
     DeadlineExceeded,
     GenerationResult,
@@ -174,16 +176,15 @@ class _Packed:
 
 
 class Scheduler:
-    """Continuous-batching scheduler over a paged :class:`BatchedGenerator`.
-
-    Requires paged KV and no mesh (the mixed program has no SPMD rule
-    yet); guided decoding and LoRA requests are refused at submit — the
-    ServingEngine routes them to the wave path or fails them loudly.
+    """Continuous-batching scheduler over a :class:`Runtime`
+    (serving/runtime.py): every name it reads off ``self.generator`` is
+    one that class defines.  Guided decoding and LoRA requests are
+    refused at submit.
     """
 
     def __init__(
         self,
-        generator: Any,
+        generator: Runtime,
         *,
         chunk: int = 64,
         token_budget: int = 0,
@@ -196,17 +197,11 @@ class Scheduler:
         fabric_mirror: bool = False,
         audit_hook: Optional[Any] = None,
     ) -> None:
-        if not getattr(generator, "paged", False):
-            raise ValueError("the continuous scheduler requires paged KV")
         if kvstore is not None and kvstore.page_size != generator.page_size:
             raise ValueError(
                 f"kvstore page_size={kvstore.page_size} != generator "
                 f"page_size={generator.page_size}: block hashes would not "
                 f"align with KV pages"
-            )
-        if getattr(generator, "mesh", None) is not None:
-            raise ValueError(
-                "the continuous scheduler does not support mesh sharding yet"
             )
         self.generator = generator
         #: what this model cannot have, switched off HERE whatever the
@@ -358,7 +353,7 @@ class Scheduler:
                 "scheduler (sched_mode=continuous); use the wave engine"
             )
         ids = g.tokenizer.encode(prompt)
-        # same truncation budget + middle-drop as the wave path's admit()
+        # the budget formula and the runtime's truncation both engines share
         budget = prompt_budget(g.max_seq, params.max_tokens)
         if resume_tokens:
             # resumed stream: the generated suffix must survive VERBATIM
@@ -473,7 +468,7 @@ class Scheduler:
         return len(self._rows) + len(self._queue)
 
     def stats(self) -> dict:
-        """Step-level occupancy/stall/pipelining stats (bench.py)."""
+        """Step-level occupancy/stall/pipelining stats."""
         proposed = self.metrics.counter("spec_proposed")
         accepted = self.metrics.counter("spec_accepted")
         rounds = self.metrics.counter("spec_rounds")
@@ -643,7 +638,7 @@ class Scheduler:
             # step accounting at dispatch: occupancy is HELD slots over
             # capacity (rows at any phase — the same "slots occupied"
             # definition the wave engine's batch_occupancy stage uses,
-            # so bench.py compares like with like); a stall step is one
+            # so the two compare like with like); a stall step is one
             # where a decode-ready row got NO token — the schedule never
             # defers decodes while token_budget >= max_slots, so the
             # counter is the proof of the property, not a mechanism
@@ -978,8 +973,7 @@ class Scheduler:
                 continue
             if outcome == "rejected":
                 # expired between the check above and the policy's clock
-                # read: minimal one-token clamp, same as the wave path's
-                # _deadline_clamp_wave
+                # read: minimal one-token clamp, as the wave path does
                 clamped = dataclasses.replace(
                     params, max_tokens=1, deadline_clamped=True
                 )

@@ -258,7 +258,7 @@ class OperatorConfig:
     # "serving" = unguided grid; "full" adds guided variants; "off" = the
     # pre-r5 behavior (first bucket hit pays its compile in-band)
     warmup_grid: str = "serving"
-    # nucleus-sampling candidate set (engine SAMPLE_TOP_K): top-p filtering
+    # nucleus-sampling candidate set (serving/sampler.py SAMPLE_TOP_K): top-p filtering
     # runs inside the top-k — raise for high-temperature diversity
     sample_top_k: int = 64
     # serving dtype: "int8" (weight-only per-channel quant, models/quant.py)

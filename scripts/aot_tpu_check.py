@@ -135,7 +135,7 @@ def _mixed_step_case(topo_device, model_id=None, slots=_SLOTS, t_budget=None,
 
     from operator_tpu.models import get_config
     from operator_tpu.ops.paged_attention import PagedKVCache
-    from operator_tpu.serving.programs import ProgramBuilderMixin
+    from operator_tpu.serving import sampler
     from operator_tpu.serving.sched.mixed import make_mixed_fn
     from operator_tpu.utils.config import OperatorConfig
 
@@ -145,12 +145,14 @@ def _mixed_step_case(topo_device, model_id=None, slots=_SLOTS, t_budget=None,
     def shaped(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
-    class _Shapes(ProgramBuilderMixin):
-        """The four attributes ``make_mixed_fn`` reads off a generator."""
+    class _Shapes:
+        """The attributes ``make_mixed_fn`` reads off a runtime."""
 
         _jax, _jnp = jax, jnp
         max_slots = slots
-        sample_top_k = ProgramBuilderMixin.SAMPLE_TOP_K
+        sample = staticmethod(
+            functools.partial(sampler.sample, top_k=sampler.SAMPLE_TOP_K)
+        )
 
     generator = _Shapes()
     generator.config = config

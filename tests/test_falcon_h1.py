@@ -131,13 +131,13 @@ def capture_logits(generator):
     """Every step's ``[slots, vocab]`` logits, as the sampler is given
     them, in step order."""
     seen = []
-    sample = generator._sample
+    sample = generator.sample
 
     def recording(logits, rng, temp, top_p):
         jax.debug.callback(lambda value: seen.append(np.asarray(value)), logits)
         return sample(logits, rng, temp, top_p)
 
-    generator._sample = recording
+    generator.sample = recording
     return seen
 
 

@@ -4,9 +4,10 @@ Covers: seeded arrival determinism (byte-identical two-replay, including
 a full storm under a composed FaultPlan), hand-valued attainment and
 goodput-under-SLO on a synthetic ledger, the fleet roll-up carrying
 sloAttainment/goodput over faked replicas (the ``GET /fleet`` payload),
-and the bench smoke: ``bench.run_open_loop`` must return a populated
-record — non-null attainment, replay-identical schedule, zero torn
-ledger lines — with no JAX in sight (synthetic replicas only).
+and the open-loop smoke: one seeded storm through ``build_storm_stack``
+/ ``run_storm`` must leave a populated report — non-null attainment,
+replay-identical schedule, zero torn ledger lines — with no JAX in sight
+(synthetic replicas only).
 """
 
 import asyncio
@@ -14,7 +15,6 @@ import json
 
 import pytest
 
-import bench
 from operator_tpu.loadgen import ArrivalProcess, ArrivalSpec
 from operator_tpu.loadgen.storm import (
     SLO_CLASS_ANNOTATION,
@@ -330,32 +330,70 @@ class TestFleetSLORollup:
 # ---------------------------------------------------------------------------
 
 
-class TestBenchOpenLoopSmoke:
-    def test_record_is_populated_and_replay_identical(self):
+async def open_loop_storm(
+    replicas, tmp_path, *, rate_per_min, duration_s, seed, time_scale, drain_s
+):
+    """One seeded open-loop storm through the FULL stack — operator
+    pipeline -> router -> replicas — and what it left behind: the storm's
+    report, whether a second materialisation of the schedule is identical,
+    and the ledger journal's line count and torn lines."""
+    spec = ArrivalSpec(
+        name="storm", rate_per_min=rate_per_min, duration_s=duration_s,
+    )
+    process = ArrivalProcess(spec, seed=seed)
+    replay = ArrivalProcess(spec, seed=seed)
+    replay_identical = (
+        process.fingerprint() == replay.fingerprint()
+        and [e.to_dict() for e in process.materialize()]
+        == [e.to_dict() for e in replay.materialize()]
+    )
+    ledger_path = tmp_path / "slo-ledger.jsonl"
+    stack = await build_storm_stack(
+        replicas=replicas, time_scale=time_scale, ledger_path=str(ledger_path),
+    )
+    try:
+        report = await run_storm(stack, process, drain_s=drain_s)
+    finally:
+        stack.close()
+    lines = [l for l in ledger_path.read_text().splitlines() if l.strip()]
+    torn = 0
+    for line in lines:
+        try:
+            json.loads(line)
+        except ValueError:
+            torn += 1
+    return report, replay_identical, len(lines), torn
+
+
+class TestOpenLoopSmoke:
+    def test_record_is_populated_and_replay_identical(self, tmp_path):
         replicas = [
             SyntheticReplica(f"bench-replica-{i}", concurrency=2,
                              time_scale=0.05)
             for i in range(2)
         ]
-        result = run(bench.run_open_loop(
-            replicas, rate_per_min=600.0, duration_s=2.0, seed=4,
+        report, replay_identical, ledger_lines, torn = run(open_loop_storm(
+            replicas, tmp_path, rate_per_min=600.0, duration_s=2.0, seed=4,
             time_scale=0.05, drain_s=30.0,
         ))
-        assert result["offered"] > 0
-        assert result["replay_identical"] is True
-        assert result["ledger_torn_lines"] == 0
-        assert result["attainment"] is not None
-        assert result["p50_s"] is not None
-        assert result["classes"]  # per-class breakdown present
-        assert result["fingerprint"]
+        total = report["slo"]["total"]
+        assert report["arrivals"] > 0
+        assert replay_identical is True
+        assert torn == 0
+        assert total["attainment"] is not None
+        assert total["p50_s"] is not None
+        assert report["slo"]["classes"]  # per-class breakdown present
+        assert report["fingerprint"]
         # conservation: every offered arrival reached a terminal outcome
-        terminal = (result["completed"] + result["degraded"] + result["shed"]
-                    + result["deadline_exceeded"] + result["failed"])
-        assert terminal == result["ledger_lines"] == result["offered"]
-        assert result["fleet"]["sloAttainment"] is None or \
-            0.0 <= result["fleet"]["sloAttainment"] <= 1.0
+        terminal = (total["completed"] + total.get("degraded", 0)
+                    + total["shed"] + total["deadline_exceeded"]
+                    + total["failed"])
+        assert terminal == ledger_lines == report["arrivals"]
+        fleet = report["fleet"]["fleet"]
+        assert fleet["sloAttainment"] is None or \
+            0.0 <= fleet["sloAttainment"] <= 1.0
 
-    def test_overloaded_synthetic_storm_records_misses_or_sheds(self):
+    def test_overloaded_synthetic_storm_records_misses_or_sheds(self, tmp_path):
         """One replica, concurrency 1, service time far above the
         interarrival gap: an open-loop storm MUST show the overload in
         the ledger (attainment < 1 via sheds/misses) instead of quietly
@@ -363,15 +401,16 @@ class TestBenchOpenLoopSmoke:
         replicas = [SyntheticReplica(
             "slow", concurrency=1, base_ms=400.0, time_scale=1.0,
         )]
-        result = run(bench.run_open_loop(
-            replicas, rate_per_min=1200.0, duration_s=1.5, seed=6,
+        report, _, _, _ = run(open_loop_storm(
+            replicas, tmp_path, rate_per_min=1200.0, duration_s=1.5, seed=6,
             time_scale=1.0, drain_s=10.0,
         ))
-        assert result["offered"] > 3
-        assert result["attainment"] is not None
-        assert result["attainment"] < 1.0
-        assert (result["shed"] + result["deadline_exceeded"]
-                + result["failed"]) > 0
+        total = report["slo"]["total"]
+        assert report["arrivals"] > 3
+        assert total["attainment"] is not None
+        assert total["attainment"] < 1.0
+        assert (total["shed"] + total["deadline_exceeded"]
+                + total["failed"]) > 0
 
 
 class TestOverloadSimulation:

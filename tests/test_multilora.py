@@ -290,7 +290,8 @@ def test_lora_dir_missing_warns_not_crashes(tmp_path, caplog):
     with caplog.at_level(logging.WARNING):
         engine, _ = build_serving_engine(config)
     try:
-        assert engine.generator.adapter_names == []
+        # a wave name: the HTTP server reads it the same way
+        assert getattr(engine.generator, "adapter_names", []) == []
         assert any("lora_dir" in r.message for r in caplog.records)
     finally:
         engine._executor.shutdown(wait=False)

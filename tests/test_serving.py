@@ -23,6 +23,7 @@ from operator_tpu.serving.engine import (  # noqa: E402
     ServingEngine,
     _bucket,
 )
+from operator_tpu.serving.sampler import SAMPLE_TOP_K, sample  # noqa: E402
 
 
 @pytest.fixture(scope="module")
@@ -172,21 +173,23 @@ class TestBatchedGenerator:
 
 
 class TestSamplerMath:
-    def test_top_p_filters_tail(self, generator):
+    def test_top_p_filters_tail(self):
         """With top_p ~ 0, sampling collapses to greedy."""
         logits = jnp.asarray(np.random.default_rng(0).normal(size=(3, 64)), jnp.float32)
         rng = jax.random.PRNGKey(0)
-        picked, _ = generator._sample(
-            logits, rng, jnp.asarray([1.5, 1.5, 1.5]), jnp.asarray([1e-6, 1e-6, 1e-6])
+        picked, _ = sample(
+            logits, rng, jnp.asarray([1.5, 1.5, 1.5]), jnp.asarray([1e-6, 1e-6, 1e-6]),
+            top_k=SAMPLE_TOP_K,
         )
         np.testing.assert_array_equal(
             np.asarray(picked), np.asarray(jnp.argmax(logits, axis=-1))
         )
 
-    def test_zero_temperature_is_greedy(self, generator):
+    def test_zero_temperature_is_greedy(self):
         logits = jnp.asarray(np.random.default_rng(1).normal(size=(2, 32)), jnp.float32)
-        picked, _ = generator._sample(
-            logits, jax.random.PRNGKey(1), jnp.zeros(2), jnp.ones(2)
+        picked, _ = sample(
+            logits, jax.random.PRNGKey(1), jnp.zeros(2), jnp.ones(2),
+            top_k=SAMPLE_TOP_K,
         )
         np.testing.assert_array_equal(
             np.asarray(picked), np.asarray(jnp.argmax(logits, axis=-1))
