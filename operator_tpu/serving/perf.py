@@ -165,7 +165,7 @@ class StepClock:
         alone and its wall is the two waits.  ``counts`` are the record's
         optional work counts (``accepted``, ``cached_tokens``,
         ``prefill_tokens``, ``kv_pages_walked``, ``q_tile_rows``,
-        ``state_rows``).  MFU stays
+        ``state_rows``, ``sampled_rows``).  MFU stays
         computed on billed ``tokens`` — the compute really ran — over the
         interval's wall."""
         if commit_t is None:

@@ -103,7 +103,7 @@ def make_mixed_fn(runtime: Any, t_budget: int, chunk: int,
     position, ``spec_len`` the slot's draft-token count this step
     (0 = plain row).
 
-    ``W = spec_width`` positions are sampled per slot, starting at
+    Up to ``W = spec_width`` positions are sampled per slot, starting at
     ``sample_start``: a plain row samples only its last valid logit
     (``toks[b, 0]``); a speculation verify row of ``q_count = 1 + k``
     tokens (committed last token + k prompt-lookup drafts) samples ALL
@@ -111,9 +111,14 @@ def make_mixed_fn(runtime: Any, t_budget: int, chunk: int,
     draft prefix the samples confirm — standard speculative-decoding
     acceptance, so the commit takes ``accept[b] + 1`` tokens
     (``toks[b, :accept[b] + 1]``) and greedy output is byte-identical to
-    one-token decoding by construction.  The returned cache's lengths
-    are corrected on device to ``kv_len - (spec_len - accept)``: the
-    rejected drafts' KV writes land but are never readable.
+    one-token decoding by construction.  The head and the sampler cost
+    rows x vocabulary, so the step's tail runs at the width THIS step's
+    drafts need: when ``W > 1`` it is one ``lax.cond`` on
+    ``spec_len.any()`` — no draft anywhere, one logit row a slot
+    (``toks[:, 1:]`` zero, ``accept`` zero); a draft somewhere, ``W`` rows
+    a slot.  Both branches advance the rng once.  The returned cache's
+    lengths are corrected on device to ``kv_len - (spec_len - accept)``:
+    the rejected drafts' KV writes land but are never readable.
     ``latest_out[b]`` carries each slot's freshest sampled token for the
     next dispatch's chaining (passthrough when the slot sat this step
     out).
@@ -213,34 +218,50 @@ def make_mixed_fn(runtime: Any, t_budget: int, chunk: int,
         )
 
         x = rms_norm(x, params["ln_final"], config.rms_norm_eps)
-        # only each slot's sampled positions need logit rows: gather them
-        # before the head matmul so the [vocab] projection runs at
-        # [B * W], not [T].  A plain row samples one position (its last
-        # valid token); a verify row samples its committed token AND
-        # every draft, in chunk order
-        samp_idx = jnp.clip(
-            sample_start[:, None] + jnp.arange(width, dtype=jnp.int32)[None],
-            0, t_budget - 1,
-        )  # [B, W]
-        with jax.named_scope("head"):
-            x_samp = x[0][samp_idx]  # [B, W, H]
-            head = (
-                params["embed"].T if config.tie_embeddings
-                else params["lm_head"]
-            )
-            logits = jnp.einsum(
-                "bwh,hv->bwv", x_samp, head,
-                preferred_element_type=jnp.float32,
-            )
-            if lm_head_multiplier != 1.0:
-                logits = logits * lm_head_multiplier
-        with jax.named_scope("sample"):
-            flat_toks, rng = runtime.sample(
-                logits.reshape(b_slots * width, -1), rng,
-                jnp.repeat(temp, width), jnp.repeat(top_p, width),
-            )
-        toks = flat_toks.reshape(b_slots, width)
-        if width > 1:
+
+        def sample_at(rows_a_slot, rng):
+            """``rows_a_slot`` positions a slot from ``sample_start`` on,
+            through the head and the sampler: (toks [B, rows_a_slot], rng).
+            Only the sampled positions get logit rows: they are gathered
+            before the head matmul, so the [vocab] projection runs at
+            [B * rows_a_slot], not [T]"""
+            samp_idx = jnp.clip(
+                sample_start[:, None]
+                + jnp.arange(rows_a_slot, dtype=jnp.int32)[None],
+                0, t_budget - 1,
+            )  # [B, rows_a_slot]
+            with jax.named_scope("head"):
+                x_samp = x[0][samp_idx]  # [B, rows_a_slot, H]
+                head = (
+                    params["embed"].T if config.tie_embeddings
+                    else params["lm_head"]
+                )
+                logits = jnp.einsum(
+                    "bwh,hv->bwv", x_samp, head,
+                    preferred_element_type=jnp.float32,
+                )
+                if lm_head_multiplier != 1.0:
+                    logits = logits * lm_head_multiplier
+            with jax.named_scope("sample"):
+                flat_toks, rng = runtime.sample(
+                    logits.reshape(b_slots * rows_a_slot, -1), rng,
+                    jnp.repeat(temp, rows_a_slot),
+                    jnp.repeat(top_p, rows_a_slot),
+                )
+            return flat_toks.reshape(b_slots, rows_a_slot), rng
+
+        def narrow(rng):
+            # no row carries a draft: a plain row samples only its last
+            # valid logit, so one row a slot is all the step needs
+            toks, rng = sample_at(1, rng)
+            if width > 1:
+                toks = jnp.pad(toks, ((0, 0), (0, width - 1)))
+            return toks, jnp.zeros((b_slots,), jnp.int32), rng
+
+        def wide(rng):
+            # a verify row samples its committed token AND every draft,
+            # in chunk order; every slot pays the row's width
+            toks, rng = sample_at(width, rng)
             # longest matching draft prefix: draft j (flat position
             # sample_start + 1 + j) is confirmed iff the sample AT the
             # position BEFORE it predicted exactly it, and every earlier
@@ -258,8 +279,18 @@ def make_mixed_fn(runtime: Any, t_budget: int, chunk: int,
             accept = jnp.sum(
                 jnp.cumprod(confirmed.astype(jnp.int32), axis=1), axis=1
             )
+            return toks, accept, rng
+
+        if width > 1:
+            # the head and the sampler cost rows x vocabulary: they run
+            # at the width THIS step's drafts need.  The branches take
+            # the normed x, the head and the per-slot vectors; the pools
+            # stay outside, in the carry they left the layer loop in
+            toks, accept, rng = lax.cond(
+                jnp.any(spec_len > 0), wide, narrow, rng
+            )
         else:
-            accept = jnp.zeros((b_slots,), jnp.int32)
+            toks, accept, rng = narrow(rng)
         # rejected drafts wrote KV the row must never read again: shrink
         # the committed lengths on device (spec_len - accept positions)
         new_lengths = kv_len - (spec_len - accept)

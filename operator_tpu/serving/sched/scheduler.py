@@ -71,6 +71,7 @@ Counters (docs/METRICS.md): ``podmortem_sched_admitted_midwave_total``,
 ``podmortem_sched_pipeline_voided_total``,
 ``podmortem_spec_rounds_total``, ``podmortem_spec_proposed_total``,
 ``podmortem_spec_accepted_total``, ``podmortem_spec_rest_total``,
+``podmortem_sample_wide_steps_total``,
 ``podmortem_kv_hit_total``, ``podmortem_kv_miss_total``,
 ``podmortem_kv_evict_total``, ``podmortem_kv_offload_total``,
 ``podmortem_kv_restore_total``,
@@ -167,6 +168,9 @@ class _Packed:
     temp: np.ndarray
     top_p: np.ndarray
     kv_len: np.ndarray
+    #: some row carries a draft (``spec_len.any()``, the predicate the
+    #: compiled step branches on): the head and the sampler run wide
+    wide: bool
     #: the step record's work counts (``StepRecord`` field names)
     counts: dict
     #: query-key pairs the attention scores: the sum over the slots it
@@ -651,6 +655,8 @@ class Scheduler:
                 self.metrics.incr("sched_stall_step")
             else:
                 self.metrics.incr("sched_stall_free_step")
+            if packed.wide:
+                self.metrics.incr("sample_wide_steps")
         elif not self._inflight:
             return outcomes
         # commit down to the pipeline bound (depth - 1 stays in flight
@@ -1249,11 +1255,12 @@ class Scheduler:
         pages, pairs = _kv_walk(
             kv_len, q_count, g.page_size, g.config.sliding_window
         )
+        wide = bool(spec_len.any())
         return _Packed(
             ids=ids, rows=rows, pos=pos, valid=valid, in_row=in_row,
             from_prev=from_prev, q_start=q_start, q_count=q_count,
             sample_start=sample_start, spec_len=spec_len, temp=temp,
-            top_p=top_p, kv_len=kv_len,
+            top_p=top_p, kv_len=kv_len, wide=wide,
             counts={
                 "prefill_tokens": prefill_tokens, "kv_pages_walked": pages,
                 # the query-tile rows one layer's kernel call works, by
@@ -1265,6 +1272,12 @@ class Scheduler:
                 "state_rows": (
                     int((q_count > 0).sum()) if self._recurrent else None
                 ),
+                # logit rows the head and the sampler are ASKED for: one
+                # a slot, or the verify width a slot in a step that
+                # carries a draft.  sched/mixed.py branches on the same
+                # spec_len; this restates the predicate on the host and
+                # does not observe which branch the device took
+                "sampled_rows": b * (self.width if wide else 1),
             },
             qk_pairs=pairs,
         )

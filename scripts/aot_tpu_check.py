@@ -17,10 +17,12 @@ CPU host finds them before chip time is spent.  Covered:
   never silently routed elsewhere — the check asserts which of the two
   happens for each config;
 - the whole mixed step (``serving/sched/mixed.py``) for the default model
-  at the server's default shape and at the 1.5B benchmark cell's (128
-  slots, 3,456 pages, 256 tokens a step), int8 weights, with XLA's memory
-  analysis, lowered as the scheduler calls it (the jitted step itself, so
-  that the cache's donation counts);
+  at the server's default shape, at the 1.5B benchmark cell's (128
+  slots, 3,456 pages, 256 tokens a step) and at the 7B cell's (32 slots,
+  800 pages), int8 weights, with XLA's memory analysis, lowered as the
+  scheduler calls it (the jitted step itself, so that the cache's donation
+  counts), and ``conditionals``: the optimised HLO's ``conditional``
+  instructions — one at a verify width (the step's tail), none at width 1;
 - the state-space scan kernel (``ops/ssm_scan.py``) alone and the whole
   mixed step of a model with recurrent state, at the benchmark cell's
   shape (``falcon-h1-34b-6l``, 128 slots, 1,536 pages): the memory
@@ -396,6 +398,10 @@ def main() -> int:
             ("mixed_step_qwen2.5-1.5b_b128", dict(
                 model_id="qwen2.5-1.5b", slots=128, t_budget=256, kv_pages=3456,
             )),
+            # the 7B cell (its token budget is the default: the chunk)
+            ("mixed_step_qwen2.5-7b_b32", dict(
+                model_id="qwen2.5-7b", slots=32, kv_pages=800,
+            )),
             ("mixed_step_falcon-h1-34b-6l_b128", dict(
                 model_id="falcon-h1-34b-6l", slots=f_slots, t_budget=f_tokens,
                 kv_pages=1536, spec_width=1,
@@ -423,6 +429,12 @@ def main() -> int:
                 }
                 if name in pools:
                     shape = pools[name]
+                    # the tail's one conditional (sched/mixed.py: the head
+                    # and the sampler at one row a slot, or at the verify
+                    # width); a step compiled at width 1 has none
+                    results[name]["conditionals"] = len(re.findall(
+                        r" conditional\(", compiled.as_text()
+                    ))
                     results[name]["kv_pool"] = {
                         "shape": list(shape),
                         "bytes": 2 * 2 * math.prod(shape),  # K and V, bf16

@@ -116,22 +116,27 @@ def test_the_recurrent_models_step_compiles_with_its_state_pool_held_once(record
     assert total < 15.75 * 2**30  # fits the chip
 
 
-@pytest.mark.parametrize("case", [
-    "mixed_step_default_model",
-    "mixed_step_qwen2.5-1.5b_b128",
-    "mixed_step_falcon-h1-34b-6l_b128",
+@pytest.mark.parametrize("case, conditionals", [
+    ("mixed_step_default_model", 1),
+    ("mixed_step_qwen2.5-1.5b_b128", 1),
+    ("mixed_step_qwen2.5-7b_b32", 1),
+    ("mixed_step_falcon-h1-34b-6l_b128", 0),
 ])
-def test_the_kv_pool_is_held_once_and_never_copied(record, case):
+def test_the_kv_pool_is_held_once_and_never_copied(record, case, conditionals):
     """The stacked KV pools ride the layer loop's carry and are written in
     place (``serving/sched/mixed.py``): in the step's optimised HLO nothing
     copies a pool, slices a layer out of one or stacks a layer back; the
     donated pools come back aliased; and the temporaries could not hold a
-    second copy of even one of them."""
+    second copy of even one of them.  That holds with the tail's one
+    conditional in the step (the head and the sampler at one row a slot,
+    or at the verify width): the pools are no operand of it.  A step
+    compiled at width 1 has no conditional."""
     step = record["kernels"][case]
     pool = step["kv_pool"]
     assert pool["moved_by"] == [], pool
     assert step["temp_bytes"] < pool["bytes"] / 2, step
     assert step["alias_bytes"] >= pool["bytes"], step
+    assert step["conditionals"] == conditionals, step
 
 
 #: the parent's way through the layer loop, cut from the optimised HLO of

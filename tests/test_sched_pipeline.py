@@ -156,6 +156,116 @@ class TestPipelinedParity:
 
 
 # ---------------------------------------------------------------------------
+# the head and the sampler run at the width the step's drafts need
+# ---------------------------------------------------------------------------
+
+
+def run_counted(params, *, depth, spec):
+    """Three greedy rows (one of them templated: it drafts) beside a row
+    at temperature 0.8, which never does.  Returns the greedy rows'
+    tokens, whether each dispatch carried a draft (in order), the step
+    records and the scheduler."""
+    import numpy as np
+
+    generator = make_generator(params)
+    sched = make_sched(generator, pipeline_depth=depth, spec_decode=spec)
+    real = sched._get_fn()
+    drafted = []
+
+    def spy(*args):
+        drafted.append(bool(np.asarray(args[13]).any()))  # spec_len
+        return real(*args)
+
+    sched._fn = spy
+    greedy = SamplingParams(max_tokens=20, temperature=0.0, stop_on_eos=False)
+    warm = SamplingParams(max_tokens=20, temperature=0.8, stop_on_eos=False)
+    ids = {sched.enqueue(p, greedy): p for p in PROMPTS}
+    sched.enqueue("the kubelet evicted the pod", warm)
+    done = drain(sched, len(PROMPTS) + 1)
+    assert all(outcome.error is None for outcome in done.values())
+    assert_no_leaks(generator)
+    tokens = {ids[r]: done[r].result.token_ids for r in ids}
+    return tokens, drafted, generator.step_clock.ring.records(), sched
+
+
+class TestSampledWidth:
+    @pytest.mark.parametrize("depth", [1, 2])
+    def test_narrow_and_wide_steps_commit_the_one_token_tokens(self, params, depth):
+        """With the verify width compiled, a step without a verify row
+        samples one row a slot and a step with one samples the width:
+        both commit, for greedy rows, byte for byte what the scheduler
+        without speculation commits; and each step's record counts the
+        logit rows its tail worked."""
+        plain, plain_drafted, plain_records, _ = run_counted(
+            params, depth=depth, spec=False
+        )
+        spec, drafted, records, sched = run_counted(params, depth=depth, spec=True)
+        assert spec == plain
+        slots, width = sched.generator.max_slots, sched.width
+        assert width == 5 and len(records) == len(drafted)
+        assert True in drafted and False in drafted  # both kinds of step ran
+        assert [r.sampled_rows for r in records] == [
+            slots * width if wide else slots for wide in drafted
+        ]
+        wide_steps = sum(drafted)
+        assert sched.metrics.counter("sample_wide_steps") == wide_steps
+        rounds = sched.stats()["spec_decode"]["verify_rounds"]
+        assert 1 <= rounds and wide_steps <= rounds
+        # compiled at width 1 every step is narrow, and none is counted
+        assert not any(plain_drafted)
+        assert {r.sampled_rows for r in plain_records} == {slots}
+
+    @pytest.mark.parametrize("name, width, conditionals", [
+        ("tiny-test", 5, 1), ("tiny-test", 1, 0), ("tiny-falcon-h1", 1, 0),
+    ])
+    def test_the_lowered_step_holds_one_conditional_when_it_can_verify(
+        self, name, width, conditionals
+    ):
+        """At a verify width the step's tail is one conditional on
+        ``spec_len``: its first (narrow) branch makes ``[slots, V]``
+        logits and no ``[slots * width, .]`` value, its second today's
+        ``[slots, width, V]``; the pools are operands of neither.  At
+        width 1 (no speculation: every recurrent model) nothing branches."""
+        import re
+
+        from operator_tpu.models import family_of, get_config
+        from operator_tpu.serving.sched.mixed import make_mixed_fn
+
+        config = get_config(name)
+        weights = family_of(config).init_params(
+            config, jax.random.PRNGKey(0), dtype=jnp.float32
+        )
+        generator = BatchedGenerator(
+            weights, config, ByteTokenizer(), paged=True, max_slots=4,
+            max_seq=128, page_size=16, cache_dtype=jnp.float32,
+            metrics=MetricsRegistry(),
+        )
+        slots, t_budget, vocab = 4, 16, config.vocab_size
+        flat_i, flat_b = jnp.zeros((t_budget,), jnp.int32), jnp.zeros((t_budget,), bool)
+        slot_i, slot_f = jnp.zeros((slots,), jnp.int32), jnp.zeros((slots,), jnp.float32)
+        text = make_mixed_fn(generator, t_budget, 8, spec_width=width).lower(
+            weights, generator.paged_cache, flat_i, flat_i, flat_i, flat_b, flat_i,
+            slot_i, slot_i, slot_i, slot_i, flat_b, slot_i, slot_i,
+            jax.random.PRNGKey(0), slot_f, slot_f,
+        ).as_text()
+        assert text.count('"stablehlo.case"') == conditionals
+        assert "stablehlo.if" not in text
+        if not conditionals:
+            return
+        opened = text.index('"stablehlo.case"')
+        narrow, wide = text[opened:].split("\n    }, {\n", 1)
+        wide = wide[: wide.index("\n    }) : (tensor<i32>)")]
+        wide_rows = re.compile(rf"tensor<{slots * width}x|tensor<{slots}x{width}x")
+        assert f"tensor<{slots}x{vocab}xf32>" in narrow
+        assert not wide_rows.search(narrow.replace(f"tensor<{slots}x{width}xi32>", ""))
+        assert f"tensor<{slots}x{width}x{vocab}xf32>" in wide
+        assert f"tensor<{slots * width}x{vocab}xf32>" in wide
+        pool = "x".join(str(d) for d in generator.paged_cache.k_pages.shape)
+        assert f"tensor<{pool}x" in text[:opened]  # the pools are in the step ...
+        assert f"tensor<{pool}x" not in narrow + wide  # ... and in no branch
+
+
+# ---------------------------------------------------------------------------
 # seeded determinism
 # ---------------------------------------------------------------------------
 

@@ -162,7 +162,7 @@ class TestStepRing:
             wall_ms=12.5, host_ms=2.0, wait_ms=10.0, xfer_ms=0.5,
             plan_ms=0.5, pack_ms=0.75, commit_ms=0.25, turn_ms=0.125,
             prefill_tokens=64, kv_pages_walked=11, q_tile_rows=80,
-            accepted=3, cached_tokens=16,
+            accepted=3, cached_tokens=16, sampled_rows=20,
         )
         raw = record.to_dict()
         assert json.loads(json.dumps(raw)) == raw  # plain JSON
@@ -174,6 +174,8 @@ class TestStepRing:
         assert StepRecord.from_dict(bare).kv_pages_walked is None
         assert "q_tile_rows" not in bare
         assert StepRecord.from_dict(bare).q_tile_rows is None
+        assert raw["sampled_rows"] == 20 and "sampled_rows" not in bare
+        assert StepRecord.from_dict(bare).sampled_rows is None
 
 
 # ---------------------------------------------------------------------------
@@ -415,16 +417,19 @@ class TestStepView:
             StepRecord(seq=1, kind="prefill", tokens=16, slots=1,
                        occupancy=0.25, wall_ms=9.0, host_ms=0.0, wait_ms=9.0,
                        xfer_ms=0.0, kv_pages_walked=7, prefill_tokens=16,
-                       q_tile_rows=64, state_rows=1),
+                       q_tile_rows=64, state_rows=1, sampled_rows=20),
         ])
         lines = table.splitlines()
         assert lines[0].split() == [
             "seq", "kind", "tok", "pf_tok", "slots", "occ",
             "wall_ms", "host_ms", "wait_ms", "xfer_ms",
-            "plan", "pack", "commit", "turn", "st_rows", "kv_pg", "q_fill", "mfu",
+            "plan", "pack", "commit", "turn",
+            "st_rows", "smp_rows", "kv_pg", "q_fill", "mfu",
         ]
         # slots whose recurrent state the step touched; "-" without such state
-        assert lines[3].split()[-4] == "1" and lines[2].split()[-4] == "-"
+        assert lines[3].split()[-5] == "1" and lines[2].split()[-5] == "-"
+        # logit rows the head and the sampler worked; "-" where not counted
+        assert lines[3].split()[-4] == "20" and lines[2].split()[-4] == "-"
         assert lines[3].split()[-3] == "7" and lines[2].split()[-3] == "-"
         # q_fill = tokens / q_tile_rows: 16 of the chunk's 64 rows
         assert lines[3].split()[-2] == "0.250" and lines[2].split()[-2] == "-"
@@ -804,14 +809,18 @@ class TestKvPagesWalked:
             # 9 + 3 + 1 + 5 + 0 + 0 + 4 pages; the unscheduled row's 13 not walked
             assert got == (22, 130 + 16 * 48 + 16 * 16 + 5 * 77 + 64)
 
-    @pytest.mark.parametrize("window", [None, 24])
+    @pytest.mark.parametrize("window, spec", [
+        (None, False), (24, False), (None, True),
+    ])
     def test_records_count_what_the_program_was_given(
-        self, params, window, monkeypatch
+        self, params, window, spec, monkeypatch
     ):
         """Every step: the record's ``kv_pages_walked`` and
         ``q_tile_rows`` and the dispatch span's ``qk_pairs`` and
         ``q_tile_rows`` against a count over the very ``kv_len`` /
-        ``q_count`` the mixed program got."""
+        ``q_count`` the mixed program got; and its ``sampled_rows``
+        against the ``spec_len`` it got (with the verify width compiled:
+        that width a slot in a step with a draft, one a slot otherwise)."""
         import dataclasses
 
         import numpy as np
@@ -835,18 +844,22 @@ class TestKvPagesWalked:
             max_seq=128, page_size=16, cache_dtype=jnp.float32,
             metrics=MetricsRegistry(),
         )
-        sched = Scheduler(generator, chunk=16, token_budget=32, pipeline_depth=2)
+        sched = Scheduler(
+            generator, chunk=16, token_budget=32, pipeline_depth=2, spec_decode=spec,
+        )
         real = sched._get_fn()
-        given = []
+        given, drafted = [], []
 
         def spy(*args):
             given.append((np.asarray(args[9]), np.asarray(args[8])))  # kv_len, q_count
+            drafted.append(bool(np.asarray(args[13]).any()))  # spec_len
             return real(*args)
 
         sched._fn = spy
         sampling = SamplingParams(max_tokens=20, temperature=0.0, stop_on_eos=False)
         for prompt in ("pod crashed with exit code 137 after the node ran out "
-                       "of memory and the kubelet evicted it", "short", "oom"):
+                       "of memory and the kubelet evicted it", "short",
+                       "oom oom oom oom oom oom" if spec else "oom"):
             sched.enqueue(prompt, sampling)
         finished = 0
         for _ in range(200):
@@ -870,6 +883,12 @@ class TestKvPagesWalked:
             )
         assert {"decode", "mixed"} <= kinds
         assert tiles == {0, 8, 16}  # idle slots, decode rows, prompt chunks
+        assert [r.sampled_rows for r in records] == [
+            4 * (sched.width if wide else 1) for wide in drafted
+        ]
+        assert sched.width == (5 if spec else 1)
+        assert generator.metrics.counter("sample_wide_steps") == sum(drafted)
+        assert any(drafted) is spec
         last = records[-1]
         assert f"{last.tokens / last.q_tile_rows:.3f}" in (
             render_steps(records).splitlines()[-1].split()
