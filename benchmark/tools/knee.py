@@ -8,8 +8,15 @@ A rate is *sustained* when at least 95% of the requests due in the window
 had their first token by its end (finished or decoding) and no more
 requests were waiting for a first token at the end than at the middle.
 The knee is the highest sustained rate; the cell's traffic file then takes
-0.8 x the knee as a number.  Prints one JSON object per window and a last
-line with the verdicts; not a cell run, and never read by the driver.
+0.8 x the knee as a number.  Beside the verdict each window says what a
+reader needs to tell whose knee it is: the generator's lateness (95th
+percentile; over ~20 ms the knee is the generator's), the most of the KV
+pool that live rows held (a pool that sheds rows sets a knee of its own),
+requests that failed, tokens a step.  Prints one JSON object per window and
+a last line with the verdicts, and keeps each window's step records
+(``tokens``, ``kv_pages_walked``, ``wall_ms``: what ``storm_model.py``'s
+constants are fitted to) under ``out/knee/``; not a cell run, and never
+read by the driver.
 """
 
 from __future__ import annotations
@@ -32,6 +39,34 @@ def waiting_at(requests: list, t: float) -> int:
         1 for r in requests
         if r.due_t <= t and r.error is None and (r.first_t is None or r.first_t > t)
     )
+
+
+async def sample_pool(handle, samples: list, every_s: float = 0.25) -> None:
+    """``(time, Handle.pool_pages())`` four times a second, until cancelled."""
+    while True:
+        pages = handle.pool_pages()
+        if pages is not None:
+            samples.append((time.perf_counter(), pages))
+        await asyncio.sleep(every_s)
+
+
+#: the cells' own per-layer readers that say whose knee it is: the
+#: generator's, the pool's or the step's
+FACTS = ("gen_lateness_p95_ms", "kv_pool_rows_share", "step_tokens_mean")
+
+
+def load_facts(manifest, run) -> dict:
+    """The window through the cells' own arithmetic (``cell.end_to_end``
+    and the readers under ``layer_metrics/``), so that the sweep's table
+    and a cell's line say the same thing."""
+    from benchmark.harness import cell
+
+    reqs = run.window.attempted
+    return {
+        "failed": sum(1 for r in reqs if r.failed),
+        "token_gap_mean_ms": cell.end_to_end(run.window, 0.0)["token_gap_mean_ms"],
+        **{name: manifest.module("layer_metrics", name).read(run) for name in FACTS},
+    }
 
 
 def judge(window) -> dict:
@@ -75,10 +110,24 @@ async def sweep(manifest, workload: str, seed: int, seconds: float, rates: list)
     try:
         await cell.warm_up(spec, handle, seed)
         cell.log(f"set-up {time.perf_counter() - _STARTED:.1f}s")
+        steps_dir = os.path.join(manifest.paths[0], "out", "knee")
+        os.makedirs(steps_dir, exist_ok=True)
         for i, rate in enumerate(rates):
+            pool: list = []
+            sampler = asyncio.create_task(sample_pool(handle, pool))
             window = await cell.measure(spec, handle, seed + i, seconds, rate=rate)
-            verdict = {"rate_per_s": rate, **judge(window)}
+            sampler.cancel()
+            window.pool = [pages for t, pages in pool if window.t0 <= t <= window.t1]
+            steps = handle.step_records(window.first_step, window.end_step)
+            run = cell.Run(spec, handle, window, steps, {}, None)
+            verdict = {"rate_per_s": rate, **judge(window), **load_facts(manifest, run)}
             out.append(verdict)
+            with open(os.path.join(steps_dir, f"{workload}.rate{rate:g}.steps.json"), "w") as f:
+                json.dump([
+                    [s.tokens, getattr(s, "prefill_tokens", None),
+                     getattr(s, "kv_pages_walked", None), s.wall_ms]
+                    for s in steps
+                ], f)
             print(json.dumps(verdict), flush=True)
             await asyncio.sleep(1.0)  # cancelled rows leave the engine
     finally:

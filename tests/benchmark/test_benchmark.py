@@ -451,12 +451,12 @@ def test_the_storm_cell_sends_one_schedule_under_every_seed(in_root):
     spec = cell.Spec.load(real, "qwen2.5-1.5b-int8.storm")
     assert "structure_seed" in spec.traffic
     a, b = cell.build_open(spec, 1, 51.0), cell.build_open(spec, 2, 51.0)
-    assert len(a) == 84
+    assert len(a) == 252  # 4.8 requests/s in the mean, the window's sixth burst cut at 51 s
     assert [(r.due_t, r.max_tokens) for r in a] == [(r.due_t, r.max_tokens) for r in b]
     assert not {r.prompt for r in a} & {r.prompt for r in b}
     prompts_a, prompts_b = [r.prompt for r in a], [r.prompt for r in b]
     assert [prompts_a.index(p) for p in prompts_a] == [prompts_b.index(p) for p in prompts_b]
-    assert len(set(prompts_a)) == 59  # 30% of 84 re-ask
+    assert len(set(prompts_a)) == 176  # 30% of 252 re-ask
     # a closed-loop mix has no such key: its shape is the seed's
     decode = cell.Spec.load(real, "qwen2.5-1.5b-int8.decode")
     assert decode.structure_seed(7) == 7
@@ -479,7 +479,7 @@ def test_the_storm_model_says_what_the_arrangement_does(in_root):
         storm_model.model_cell(real, cell_name, seed, 51.0, structure=seed) for seed in seeds
     ]
     assert fixed[0] == storm_model.model_cell(real, cell_name, 1, 51.0)
-    assert all(row["first_tokens"] == 84 for row in fixed + free)
+    assert all(row["first_tokens"] == 252 for row in fixed + free)
     for name, times in (("token_gap_mean_ms", 4.0), ("ttft_mean_ms", 2.0)):
         moved_fixed = max(r[name] for r in fixed) - min(r[name] for r in fixed)
         moved_free = max(r[name] for r in free) - min(r[name] for r in free)
@@ -488,14 +488,15 @@ def test_the_storm_model_says_what_the_arrangement_does(in_root):
     # a hand-made case: one row alone, 100 prompt tokens in two chunks of 64
     # and 36, then two more tokens, each step walking the pages it has
     out = storm_model.replay(
-        [(0.0, tuple(range(100)), 3)], page=64, chunk=64, budget=256, seconds=10.0
+        [(0.0, tuple(range(100)), 3)], page=64, chunk=64, budget=256, seconds=10.0,
+        step_ms=(90.0, 0.11, 0.05),
     )
 
-    def step(pages):
-        return (storm_model.STEP_BASE_MS + storm_model.MS_PER_PAGE * pages) / 1e3
+    def step(pages, tokens):
+        return (90.0 + 0.11 * pages + 0.05 * tokens) / 1e3
 
-    assert out["ttft_mean_ms"] == pytest.approx((step(1) + step(2)) * 1e3)
-    assert out["token_gap_mean_ms"] == pytest.approx(step(2) * 1e3)
+    assert out["ttft_mean_ms"] == pytest.approx((step(1, 64) + step(2, 36)) * 1e3)
+    assert out["token_gap_mean_ms"] == pytest.approx(step(2, 1) * 1e3)
     assert out["out_tokens_per_s"] == pytest.approx(0.3)
 
 

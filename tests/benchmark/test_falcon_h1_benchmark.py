@@ -10,7 +10,9 @@ files only.
 
 from __future__ import annotations
 
+import copy
 import dataclasses
+import inspect
 import json
 import os
 import subprocess
@@ -30,6 +32,12 @@ from benchmark.layer_metrics import (  # noqa: E402
     step_state_rows_mean,
 )
 from benchmark.trace import kernel_cost, ssm_cost  # noqa: E402
+
+# the benchmark's other test files, as modules (their directory is on the
+# path: pytest put it there to import this file)
+import test_benchmark as rules  # noqa: E402
+import test_step_sampled_rows  # noqa: E402
+import test_step_tracing  # noqa: E402
 
 MANIFEST = "tests/benchmark/rehearsal-falcon-h1.json"
 CELL = "falcon-h1-34b-int8.decode"
@@ -77,32 +85,105 @@ def test_the_configuration_file_holds_the_published_config_cut_in_depth_only(in_
     assert differing == {"num_hidden_layers"}  # every other key as published
 
 
-def test_the_manifest_gains_one_configuration_one_cell_and_three_metrics(in_root):
-    real = Manifest(os.path.join(ROOT, "BENCHMARK.json"))
-    assert real.doc["configs"][-1]["name"] == "falcon-h1-34b-int8"
-    assert real.doc["workloads"][-1] == {
-        **real.doc["workloads"][-1], "name": CELL, "traffic": "decode", "chips": 1,
-    }
-    assert [m["name"] for m in real.doc["per_layer"][-3:]] == [
-        "ssm_kernel_share", "ssm_kernel_roofline_share", "step_state_rows_mean",
-    ]
-    mine = {m["name"] for m in real.metrics_for("per_layer", CELL)}
+def by_name(entries):
+    """A manifest section's entries by ``name``: a later PR appends to
+    ``configs``, ``workloads`` and ``per_layer``, so a test finds an entry
+    by its name and never by its position."""
+    return {entry["name"]: entry for entry in entries}
+
+
+def check_the_falcon_entries(manifest):
+    """What PR 29 added, wherever in its sections it stands today."""
+    doc = manifest.doc
+    assert "falcon-h1-34b-int8" in by_name(doc["configs"])
+    cell = by_name(doc["workloads"])[CELL]
+    assert cell == {**cell, "name": CELL, "traffic": "decode", "chips": 1}
+    per_layer = by_name(doc["per_layer"])
+    assert NEW <= set(per_layer)
+    mine = {m["name"] for m in manifest.metrics_for("per_layer", CELL)}
     assert NEW | {
         "attn_kernel_roofline_share", "step_kv_pages_mean", "step_tokens_mean",
         "step_prefill_token_share", "step_host_ms", "step_host_wait_share",
         "attn_kernel_share", "device_idle_share", "peak_hbm_gb", "midrun_compiles",
     } <= mine
-    assert {m["name"] for m in real.metrics_for("end_to_end", CELL)} == {
+    assert {m["name"] for m in manifest.metrics_for("end_to_end", CELL)} == {
         "token_gap_mean_ms", "out_tokens_per_s", "setup_s",
     }
-    for entry in real.doc["per_layer"][-3:]:
+    for entry in (per_layer[name] for name in sorted(NEW)):
         assert entry["workloads"] == [CELL] and entry["layer"] == "kernels"
-        reader = real.module("layer_metrics", entry["name"])
+        reader = manifest.module("layer_metrics", entry["name"])
         assert (reader.NAME, reader.UNIT, reader.LAYER, reader.MOVES, reader.SOURCE) == (
             entry["name"], entry["unit"], entry["layer"], entry["moves"], entry["source"],
         )
     # the traffic file is the other `.decode` cells', unedited
-    assert real.traffic("decode")["greedy"]["every"] == 8
+    assert manifest.traffic("decode")["greedy"]["every"] == 8
+
+
+def test_the_manifest_gains_one_configuration_one_cell_and_three_metrics(in_root):
+    check_the_falcon_entries(Manifest(os.path.join(ROOT, "BENCHMARK.json")))
+
+
+# -- the contract a later PR adds under: append, and nothing that is here moves --
+
+#: what a later PR brings, as the rehearsal's files: one configuration, one
+#: cell, one per-layer metric with a reader of its own
+APPENDED_CONFIG = {
+    "name": "tiny-test", "source": "operator_tpu/models/configs.py TINY_TEST (a test preset, never a cell)",
+    "file": "tests/benchmark/configs/tiny-test.json", "reduced": [],
+    "why": "stands for the configuration a later PR appends",
+}
+APPENDED_CELL = {
+    "name": "tiny-test.decode", "config": "tiny-test", "traffic": "tiny-decode", "chips": 1,
+    "why": "stands for the cell a later PR appends",
+}
+APPENDED_METRIC = {
+    "name": "appended_requests_finished", "unit": "count", "better": "higher",
+    "source": "host_clock", "layer": "service", "moves": "token_gap_mean_ms",
+    "workloads": ["tiny-test.decode"],
+}
+#: every rule of ``test_benchmark.py`` that takes a manifest and nothing
+#: else, by name: a rule added there later is held here too
+MANIFEST_RULES = sorted(
+    name for name, rule in vars(rules).items()
+    if name.startswith("test_") and list(inspect.signature(rule).parameters) == ["manifest"]
+)
+
+
+@pytest.fixture(scope="module")
+def appended(in_root, tmp_path_factory):
+    """``BENCHMARK.json`` as a later PR leaves it: a copy, in a temporary
+    directory, with one more configuration, cell and per-layer entry
+    **appended**; its files are found from the checkout's root, as the
+    real one's are."""
+    before = load_json(os.path.join(ROOT, "BENCHMARK.json"))
+    doc = copy.deepcopy(before)
+    doc["configs"].append(APPENDED_CONFIG)
+    doc["workloads"].append(APPENDED_CELL)
+    doc["per_layer"].append(APPENDED_METRIC)
+    path = tmp_path_factory.mktemp("appended") / "BENCHMARK.json"
+    path.write_text(json.dumps(doc, indent=2))
+    manifest = Manifest(str(path))
+    # appending moved nothing that was there
+    for section in ("configs", "workloads", "end_to_end", "per_layer"):
+        assert manifest.doc[section][:len(before[section])] == before[section]
+    return manifest
+
+
+@pytest.mark.parametrize("rule", MANIFEST_RULES)
+def test_appending_to_the_manifest_breaks_no_manifest_rule(appended, rule):
+    getattr(rules, rule)(appended)
+
+
+def test_appending_to_the_manifest_breaks_no_test_that_finds_by_name(appended):
+    check_the_falcon_entries(appended)
+    test_step_tracing.check_the_counter_metrics(appended)
+    test_step_sampled_rows.check_the_entry(appended)
+    cell = appended.cell("tiny-test.decode")
+    assert cell == APPENDED_CELL and appended.config("tiny-test")["model_id"] == "tiny-test"
+    mine = {m["name"] for m in appended.metrics_for("per_layer", "tiny-test.decode")}
+    assert "appended_requests_finished" in mine and not mine & NEW
+    reader = appended.module("layer_metrics", "appended_requests_finished")
+    assert reader.NAME == APPENDED_METRIC["name"] and callable(reader.read)
 
 
 def test_the_kernels_names_keep_the_two_shares_apart():

@@ -1,10 +1,8 @@
 """The reader of ``step_sampled_rows_mean`` (PR 32), CPU only: on
 hand-made step records, on a program without the field, and its
-constants against the manifest.  ``BENCHMARK.json`` has no entry for it
-yet: ``test_falcon_h1_benchmark.py`` holds the manifest's last three
-``per_layer`` entries by position, so appending one takes a ``benchmark``
-PR that also makes that test find its entries by name (PERF.md, Open
-questions).
+entry in ``BENCHMARK.json`` (appended by PR 33, once the benchmark's
+tests found their entries by name) against the reader and the step
+record's field.
 """
 
 from __future__ import annotations
@@ -22,9 +20,11 @@ if ROOT not in sys.path:
 from benchmark.harness import manifest as manifest_mod  # noqa: E402
 from benchmark.layer_metrics import step_sampled_rows_mean  # noqa: E402
 
+#: every cell whose entry is the serving engine (all of them today)
 SERVING_CELLS = [
     "qwen2.5-1.5b-int8.storm", "qwen2.5-1.5b-int8.decode",
     "qwen2.5-7b-int8.decode", "falcon-h1-34b-int8.decode",
+    "qwen2.5-7b-int8.storm",
 ]
 
 
@@ -60,17 +60,39 @@ def test_sampled_rows_reader_by_hand(records, want):
     assert got is None if want is None else got == pytest.approx(want)
 
 
-def test_the_reader_is_ready_for_an_entry_in_the_four_serving_cells():
-    """What an appended ``per_layer`` entry would have to agree with: a
-    layer the manifest already names, an end-to-end metric every serving
-    cell reports, and the step record's field."""
+def check_the_entry(manifest):
+    """The manifest's entry, found by name, against the reader: a layer
+    the manifest names elsewhere too, an end-to-end metric every serving
+    cell reports, every serving cell listed, and the step record's field."""
     from operator_tpu.obs.steptrace import StepRecord
 
     reader = step_sampled_rows_mean
-    real = manifest_mod.Manifest(os.path.join(ROOT, "BENCHMARK.json"))
+    entry = next(m for m in manifest.doc["per_layer"] if m["name"] == reader.NAME)
     assert manifest_mod.NAME.match(reader.NAME) and manifest_mod.UNIT.match(reader.UNIT)
-    assert (reader.UNIT, reader.SOURCE) == ("count", "program_counter")
-    assert reader.LAYER in {m["layer"] for m in real.doc["per_layer"]}
-    for cell in SERVING_CELLS:
-        assert reader.MOVES in {m["name"] for m in real.metrics_for("end_to_end", cell)}
+    assert entry == {
+        "name": reader.NAME, "unit": "count", "better": "lower",
+        "source": "program_counter", "layer": reader.LAYER, "moves": reader.MOVES,
+        "workloads": entry["workloads"],
+    }
+    assert (reader.UNIT, reader.SOURCE) == (entry["unit"], entry["source"])
+    assert reader.LAYER in {
+        m["layer"] for m in manifest.doc["per_layer"] if m["name"] != reader.NAME
+    }
+    assert set(SERVING_CELLS) <= set(entry["workloads"])
+    for cell in entry["workloads"]:
+        assert reader.MOVES in {m["name"] for m in manifest.metrics_for("end_to_end", cell)}
+        assert reader.NAME in {m["name"] for m in manifest.metrics_for("per_layer", cell)}
+        assert manifest.config(manifest.cell(cell)["config"])["entry"] == "engine"
     assert "sampled_rows" in {f.name for f in dataclasses.fields(StepRecord)}
+
+
+@pytest.fixture
+def in_root():
+    before = os.getcwd()
+    os.chdir(ROOT)  # a manifest finds its files from the checkout's root
+    yield ROOT
+    os.chdir(before)
+
+
+def test_the_manifests_entry_holds_the_reader_in_every_serving_cell(in_root):
+    check_the_entry(manifest_mod.Manifest(os.path.join(ROOT, "BENCHMARK.json")))
