@@ -206,6 +206,13 @@ def kernels_leg(interpret: bool) -> dict:
             kv_len=[130, 5, 512, 0], q_count=[width, width, 3, 0],
             layer=layers - 1,
         ),
+        # one query head a KV head (ouro-2.6b: 16 x 128): sixteen
+        # [tile, 1, D] slabs a page, both rungs of the query tile
+        "mha_rows_c64": ragged_case(
+            "mha", *((16, 16, 128) if not interpret else (4, 4, 128)), chunk=64,
+            kv_len=[1, 200, 0, 64, 448, 333, 300, 0],
+            q_count=[1, 1, 0, 64, 64, 17, 8, 0], layer=2,
+        ),
         # Mistral's geometry with a window that bites inside 512 tokens
         # (its published 4096 never does below the serving cap)
         "sliding_window": ragged_case(

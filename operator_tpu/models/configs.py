@@ -49,13 +49,62 @@ class ModelConfig:
     #: draft roll-backs cannot restore it, so the serving path switches
     #: both off for such a model (serving/provider.py)
     recurrent_state: ClassVar[bool] = False
+    #: what of the model only the unsharded continuous scheduler serves
+    #: (``sched/mixed.py``), in words for the start-up error that refuses
+    #: the wave engine, a mesh and LoRA for it (serving/provider.py); None
+    #: for a model every engine serves
+    continuous_only: ClassVar[Optional[str]] = None
 
     @property
     def q_per_kv(self) -> int:
         return self.num_heads // self.num_kv_heads
 
+    @property
+    def kv_planes(self) -> int:
+        """Planes of the paged KV pool (its first axis): one a layer for a
+        model that runs its stack once a token."""
+        return self.num_layers
+
     def __post_init__(self) -> None:
         assert self.num_heads % self.num_kv_heads == 0, "heads must divide evenly into kv groups"
+
+
+@dataclass(frozen=True)
+class OuroConfig(ModelConfig):
+    """Ouro (LoopLM): one stack of sandwich-normed decoder layers run
+    ``total_ut_steps`` times a token with the same weights, the final norm
+    after every pass, and an exit gate read after each
+    (``models/ouro.py`` has the equations).  A pass attends only to its
+    own keys and values, so the cache holds a plane for every pass and
+    layer: plane ``pass * num_layers + layer``.  A sibling of
+    ``ModelConfig`` as ``FalconH1Config`` is; field names follow the
+    published ``config.json``."""
+
+    total_ut_steps: int = 4
+    #: the cumulative exit probability at which a row would leave the loop;
+    #: at the published 1.0 none does, and the serving path runs every
+    #: pass for every token whatever is set here
+    early_exit_threshold: float = 1.0
+    hidden_act: str = "silu"
+
+    family: ClassVar[str] = "ouro"
+    continuous_only: ClassVar[Optional[str]] = (
+        "runs its layer stack several times a token over a KV plane for "
+        "every pass and layer"
+    )
+
+    @property
+    def kv_planes(self) -> int:
+        return self.total_ut_steps * self.num_layers
+
+    def __post_init__(self) -> None:
+        super().__post_init__()
+        assert self.total_ut_steps >= 1
+        assert (
+            self.hidden_act == "silu" and not self.attention_bias
+            and not self.tie_embeddings and self.sliding_window is None
+            and self.rope_scaling is None
+        ), f"{self.name}: an Ouro variant the layer body does not implement"
 
 
 @dataclass(frozen=True)
@@ -97,6 +146,7 @@ class FalconH1Config(ModelConfig):
 
     family: ClassVar[str] = "falcon_h1"
     recurrent_state: ClassVar[bool] = True
+    continuous_only: ClassVar[Optional[str]] = "keeps a recurrent state per slot"
 
     @property
     def mamba_conv_dim(self) -> int:
@@ -333,6 +383,41 @@ TINY_FALCON_H1 = FalconH1Config(
     ssm_multipliers=(0.9, 0.8, 0.7, 1.2, 0.6),
 )
 
+# Ouro-2.6B as published (ByteDance/Ouro-2.6B, config.json): 48 layers run
+# four times a token; the serving cap on positions is provider.py's
+OURO_2_6B = OuroConfig(
+    name="ouro-2.6b",
+    vocab_size=49152,
+    hidden_size=2048,
+    intermediate_size=5632,
+    num_layers=48,
+    num_heads=16,
+    num_kv_heads=16,
+    head_dim=128,
+    rope_theta=1_000_000.0,
+    rms_norm_eps=1e-6,
+    max_seq_len=16384,  # serving cap; the model supports 64k
+    total_ut_steps=4,
+    early_exit_threshold=1.0,
+)
+
+#: the family's small config for tests: MHA at the kernel's head size,
+#: three passes over two layers
+TINY_OURO = OuroConfig(
+    name="tiny-ouro",
+    vocab_size=512,
+    hidden_size=128,
+    intermediate_size=352,
+    num_layers=2,
+    num_heads=4,
+    num_kv_heads=4,
+    head_dim=128,
+    rope_theta=10_000.0,
+    rms_norm_eps=1e-6,
+    max_seq_len=256,
+    total_ut_steps=3,
+)
+
 _REGISTRY = {
     cfg.name: cfg
     for cfg in (
@@ -348,6 +433,8 @@ _REGISTRY = {
         FALCON_H1_34B,
         FALCON_H1_34B_6L,
         TINY_FALCON_H1,
+        OURO_2_6B,
+        TINY_OURO,
     )
 }
 

@@ -55,6 +55,24 @@ _LAYER_MAP = {
 }
 
 
+#: what a family's checkpoint holds beyond the Llama layout.  Ouro's
+#: published names (transformers ``modeling_ouro.py``): a second norm
+#: after the attention and after the MLP of every layer, and the exit
+#: gate's linear layer beside the final norm
+_OURO_LAYER_MAP = {
+    **_LAYER_MAP,
+    "input_layernorm_2": ("ln_attn_post", False),
+    "post_attention_layernorm_2": ("ln_mlp_post", False),
+}
+#: HF name -> (our top-level name, the shape it is stored in here)
+_OURO_TOP = {
+    "model.early_exit_gate.weight": ("exit_w", (-1,)),  # [1, hidden]
+    "model.early_exit_gate.bias": ("exit_b", ()),  # [1]
+}
+_FAMILY_LAYER_MAPS = {"llama": _LAYER_MAP, "ouro": _OURO_LAYER_MAP}
+_FAMILY_TOPS = {"llama": {}, "ouro": _OURO_TOP}
+
+
 def _to_numpy(value: Any) -> np.ndarray:
     """Accept numpy / jax arrays and torch tensors (incl. bfloat16)."""
     if isinstance(value, np.ndarray):
@@ -74,7 +92,9 @@ def convert_hf_state_dict(
     *,
     put: Optional[Callable[[str, np.ndarray], jax.Array]] = None,
 ) -> Params:
-    """Map HF Llama names to the stacked pytree ``llama.init_params`` uses.
+    """Map HF names to the stacked pytree the family's ``init_params``
+    makes: the Llama layout, and for ``config.family == "ouro"`` the two
+    further norms a layer and the exit gate.
 
     ``state`` may be a dict (e.g. a torch ``state_dict()``) or a lazy
     ``(name, tensor)`` iterable (``iter_safetensors``).  ``put(name, array)``
@@ -86,8 +106,10 @@ def convert_hf_state_dict(
             return jnp.asarray(array, dtype)
 
     n = config.num_layers
+    layer_map = _FAMILY_LAYER_MAPS[config.family]
+    family_top = _FAMILY_TOPS[config.family]
     per_layer: dict[str, list[Optional[np.ndarray]]] = {
-        ours: [None] * n for ours, _ in _LAYER_MAP.values()
+        ours: [None] * n for ours, _ in layer_map.values()
     }
     if config.attention_bias:
         per_layer.update({f"b{axis}": [None] * n for axis in "qkv"})
@@ -110,6 +132,9 @@ def convert_hf_state_dict(
             top["ln_final"] = put("ln_final", _to_numpy(raw))
         elif name == "lm_head.weight":
             top["lm_head"] = put("lm_head", _to_numpy(raw).T)
+        elif name in family_top:
+            ours, shape = family_top[name]
+            top[ours] = put(ours, _to_numpy(raw).reshape(shape))
         else:
             bias_match = _BIAS_RE.fullmatch(name)
             if bias_match:
@@ -124,7 +149,7 @@ def convert_hf_state_dict(
                 log.debug("ignoring unknown checkpoint tensor %s", name)
                 continue
             idx, sub = int(match.group(1)), match.group(2)
-            mapped = _LAYER_MAP.get(sub)
+            mapped = layer_map.get(sub)
             if mapped is None:
                 log.debug("ignoring unknown layer tensor %s", name)
                 continue
@@ -143,7 +168,11 @@ def convert_hf_state_dict(
     ]
     if missing:
         raise ValueError(f"checkpoint is missing {len(missing)} tensors, e.g. {missing[:4]}")
+    absent = [hf for hf, (ours, _) in family_top.items() if ours not in top]
+    if absent:
+        raise ValueError(f"checkpoint is missing {absent}")
     params: Params = {"embed": top["embed"], "layers": layers, "ln_final": top["ln_final"]}
+    params.update({ours: top[ours] for ours, _ in family_top.values()})
     if config.tie_embeddings:
         if "lm_head" in top:
             log.info("config ties embeddings; ignoring checkpoint lm_head")
@@ -213,6 +242,12 @@ def save_params(
     from safetensors.numpy import save_file
 
     from .quant import is_quantized
+
+    if config.family != "llama":
+        raise NotImplementedError(
+            f"save_params writes the Llama layout only, not the "
+            f"{config.family!r} family's (model {config.name!r})"
+        )
 
     if is_quantized(params):
         raise ValueError(
@@ -319,11 +354,12 @@ def load_params(
     """
     from .quant import quantize_matrix, quantized_layer_matrices
 
-    if config.family != "llama":
+    if config.family not in _FAMILY_LAYER_MAPS:
         raise NotImplementedError(
             f"no checkpoint converter for the {config.family!r} family "
-            f"(model {config.name!r}): convert_hf_state_dict maps Llama-family "
-            "tensor names only; serve it with ALLOW_RANDOM_WEIGHTS=true"
+            f"(model {config.name!r}): convert_hf_state_dict maps the tensor "
+            f"names of {sorted(_FAMILY_LAYER_MAPS)} only; serve it with "
+            "ALLOW_RANDOM_WEIGHTS=true"
         )
     quantized = quantized_layer_matrices(config)
     state = iter_safetensors(checkpoint_dir)

@@ -92,13 +92,23 @@ def quantize_params(params: Params, config: ModelConfig) -> Params:
     return {**params, "layers": layers}
 
 
-def mm(x: jax.Array, w: "jax.Array | dict[str, jax.Array]") -> jax.Array:
+def mm(
+    x: jax.Array, w: "jax.Array | dict[str, jax.Array]", out_dtype: Any = None,
+) -> jax.Array:
     """``x @ W`` for plain or quantized weights.
 
     The int8 matrix is cast to the activation dtype going INTO the matmul
     (the MXU has no int8xbf16 path; the cast is free relative to the HBM
     read we saved) and the per-channel scale folds into the epilogue.
+    With ``out_dtype`` the product leaves the accumulator in that dtype
+    and the scale is applied there: no rounding to the activations' dtype
+    between the sum and whoever reads it (models/ouro.py norms every
+    branch in float32).
     """
+    if out_dtype is not None:
+        weight = w["q"] if isinstance(w, dict) else w
+        y = jnp.matmul(x, weight.astype(x.dtype), preferred_element_type=out_dtype)
+        return y * w["s"].astype(out_dtype) if isinstance(w, dict) else y
     if isinstance(w, dict):
         return (x @ w["q"].astype(x.dtype)) * w["s"].astype(x.dtype)
     return x @ w

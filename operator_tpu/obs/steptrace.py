@@ -99,6 +99,10 @@ class StepRecord:
     #: host's count of what ``sched/mixed.py`` branches on, not of what
     #: ran); None on engines that do not count them
     sampled_rows: Optional[int] = None
+    #: times every token of the step took the layer stack: 1, or a looped
+    #: model's ``total_ut_steps`` (``sched/mixed.py`` runs every pass for
+    #: every row); None on engines that do not say
+    passes: Optional[int] = None
     #: per-step achieved MFU when the ring's owner knows the model's
     #: flops/token (serving/perf.py StepClock); None on bare rings
     mfu: Optional[float] = None
@@ -157,7 +161,7 @@ _MS_FIELDS = (
 )
 _COUNT_FIELDS = (
     "accepted", "cached_tokens", "prefill_tokens", "kv_pages_walked",
-    "q_tile_rows", "state_rows", "sampled_rows",
+    "q_tile_rows", "state_rows", "sampled_rows", "passes",
 )
 
 
@@ -338,7 +342,7 @@ def render_steps(records: "Iterable[StepRecord]") -> str:
     header = (
         f"{'seq':>5}  {'kind':<7} {'tok':>5} {'pf_tok':>6} {'slots':>5} {'occ':>5} "
         f"{'wall_ms':>8} {'host_ms':>8} {'wait_ms':>8} {'xfer_ms':>8} "
-        f"{'plan':>7} {'pack':>7} {'commit':>7} {'turn':>7} "
+        f"{'plan':>7} {'pack':>7} {'commit':>7} {'turn':>7} {'passes':>6} "
         f"{'st_rows':>7} {'smp_rows':>8} {'kv_pg':>6} {'q_fill':>6} {'mfu':>8}"
     )
     lines = [header, "-" * len(header)]
@@ -349,11 +353,12 @@ def render_steps(records: "Iterable[StepRecord]") -> str:
         fill = f"{r.tokens / r.q_tile_rows:.3f}" if r.q_tile_rows else "-"
         state = r.state_rows if r.state_rows is not None else "-"
         sampled = r.sampled_rows if r.sampled_rows is not None else "-"
+        passes = r.passes if r.passes is not None else "-"
         lines.append(
             f"{r.seq:>5}  {r.kind:<7} {r.tokens:>5} {prompt:>6} {r.slots:>5} "
             f"{r.occupancy:>5.2f} {r.wall_ms:>8.3f} {r.host_ms:>8.3f} "
             f"{r.wait_ms:>8.3f} {r.xfer_ms:>8.3f} {r.plan_ms:>7.3f} "
-            f"{r.pack_ms:>7.3f} {r.commit_ms:>7.3f} {r.turn_ms:>7.3f} "
+            f"{r.pack_ms:>7.3f} {r.commit_ms:>7.3f} {r.turn_ms:>7.3f} {passes:>6} "
             f"{state:>7} {sampled:>8} {pages:>6} {fill:>6} {mfu:>8}"
         )
     return "\n".join(lines)

@@ -42,9 +42,12 @@ _NEG_INF = -1e30
 
 @dataclass
 class PagedKVCache:
-    """Per-layer paged KV storage (layers stacked on axis 0 for lax.scan)."""
+    """Per-layer paged KV storage, the planes stacked on axis 0: one a
+    layer, or one a pass and layer (``pass * layers + layer``) for a model
+    whose stack runs several times a token (``config.kv_planes``).  A page
+    id names one page in every plane."""
 
-    k_pages: jax.Array  # [layers, num_pages, page_size, kv_heads, head_dim]
+    k_pages: jax.Array  # [planes, num_pages, page_size, kv_heads, head_dim]
     v_pages: jax.Array
     page_table: jax.Array  # [batch, pages_per_seq] int32
     lengths: jax.Array  # [batch] int32
@@ -86,7 +89,9 @@ class PagedKVCache:
         dtype: jnp.dtype = jnp.bfloat16,
         recurrent: Optional[tuple] = None,
     ) -> "PagedKVCache":
-        """``recurrent`` is :meth:`recurrent_shapes`' pair: the state is
+        """``num_layers`` is the pool's planes, the model's ``kv_planes``
+        (a looped model's passes x layers).  ``recurrent`` is
+        :meth:`recurrent_shapes`' pair: the state is
         float32 (a sum over every token the row has seen), the conv tail
         the activations' ``dtype``."""
         shape = (num_layers, num_pages, page_size, kv_heads, head_dim)

@@ -2147,8 +2147,8 @@ class ServingEngine:
             "continuous scheduler mode (sched_mode=continuous)"
             + (
                 f", the only mode that serves model {model.name!r} "
-                f"({model.family} family: a recurrent state per slot)"
-                if getattr(model, "recurrent_state", False) else ""
+                f"({model.family} family: {model.continuous_only})"
+                if getattr(model, "continuous_only", None) else ""
             )
         )
 

@@ -42,16 +42,18 @@ def matmul_param_count(config: Any) -> int:
     pass: the layer matrices the model's own family names
     (``models.family_of(config).layer_matrix_shapes``: attention
     projections and MLP, and a state-space mixer's in- and out-projection
-    where the family has one), plus the LM head — which multiplies even
-    when tied to the embedding.  Norm scales, convolution taps and the
-    embedding GATHER move no matmul MACs, so they are excluded;
-    ``param_count(params)`` counts them and is the storage number, not
-    the compute number."""
+    where the family has one), once for every pass a token takes through
+    the stack (``total_ut_steps``: models/ouro.py), plus the LM head —
+    which multiplies even when tied to the embedding.  Norm scales,
+    convolution taps and the embedding GATHER move no matmul MACs, so
+    they are excluded; ``param_count(params)`` counts them and is the
+    storage number, not the compute number."""
     from ..models import family_of
 
     shapes = family_of(config).layer_matrix_shapes(config)
     layers = sum(n * rows * cols for n, rows, cols in shapes.values())
-    return layers + config.hidden_size * config.vocab_size
+    passes = int(getattr(config, "total_ut_steps", 1))
+    return passes * layers + config.hidden_size * config.vocab_size
 
 
 def flops_per_token(config: Any, dtype: str = "bf16") -> float:
@@ -165,7 +167,7 @@ class StepClock:
         alone and its wall is the two waits.  ``counts`` are the record's
         optional work counts (``accepted``, ``cached_tokens``,
         ``prefill_tokens``, ``kv_pages_walked``, ``q_tile_rows``,
-        ``state_rows``, ``sampled_rows``).  MFU stays
+        ``state_rows``, ``sampled_rows``, ``passes``).  MFU stays
         computed on billed ``tokens`` — the compute really ran — over the
         interval's wall."""
         if commit_t is None:

@@ -365,13 +365,14 @@ def build_serving_engine(
             f"unknown sched_mode {config.sched_mode!r}: expected "
             "'wave' or 'continuous'"
         )
-    if model_config.recurrent_state and (
+    if model_config.continuous_only and (
         config.sched_mode != "continuous" or mesh is not None or lora_adapters
     ):
         # a recurrent state per slot lives only in the continuous path's
-        # cache (ops/paged_attention.PagedKVCache.ssm_state): the wave
-        # engine's programs, a sharded pool and the LoRA path know nothing
-        # of it, and serving without it would be wrong, not slow
+        # cache (ops/paged_attention.PagedKVCache.ssm_state), and only its
+        # step loops over a looped model's passes: the wave engine's
+        # programs, a sharded pool and the LoRA path know nothing of
+        # either, and serving without them would be wrong, not slow
         asked = ", ".join(
             text for on, text in (
                 (config.sched_mode != "continuous", f"sched_mode={config.sched_mode!r}"),
@@ -380,10 +381,10 @@ def build_serving_engine(
             ) if on
         )
         raise ValueError(
-            f"model {model_id!r} ({model_config.family} family) keeps a "
-            f"recurrent state per slot, which only the unsharded continuous "
-            f"scheduler serves (sched_mode=continuous, no serving_mesh, no "
-            f"LoRA); this configuration asks for {asked}"
+            f"model {model_id!r} ({model_config.family} family) "
+            f"{model_config.continuous_only}, which only the unsharded "
+            f"continuous scheduler serves (sched_mode=continuous, no "
+            f"serving_mesh, no LoRA); this configuration asks for {asked}"
         )
     if config.sched_mode == "continuous":
         blockers = [

@@ -161,7 +161,7 @@ class Runtime:
         self.allocator = PageAllocator(self._kv_pages)
         self.paged_cache = self._place(
             lambda: PagedKVCache.create(
-                self.config.num_layers, self._kv_pages,
+                self.config.kv_planes, self._kv_pages,
                 self.page_size, self.config.num_kv_heads,
                 self.config.head_dim, self.max_slots, self.pages_per_seq,
                 dtype=self.cache_dtype,

@@ -21,7 +21,11 @@ CARRY, whole, beside the residual stream (and a recurrent model's state
 pools): a layer scatters its step's rows at ``(layer, page, slot)`` and
 the kernel fetches its pages from ``(layer, page)``, so the donated pool
 is updated in place and held once — no layer's slice is cut out, none is
-stacked back, and no step copies the pool.
+stacked back, and no step copies the pool.  A model that runs its stack
+``passes`` times a token (``config.total_ut_steps``: models/ouro.py) has
+``passes x L`` planes, and the layer loop runs inside a loop over passes:
+pass ``t``'s layer ``l`` writes and reads plane ``t * L + l``, the final
+norm ends every pass, and the carry goes through both loops.
 
 Exactly ONE program compiles per engine (static ``t_budget`` / ``chunk``
 / ``max_slots``): there is no bucket grid to warm, no per-shape compile
@@ -68,10 +72,12 @@ class StepView:
     q_count: Any  # [S] the slot's tokens this step
     #: ``attend(q, k, v, pools, layer) -> (attn [1, T, QH * D], pools)``
     #: with ``pools = {"k", "v"}`` the WHOLE stacked KV pools
-    #: ``[L, pages, page, KH, D]`` off the layer loop's carry: RoPE, the
-    #: step's K/V into ``layer``'s pages in place, the ragged kernel over
-    #: that layer, the rows back on the flat axis.  The pools it returns
-    #: go back into the carry
+    #: ``[planes, pages, page, KH, D]`` off the layer loop's carry: RoPE,
+    #: the step's K/V into plane ``layer``'s pages in place, the ragged
+    #: kernel over that plane, the rows back on the flat axis.  ``layer``
+    #: is the scanned index the body was given: the layer, or for a model
+    #: of several passes ``pass * L + layer``.  The pools it returns go
+    #: back into the carry
     attend: Callable
 
 
@@ -132,6 +138,13 @@ def make_mixed_fn(runtime: Any, t_budget: int, chunk: int,
     # muP models scale the embedding and the logits (models/falcon_h1.py)
     embedding_multiplier = float(getattr(config, "embedding_multiplier", 1.0))
     lm_head_multiplier = float(getattr(config, "lm_head_multiplier", 1.0))
+    # how many times a token takes the layer stack (models/ouro.py); the
+    # exit gate of such a model is not evaluated here: every row takes
+    # every pass
+    passes = int(getattr(config, "total_ut_steps", 1))
+    # a family may carry the residual stream in another dtype than its
+    # parameters' (models/ouro.py: float32 through 384 additions a token)
+    stream_dtype = getattr(family_of(config), "STREAM_DTYPE", None)
 
     def mixed_fn(params, paged, ids, rows, pos, valid, in_row,
                  q_start, q_count, kv_len, latest, from_prev,
@@ -147,6 +160,8 @@ def make_mixed_fn(runtime: Any, t_budget: int, chunk: int,
             x = jnp.take(params["embed"], eff_ids, axis=0)[None]  # [1, T, H]
             if embedding_multiplier != 1.0:
                 x = (x.astype(jnp.float32) * embedding_multiplier).astype(x.dtype)
+            if stream_dtype is not None:
+                x = x.astype(stream_dtype)
         positions = pos[None]  # [1, T]
         # flat -> per-row packing indices for the attention re-pack
         pack_idx = jnp.clip(
@@ -160,6 +175,7 @@ def make_mixed_fn(runtime: Any, t_budget: int, chunk: int,
         page_slots = jnp.where(valid, pos % page_size, 0)
 
         def attend(q, k, v, pools, layer):
+            dtype = q.dtype  # the projections': what comes back
             q = q.reshape(1, t_budget, config.num_heads, config.head_dim)
             k = k.reshape(1, t_budget, config.num_kv_heads, config.head_dim)
             v = v.reshape(1, t_budget, config.num_kv_heads, config.head_dim)
@@ -190,7 +206,7 @@ def make_mixed_fn(runtime: Any, t_budget: int, chunk: int,
                     valid[:, None, None], attn_pack[rows, in_row], 0
                 )
             return (
-                attn.astype(x.dtype).reshape(1, t_budget, -1),
+                attn.astype(dtype).reshape(1, t_budget, -1),
                 {"k": k_pages, "v": v_pages},
             )
 
@@ -209,15 +225,31 @@ def make_mixed_fn(runtime: Any, t_budget: int, chunk: int,
         recurrent = None
         if paged.ssm_state is not None:
             recurrent = {"ssm": paged.ssm_state, "conv": paged.conv_state}
-        (x, pools, recurrent), _ = lax.scan(
-            layer_step, (x, pools, recurrent),
-            {
-                "w": params["layers"],
-                "layer": jnp.arange(config.num_layers, dtype=jnp.int32),
-            },
-        )
 
-        x = rms_norm(x, params["ln_final"], config.rms_norm_eps)
+        def one_pass(carry, planes):
+            """The layer stack once, over the pool planes ``planes [L]``,
+            and the norm that ends a pass: the head's input after the
+            last pass, the next pass's after any other."""
+            (x, pools, recurrent), _ = lax.scan(
+                layer_step, carry, {"w": params["layers"], "layer": planes},
+            )
+            with jax.named_scope("pass_norm"):
+                x = rms_norm(x, params["ln_final"], config.rms_norm_eps)
+            return (x, pools, recurrent), None
+
+        # a model whose stack runs several times a token (models/ouro.py)
+        # has a plane of the pool for every pass and layer, pass-major: the
+        # same weights, each pass writing and reading its own planes.  The
+        # pools stay in the carry through both loops.  One pass is the
+        # plain layer loop, under no second loop
+        planes = jnp.arange(passes * config.num_layers, dtype=jnp.int32)
+        if passes == 1:
+            (x, pools, recurrent), _ = one_pass((x, pools, recurrent), planes)
+        else:
+            (x, pools, recurrent), _ = lax.scan(
+                one_pass, (x, pools, recurrent),
+                planes.reshape(passes, config.num_layers),
+            )
 
         def sample_at(rows_a_slot, rng):
             """``rows_a_slot`` positions a slot from ``sample_start`` on,
@@ -231,11 +263,13 @@ def make_mixed_fn(runtime: Any, t_budget: int, chunk: int,
                 0, t_budget - 1,
             )  # [B, rows_a_slot]
             with jax.named_scope("head"):
-                x_samp = x[0][samp_idx]  # [B, rows_a_slot, H]
                 head = (
                     params["embed"].T if config.tie_embeddings
                     else params["lm_head"]
                 )
+                # [B, rows_a_slot, H], in the head's dtype whatever the
+                # stream's
+                x_samp = x[0][samp_idx].astype(head.dtype)
                 logits = jnp.einsum(
                     "bwh,hv->bwv", x_samp, head,
                     preferred_element_type=jnp.float32,

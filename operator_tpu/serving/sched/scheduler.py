@@ -216,6 +216,9 @@ class Scheduler:
         self.switched_off: dict[str, str] = {}
         model = generator.config
         self._recurrent = bool(getattr(model, "recurrent_state", False))
+        #: times a token takes the layer stack in the mixed step
+        #: (``StepRecord.passes``, the dispatch span's ``passes``)
+        self._passes = int(getattr(model, "total_ut_steps", 1))
         if self._recurrent:
             if spec_decode:
                 self.switched_off["spec_decode"] = (
@@ -627,6 +630,10 @@ class Scheduler:
                 qk_pairs=packed.qk_pairs,
                 tokens=plan.tokens_planned,
                 q_tile_rows=packed.counts["q_tile_rows"],
+                # the pool planes the step writes and walks: one a pass
+                # and layer
+                passes=self._passes,
+                kv_planes=int(g.paged_cache.k_pages.shape[0]),
                 **(
                     {"state_rows": packed.counts["state_rows"]}
                     if self._recurrent else {}  # no such argument without the state
@@ -1278,6 +1285,7 @@ class Scheduler:
                 # spec_len; this restates the predicate on the host and
                 # does not observe which branch the device took
                 "sampled_rows": b * (self.width if wide else 1),
+                "passes": self._passes,
             },
             qk_pairs=pairs,
         )

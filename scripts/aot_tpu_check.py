@@ -11,8 +11,8 @@ CPU host finds them before chip time is spent.  Covered:
 - the ragged mixed-phase kernel — the continuous scheduler's ONLY
   attention on a TPU — at the ``(QH, KH, D)`` of every registered model
   config, bf16, page 64, chunk widths 5 (verify) and 64 (prefill), plus a
-  sliding-window case, and alone at the benchmark's two cells (12/2 heads
-  x 128 slots, 28/4 x 32): both rungs of its query tile.  A config the kernel cannot serve must be REFUSED
+  sliding-window case, and alone at the benchmark's cells (12/2 heads
+  x 128 slots, 28/4 x 32, 16/16 x 10): both rungs of its query tile.  A config the kernel cannot serve must be REFUSED
   by ``require_ragged_kernel_support`` (a named error at engine build),
   never silently routed elsewhere — the check asserts which of the two
   happens for each config;
@@ -27,6 +27,9 @@ CPU host finds them before chip time is spent.  Covered:
   mixed step of a model with recurrent state, at the benchmark cell's
   shape (``falcon-h1-34b-6l``, 128 slots, 1,536 pages): the memory
   analysis shows the 3.2 GB state pool aliased, held once;
+- the whole mixed step of a model whose stack runs several times a token
+  (``ouro-2.6b``, 10 slots, 112 pages of 192 planes: the pass loop round
+  the layer loop), and the ragged kernel alone at its 16 MHA heads;
 - for each whole mixed step, ``kv_pool``: the stacked KV pools' bytes and
   the names of the optimised HLO's instructions that MOVE a pool — a
   ``copy``, ``dynamic-slice`` or ``dynamic-update-slice`` (or a fusion that
@@ -163,8 +166,8 @@ def _mixed_step_case(topo_device, model_id=None, slots=_SLOTS, t_budget=None,
     pool = (num_pages, _PAGE, config.num_kv_heads, config.head_dim)
     recurrent = PagedKVCache.recurrent_shapes(config, slots)
     paged = PagedKVCache(
-        k_pages=shaped((config.num_layers, *pool), jnp.bfloat16),
-        v_pages=shaped((config.num_layers, *pool), jnp.bfloat16),
+        k_pages=shaped((config.kv_planes, *pool), jnp.bfloat16),
+        v_pages=shaped((config.kv_planes, *pool), jnp.bfloat16),
         page_table=shaped((slots, pages_per_seq), jnp.int32),
         lengths=shaped((slots,), jnp.int32),
         ssm_state=None if recurrent is None else shaped(recurrent[0], jnp.float32),
@@ -185,7 +188,7 @@ def _mixed_step_case(topo_device, model_id=None, slots=_SLOTS, t_budget=None,
     )
     return (
         make_mixed_fn(generator, t, _CHUNK, spec_width=spec_width), args,
-        (config.num_layers, *pool),
+        (config.kv_planes, *pool),
     )
 
 
@@ -352,6 +355,8 @@ def main() -> int:
     # too, at the slot counts the cells run
     for tag, heads, kv_heads, slots in (
         ("qwen2.5-1.5b", 12, 2, 128), ("qwen2.5-7b", 28, 4, 32),
+        # one query head a kv head: sixteen [tile, 1, D] slabs
+        ("ouro-2.6b", 16, 16, 10),
     ):
         cases.append((
             f"ragged_cell_{tag}_b{slots}", _ragged_attention_pallas,
@@ -405,6 +410,11 @@ def main() -> int:
             ("mixed_step_falcon-h1-34b-6l_b128", dict(
                 model_id="falcon-h1-34b-6l", slots=f_slots, t_budget=f_tokens,
                 kv_pages=1536, spec_width=1,
+            )),
+            # the looped model's cell: 4 passes x 48 layers = 192 planes
+            # of 112 pages, 11.3 GB, through both loops and held once
+            ("mixed_step_ouro-2.6b_b10", dict(
+                model_id="ouro-2.6b", slots=10, kv_pages=112,
             )),
         ):
             fn, args, pools[name] = _mixed_step_case(topo.devices[0], **cell)

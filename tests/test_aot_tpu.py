@@ -84,6 +84,8 @@ def test_all_kernels_aot_compile_for_v5e(record):
     # tile that cannot lower at 7B's 7 queries a kv head is found here
     assert "ragged_cell_qwen2.5-1.5b_b128" in kernels, record
     assert "ragged_cell_qwen2.5-7b_b32" in kernels, record
+    # one query head a kv head: sixteen [tile, 1, D] slabs
+    assert "ragged_cell_ouro-2.6b_b10" in kernels, record
     # the whole mixed step at the server's default shape, for the default
     # model — which must therefore be one the kernel serves
     assert _REGISTRY[OperatorConfig().model_id].head_dim % 128 == 0
@@ -116,11 +118,28 @@ def test_the_recurrent_models_step_compiles_with_its_state_pool_held_once(record
     assert total < 15.75 * 2**30  # fits the chip
 
 
+def test_the_looped_models_pool_has_a_plane_a_pass_and_layer_and_fits(record):
+    """The benchmark cell of the model whose stack runs four times a
+    token: the pool's first axis is passes x layers, it is most of the
+    step's arguments, comes back aliased, and the whole step leaves the
+    chip over a GB."""
+    step = record["kernels"]["mixed_step_ouro-2.6b_b10"]
+    assert step["kv_pool"]["shape"] == [4 * 48, 112, 64, 16, 128]
+    assert step["kv_pool"]["bytes"] > 11e9
+    assert step["alias_bytes"] >= step["kv_pool"]["bytes"]
+    assert step["output_bytes"] - step["alias_bytes"] < 1e6
+    assert step["temp_bytes"] < 1e9, step
+    total = step["argument_bytes"] + step["output_bytes"] - step["alias_bytes"] + step["temp_bytes"]
+    assert total < 15.75 * 2**30 - 1e9
+
+
 @pytest.mark.parametrize("case, conditionals", [
     ("mixed_step_default_model", 1),
     ("mixed_step_qwen2.5-1.5b_b128", 1),
     ("mixed_step_qwen2.5-7b_b32", 1),
     ("mixed_step_falcon-h1-34b-6l_b128", 0),
+    # 192 planes, through the pass loop and the layer loop inside it
+    ("mixed_step_ouro-2.6b_b10", 1),
 ])
 def test_the_kv_pool_is_held_once_and_never_copied(record, case, conditionals):
     """The stacked KV pools ride the layer loop's carry and are written in

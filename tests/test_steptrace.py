@@ -417,15 +417,17 @@ class TestStepView:
             StepRecord(seq=1, kind="prefill", tokens=16, slots=1,
                        occupancy=0.25, wall_ms=9.0, host_ms=0.0, wait_ms=9.0,
                        xfer_ms=0.0, kv_pages_walked=7, prefill_tokens=16,
-                       q_tile_rows=64, state_rows=1, sampled_rows=20),
+                       q_tile_rows=64, state_rows=1, sampled_rows=20, passes=4),
         ])
         lines = table.splitlines()
         assert lines[0].split() == [
             "seq", "kind", "tok", "pf_tok", "slots", "occ",
             "wall_ms", "host_ms", "wait_ms", "xfer_ms",
-            "plan", "pack", "commit", "turn",
+            "plan", "pack", "commit", "turn", "passes",
             "st_rows", "smp_rows", "kv_pg", "q_fill", "mfu",
         ]
+        # passes a token took through the layer stack; "-" where not said
+        assert lines[3].split()[-6] == "4" and lines[2].split()[-6] == "-"
         # slots whose recurrent state the step touched; "-" without such state
         assert lines[3].split()[-5] == "1" and lines[2].split()[-5] == "-"
         # logit rows the head and the sampler worked; "-" where not counted
