@@ -85,6 +85,11 @@ class StepRecord:
     #: less the pages a sliding window skips — ``num_live - first`` of
     #: ``ops/ragged_attention._ragged_attn_kernel``
     kv_pages_walked: Optional[int] = None
+    #: flash updates ONE layer's ragged-attention call makes this step:
+    #: each walking slot's pages in blocks of what its rung folds into
+    #: one update (``ops/ragged_attention.kv_blocks_walked``);
+    #: ``kv_pages_walked`` over it is how full the blocks ran
+    kv_blocks_walked: Optional[int] = None
     #: query-tile rows ONE layer's ragged-attention call computes this
     #: step: the sum over slots with ``q_count > 0`` of the tile the
     #: kernel chooses for them (``ops/ragged_attention.query_tile_rows``);
@@ -161,7 +166,7 @@ _MS_FIELDS = (
 )
 _COUNT_FIELDS = (
     "accepted", "cached_tokens", "prefill_tokens", "kv_pages_walked",
-    "q_tile_rows", "state_rows", "sampled_rows", "passes",
+    "kv_blocks_walked", "q_tile_rows", "state_rows", "sampled_rows", "passes",
 )
 
 
@@ -343,12 +348,18 @@ def render_steps(records: "Iterable[StepRecord]") -> str:
         f"{'seq':>5}  {'kind':<7} {'tok':>5} {'pf_tok':>6} {'slots':>5} {'occ':>5} "
         f"{'wall_ms':>8} {'host_ms':>8} {'wait_ms':>8} {'xfer_ms':>8} "
         f"{'plan':>7} {'pack':>7} {'commit':>7} {'turn':>7} {'passes':>6} "
-        f"{'st_rows':>7} {'smp_rows':>8} {'kv_pg':>6} {'q_fill':>6} {'mfu':>8}"
+        f"{'st_rows':>7} {'smp_rows':>8} {'kv_pg':>6} {'pg_blk':>6} {'q_fill':>6} "
+        f"{'mfu':>8}"
     )
     lines = [header, "-" * len(header)]
     for r in records:
         mfu = f"{r.mfu:.4f}" if r.mfu is not None else "-"
         pages = r.kv_pages_walked if r.kv_pages_walked is not None else "-"
+        # pages a flash update folded in, of the block its rung could take
+        block = (
+            f"{r.kv_pages_walked / r.kv_blocks_walked:.2f}"
+            if r.kv_pages_walked and r.kv_blocks_walked else "-"
+        )
         prompt = r.prefill_tokens if r.prefill_tokens is not None else "-"
         fill = f"{r.tokens / r.q_tile_rows:.3f}" if r.q_tile_rows else "-"
         state = r.state_rows if r.state_rows is not None else "-"
@@ -359,6 +370,6 @@ def render_steps(records: "Iterable[StepRecord]") -> str:
             f"{r.occupancy:>5.2f} {r.wall_ms:>8.3f} {r.host_ms:>8.3f} "
             f"{r.wait_ms:>8.3f} {r.xfer_ms:>8.3f} {r.plan_ms:>7.3f} "
             f"{r.pack_ms:>7.3f} {r.commit_ms:>7.3f} {r.turn_ms:>7.3f} {passes:>6} "
-            f"{state:>7} {sampled:>8} {pages:>6} {fill:>6} {mfu:>8}"
+            f"{state:>7} {sampled:>8} {pages:>6} {block:>6} {fill:>6} {mfu:>8}"
         )
     return "\n".join(lines)

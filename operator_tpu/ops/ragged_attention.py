@@ -47,9 +47,9 @@ prefix (sched/mixed.py).
 The Pallas kernel walks each row's live pages with in-kernel
 double-buffered DMAs steered by the scalar-prefetched page table (the
 ``_paged_attn_kernel_v2`` design: exactly ``ceil(kv_len/page)`` pages
-move from HBM) and keeps a flash-attention running (max, sum, acc) per
-(query row, head) in VMEM.  The dense reference is the oracle for parity
-tests and the CPU path.
+move from HBM), a block of several pages to a loop turn, and keeps a
+flash-attention running (max, sum, acc) per (query row, head) in VMEM.
+The dense reference is the oracle for parity tests and the CPU path.
 
 **The kernel pays by the work.**  One grid step serves one row, and what
 it computes follows that row's ``q_count``, not the compiled chunk ``C``:
@@ -60,6 +60,44 @@ it computes follows that row's ``q_count``, not the compiled chunk ``C``:
   state, score block, mask, ``exp`` and both products have the tile's
   rows (:func:`query_tile_rows` is the rule, and what the scheduler's
   ``q_tile_rows`` counts).  Both rungs are branches of the one kernel;
+- *the KV block*: a live row's pages ``first .. num_live`` are walked
+  ``N`` to a turn of the loop, and a turn scores, masks and folds its
+  ``N x page_size`` keys into the flash state in ONE update (one wait
+  for the copies, one score product a KV head of ``[slab, D] x [D, N x
+  page_size]``, one mask, one lane reduction, one read and write of max,
+  sum and accumulator, one value product) where a page a turn paid that
+  chain once for every 64 keys.  A page is still one ``[page_size, KH,
+  D]`` copy from ``(layer, page_table[b, j])`` into its rows of the
+  block's buffer slot, the next block's copies run while this one
+  computes, and ONLY LIVE PAGES MOVE: no copy starts for a page at or
+  past ``num_live``, and a window's walk starts at page ``first``, not
+  at a block's edge below it.  On the small tile's rung a partial last
+  block is folded at the narrowest of the widths 1, 2, 4 .. ``N`` pages
+  that holds its live pages, so a decode row of one page pays for one
+  (the whole chunk's rung folds every block at ``N``: its fold is the
+  kernel's largest code, and copies of it at other widths outgrew the
+  core's instruction memory at 28 / 4 heads).  What a buffer holds past
+  ``kv_len`` (rows no copy fetched, the tail of a last page) is selected
+  out of the value product: the mask makes its probability 0, and 0 x
+  NaN is NaN.  ``N`` follows from static shapes, a rung at a time
+  (:func:`kv_block_pages`; :func:`kv_blocks_walked` is what the
+  scheduler's ``kv_blocks_walked`` counts): the largest of 1, 2, 4, 8 at
+  which neither the block's page buffers nor one of its score-shaped
+  float32 blocks passes ``VMEM_BLOCK_BUDGET``, 2 MiB.  Both are what
+  grows with ``N`` in the 16 MiB of VMEM a kernel gets: the buffers hold
+  K and V in two slots, ``4 x N x page_size x KH x D x itemsize``, and a
+  flash update holds four or so ``[tile x QH, N x page_size]`` float32
+  blocks at once (scores, the mask's positions, probabilities), so 2 MiB
+  of one is ~8 MiB of them beside the flash state and the q / out
+  blocks.  The 7B whole-chunk rung (64 x 28 = 1,792 flash rows): a score
+  block is 1,792 x 256 x 4 B = 1.84 MB at four pages and 3.67 MB at
+  eight, so it takes 4; its small tile (8 x 28 = 224 rows) 8.  An Ouro
+  page (16 KV heads) is 64 x 16 x 128 x 2 B = 256 KiB of K alone, so
+  K and V in two slots are 2 MiB at two pages: both its rungs take 2.
+  The 1.5B (12 / 2 heads) takes 8 on both rungs.  A longer block also
+  delays a row's first update behind more copies (nothing of the row
+  before overlaps them), which is the other reason the buffers are
+  capped;
 - *the idle rule*: a row with ``q_count == 0`` (an empty slot, or a live
   row the step's token budget left out) runs nothing — no state, no page,
   no finalise — and moves no block: its grid step's q / out index is the
@@ -107,12 +145,57 @@ KERNEL_NAME = "ragged_attention_kernel"
 SMALL_TILE = 8
 
 
+#: the KV blocks a rung may walk its pages in, in pages
+KV_BLOCK_PAGES = (1, 2, 4, 8)
+#: what a KV block may take of VMEM, twice over: its page buffers (K and
+#: V, two slots each) and ONE of the score-shaped float32 blocks a flash
+#: update holds (scores, the mask's positions, probabilities: four or so
+#: at once).  The module doc has the arithmetic
+VMEM_BLOCK_BUDGET = 2 * 2**20
+
+
+def query_tiles(chunk: int) -> tuple[int, ...]:
+    """The kernel's rungs at a compiled chunk: the query tiles, ascending."""
+    return tuple(sorted({min(SMALL_TILE, chunk), chunk}))
+
+
 def query_tile_rows(q_count: np.ndarray, chunk: int) -> np.ndarray:
     """The query tile the kernel works for each slot (0 for a slot it
     skips), by the rule ``_ragged_attn_kernel`` branches on.  On the
     host: the scheduler counts its ``q_tile_rows`` with it."""
     small = min(SMALL_TILE, chunk)
     return np.where(q_count > small, chunk, np.where(q_count > 0, small, 0))
+
+
+def kv_block_pages(
+    tile: int, *, q_per_kv: int, kv_heads: int, head_dim: int,
+    page_size: int, itemsize: int,
+) -> int:
+    """Pages one flash update folds in at the rung of query tile ``tile``:
+    the largest of ``KV_BLOCK_PAGES`` at which neither the page buffers
+    (K and V, two slots) nor one ``[flash rows, keys]`` float32 block
+    passes ``VMEM_BLOCK_BUDGET``.  Static shapes in, so the kernel's
+    trace and the scheduler's ``kv_blocks_walked`` agree by construction."""
+    buffers = 4 * page_size * kv_heads * head_dim * itemsize
+    scores = tile * q_per_kv * kv_heads * page_size * 4
+    return max(
+        [n for n in KV_BLOCK_PAGES if n * max(buffers, scores) <= VMEM_BLOCK_BUDGET],
+        default=KV_BLOCK_PAGES[0],
+    )
+
+
+def kv_blocks_walked(
+    pages: np.ndarray, tile_rows: np.ndarray, block_of: dict[int, int]
+) -> int:
+    """Flash updates ONE layer's call makes: the sum over slots of
+    ``ceil(pages / N)``, with ``pages`` the slot's ``num_live - first``,
+    ``tile_rows`` its :func:`query_tile_rows` and ``N = block_of[tile]``
+    the :func:`kv_block_pages` of that rung.  On the host, for the
+    scheduler."""
+    block = np.ones_like(pages)
+    for tile, n in block_of.items():
+        block[tile_rows == tile] = n
+    return int((-(-pages // block))[tile_rows > 0].sum())
 
 
 class UnsupportedHeadDim(ValueError):
@@ -196,11 +279,12 @@ def _ragged_attn_kernel(
     v_hbm,
     out_ref,  # [1, C, QH, D] in q's dtype
     # scratch
-    k_buf,  # [2, page_size, KH, D] VMEM double buffer
+    k_buf,  # [2, max(blocks) * page_size, KH, D] VMEM double buffer
     v_buf,
-    sem,  # DMA semaphores [2, 2]: page slot x (k, v)
+    sem,  # DMA semaphores [2, 2]: block slot x (k, v)
     *,
     tiles: tuple[int, ...],
+    blocks: tuple[int, ...],
     kv_heads: int,
     q_per_kv: int,
     page_size: int,
@@ -212,8 +296,12 @@ def _ragged_attn_kernel(
     tokens, ascending) that holds its ``q_count`` — sets the rows of
     everything computed:
     flash state, scores, mask, ``exp`` and both products.  Its live KV
-    pages stream through a manual double-buffered DMA walk (the
-    ``ops/paged_attention.py`` v2 design).  Flash-state rows are laid
+    pages are walked in blocks of ``blocks[rung]`` pages from page
+    ``first``: a turn of the loop starts the next block's page copies
+    into the other buffer slot (one copy a live page, none for a page at
+    or past ``num_live``), waits for exactly the copies the turn before
+    started for it, and folds the block's ``pages x page_size`` keys into
+    the flash state in one update.  Flash-state rows are laid
     out head-major — row ``h*T*G + i*G + j`` is query token ``i`` of q
     head ``h*G + j`` at tile ``T`` — so the per-kv-head GQA dots write
     contiguous slabs; the finalize transposes back to [T, QH, D] and
@@ -234,35 +322,54 @@ def _ragged_attn_kernel(
     head_dim = q_ref.shape[-1]
     layer = layer_ref[0]
 
-    def dma(slot, j):
-        return (
-            pltpu.make_async_copy(
-                k_hbm.at[layer, pt_ref[b, j]], k_buf.at[slot], sem.at[slot, 0]
-            ),
-            pltpu.make_async_copy(
-                v_hbm.at[layer, pt_ref[b, j]], v_buf.at[slot], sem.at[slot, 1]
-            ),
-        )
-
-    def rung(tile: int):
-        """The whole of a live row's work at a static query tile."""
+    def rung(tile: int, pages: int):
+        """The whole of a live row's work at a static query tile, its
+        pages walked ``pages`` to a block."""
         slab = tile * q_per_kv  # flash rows per kv head (token-major within)
         total = kv_heads * slab
+        walked = num_live - first  # >= 1 for a live row
+        n_blocks = pl.cdiv(walked, pages)
+        rest = walked % pages  # live pages of a partial last block
+        # the widths a partial last block may be folded at, beside the
+        # whole block's: the small tile's rung alone has them (its fold is
+        # little code; a whole chunk's is the kernel's largest, and a
+        # kernel that outgrows the core's instruction memory reloads its
+        # code row by row: 13 us a chunk row at 28 / 4 heads)
+        narrow = ()
+        # graftlint: disable=GL002 reason=tile is a static int, bound with functools.partial where the rungs are laid out
+        if tile <= SMALL_TILE:
+            narrow = tuple(fit for fit in KV_BLOCK_PAGES if fit < pages)
+        # turns of the loop, each a whole block's width: every full block,
+        # and a last one too full for the narrow widths
+        n_turns = n_blocks
+        if narrow:
+            n_turns -= ((rest > 0) & (rest <= narrow[-1])).astype(jnp.int32)
+
+        def each_live_page(t, act, upto=pages):
+            """``act`` on both copies of every live page of block ``t``:
+            page ``i`` of the block lands at rows ``i * page_size`` of
+            slot ``t % 2``.  The pages at or past ``num_live`` of a last
+            block are not touched: no copy starts, none is waited for."""
+            slot = t % 2
+            for i in range(upto):
+                j = first + t * pages + i
+                rows = pl.ds(i * page_size, page_size)
+
+                @pl.when(j < num_live)
+                def _():
+                    page = pt_ref[b, j]
+                    act(pltpu.make_async_copy(
+                        k_hbm.at[layer, page], k_buf.at[slot, rows],
+                        sem.at[slot, 0],
+                    ))
+                    act(pltpu.make_async_copy(
+                        v_hbm.at[layer, page], v_buf.at[slot, rows],
+                        sem.at[slot, 1],
+                    ))
 
         def walk(m_scratch, l_scratch, acc_scratch):
-            @pl.when(num_live > first)
-            def _prologue():
-                for copy in dma(first % 2, first):
-                    copy.start()
-
+            each_live_page(0, lambda copy: copy.start())
             init_state(m_scratch, l_scratch, acc_scratch)
-            # flash rows: kv-head slabs stacked, token-major inside each —
-            # row h*slab + i*G + j is query token i of q head h*G + j.  Its
-            # q position depends only on the token index within the slab.
-            row_iota = jax.lax.broadcasted_iota(
-                jnp.int32, (total, page_size), 0
-            )
-            q_pos = q_base + (row_iota % slab) // q_per_kv
             q = q_ref[0, :tile].astype(jnp.float32)  # [tile, QH, D]
             # the score product's operands keep the pool's dtype (bf16
             # values multiply exactly into the f32 accumulator); the
@@ -273,24 +380,34 @@ def _ragged_attn_kernel(
                 for h in range(kv_heads)
             ]
 
-            def body(j, _):
-                slot = j % 2
-
-                @pl.when(j + 1 < num_live)
-                def _prefetch_next():
-                    for copy in dma((j + 1) % 2, j + 1):
-                        copy.start()
-
-                for copy in dma(slot, j):
-                    copy.wait()
-
-                k = k_buf[slot]  # [page, KH, D]
-                v = v_buf[slot]
-                kv_pos = j * page_size + jax.lax.broadcasted_iota(
-                    jnp.int32, (total, page_size), 1
+            def positions(width):
+                """What a fold of ``width`` keys masks with and nothing of
+                it follows the block: each flash row's query position, a
+                key's and a value row's place in the block."""
+                # flash rows: kv-head slabs stacked, token-major inside
+                # each — row h*slab + i*G + j is query token i of q head
+                # h*G + j.  Its q position depends only on the token
+                # index within the slab.
+                row_iota = jax.lax.broadcasted_iota(jnp.int32, (total, width), 0)
+                return (
+                    q_base + (row_iota % slab) // q_per_kv,
+                    jax.lax.broadcasted_iota(jnp.int32, (total, width), 1),
+                    jax.lax.broadcasted_iota(jnp.int32, (width, head_dim), 0),
                 )
 
-                # scores for every slab against this page, [total, page]
+            def fold(t, width, q_pos, key_iota, value_iota):
+                """Block ``t``'s first ``width`` keys into the flash state
+                in one update, once its copies have landed."""
+                slot = t % 2
+                each_live_page(
+                    t, lambda copy: copy.wait(), upto=width // page_size
+                )
+                k = k_buf[slot, :width]  # [width, KH, D]
+                v = v_buf[slot, :width]
+                block_pos = (first + t * pages) * page_size
+                kv_pos = block_pos + key_iota
+
+                # scores for every slab against this block, [total, width]
                 s = jnp.concatenate(
                     [
                         jax.lax.dot_general(
@@ -305,13 +422,21 @@ def _ragged_attn_kernel(
                 if window is not None:
                     mask = mask & (kv_pos > q_pos - window)
                 s = jnp.where(mask, s, _NEG_INF)
+                # the mask zeroes the probability of a key at or past
+                # ``kv_len``, and 0 x NaN is NaN: what a buffer holds
+                # there (the rows of a page no copy fetched, the tail of
+                # a last page) must not reach the value product
+                value_live = value_iota < seq_len - block_pos
 
                 def values(p):
                     return jnp.concatenate(
                         [
                             jax.lax.dot_general(
                                 p[h * slab : (h + 1) * slab],
-                                v[:, h, :].astype(jnp.float32),
+                                jnp.where(
+                                    value_live,
+                                    v[:, h, :].astype(jnp.float32), 0.0,
+                                ),
                                 (((1,), (0,)), ((), ())),
                                 preferred_element_type=jnp.float32,
                             )
@@ -321,9 +446,31 @@ def _ragged_attn_kernel(
                     )
 
                 update_state(m_scratch, l_scratch, acc_scratch, s, values)
-                return 0
 
-            jax.lax.fori_loop(first, num_live, body, 0)
+            @pl.when(n_turns > 0)
+            def _whole_blocks():
+                at_width = positions(pages * page_size)
+
+                def body(t, _):
+                    @pl.when(t + 1 < n_blocks)
+                    def _prefetch_next():
+                        each_live_page(t + 1, lambda copy: copy.start())
+
+                    fold(t, pages * page_size, *at_width)
+                    return 0
+
+                jax.lax.fori_loop(0, n_turns, body, 0)
+
+            # a last block of few pages is folded at the narrowest width
+            # that holds them: a row of one page pays for one
+            below = 0
+            for fit in narrow:
+                @pl.when((rest > below) & (rest <= fit))
+                def _last_block(fit=fit):
+                    fold(n_turns, fit * page_size, *positions(fit * page_size))
+
+                below = fit
+
             out = finalize(l_scratch, acc_scratch)  # [KH*tile*G, D]
             # slab h holds [tile, G, D]: the head band of [tile, QH, D]
             for h in range(kv_heads):
@@ -341,13 +488,17 @@ def _ragged_attn_kernel(
 
     # the smallest tile that holds the row's queries; none for count == 0
     below = 0
-    # graftlint: disable=GL002 reason=tiles is a static tuple of ints, bound with functools.partial before the pallas_call
-    for tile in tiles:
-        pl.when((count > below) & (count <= tile))(functools.partial(rung, tile))
+    # graftlint: disable=GL002 reason=tiles and blocks are static tuples of ints, bound with functools.partial before the pallas_call
+    for tile, pages in zip(tiles, blocks):
+        pl.when((count > below) & (count <= tile))(
+            functools.partial(rung, tile, pages)
+        )
         below = tile
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "sliding_window"))
+@functools.partial(
+    jax.jit, static_argnames=("interpret", "sliding_window", "block_pages")
+)
 def _ragged_attention_pallas(
     q: jax.Array,
     k_pages: jax.Array,
@@ -359,16 +510,30 @@ def _ragged_attention_pallas(
     *,
     interpret: bool = False,
     sliding_window: Optional[int] = None,
+    block_pages: Optional[tuple[int, ...]] = None,
 ) -> jax.Array:
+    """``block_pages`` names the KV block of every rung, smallest tile
+    first (a test's and a probe's argument: the serving path leaves it
+    to :func:`kv_block_pages`)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, c, qh, d = q.shape
     _, _, page_size, kh, _ = k_pages.shape
 
+    tiles = query_tiles(c)
+    blocks = block_pages or tuple(
+        kv_block_pages(
+            tile, q_per_kv=qh // kh, kv_heads=kh, head_dim=d,
+            page_size=page_size, itemsize=k_pages.dtype.itemsize,
+        )
+        for tile in tiles
+    )
+    assert len(blocks) == len(tiles), (blocks, tiles)
     kernel = functools.partial(
         _ragged_attn_kernel,
-        tiles=tuple(sorted({min(SMALL_TILE, c), c})),
+        tiles=tiles,
+        blocks=blocks,
         kv_heads=kh,
         q_per_kv=qh // kh,
         page_size=page_size,
@@ -388,14 +553,15 @@ def _ragged_attention_pallas(
         return (blk[i], 0, 0, 0)
 
     any_space = pl.BlockSpec(memory_space=pl.ANY)
+    buffer_rows = max(blocks) * page_size
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
         grid=(b,),
         in_specs=[pl.BlockSpec((1, c, qh, d), row_block), any_space, any_space],
         out_specs=pl.BlockSpec((1, c, qh, d), row_block),
         scratch_shapes=[
-            pltpu.VMEM((2, page_size, kh, d), k_pages.dtype),
-            pltpu.VMEM((2, page_size, kh, d), v_pages.dtype),
+            pltpu.VMEM((2, buffer_rows, kh, d), k_pages.dtype),
+            pltpu.VMEM((2, buffer_rows, kh, d), v_pages.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
         ],
     )

@@ -166,7 +166,8 @@ class StepClock:
         clock: the wave engine's admission prefill) the record stands
         alone and its wall is the two waits.  ``counts`` are the record's
         optional work counts (``accepted``, ``cached_tokens``,
-        ``prefill_tokens``, ``kv_pages_walked``, ``q_tile_rows``,
+        ``prefill_tokens``, ``kv_pages_walked``, ``kv_blocks_walked``,
+        ``q_tile_rows``,
         ``state_rows``, ``sampled_rows``, ``passes``).  MFU stays
         computed on billed ``tokens`` — the compute really ran — over the
         interval's wall."""

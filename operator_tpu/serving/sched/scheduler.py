@@ -89,6 +89,12 @@ from typing import Any, Optional
 
 import numpy as np
 
+from ...ops.ragged_attention import (
+    kv_block_pages,
+    kv_blocks_walked,
+    query_tile_rows,
+    query_tiles,
+)
 from ..runtime import Runtime
 from ..types import (
     DeadlineExceeded,
@@ -111,15 +117,15 @@ __all__ = ["Scheduler"]
 def _kv_walk(
     kv_len: np.ndarray, q_count: np.ndarray, page_size: int,
     window: Optional[int] = None,
-) -> tuple[int, int]:
+) -> tuple[np.ndarray, int]:
     """What ONE layer's ragged-attention call does with these per-slot
     arrays, by the kernel's own rule (``ops/ragged_attention.py``
     ``_ragged_attn_kernel``): a slot with ``q_count > 0`` walks pages
     ``first .. cdiv(kv_len, page_size)``, the others none, and a sliding
     window skips the pages wholly before the earliest position any of
-    the row's queries can see.  Returns (KV pages walked, query-key
-    pairs scored = ``q_count`` x the positions on the walked pages up to
-    ``kv_len``)."""
+    the row's queries can see.  Returns (KV pages each slot's walk
+    takes, query-key pairs scored = ``q_count`` x the positions on the
+    walked pages up to ``kv_len``)."""
     kv = kv_len.astype(np.int64)
     count = q_count.astype(np.int64)
     first = np.zeros_like(kv)
@@ -127,7 +133,7 @@ def _kv_walk(
         first = np.maximum(kv - count - window + 1, 0) // page_size
     pages = np.where(count > 0, np.maximum(-(-kv // page_size) - first, 0), 0)
     seen = np.maximum(kv - first * page_size, 0)
-    return int(pages.sum()), int((count * seen).sum())
+    return pages, int((count * seen).sum())
 
 
 @dataclasses.dataclass
@@ -238,6 +244,17 @@ class Scheduler:
                     feature, model.name, model.family, why,
                 )
         self.chunk = max(1, min(chunk, generator.max_seq))
+        #: pages a flash update of the ragged kernel folds in, by query
+        #: tile: the kernel's own rule on the static shapes it is given
+        self._kv_block_of = {
+            tile: kv_block_pages(
+                tile, q_per_kv=model.num_heads // model.num_kv_heads,
+                kv_heads=model.num_kv_heads, head_dim=model.head_dim,
+                page_size=generator.page_size,
+                itemsize=np.dtype(generator.cache_dtype).itemsize,
+            )
+            for tile in query_tiles(self.chunk)
+        }
         self.t_budget = token_budget or max(self.chunk, generator.max_slots)
         if self.t_budget < generator.max_slots:
             # a full decode batch must always fit one step, or decode
@@ -627,6 +644,7 @@ class Scheduler:
                 [row.params for row in self._rows.values()],
                 step=seq,
                 kv_pages=packed.counts["kv_pages_walked"],
+                kv_blocks=packed.counts["kv_blocks_walked"],
                 qk_pairs=packed.qk_pairs,
                 tokens=plan.tokens_planned,
                 q_tile_rows=packed.counts["q_tile_rows"],
@@ -1257,11 +1275,10 @@ class Scheduler:
             kv_len[work.slot] = work.pos0 + work.count
             temp[work.slot] = row.params.temperature
             top_p[work.slot] = row.params.top_p
-        from ...ops.ragged_attention import query_tile_rows
-
         pages, pairs = _kv_walk(
             kv_len, q_count, g.page_size, g.config.sliding_window
         )
+        tile_rows = query_tile_rows(q_count, self.chunk)
         wide = bool(spec_len.any())
         return _Packed(
             ids=ids, rows=rows, pos=pos, valid=valid, in_row=in_row,
@@ -1269,11 +1286,17 @@ class Scheduler:
             sample_start=sample_start, spec_len=spec_len, temp=temp,
             top_p=top_p, kv_len=kv_len, wide=wide,
             counts={
-                "prefill_tokens": prefill_tokens, "kv_pages_walked": pages,
+                "prefill_tokens": prefill_tokens,
+                "kv_pages_walked": int(pages.sum()),
+                # flash updates one layer's kernel call makes: each slot's
+                # pages in blocks of what its rung walks at a time
+                "kv_blocks_walked": kv_blocks_walked(
+                    pages, tile_rows, self._kv_block_of
+                ),
                 # the query-tile rows one layer's kernel call works, by
                 # the kernel's own rule: nothing for a slot without
                 # queries, the small tile or the whole chunk for the rest
-                "q_tile_rows": int(query_tile_rows(q_count, self.chunk).sum()),
+                "q_tile_rows": int(tile_rows.sum()),
                 # slots whose recurrent state a layer's scan call reads
                 # and rewrites (the others it skips); no such state, no count
                 "state_rows": (

@@ -12,7 +12,10 @@ CPU host finds them before chip time is spent.  Covered:
   attention on a TPU — at the ``(QH, KH, D)`` of every registered model
   config, bf16, page 64, chunk widths 5 (verify) and 64 (prefill), plus a
   sliding-window case, and alone at the benchmark's cells (12/2 heads
-  x 128 slots, 28/4 x 32, 16/16 x 10): both rungs of its query tile.  A config the kernel cannot serve must be REFUSED
+  x 128 slots, 28/4 x 32, 20/4 x 128, 16/16 x 10): both rungs of its
+  query tile, each with the KV block ``kv_block_pages`` gives it (the
+  record's ``kv_block_pages``: pages a flash update folds in, small tile
+  then chunk).  A config the kernel cannot serve must be REFUSED
   by ``require_ragged_kernel_support`` (a named error at engine build),
   never silently routed elsewhere — the check asserts which of the two
   happens for each config;
@@ -269,6 +272,8 @@ def main() -> int:
     from operator_tpu.ops.ragged_attention import (
         UnsupportedHeadDim,
         _ragged_attention_pallas,
+        kv_block_pages,
+        query_tiles,
         require_ragged_kernel_support,
     )
     from operator_tpu.ops.similarity import _best_window_pallas
@@ -332,6 +337,24 @@ def main() -> int:
     ))
 
     results, failed = {}, 0
+    kv_blocks = {}  # ragged cases: the KV block each rung of the tile walks
+
+    def ragged_case(name, fn, args):
+        """A case of the ragged kernel alone, and the pages a flash update
+        folds in at each of its rungs (small tile first), by the module's
+        own rule from the very shapes compiled."""
+        q, k_pool = args[0], args[1]
+        _, chunk, heads, head_dim = q.shape
+        _, _, page_size, kv_heads, _ = k_pool.shape
+        kv_blocks[name] = [
+            kv_block_pages(
+                tile, q_per_kv=heads // kv_heads, kv_heads=kv_heads,
+                head_dim=head_dim, page_size=page_size,
+                itemsize=k_pool.dtype.itemsize,
+            )
+            for tile in query_tiles(chunk)
+        ]
+        cases.append((name, fn, args))
 
     # ragged kernel x every registered config: compile, or a NAMED refusal
     for name, config in sorted(_REGISTRY.items()):
@@ -346,29 +369,29 @@ def main() -> int:
             fn = _ragged_attention_pallas
             if config.sliding_window is not None:
                 fn = functools.partial(fn, sliding_window=config.sliding_window)
-            cases.append(
-                (f"ragged_{name}_c{chunk}", fn, ragged_args(*geometry, chunk))
-            )
-    # the kernel alone at the benchmark's two cells (BENCHMARK.json: heads
+            ragged_case(f"ragged_{name}_c{chunk}", fn, ragged_args(*geometry, chunk))
+    # the kernel alone at the benchmark's cells (BENCHMARK.json: heads
     # x slots, bf16 pool, page 64, chunk 64): both rungs of the query tile
-    # at 6 and at 7 queries a kv head, which the 4-row cases above lower
-    # too, at the slot counts the cells run
+    # at 6, 7 and 5 queries a kv head, which the 4-row cases above lower
+    # too, at the slot counts the cells run, each rung with the KV block
+    # its VMEM budget gives it
     for tag, heads, kv_heads, slots in (
         ("qwen2.5-1.5b", 12, 2, 128), ("qwen2.5-7b", 28, 4, 32),
+        ("falcon-h1-34b", 20, 4, 128),
         # one query head a kv head: sixteen [tile, 1, D] slabs
         ("ouro-2.6b", 16, 16, 10),
     ):
-        cases.append((
+        ragged_case(
             f"ragged_cell_{tag}_b{slots}", _ragged_attention_pallas,
             ragged_args(heads, kv_heads, 128, _CHUNK, rows=slots),
-        ))
+        )
     # a window that actually bites inside max_seq (Mistral's 4096 is wider
     # than the serving cap, so its first-page term folds to zero above)
-    cases.append((
+    ragged_case(
         "ragged_window",
         functools.partial(_ragged_attention_pallas, sliding_window=256),
         ragged_args(32, 8, 128, _CHUNK),
-    ))
+    )
 
     # the state-space scan kernel alone at the benchmark's cell: 32 heads x
     # 128 x 256 float32 a slot and layer, 128 slots, 256 flat tokens
@@ -437,6 +460,8 @@ def main() -> int:
                         compiled.as_text(),
                     ))),
                 }
+                if name in kv_blocks:
+                    results[name]["kv_block_pages"] = kv_blocks[name]
                 if name in pools:
                     shape = pools[name]
                     # the tail's one conditional (sched/mixed.py: the head

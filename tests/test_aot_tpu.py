@@ -84,6 +84,7 @@ def test_all_kernels_aot_compile_for_v5e(record):
     # tile that cannot lower at 7B's 7 queries a kv head is found here
     assert "ragged_cell_qwen2.5-1.5b_b128" in kernels, record
     assert "ragged_cell_qwen2.5-7b_b32" in kernels, record
+    assert "ragged_cell_falcon-h1-34b_b128" in kernels, record
     # one query head a kv head: sixteen [tile, 1, D] slabs
     assert "ragged_cell_ouro-2.6b_b10" in kernels, record
     # the whole mixed step at the server's default shape, for the default
@@ -92,6 +93,39 @@ def test_all_kernels_aot_compile_for_v5e(record):
     assert kernels["mixed_step_default_model"]["argument_bytes"] > 1e9, record
     # SERVING_MESH=dp=1,tp=4: the paged kernel inside a shard_map
     assert "mesh_tp4_paged_decode" in kernels, record
+
+
+def test_every_ragged_case_names_the_kv_block_of_each_rung(record):
+    """The kernel compiled for v5e with the KV blocks its VMEM rule gave
+    it, and the record says which: a block too large for the chip's VMEM
+    at any served geometry fails the compile above; a rule that stopped
+    folding pages at the cells' decode rows shows here."""
+    from operator_tpu.ops.ragged_attention import KV_BLOCK_PAGES
+
+    kernels = record["kernels"]
+    ragged = {
+        name: k for name, k in kernels.items()
+        if name.startswith("ragged_") and "refused" not in k
+    }
+    assert len(ragged) >= 8, sorted(ragged)
+    for name, k in ragged.items():
+        assert k["ok"], (name, k)
+        blocks = k["kv_block_pages"]
+        # one rung at a verify width under the small tile, two at a chunk
+        assert len(blocks) == (1 if name.endswith("_c5") else 2), (name, blocks)
+        assert set(blocks) <= set(KV_BLOCK_PAGES), (name, blocks)
+        # the small tile never walks narrower than the chunk
+        assert blocks == sorted(blocks, reverse=True), (name, blocks)
+    cells = {
+        name: k["kv_block_pages"] for name, k in ragged.items()
+        if name.startswith("ragged_cell_")
+    }
+    assert cells == {
+        "ragged_cell_qwen2.5-1.5b_b128": [8, 8],
+        "ragged_cell_qwen2.5-7b_b32": [8, 4],
+        "ragged_cell_falcon-h1-34b_b128": [8, 4],
+        "ragged_cell_ouro-2.6b_b10": [2, 2],
+    }, cells
 
 
 def test_the_recurrent_models_step_compiles_with_its_state_pool_held_once(record):

@@ -12,6 +12,7 @@ determinism for a fixed arrival trace.
 
 import asyncio
 import dataclasses
+import functools
 
 import numpy as np
 import pytest
@@ -120,6 +121,21 @@ class TestRaggedKernel:
         kv_len=[12, 33, 0, 21, 8, 40, 30, 48],
     )
 
+    #: pages of 16 keys, 8 a row, so that a row's walk ends at a block's
+    #: edges on BOTH rungs with idle slots between (slots 1, 4, 8, 11):
+    #: at 4 pages a block, rows of 1 page (slot 0: one key in it), of
+    #: exactly N (3, 5), of N + 1 with one key on the last page (6, 7) and
+    #: of 2N - 1 (9, 10); slots 0, 3, 6, 9 take the small tile
+    _BLOCK_ROWS = dict(
+        b=12, c=16, ps=16, pps=8, qh=4, kh=2,
+        q_count=[1, 0, 16, 5, 0, 16, 8, 16, 0, 1, 9, 0],
+        kv_len=[1, 40, 16, 64, 0, 64, 65, 65, 30, 100, 112, 7],
+    )
+    #: the same at 2 pages a block: 1, N (32 keys), N + 1 = 2N - 1 (33, 48)
+    _BLOCK_ROWS_2 = dict(
+        _BLOCK_ROWS, kv_len=[1, 40, 16, 32, 0, 32, 33, 33, 30, 48, 48, 7],
+    )
+
     @pytest.mark.parametrize("case", [
         dict(id="edges-4x2", qh=4, kh=2, **_EDGES),
         # group sizes that are no power of two: 6 and 7 queries a kv head
@@ -137,6 +153,36 @@ class TestRaggedKernel:
         dict(id="layer-first", qh=4, kh=2, layer=0, **_EDGES),
         dict(id="layer-middle", qh=4, kh=2, layer=1, **_EDGES),
         dict(id="layer-last", qh=4, kh=2, layer=2, **_EDGES),
+        # the KV block's edges, on both rungs (blocks: pages a flash
+        # update folds in, small tile then chunk)
+        dict(id="block-edges-4x4", blocks=(4, 4), **_BLOCK_ROWS),
+        dict(id="block-edges-2x2", blocks=(2, 2), **_BLOCK_ROWS_2),
+        # each rung its own block; 7 pages are one partial block of 8
+        dict(id="block-edges-8x2", blocks=(8, 2), **_BLOCK_ROWS),
+        # a partial last block is folded at the narrowest width that
+        # holds it: rows of 2, 3, 6 and 8 pages at 8 a block fold 2, 4, 8
+        # and a whole block, on both rungs
+        dict(id="block-last-widths", blocks=(8, 8), b=8, c=16, ps=16, pps=8,
+             qh=4, kh=2, q_count=[1, 16, 1, 16, 5, 16, 8, 9],
+             kv_len=[32, 30, 40, 48, 90, 96, 128, 128]),
+        dict(id="block-bf16-pool", blocks=(4, 2), dtype=jnp.bfloat16,
+             **_BLOCK_ROWS),
+        # windows whose first page (5, 5 and 3) is no multiple of the
+        # block: the walk starts there, not at the block's edge below
+        dict(id="block-window-off-edge", blocks=(2, 2), window=20,
+             b=4, c=16, ps=16, pps=8, qh=4, kh=2,
+             q_count=[1, 16, 0, 5], kv_len=[100, 120, 50, 77]),
+        dict(id="block-window-off-edge-4", blocks=(4, 4), window=20,
+             b=4, c=16, ps=16, pps=8, qh=4, kh=2,
+             q_count=[1, 16, 0, 5], kv_len=[100, 120, 50, 77]),
+        # NaN in every page no row's table names and in the keys past
+        # kv_len of every last page: a probability of 0 times what an
+        # unfetched or dead buffer row holds must not reach the output
+        dict(id="block-nan-poison", blocks=(4, 4), poison=True, **_BLOCK_ROWS),
+        dict(id="block-nan-poison-1", blocks=(1, 1), poison=True, **_BLOCK_ROWS),
+        # one page a turn and four serve the same rows alike
+        dict(id="block-1-and-4-agree", blocks=(4, 4), agree_with=(1, 1),
+             **_BLOCK_ROWS),
     ], ids=lambda case: case["id"])
     def test_parity_by_rung(self, case):
         """The kernel against the reference where what it works follows
@@ -146,23 +192,37 @@ class TestRaggedKernel:
 
         dtype = case.get("dtype", jnp.float32)
         rng = np.random.default_rng(len(case["id"]))
+        ps, pps = case.get("ps", 8), case.get("pps", 6)
         q, k, v, table = self._setup(
-            rng, b=case["b"], c=case["c"], qh=case["qh"], kh=case["kh"]
+            rng, b=case["b"], c=case["c"], qh=case["qh"], kh=case["kh"],
+            ps=ps, pps=pps,
         )
         q, k, v = (x.astype(dtype) for x in (q, k, v))
         kv_len = jnp.asarray(case["kv_len"], jnp.int32)
         q_count = jnp.asarray(case["q_count"], jnp.int32)
+        assert max(case["kv_len"]) <= ps * pps
         tiles = set(query_tile_rows(np.asarray(q_count), case["c"]).tolist())
         assert tiles <= {0, SMALL_TILE, case["c"]}
         if case["id"].startswith("edges"):
             assert tiles == {0, SMALL_TILE, case["c"]}
         window = case.get("window")
         layer = case.get("layer", self.LAYERS - 1)
-        got = _ragged_attention_pallas(
-            q, k, v, table, kv_len, q_count, jnp.int32(layer), interpret=True,
-            sliding_window=window,
+        given = (k, v)
+        if case.get("poison"):
+            given = self._poisoned(k, v, table, case["kv_len"], ps)
+        run = functools.partial(
+            _ragged_attention_pallas, q, *given, table, kv_len, q_count,
+            jnp.int32(layer), interpret=True, sliding_window=window,
         )
+        got = run(block_pages=case.get("blocks"))
         assert got.shape == q.shape and got.dtype == dtype
+        if "agree_with" in case:
+            other = run(block_pages=case["agree_with"])
+            for row, n in enumerate(case["q_count"]):
+                np.testing.assert_allclose(
+                    np.asarray(got[row, :n]), np.asarray(other[row, :n]),
+                    rtol=2e-6, atol=2e-6,
+                )
         # the oracle is handed the one layer alone, as a pool of one: it
         # cannot read another layer's pages whatever it does with ``layer``
         want = ragged_attention_reference(
@@ -193,6 +253,21 @@ class TestRaggedKernel:
                     assert np.abs(
                         np.asarray(others[other][row, :n] - want[row, :n])
                     ).max() > 1e-2, (row, other)
+
+    @staticmethod
+    def _poisoned(k, v, table, kv_len, ps):
+        """The pools with NaN wherever no row may look: every page no
+        row's table names up to its ``kv_len``, and the keys past
+        ``kv_len`` of each row's last page."""
+        dead = np.ones(k.shape[1:3], bool)  # [page, key]
+        for row, kv in enumerate(kv_len):
+            live = -(-kv // ps)
+            dead[np.asarray(table)[row, :live]] = False
+            if kv % ps:
+                dead[int(table[row, live - 1]), kv % ps:] = True
+        bad = jnp.asarray(dead)[None, :, :, None, None]
+        assert bool(bad.any())
+        return tuple(jnp.where(bad, jnp.nan, x) for x in (k, v))
 
     def test_both_rungs_serve_the_same_queries_alike(self):
         """One row's last five queries, once as the tail of a 9-query row

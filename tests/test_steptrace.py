@@ -161,8 +161,8 @@ class TestStepRing:
             seq=9, kind="mixed", tokens=70, slots=3, occupancy=0.75,
             wall_ms=12.5, host_ms=2.0, wait_ms=10.0, xfer_ms=0.5,
             plan_ms=0.5, pack_ms=0.75, commit_ms=0.25, turn_ms=0.125,
-            prefill_tokens=64, kv_pages_walked=11, q_tile_rows=80,
-            accepted=3, cached_tokens=16, sampled_rows=20,
+            prefill_tokens=64, kv_pages_walked=11, kv_blocks_walked=4,
+            q_tile_rows=80, accepted=3, cached_tokens=16, sampled_rows=20,
         )
         raw = record.to_dict()
         assert json.loads(json.dumps(raw)) == raw  # plain JSON
@@ -174,6 +174,8 @@ class TestStepRing:
         assert StepRecord.from_dict(bare).kv_pages_walked is None
         assert "q_tile_rows" not in bare
         assert StepRecord.from_dict(bare).q_tile_rows is None
+        assert raw["kv_blocks_walked"] == 4 and "kv_blocks_walked" not in bare
+        assert StepRecord.from_dict(bare).kv_blocks_walked is None
         assert raw["sampled_rows"] == 20 and "sampled_rows" not in bare
         assert StepRecord.from_dict(bare).sampled_rows is None
 
@@ -416,23 +418,26 @@ class TestStepView:
             _decode_record(),
             StepRecord(seq=1, kind="prefill", tokens=16, slots=1,
                        occupancy=0.25, wall_ms=9.0, host_ms=0.0, wait_ms=9.0,
-                       xfer_ms=0.0, kv_pages_walked=7, prefill_tokens=16,
-                       q_tile_rows=64, state_rows=1, sampled_rows=20, passes=4),
+                       xfer_ms=0.0, kv_pages_walked=7, kv_blocks_walked=2,
+                       prefill_tokens=16, q_tile_rows=64, state_rows=1,
+                       sampled_rows=20, passes=4),
         ])
         lines = table.splitlines()
         assert lines[0].split() == [
             "seq", "kind", "tok", "pf_tok", "slots", "occ",
             "wall_ms", "host_ms", "wait_ms", "xfer_ms",
             "plan", "pack", "commit", "turn", "passes",
-            "st_rows", "smp_rows", "kv_pg", "q_fill", "mfu",
+            "st_rows", "smp_rows", "kv_pg", "pg_blk", "q_fill", "mfu",
         ]
         # passes a token took through the layer stack; "-" where not said
-        assert lines[3].split()[-6] == "4" and lines[2].split()[-6] == "-"
+        assert lines[3].split()[-7] == "4" and lines[2].split()[-7] == "-"
         # slots whose recurrent state the step touched; "-" without such state
-        assert lines[3].split()[-5] == "1" and lines[2].split()[-5] == "-"
+        assert lines[3].split()[-6] == "1" and lines[2].split()[-6] == "-"
         # logit rows the head and the sampler worked; "-" where not counted
-        assert lines[3].split()[-4] == "20" and lines[2].split()[-4] == "-"
-        assert lines[3].split()[-3] == "7" and lines[2].split()[-3] == "-"
+        assert lines[3].split()[-5] == "20" and lines[2].split()[-5] == "-"
+        assert lines[3].split()[-4] == "7" and lines[2].split()[-4] == "-"
+        # pages a flash update folded in: 7 pages in 2 blocks
+        assert lines[3].split()[-3] == "3.50" and lines[2].split()[-3] == "-"
         # q_fill = tokens / q_tile_rows: 16 of the chunk's 64 rows
         assert lines[3].split()[-2] == "0.250" and lines[2].split()[-2] == "-"
         assert lines[3].split()[3] == "16" and lines[2].split()[3] == "-"
@@ -765,6 +770,7 @@ class TestSchedulerOnAnHonestClock:
         for args in dispatched:
             record = records[args["step"]]
             assert args["kv_pages"] == record.kv_pages_walked
+            assert 0 < args["kv_blocks"] == record.kv_blocks_walked <= args["kv_pages"]
             assert args["qk_pairs"] >= args["tokens"] == record.tokens
             assert args["q_tile_rows"] == record.q_tile_rows >= record.tokens
         committed = [a["step"] for name, a in spans if name == "podmortem.sched.commit"]
@@ -805,7 +811,8 @@ class TestKvPagesWalked:
         #            decode  chunk  whole-prompt  verify  unscheduled  empty  page-edge
         kv_len = np.array([130, 48, 16, 77, 200, 0, 64], np.int32)
         q_count = np.array([1, 16, 16, 5, 0, 0, 1], np.int32)
-        got = _kv_walk(kv_len, q_count, 16, window)
+        by_slot, pairs = _kv_walk(kv_len, q_count, 16, window)
+        got = (int(by_slot.sum()), pairs)
         assert got == _walk_by_the_references_rule(kv_len, q_count, 16, window)
         if window is None:
             # 9 + 3 + 1 + 5 + 0 + 0 + 4 pages; the unscheduled row's 13 not walked
@@ -827,18 +834,33 @@ class TestKvPagesWalked:
 
         import numpy as np
 
-        from operator_tpu.ops.ragged_attention import query_tile_rows
+        from operator_tpu.ops import ragged_attention
+        from operator_tpu.ops.ragged_attention import (
+            kv_block_pages,
+            query_tile_rows,
+        )
 
-        span_pairs, span_rows = {}, {}
+        span_pairs, span_rows, span_blocks = {}, {}, {}
         real_annotation = BatchedGenerator._annotation
 
         def spy_annotation(self, name, params_list=None, **args):
             if name == "podmortem.sched.dispatch":
                 span_pairs[args["step"]] = args["qk_pairs"]
                 span_rows[args["step"]] = args["q_tile_rows"]
+                span_blocks[args["step"]] = args["kv_blocks"]
             return real_annotation(self, name, params_list, **args)
 
         monkeypatch.setattr(BatchedGenerator, "_annotation", spy_annotation)
+        # a budget at which this geometry walks two pages a block (the
+        # rule is read where the scheduler counts, step by step)
+        geometry = dict(
+            q_per_kv=TINY_TEST.num_heads // TINY_TEST.num_kv_heads,
+            kv_heads=TINY_TEST.num_kv_heads, head_dim=TINY_TEST.head_dim,
+            page_size=16, itemsize=4,
+        )
+        monkeypatch.setattr(ragged_attention, "VMEM_BLOCK_BUDGET", 16_384)
+        block_of = {tile: kv_block_pages(tile, **geometry) for tile in (8, 16)}
+        assert block_of == {8: 2, 16: 2}
 
         config = dataclasses.replace(TINY_TEST, sliding_window=window)
         generator = BatchedGenerator(
@@ -871,13 +893,23 @@ class TestKvPagesWalked:
         assert finished == 3
         records = generator.step_clock.ring.records()
         assert len(records) == len(given)
-        kinds, tiles = set(), set()
+        kinds, tiles, several = set(), set(), False
         for record, (kv_len, q_count) in zip(records, given):
             pages, pairs = _walk_by_the_references_rule(kv_len, q_count, 16, window)
             assert (record.kv_pages_walked, span_pairs[record.seq]) == (pages, pairs)
             assert record.tokens == int(q_count.sum())
             rows = query_tile_rows(q_count, 16)
             assert record.q_tile_rows == span_rows[record.seq] == int(rows.sum())
+            # flash updates: each walking slot's pages, counted by the
+            # reference's rule, in blocks of what its rung takes
+            blocks = sum(
+                -(-_walk_by_the_references_rule(
+                    kv_len[i:i + 1], q_count[i:i + 1], 16, window
+                )[0] // block_of[int(tile)])
+                for i, tile in enumerate(rows) if tile
+            )
+            assert record.kv_blocks_walked == span_blocks[record.seq] == blocks
+            several |= blocks < pages
             tiles.update(rows.tolist())
             kinds.add(record.kind)
             kinds.update(
@@ -885,6 +917,7 @@ class TestKvPagesWalked:
             )
         assert {"decode", "mixed"} <= kinds
         assert tiles == {0, 8, 16}  # idle slots, decode rows, prompt chunks
+        assert several  # some block held more than one page
         assert [r.sampled_rows for r in records] == [
             4 * (sched.width if wide else 1) for wide in drafted
         ]
@@ -892,9 +925,68 @@ class TestKvPagesWalked:
         assert generator.metrics.counter("sample_wide_steps") == sum(drafted)
         assert any(drafted) is spec
         last = records[-1]
-        assert f"{last.tokens / last.q_tile_rows:.3f}" in (
-            render_steps(records).splitlines()[-1].split()
+        rendered = render_steps(records).splitlines()[-1].split()
+        assert f"{last.tokens / last.q_tile_rows:.3f}" in rendered
+        assert rendered[-3] == (
+            f"{last.kv_pages_walked / last.kv_blocks_walked:.2f}"
         )
+
+
+class TestKvBlocksWalked:
+    """``kv_blocks_walked``: the flash updates one layer's kernel call
+    makes, by the rule the kernel sizes its KV blocks with
+    (``ops/ragged_attention.kv_block_pages``)."""
+
+    GEOMETRY = dict(q_per_kv=7, kv_heads=4, head_dim=128, page_size=64, itemsize=2)
+
+    @pytest.mark.parametrize("name, pages, q_count, blocks", [
+        # decode rows on the small tile, 8 pages a block: 6 pages are one
+        # update, 8 one, 9 two, 17 three
+        ("decode", [6, 8, 9, 17], [1, 1, 1, 5], 1 + 1 + 2 + 3),
+        # whole chunks, 4 pages a block: 1, 4, 5 and 16 pages
+        ("chunks", [1, 4, 5, 16], [64, 9, 64, 64], 1 + 1 + 2 + 4),
+        # a slot without queries walks nothing whatever its pages
+        ("idle-between", [6, 13, 0, 9], [1, 0, 0, 64], 1 + 3),
+        ("empty", [0, 0], [0, 0], 0),
+    ])
+    def test_count_is_the_kernels_rule(self, name, pages, q_count, blocks):
+        import numpy as np
+
+        from operator_tpu.ops.ragged_attention import (
+            kv_block_pages,
+            kv_blocks_walked,
+            query_tile_rows,
+        )
+
+        # the 7B cells' geometry: the rungs' blocks the cases are written for
+        block_of = {t: kv_block_pages(t, **self.GEOMETRY) for t in (8, 64)}
+        assert block_of == {8: 8, 64: 4}
+        tile_rows = query_tile_rows(np.asarray(q_count, np.int32), 64)
+        assert kv_blocks_walked(np.asarray(pages), tile_rows, block_of) == blocks
+
+    @pytest.mark.parametrize("name, geometry, small, chunk", [
+        ("qwen2.5-1.5b", dict(q_per_kv=6, kv_heads=2), 8, 8),
+        ("qwen2.5-7b", dict(q_per_kv=7, kv_heads=4), 8, 4),
+        ("falcon-h1-34b", dict(q_per_kv=5, kv_heads=4), 8, 4),
+        ("ouro-2.6b", dict(q_per_kv=1, kv_heads=16), 2, 2),
+    ])
+    def test_the_cells_blocks(self, name, geometry, small, chunk):
+        """The block each rung takes at the four served geometries: a
+        change of the budget or of the rule shows here first."""
+        from operator_tpu.ops.ragged_attention import kv_block_pages, query_tiles
+
+        shapes = dict(head_dim=128, page_size=64, itemsize=2, **geometry)
+        assert query_tiles(64) == (8, 64)
+        assert [kv_block_pages(t, **shapes) for t in query_tiles(64)] == [small, chunk]
+
+    def test_a_block_never_outgrows_the_budget_and_one_page_always_goes(self):
+        from operator_tpu.ops import ragged_attention
+        from operator_tpu.ops.ragged_attention import kv_block_pages
+
+        huge = dict(q_per_kv=8, kv_heads=32, head_dim=256, page_size=128, itemsize=4)
+        assert kv_block_pages(64, **huge) == 1  # nothing fits: still one page
+        tiny = dict(q_per_kv=1, kv_heads=1, head_dim=128, page_size=8, itemsize=2)
+        assert kv_block_pages(8, **tiny) == max(ragged_attention.KV_BLOCK_PAGES)
 
 
 class TestQTileRows:
