@@ -137,7 +137,7 @@ def kernels_leg(interpret: bool) -> dict:
     layers = 3  # a stacked pool, every layer's pages different
 
     def ragged_case(name, heads, kv_heads, head_dim, chunk, kv_len, q_count,
-                    layer, window=None):
+                    layer, window=None, attend_block=1):
         """One kernel-vs-reference comparison over rows of mixed phases,
         at one layer of the stacked pool."""
         rows = len(kv_len)
@@ -155,13 +155,13 @@ def kernels_leg(interpret: bool) -> dict:
         count = jnp.asarray(q_count, jnp.int32)
         got = _ragged_attention_pallas(
             q, k_pages, v_pages, table, kv, count, layer,
-            interpret=interpret, sliding_window=window,
+            interpret=interpret, sliding_window=window, attend_block=attend_block,
         )
         with jax.default_matmul_precision("highest"):
             want = ragged_attention_reference(
                 q.astype(jnp.float32), k_pages.astype(jnp.float32),
                 v_pages.astype(jnp.float32), table, kv, count, layer,
-                sliding_window=window,
+                sliding_window=window, attend_block=attend_block,
             )
         # rows past q_count (and whole inactive rows) are garbage by
         # contract on both sides: compare the live query rows only
@@ -212,6 +212,14 @@ def kernels_leg(interpret: bool) -> dict:
             "mha", *((16, 16, 128) if not interpret else (4, 4, 128)), chunk=64,
             kv_len=[1, 200, 0, 64, 448, 333, 300, 0],
             q_count=[1, 1, 0, 64, 64, 17, 8, 0], layer=2,
+        ),
+        # eight query heads a KV head under the block-causal mask
+        # (sdar-30b-a3b: 32 / 4 x 128, blocks of 4): rows of a block, of a
+        # block led by the one before it, and a prompt chunk
+        "block_rows_c64": ragged_case(
+            "block", *((32, 4, 128) if not interpret else (8, 2, 128)), chunk=64,
+            kv_len=[4, 200, 0, 64, 448, 336, 300, 0],
+            q_count=[4, 8, 0, 64, 64, 8, 4, 0], layer=1, attend_block=4,
         ),
         # Mistral's geometry with a window that bites inside 512 tokens
         # (its published 4096 never does below the serving cap)
@@ -317,6 +325,40 @@ def kernels_leg(interpret: bool) -> dict:
         "y": close("ssm_scan y", np.asarray(got_y)[live], np.asarray(want_y)[live]),
         "state": close("ssm_scan state", np.asarray(got_state), np.asarray(want_state)),
     }
+
+    # the grouped expert product (a model with sparse experts,
+    # ops/moe_experts.py) against a plain product over every expert: int8
+    # stacks of two layers, a quarter of the tokens routed nowhere, one
+    # expert that no token chose
+    from operator_tpu.models.quant import quantize_matrix
+    from operator_tpu.ops.moe_experts import _moe_experts_pallas, moe_experts_reference
+
+    m_tokens, m_experts, m_top, m_hidden, m_inner = (
+        (64, 8, 2, 128, 128) if interpret else (256, 32, 8, 2048, 768)
+    )
+    keys = jax.random.split(jax.random.PRNGKey(31), 5)
+
+    def expert_stack(key, rows, cols):
+        drawn = jax.random.normal(key, (2, m_experts, rows, cols), jnp.float32) * rows ** -0.5
+        return quantize_matrix(drawn.astype(jnp.bfloat16))
+
+    stacks = (
+        expert_stack(keys[0], m_hidden, m_inner), expert_stack(keys[1], m_hidden, m_inner),
+        expert_stack(keys[2], m_inner, m_hidden),
+    )
+    x = jax.random.normal(keys[3], (m_tokens, m_hidden), jnp.bfloat16)
+    gates, chosen = jax.lax.top_k(
+        jax.nn.softmax(jax.random.normal(keys[4], (m_tokens, m_experts))), m_top
+    )
+    chosen = jnp.where(chosen == 3, 4, chosen).astype(jnp.int32)  # expert 3 idles
+    chosen = jnp.where((jnp.arange(m_tokens) % 4 == 3)[:, None], m_experts, chosen)
+    got = _moe_experts_pallas(x, chosen, gates, *stacks, jnp.int32(1), interpret=interpret)
+    want = moe_experts_reference(x, chosen, gates, *stacks, jnp.int32(1))
+    check(
+        float(jnp.abs(got[3::4]).max()) == 0.0,
+        "moe_experts: a token routed nowhere was given an expert's output",
+    )
+    report["moe_experts"] = close("moe_experts", np.asarray(got), np.asarray(want))
 
     # similarity: the semantic matcher's shape (1000 windows x 300 patterns)
     # and incident recall's (one query row x a handful of incidents)

@@ -108,6 +108,51 @@ class OuroConfig(ModelConfig):
 
 
 @dataclass(frozen=True)
+class SdarConfig(ModelConfig):
+    """SDAR (JetLM, ``model_type: sdar_moe``): the Qwen3-MoE decoder layer
+    (per-head RMSNorm on q and k, a router over ``num_experts`` gated MLPs
+    of which a token takes ``num_experts_per_tok``) under a block-causal
+    mask, generating by denoising blocks of ``block_length`` positions
+    (``models/sdar.py`` has the equations).  Every layer is sparse
+    (``decoder_sparse_step`` 1, no dense layer, no shared expert), so
+    ``intermediate_size`` is carried and unused.  A sibling of
+    ``ModelConfig`` as ``FalconH1Config`` is; field names follow the
+    published ``config.json`` where it has the key."""
+
+    num_experts: int = 128
+    num_experts_per_tok: int = 8
+    moe_intermediate_size: int = 768
+    norm_topk_prob: bool = True
+    hidden_act: str = "silu"
+    #: positions a block holds: position ``i`` attends to every ``j`` with
+    #: ``j // block_length <= i // block_length``, and a step denoises one
+    #: block a row.  Not a key of the published config (the family's
+    #: ``generate.py`` takes it as an argument)
+    block_length: int = 4
+    #: the id a position holds until a step keeps a token for it
+    mask_token_id: int = 151669
+
+    family: ClassVar[str] = "sdar"
+    continuous_only: ClassVar[Optional[str]] = (
+        "routes every token to a few of its experts and denoises a block "
+        "of positions a step"
+    )
+
+    def __post_init__(self) -> None:
+        super().__post_init__()
+        assert 1 <= self.num_experts_per_tok <= self.num_experts
+        assert self.block_length >= 1 and (
+            self.block_length & (self.block_length - 1) == 0
+        ), "block_length must be a power of two (the mask ORs positions with it)"
+        assert 0 <= self.mask_token_id < self.vocab_size
+        assert (
+            self.hidden_act == "silu" and not self.attention_bias
+            and not self.tie_embeddings and self.sliding_window is None
+            and self.rope_scaling is None
+        ), f"{self.name}: an SDAR variant the layer body does not implement"
+
+
+@dataclass(frozen=True)
 class FalconH1Config(ModelConfig):
     """Falcon-H1: in every layer a Mamba-2 mixer in parallel with
     grouped-query attention, then a gated MLP; muP multipliers on nearly
@@ -418,6 +463,54 @@ TINY_OURO = OuroConfig(
     total_ut_steps=3,
 )
 
+# SDAR-30B-A3B-Chat as published (JetLM/SDAR-30B-A3B-Chat, config.json): 48
+# identical sparse layers.  ``sdar-30b-a3b-12l`` is the same model cut in
+# depth only, one pipeline stage's twelve layers with the embedding and the
+# head (benchmark/configs/sdar-30b-a3b-int8.json)
+SDAR_30B_A3B = SdarConfig(
+    name="sdar-30b-a3b",
+    vocab_size=151936,
+    hidden_size=2048,
+    intermediate_size=6144,
+    num_layers=48,
+    num_heads=32,
+    num_kv_heads=4,
+    head_dim=128,
+    rope_theta=1_000_000.0,
+    rms_norm_eps=1e-6,
+    max_seq_len=16384,  # serving cap; the model supports 32k
+    num_experts=128,
+    num_experts_per_tok=8,
+    moe_intermediate_size=768,
+    block_length=4,
+    mask_token_id=151669,
+)
+SDAR_30B_A3B_12L = replace(SDAR_30B_A3B, name="sdar-30b-a3b-12l", num_layers=12)
+
+#: the family's small config for tests: four layers of sixteen experts, eight
+#: a token (at two of eight, one expert chosen otherwise in bfloat16 moves a
+#: logit by 1 to 2.5 and no limit tells sound from int4; at eight of sixteen
+#: by under 0.03: tests/benchmark/configs/tiny-sdar.json), and a mask id
+#: outside the byte tokenizer's
+TINY_SDAR = SdarConfig(
+    name="tiny-sdar",
+    vocab_size=512,
+    hidden_size=64,
+    intermediate_size=192,
+    num_layers=4,
+    num_heads=4,
+    num_kv_heads=2,
+    head_dim=16,
+    rope_theta=10_000.0,
+    rms_norm_eps=1e-6,
+    max_seq_len=256,
+    num_experts=16,
+    num_experts_per_tok=8,
+    moe_intermediate_size=32,
+    block_length=4,
+    mask_token_id=511,
+)
+
 _REGISTRY = {
     cfg.name: cfg
     for cfg in (
@@ -435,6 +528,9 @@ _REGISTRY = {
         TINY_FALCON_H1,
         OURO_2_6B,
         TINY_OURO,
+        SDAR_30B_A3B,
+        SDAR_30B_A3B_12L,
+        TINY_SDAR,
     )
 }
 

@@ -108,6 +108,24 @@ class StepRecord:
     #: model's ``total_ut_steps`` (``sched/mixed.py`` runs every pass for
     #: every row); None on engines that do not say
     passes: Optional[int] = None
+    #: of a model that denoises blocks of positions (models/sdar.py; None
+    #: for any other): rows in a denoising step, the answer tokens those
+    #: rows KEPT this step (``unmasked_tokens / block_rows`` is
+    #: ``block_length / denoise_steps`` but for a request's first and last
+    #: block), and the query tokens among ``tokens`` that only rewrite a
+    #: finished block's keys (a block's first step is led by the block
+    #: before it)
+    block_rows: Optional[int] = None
+    unmasked_tokens: Optional[int] = None
+    commit_tokens: Optional[int] = None
+    #: of a model with experts (None for any other): valid tokens the
+    #: step's layers routed (each to ``num_experts_per_tok`` experts),
+    #: experts given at least one token SUMMED OVER LAYERS, and the tokens
+    #: of the fullest expert, the LARGEST over layers: the last two come
+    #: back from the device beside the step's tokens
+    moe_tokens: Optional[int] = None
+    moe_experts_hit: Optional[int] = None
+    moe_assign_max: Optional[int] = None
     #: per-step achieved MFU when the ring's owner knows the model's
     #: flops/token (serving/perf.py StepClock); None on bare rings
     mfu: Optional[float] = None
@@ -167,6 +185,8 @@ _MS_FIELDS = (
 _COUNT_FIELDS = (
     "accepted", "cached_tokens", "prefill_tokens", "kv_pages_walked",
     "kv_blocks_walked", "q_tile_rows", "state_rows", "sampled_rows", "passes",
+    "block_rows", "unmasked_tokens", "commit_tokens",
+    "moe_tokens", "moe_experts_hit", "moe_assign_max",
 )
 
 
@@ -347,29 +367,38 @@ def render_steps(records: "Iterable[StepRecord]") -> str:
     header = (
         f"{'seq':>5}  {'kind':<7} {'tok':>5} {'pf_tok':>6} {'slots':>5} {'occ':>5} "
         f"{'wall_ms':>8} {'host_ms':>8} {'wait_ms':>8} {'xfer_ms':>8} "
-        f"{'plan':>7} {'pack':>7} {'commit':>7} {'turn':>7} {'passes':>6} "
+        f"{'plan':>7} {'pack':>7} {'commit':>7} {'turn':>7} "
+        f"{'blk_rows':>8} {'unmask':>6} {'cmt_tok':>7} {'moe_tok':>7} {'exp_hit':>7} "
+        f"{'exp_max':>7} {'passes':>6} "
         f"{'st_rows':>7} {'smp_rows':>8} {'kv_pg':>6} {'pg_blk':>6} {'q_fill':>6} "
         f"{'mfu':>8}"
     )
+
+    def shown(value):
+        return "-" if value is None else value
+
     lines = [header, "-" * len(header)]
     for r in records:
         mfu = f"{r.mfu:.4f}" if r.mfu is not None else "-"
-        pages = r.kv_pages_walked if r.kv_pages_walked is not None else "-"
         # pages a flash update folded in, of the block its rung could take
         block = (
             f"{r.kv_pages_walked / r.kv_blocks_walked:.2f}"
             if r.kv_pages_walked and r.kv_blocks_walked else "-"
         )
-        prompt = r.prefill_tokens if r.prefill_tokens is not None else "-"
         fill = f"{r.tokens / r.q_tile_rows:.3f}" if r.q_tile_rows else "-"
-        state = r.state_rows if r.state_rows is not None else "-"
-        sampled = r.sampled_rows if r.sampled_rows is not None else "-"
-        passes = r.passes if r.passes is not None else "-"
+        pages, prompt = shown(r.kv_pages_walked), shown(r.prefill_tokens)
+        state, sampled, passes = shown(r.state_rows), shown(r.sampled_rows), shown(r.passes)
         lines.append(
             f"{r.seq:>5}  {r.kind:<7} {r.tokens:>5} {prompt:>6} {r.slots:>5} "
             f"{r.occupancy:>5.2f} {r.wall_ms:>8.3f} {r.host_ms:>8.3f} "
             f"{r.wait_ms:>8.3f} {r.xfer_ms:>8.3f} {r.plan_ms:>7.3f} "
-            f"{r.pack_ms:>7.3f} {r.commit_ms:>7.3f} {r.turn_ms:>7.3f} {passes:>6} "
+            f"{r.pack_ms:>7.3f} {r.commit_ms:>7.3f} {r.turn_ms:>7.3f} "
+            # a denoising step's rows, what they kept, the tokens that only
+            # rewrite a finished block's keys; the tokens routed, experts
+            # given one (over the layers), the fullest expert's
+            f"{shown(r.block_rows):>8} {shown(r.unmasked_tokens):>6} "
+            f"{shown(r.commit_tokens):>7} {shown(r.moe_tokens):>7} "
+            f"{shown(r.moe_experts_hit):>7} {shown(r.moe_assign_max):>7} {passes:>6} "
             f"{state:>7} {sampled:>8} {pages:>6} {block:>6} {fill:>6} {mfu:>8}"
         )
     return "\n".join(lines)

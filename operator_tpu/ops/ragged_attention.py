@@ -31,7 +31,10 @@ it.  One layer's pages are all a call ever touches.
 
 Query token ``i`` of row ``b`` sits at absolute position
 ``kv_len[b] - q_count[b] + i`` and attends causally over positions
-``<= `` its own.  A decode row is the ``q_count == 1`` special case; a
+``<= `` its own (``attend_block`` 1), or, under the block-causal mask of a
+model that denoises blocks of ``attend_block`` positions (models/sdar.py),
+over positions ``<= q_pos | (attend_block - 1)``: every earlier block and
+the whole of its own, written by this step like any other key.  A decode row is the ``q_count == 1`` special case; a
 whole-prompt prefill is ``q_count == kv_len``; a mid-prompt chunk is
 anything in between — one program covers all three, which is what lets
 the scheduler (serving/sched/) dispatch a mixed wave every step.  A
@@ -219,6 +222,16 @@ def require_ragged_kernel_support(config) -> None:
 # ---------------------------------------------------------------------------
 
 
+def _block_end(q_pos, attend_block: int):
+    """The last position a query at ``q_pos`` sees: its own under the
+    causal mask (``attend_block`` 1, and then nothing is traced), the end
+    of its block of ``attend_block`` positions (a power of two) under the
+    block-causal one."""
+    if attend_block == 1:
+        return q_pos
+    return q_pos | (attend_block - 1)
+
+
 def ragged_attention_reference(
     q: jax.Array,  # [B, C, QH, D]
     k_pages: jax.Array,  # [L, num_pages, page_size, KH, D]
@@ -228,6 +241,7 @@ def ragged_attention_reference(
     q_count: jax.Array,  # [B]
     layer: jax.Array,  # [] int32
     sliding_window: Optional[int] = None,
+    attend_block: int = 1,
 ) -> jax.Array:
     """Gather-then-attend oracle.  Returns [B, C, QH, D] in q.dtype.
 
@@ -252,7 +266,9 @@ def ragged_attention_reference(
         (kv_len - q_count)[:, None]
         + jnp.arange(c, dtype=jnp.int32)[None, :]
     )[:, :, None]  # [B, C, 1]
-    mask = (kv_pos <= q_pos) & (kv_pos < kv_len[:, None, None])
+    mask = (kv_pos <= _block_end(q_pos, attend_block)) & (
+        kv_pos < kv_len[:, None, None]
+    )
     if sliding_window is not None:
         mask = mask & (kv_pos > q_pos - sliding_window)
     scores = jnp.where(mask[:, None, None, :, :], scores, _NEG_INF)
@@ -290,6 +306,7 @@ def _ragged_attn_kernel(
     page_size: int,
     scale: float,
     window: Optional[int] = None,
+    attend_block: int = 1,
 ):
     """One grid step per batch row; a row with ``q_count == 0`` runs
     nothing.  A live row's query tile — the smallest of ``tiles`` (query
@@ -418,7 +435,9 @@ def _ragged_attn_kernel(
                     ],
                     axis=0,
                 ) * scale
-                mask = (kv_pos <= q_pos) & (kv_pos < seq_len)
+                mask = (kv_pos <= _block_end(q_pos, attend_block)) & (
+                    kv_pos < seq_len
+                )
                 if window is not None:
                     mask = mask & (kv_pos > q_pos - window)
                 s = jnp.where(mask, s, _NEG_INF)
@@ -497,7 +516,8 @@ def _ragged_attn_kernel(
 
 
 @functools.partial(
-    jax.jit, static_argnames=("interpret", "sliding_window", "block_pages")
+    jax.jit,
+    static_argnames=("interpret", "sliding_window", "block_pages", "attend_block"),
 )
 def _ragged_attention_pallas(
     q: jax.Array,
@@ -511,6 +531,7 @@ def _ragged_attention_pallas(
     interpret: bool = False,
     sliding_window: Optional[int] = None,
     block_pages: Optional[tuple[int, ...]] = None,
+    attend_block: int = 1,
 ) -> jax.Array:
     """``block_pages`` names the KV block of every rung, smallest tile
     first (a test's and a probe's argument: the serving path leaves it
@@ -539,6 +560,7 @@ def _ragged_attention_pallas(
         page_size=page_size,
         scale=d**-0.5,
         window=sliding_window,
+        attend_block=attend_block,
     )
     # an idle slot's grid step holds the block of the last live slot
     # before it (of the first live slot, ahead of it): the pipeline moves
@@ -586,6 +608,7 @@ def ragged_paged_attention(
     q_count: jax.Array,
     layer: jax.Array,
     sliding_window: Optional[int] = None,
+    attend_block: int = 1,
 ) -> jax.Array:
     """Dispatch: Pallas kernel on TPU, dense reference elsewhere."""
     from ._dispatch import on_tpu
@@ -593,9 +616,9 @@ def ragged_paged_attention(
     if on_tpu():
         return _ragged_attention_pallas(
             q, k_pages, v_pages, page_table, kv_len, q_count, layer,
-            sliding_window=sliding_window,
+            sliding_window=sliding_window, attend_block=attend_block,
         )
     return ragged_attention_reference(
         q, k_pages, v_pages, page_table, kv_len, q_count, layer,
-        sliding_window=sliding_window,
+        sliding_window=sliding_window, attend_block=attend_block,
     )

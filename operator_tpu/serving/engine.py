@@ -81,6 +81,7 @@ from .types import (  # noqa: F401
     _bucket,
     _PrefillJob,
     _Slot,
+    check_denoise,
 )
 
 log = logging.getLogger(__name__)
@@ -2247,6 +2248,9 @@ class ServingEngine:
         if params is not None and params.guided_choice is not None \
                 and params.guided_regex is not None:
             raise ValueError("guided_choice and guided_regex are mutually exclusive")
+        if params is not None:
+            # refused at SUBMIT, to this caller, as the scheduler would
+            check_denoise(params, getattr(self.generator.config, "block_length", 0))
         if self._sched is not None:
             if params is not None and (
                 params.guided_choice is not None

@@ -47,11 +47,17 @@ def matmul_param_count(config: Any) -> int:
     which multiplies even when tied to the embedding.  Norm scales,
     convolution taps and the embedding GATHER move no matmul MACs, so
     they are excluded; ``param_count(params)`` counts them and is the
-    storage number, not the compute number."""
+    storage number, not the compute number.  A family whose token meets
+    only some of its layer weights says how many itself
+    (``matmul_params_per_token``: models/sdar.py, 8 of 128 experts)."""
     from ..models import family_of
 
-    shapes = family_of(config).layer_matrix_shapes(config)
-    layers = sum(n * rows * cols for n, rows, cols in shapes.values())
+    family = family_of(config)
+    if hasattr(family, "matmul_params_per_token"):
+        layers = family.matmul_params_per_token(config)
+    else:
+        shapes = family.layer_matrix_shapes(config)
+        layers = sum(n * rows * cols for n, rows, cols in shapes.values())
     passes = int(getattr(config, "total_ut_steps", 1))
     return passes * layers + config.hidden_size * config.vocab_size
 

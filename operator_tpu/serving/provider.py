@@ -416,6 +416,12 @@ def build_serving_engine(
     if config.aot_cache_path:
         from .aotcache import AotCache, generator_fingerprint
 
+        # what the scheduler will really run: it switches speculation and
+        # the prefix store off for a model with recurrent state and for one
+        # that denoises blocks (sched/scheduler.py)
+        plain_rows_only = bool(
+            model_config.recurrent_state or getattr(model_config, "block_length", 0)
+        )
         try:
             aot = AotCache(config.aot_cache_path, generator_fingerprint(
                 config=model_config,
@@ -431,16 +437,12 @@ def build_serving_engine(
                 pipeline_depth=config.pipeline_depth,
                 prefill_chunk=prefill_chunk,
                 sched_pipeline_depth=config.sched_pipeline_depth,
-                # what the scheduler will really run: it switches both
-                # off for a model with recurrent state (sched/scheduler.py)
                 spec_width=1 + (
                     config.spec_lookup_k
-                    if config.spec_decode and not model_config.recurrent_state
+                    if config.spec_decode and not plain_rows_only
                     else 0
                 ),
-                kv_prefix_cache=(
-                    config.kv_prefix_cache and not model_config.recurrent_state
-                ),
+                kv_prefix_cache=config.kv_prefix_cache and not plain_rows_only,
                 lora_names=sorted(lora_adapters) if lora_adapters else (),
             ))
         except Exception:  # noqa: BLE001 - cache is an optimisation only

@@ -31,7 +31,7 @@ from typing import Any, Optional
 from ..models.configs import ModelConfig
 from ..models.tokenizer import Tokenizer
 from ..utils.timing import METRICS, MetricsRegistry
-from .sampler import SAMPLE_TOP_K, sample
+from .sampler import SAMPLE_TOP_K, sample, sample_with_confidence
 from .types import PageAllocator, SamplingParams, _Slot
 
 __all__ = ["Runtime"]
@@ -124,6 +124,11 @@ class Runtime:
         #: (serving/sampler.py), as ``sample(logits, rng, temp, top_p)``;
         #: an attribute, so a test can put a recording fake in its place
         self.sample = functools.partial(sample, top_k=self.sample_top_k)
+        #: the same draw with each token's confidence beside it: what a
+        #: model that denoises blocks ranks a step's positions by
+        self.sample_confident = functools.partial(
+            sample_with_confidence, top_k=self.sample_top_k
+        )
         #: persisted AOT executables (serving/aotcache.py): a prebuilt
         #: ``AotCache`` or None.  Every program construction site routes
         #: through ``_aot_wrap``, so a warm boot (or a supervised restart)

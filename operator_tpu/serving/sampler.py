@@ -21,6 +21,23 @@ from __future__ import annotations
 SAMPLE_TOP_K = 64
 
 
+def _nucleus(logits, temp, top_p, top_k: int):
+    """The candidates a row is sampled from: ``(top_idx [B, k], filtered
+    [B, k])``, the ``k`` largest logits' ids and their temperature-scaled
+    logits with minus infinity outside the nucleus."""
+    import jax
+    import jax.numpy as jnp
+
+    safe_temp = jnp.maximum(temp, 1e-4)[:, None]
+    scaled = logits.astype(jnp.float32) / safe_temp
+    k = min(top_k, logits.shape[-1])
+    top_logits, top_idx = jax.lax.top_k(scaled, k)
+    probs = jax.nn.softmax(top_logits, axis=-1)
+    cumulative = jnp.cumsum(probs, axis=-1) - probs  # exclusive prefix
+    keep = cumulative < top_p[:, None]  # first token always kept
+    return top_idx, jnp.where(keep, top_logits, -jnp.inf)
+
+
 def sample(logits, rng, temp, top_p, *, top_k: int):
     """Temperature + truncated-nucleus sampling; temp<=0 means greedy.
 
@@ -34,16 +51,31 @@ def sample(logits, rng, temp, top_p, *, top_k: int):
 
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
-    safe_temp = jnp.maximum(temp, 1e-4)[:, None]
-    scaled = logits.astype(jnp.float32) / safe_temp
-    k = min(top_k, logits.shape[-1])
-    top_logits, top_idx = jax.lax.top_k(scaled, k)
-    probs = jax.nn.softmax(top_logits, axis=-1)
-    cumulative = jnp.cumsum(probs, axis=-1) - probs  # exclusive prefix
-    keep = cumulative < top_p[:, None]  # first token always kept
-    filtered = jnp.where(keep, top_logits, -jnp.inf)
+    top_idx, filtered = _nucleus(logits, temp, top_p, top_k)
     rng, sub = jax.random.split(rng)
     choice = jax.random.categorical(sub, filtered, axis=-1)
     sampled = jnp.take_along_axis(top_idx, choice[:, None], axis=-1)[:, 0]
     picked = jnp.where(temp <= 0.0, greedy, sampled.astype(jnp.int32))
     return picked, rng
+
+
+def sample_with_confidence(logits, rng, temp, top_p, *, top_k: int):
+    """:func:`sample`, and beside each token its confidence: the
+    probability the token had among the candidates it was drawn from (the
+    nucleus inside the top ``top_k``, at the row's temperature).  What a
+    denoising step ranks its positions by (``sched/mixed.py``).  A greedy
+    row's token is the first candidate, and its confidence that one's.
+
+    [B, V] logits -> ([B] token ids, [B] float32 confidences, the rng).
+    """
+    import jax
+    import jax.numpy as jnp
+
+    top_idx, filtered = _nucleus(logits, temp, top_p, top_k)
+    rng, sub = jax.random.split(rng)
+    choice = jax.random.categorical(sub, filtered, axis=-1)
+    choice = jnp.where(temp <= 0.0, 0, choice)  # the largest logit leads
+    picked = jnp.take_along_axis(top_idx, choice[:, None], axis=-1)[:, 0]
+    among = jax.nn.softmax(filtered, axis=-1)
+    confidence = jnp.take_along_axis(among, choice[:, None], axis=-1)[:, 0]
+    return picked.astype(jnp.int32), confidence, rng

@@ -79,6 +79,11 @@ class StepView:
     #: of several passes ``pass * L + layer``.  The pools it returns go
     #: back into the carry
     attend: Callable
+    #: the layer leaves the loop does NOT scan (the family's
+    #: ``WHOLE_STACKS``, e.g. the expert stacks of models/sdar.py), whole:
+    #: the body indexes them with ``scanned["layer"]``.  Empty for a family
+    #: that names none
+    stacks: Any = None
 
 
 def make_mixed_fn(runtime: Any, t_budget: int, chunk: int,
@@ -90,7 +95,7 @@ def make_mixed_fn(runtime: Any, t_budget: int, chunk: int,
 
         fn(params, paged, ids, rows, pos, valid, in_row,
            q_start, q_count, kv_len, latest, from_prev,
-           sample_start, spec_len, rng, temp, top_p)
+           sample_start, spec_len, rng, temp, top_p, denoise=None)
         -> (new_paged, toks [B, W], accept [B], latest_out [B], rng)
 
     Flat inputs (length ``t_budget``): ``ids`` token ids, ``rows`` the
@@ -128,6 +133,30 @@ def make_mixed_fn(runtime: Any, t_budget: int, chunk: int,
     ``latest_out[b]`` carries each slot's freshest sampled token for the
     next dispatch's chaining (passthrough when the slot sat this step
     out).
+
+    **A model that denoises blocks** (``config.block_length``, models/
+    sdar.py) has another tail and no draft.  A generating row's step runs
+    the ``N = block_length`` positions of its current block (after, in a
+    block's first step, the ``N`` clean positions of the block before,
+    which only write that block's final keys) under the block-causal mask;
+    ``sample_start`` is the flat offset of the block's first position.
+    The carried buffer is ``latest [B, N]``, the slot's block as the last
+    step left it, and a ``from_prev`` token takes ``latest[slot, pos %
+    N]``.  ``denoise`` (None for every other model) holds per slot ``keep``
+    (positions this step keeps; 0 = no block row), ``limit`` (positions of
+    the block at or past it are never kept: they lie past ``max_tokens``)
+    and ``low_confidence`` (the row's remask rule).  The tail samples all
+    ``N`` positions a slot, always (no conditional), with each token's
+    confidence (``runtime.sample_confident``; the mask id's logit is taken
+    out first, so no position is ever denoised into a mask), and keeps
+    ``keep`` of the positions that still hold the mask id: the most
+    confident, or under the sequential rule the leftmost, ties to the
+    left.  ``toks [B, N]`` is the block after the step, ``accept [B]`` the
+    bits of the positions this step kept, and ``latest_out`` the block of
+    every slot that had a block row.  A model with experts returns a sixth
+    value, ``[2]``: experts given a token, summed over layers
+    (``StepRecord.moe_experts_hit``), and the fullest expert's tokens, the
+    largest over layers (``moe_assign_max``).
     """
     jax, jnp = runtime._jax, runtime._jnp
     config = runtime.config
@@ -144,18 +173,29 @@ def make_mixed_fn(runtime: Any, t_budget: int, chunk: int,
     passes = int(getattr(config, "total_ut_steps", 1))
     # a family may carry the residual stream in another dtype than its
     # parameters' (models/ouro.py: float32 through 384 additions a token)
-    stream_dtype = getattr(family_of(config), "STREAM_DTYPE", None)
+    family = family_of(config)
+    stream_dtype = getattr(family, "STREAM_DTYPE", None)
+    # leaves of the layers the loop does not scan (a family's expert stacks)
+    whole = tuple(getattr(family, "WHOLE_STACKS", ()))
+    # positions of a block a model denoises a step (models/sdar.py); 0 for
+    # a model that commits one token a row
+    block = int(getattr(config, "block_length", 0))
+    assert not (block and width > 1), "a denoising row carries no draft"
 
     def mixed_fn(params, paged, ids, rows, pos, valid, in_row,
                  q_start, q_count, kv_len, latest, from_prev,
-                 sample_start, spec_len, rng, temp, top_p):
+                 sample_start, spec_len, rng, temp, top_p, denoise=None):
         from ...ops.ragged_attention import ragged_paged_attention
 
         page_size = paged.page_size
         # decode-ahead chaining: a token flagged from_prev takes its id
         # from the carried per-slot latest-sample buffer instead of the
         # host-packed placeholder — the sampled id never visits the host
-        eff_ids = jnp.where(from_prev, latest[rows], ids)
+        if block:
+            # the carry is each slot's block as its last step left it
+            eff_ids = jnp.where(from_prev, latest[rows, pos % block], ids)
+        else:
+            eff_ids = jnp.where(from_prev, latest[rows], ids)
         with jax.named_scope("embed"):
             x = jnp.take(params["embed"], eff_ids, axis=0)[None]  # [1, T, H]
             if embedding_multiplier != 1.0:
@@ -198,6 +238,7 @@ def make_mixed_fn(runtime: Any, t_budget: int, chunk: int,
                     q_pack.astype(k_pages.dtype), k_pages, v_pages,
                     paged.page_table, kv_len, q_count, layer,
                     sliding_window=config.sliding_window,
+                    attend_block=max(1, block),
                 )
                 # back to flat [T, QH, D].  The kernel leaves what it was
                 # not asked for unwritten (idle slots, rows past q_count):
@@ -215,6 +256,7 @@ def make_mixed_fn(runtime: Any, t_budget: int, chunk: int,
         layer_step = family_of(config).mixed_layer(config, StepView(
             t_budget=t_budget, chunk=chunk, rows=rows, in_row=in_row, pos=pos,
             valid=valid, q_start=q_start, q_count=q_count, attend=attend,
+            stacks={name: params["layers"][name] for name in whole},
         ))
         # the pools ride the layer loop's carry WHOLE, beside the residual
         # stream: the KV pools every model has, and the recurrent pools of
@@ -230,12 +272,13 @@ def make_mixed_fn(runtime: Any, t_budget: int, chunk: int,
             """The layer stack once, over the pool planes ``planes [L]``,
             and the norm that ends a pass: the head's input after the
             last pass, the next pass's after any other."""
-            (x, pools, recurrent), _ = lax.scan(
-                layer_step, carry, {"w": params["layers"], "layer": planes},
+            scanned = {k: v for k, v in params["layers"].items() if k not in whole}
+            (x, pools, recurrent), per_layer = lax.scan(
+                layer_step, carry, {"w": scanned, "layer": planes},
             )
             with jax.named_scope("pass_norm"):
                 x = rms_norm(x, params["ln_final"], config.rms_norm_eps)
-            return (x, pools, recurrent), None
+            return (x, pools, recurrent), per_layer
 
         # a model whose stack runs several times a token (models/ouro.py)
         # has a plane of the pool for every pass and layer, pass-major: the
@@ -244,24 +287,18 @@ def make_mixed_fn(runtime: Any, t_budget: int, chunk: int,
         # plain layer loop, under no second loop
         planes = jnp.arange(passes * config.num_layers, dtype=jnp.int32)
         if passes == 1:
-            (x, pools, recurrent), _ = one_pass((x, pools, recurrent), planes)
+            (x, pools, recurrent), per_layer = one_pass((x, pools, recurrent), planes)
         else:
-            (x, pools, recurrent), _ = lax.scan(
+            (x, pools, recurrent), per_layer = lax.scan(
                 one_pass, (x, pools, recurrent),
                 planes.reshape(passes, config.num_layers),
             )
 
-        def sample_at(rows_a_slot, rng):
-            """``rows_a_slot`` positions a slot from ``sample_start`` on,
-            through the head and the sampler: (toks [B, rows_a_slot], rng).
-            Only the sampled positions get logit rows: they are gathered
-            before the head matmul, so the [vocab] projection runs at
-            [B * rows_a_slot], not [T]"""
-            samp_idx = jnp.clip(
-                sample_start[:, None]
-                + jnp.arange(rows_a_slot, dtype=jnp.int32)[None],
-                0, t_budget - 1,
-            )  # [B, rows_a_slot]
+        def logits_at(samp_idx):
+            """The logit rows of the flat positions ``samp_idx [B,
+            rows_a_slot]``.  Only the sampled positions get logit rows:
+            they are gathered before the head matmul, so the [vocab]
+            projection runs at [B * rows_a_slot], not [T]"""
             with jax.named_scope("head"):
                 head = (
                     params["embed"].T if config.tie_embeddings
@@ -276,6 +313,17 @@ def make_mixed_fn(runtime: Any, t_budget: int, chunk: int,
                 )
                 if lm_head_multiplier != 1.0:
                     logits = logits * lm_head_multiplier
+            return logits
+
+        def sample_at(rows_a_slot, rng):
+            """``rows_a_slot`` positions a slot from ``sample_start`` on,
+            through the head and the sampler: (toks [B, rows_a_slot], rng)"""
+            samp_idx = jnp.clip(
+                sample_start[:, None]
+                + jnp.arange(rows_a_slot, dtype=jnp.int32)[None],
+                0, t_budget - 1,
+            )  # [B, rows_a_slot]
+            logits = logits_at(samp_idx)
             with jax.named_scope("sample"):
                 flat_toks, rng = runtime.sample(
                     logits.reshape(b_slots * rows_a_slot, -1), rng,
@@ -315,6 +363,57 @@ def make_mixed_fn(runtime: Any, t_budget: int, chunk: int,
             )
             return toks, accept, rng
 
+        def denoise_block(rng):
+            """The tail of a model that denoises blocks: every slot's
+            ``block`` positions through the head and the sampler, and of
+            those that hold the mask id the ``keep`` best kept."""
+            place = jnp.arange(block, dtype=jnp.int32)[None]  # [1, N]
+            samp_idx = jnp.clip(sample_start[:, None] + place, 0, t_budget - 1)
+            state = eff_ids[samp_idx]  # [B, N]: the block as the step found it
+            # a position is never denoised into the mask id
+            logits = logits_at(samp_idx).at[..., config.mask_token_id].set(-jnp.inf)
+            with jax.named_scope("sample"):
+                flat_toks, flat_conf, rng = runtime.sample_confident(
+                    logits.reshape(b_slots * block, -1), rng,
+                    jnp.repeat(temp, block), jnp.repeat(top_p, block),
+                )
+            with jax.named_scope("unmask"):
+                drawn = flat_toks.reshape(b_slots, block)
+                conf = flat_conf.reshape(b_slots, block)
+                open_ = (state == config.mask_token_id) & (
+                    place < denoise["limit"][:, None]
+                )
+                score = jnp.where(
+                    denoise["low_confidence"][:, None], conf,
+                    -place.astype(jnp.float32),
+                )
+                score = jnp.where(open_, score, -jnp.inf)
+                # ahead[b, j, i]: position i is kept before position j
+                ahead = (score[:, None, :] > score[:, :, None]) | (
+                    (score[:, None, :] == score[:, :, None])
+                    & (place[:, None, :] < place[:, :, None])
+                )
+                rank = jnp.sum(ahead, axis=-1, dtype=jnp.int32)
+                kept = open_ & (rank < denoise["keep"][:, None])
+                after = jnp.where(kept, drawn, state)
+                bits = jnp.sum(kept.astype(jnp.int32) << place, axis=-1)
+            return after, bits, rng
+
+        if block:
+            toks, accept, rng = denoise_block(rng)
+            block_row = denoise["keep"] > 0
+            new_paged = dataclasses.replace(
+                paged, k_pages=pools["k"], v_pages=pools["v"], lengths=kv_len,
+            )
+            counts = per_layer  # [L, E]: tokens each layer's experts took
+            return (
+                new_paged, toks, accept,
+                jnp.where(block_row[:, None], toks, latest), rng,
+                jnp.stack([
+                    jnp.sum(counts > 0, dtype=jnp.int32),
+                    jnp.max(counts).astype(jnp.int32),
+                ]),
+            )
         if width > 1:
             # the head and the sampler cost rows x vocabulary: they run
             # at the width THIS step's drafts need.  The branches take

@@ -62,6 +62,21 @@ the commit step's existing sync window, restorable later with one DMA.
 Greedy output is byte-identical cache-on vs cache-off: KV vectors are
 per-token projections, independent of how the prompt was chunked.
 
+**Rows that denoise blocks** (a model with ``block_length``, models/
+sdar.py): a generating row is in step ``s`` of a block of ``N`` positions.
+Its step packs the block's ``N`` query tokens (after, in a block's first
+step, the ``N`` clean ids of the block before, which write that block's
+final keys), samples all of them and keeps a few (``sched/mixed.py``); the
+prompt's whole blocks are prefilled in chunks that end on block boundaries
+and its tail opens the first generated block.  With ``denoise_steps``
+fixed a request, every count the host packs is known ahead
+(``sched/types.py BlockSchedule``), so decode-ahead pipelining stays: the
+per-slot carry is the block as the last step left it, and ``from_prev``
+tokens read it.  The commit takes what a step kept, streams a position
+once everything left of it is kept, and cuts the answer at ``max_tokens``
+and at EOS.  Speculation and the prefix store are switched off for such a
+model by the scheduler itself.
+
 Counters (docs/METRICS.md): ``podmortem_sched_admitted_midwave_total``,
 ``podmortem_sched_chunked_prefill_total``,
 ``podmortem_sched_recycled_slot_total``,
@@ -75,7 +90,8 @@ Counters (docs/METRICS.md): ``podmortem_sched_admitted_midwave_total``,
 ``podmortem_kv_hit_total``, ``podmortem_kv_miss_total``,
 ``podmortem_kv_evict_total``, ``podmortem_kv_offload_total``,
 ``podmortem_kv_restore_total``,
-``podmortem_kv_prefill_tokens_saved_total``.
+``podmortem_kv_prefill_tokens_saved_total``,
+``podmortem_unmasked_tokens_total``.
 """
 
 from __future__ import annotations
@@ -103,11 +119,12 @@ from ..types import (
     SamplingParams,
     ShedLowValue,
     _Slot,
+    check_denoise,
     pages_needed,
     prompt_budget,
 )
 from .draft import PromptLookupDraft
-from .types import RowWork, StepOutcome, StepPlan, _Row
+from .types import BlockSchedule, RowWork, StepOutcome, StepPlan, _Row
 
 log = logging.getLogger(__name__)
 
@@ -151,6 +168,9 @@ class _InFlight:
     #: each appends one record) — what its host spans carry as ``step``
     seq: int = 0
     held_rows: int = 0
+    #: device [2] of a model with experts: experts given a token (summed
+    #: over layers), the fullest expert's tokens; None for any other
+    moe: Any = None
 
 
 @dataclasses.dataclass
@@ -174,6 +194,9 @@ class _Packed:
     temp: np.ndarray
     top_p: np.ndarray
     kv_len: np.ndarray
+    #: per slot, for a model that denoises blocks (None for any other):
+    #: ``keep``, ``limit``, ``low_confidence`` (``sched/mixed.py``)
+    denoise: Optional[dict]
     #: some row carries a draft (``spec_len.any()``, the predicate the
     #: compiled step branches on): the head and the sampler run wide
     wide: bool
@@ -225,6 +248,25 @@ class Scheduler:
         #: times a token takes the layer stack in the mixed step
         #: (``StepRecord.passes``, the dispatch span's ``passes``)
         self._passes = int(getattr(model, "total_ut_steps", 1))
+        #: positions of a block a row denoises a step (models/sdar.py); 0
+        #: for a model whose step commits one token a row
+        self._block = int(getattr(model, "block_length", 0))
+        #: a model with experts: its steps count ``moe_tokens`` and bring
+        #: two expert counters back
+        self._moe = bool(getattr(model, "num_experts", 0))
+        if self._block:
+            if spec_decode:
+                self.switched_off["spec_decode"] = (
+                    "a row that denoises a block of positions a step has no "
+                    "next token to draft"
+                )
+                spec_decode = False
+            if kvstore is not None:
+                self.switched_off["kv_prefix_cache"] = (
+                    "under the block-causal mask a cached page's keys depend "
+                    "on where the prompt's blocks end"
+                )
+                kvstore = None
         if self._recurrent:
             if spec_decode:
                 self.switched_off["spec_decode"] = (
@@ -238,11 +280,11 @@ class Scheduler:
                     "recurrent state"
                 )
                 kvstore = None
-            for feature, why in self.switched_off.items():
-                log.warning(
-                    "%s is OFF for model %r (%s family): %s",
-                    feature, model.name, model.family, why,
-                )
+        for feature, why in self.switched_off.items():
+            log.warning(
+                "%s is OFF for model %r (%s family): %s",
+                feature, model.name, model.family, why,
+            )
         self.chunk = max(1, min(chunk, generator.max_seq))
         #: pages a flash update of the ragged kernel folds in, by query
         #: tile: the kernel's own rule on the static shapes it is given
@@ -255,13 +297,24 @@ class Scheduler:
             )
             for tile in query_tiles(self.chunk)
         }
-        self.t_budget = token_budget or max(self.chunk, generator.max_slots)
-        if self.t_budget < generator.max_slots:
+        #: the most query tokens a generating row packs a step: one, or a
+        #: block led by the block before it
+        row_tokens = 2 * self._block or 1
+        if self._block and (self.chunk % self._block or self.chunk < row_tokens):
+            raise ValueError(
+                f"sched chunk={self.chunk} must be a multiple of the model's "
+                f"block of {self._block} positions and hold two blocks"
+            )
+        self.t_budget = token_budget or max(
+            self.chunk, generator.max_slots * row_tokens
+        )
+        if self.t_budget < generator.max_slots * row_tokens:
             # a full decode batch must always fit one step, or decode
             # rows would be starved by construction
             raise ValueError(
                 f"sched token_budget={self.t_budget} < max_slots="
-                f"{generator.max_slots}: a full decode batch would not fit"
+                f"{generator.max_slots} x {row_tokens}: a full decode batch "
+                "would not fit"
             )
         if self.chunk > self.t_budget:
             raise ValueError(
@@ -376,6 +429,7 @@ class Scheduler:
                 "LoRA adapters are not supported by the continuous "
                 "scheduler (sched_mode=continuous); use the wave engine"
             )
+        check_denoise(params, self._block)
         ids = g.tokenizer.encode(prompt)
         # the budget formula and the runtime's truncation both engines share
         budget = prompt_budget(g.max_seq, params.max_tokens)
@@ -656,6 +710,13 @@ class Scheduler:
                     {"state_rows": packed.counts["state_rows"]}
                     if self._recurrent else {}  # no such argument without the state
                 ),
+                # a model that denoises blocks, a model with experts: the
+                # same rule
+                **{
+                    name: packed.counts[name]
+                    for name in ("block_rows", "commit_tokens", "moe_tokens")
+                    if packed.counts[name] is not None
+                },
             ):
                 entry = self._dispatch(plan, packed)
             clock.add("pack", (entry.dispatch_t - t1) * 1e3)
@@ -1038,6 +1099,14 @@ class Scheduler:
                 req_id=req_id, slot=slot, tokens=tokens, params=clamped,
                 pages=grant, submitted=submitted,
             )
+            if self._block:
+                row.blocks = BlockSchedule(
+                    size=self._block,
+                    per_step=self._block // (clamped.denoise_steps or self._block),
+                    prompt_len=len(tokens), max_tokens=clamped.max_tokens,
+                    low_confidence=clamped.remask == "low_confidence",
+                    answer=[None] * clamped.max_tokens,
+                )
             if picked:
                 # cached blocks ARE the prompt head: prefill starts at
                 # cached_len (always inside a row-owned page — the match
@@ -1096,7 +1165,9 @@ class Scheduler:
         # in-flight decode).  A row predicted to have hit max_tokens or
         # the sequence cap sits out: its in-flight tokens already cover
         # the request, and commit will finish it.
-        decode_ready = [
+        if self._block:
+            cursor = self._plan_block_rows(plan)
+        decode_ready = [] if self._block else [
             (req_id, row) for req_id, row in self._rows.items()
             if not row.pend_spec
             and row.pred_decoding
@@ -1181,11 +1252,16 @@ class Scheduler:
             if row.pend_spec or row.pred_decoding:
                 continue
             remaining = budget - cursor
-            count = min(self.chunk, row.prompt_len - row.pred_pos, remaining)
+            count = min(self.chunk, row.prefill_len - row.pred_pos, remaining)
+            if self._block:
+                count -= count % self._block  # chunks end where blocks do
             if count <= 0:
                 continue
+            # a denoising row's last chunk samples nothing: its first
+            # tokens come of its first block's steps
             kind = (
-                "finish" if row.pred_pos + count >= row.prompt_len
+                "finish"
+                if row.blocks is None and row.pred_pos + count >= row.prompt_len
                 else "prefill"
             )
             plan.work.append(RowWork(
@@ -1195,6 +1271,30 @@ class Scheduler:
             plan.prefill_rows += 1
         plan.tokens_planned = cursor
         return plan
+
+    def _plan_block_rows(self, plan: StepPlan) -> int:
+        """The generating rows of a model that denoises blocks: each its
+        next step by its schedule (``BlockSchedule.next``: past what is
+        committed or in flight), never deferred while ``token_budget``
+        holds two blocks a slot.  A row whose last step is in flight sits
+        out: its commit will finish it.  Returns the flat tokens taken."""
+        cursor = 0
+        for req_id, row in self._rows.items():
+            if not row.pred_decoding:
+                continue
+            step = row.blocks.next
+            if step is None:
+                continue
+            if cursor + step.count > self.t_budget:
+                plan.deferred_decode += 1
+                continue
+            plan.work.append(RowWork(
+                row.slot, req_id, cursor, step.count, "block",
+                pos0=step.start - step.commit, block=step,
+            ))
+            cursor += step.count
+            plan.decode_rows += 1
+        return cursor
 
     # -- dispatch ------------------------------------------------------
 
@@ -1235,7 +1335,14 @@ class Scheduler:
         temp = np.zeros((b,), np.float32)
         top_p = np.ones((b,), np.float32)
         kv_len = self._kv_shadow.copy()
-        prefill_tokens = 0
+        denoise = None
+        if self._block:
+            denoise = {
+                "keep": np.zeros((b,), np.int32),
+                "limit": np.zeros((b,), np.int32),
+                "low_confidence": np.zeros((b,), bool),
+            }
+        prefill_tokens = commit_tokens = 0
         for work in plan.work:
             row = self._rows[work.req_id]
             span = slice(work.start, work.start + work.count)
@@ -1253,6 +1360,27 @@ class Scheduler:
                 pos[span] = np.arange(
                     work.pos0, work.pos0 + work.count, dtype=np.int32
                 )
+            elif work.kind == "block":
+                step = work.block
+                lead = work.start + step.commit
+                pos[span] = np.arange(
+                    work.pos0, work.pos0 + work.count, dtype=np.int32
+                )
+                if step.step:
+                    # the block as the step before left it, on the device
+                    from_prev[span] = True
+                else:
+                    # a new block: masks, after the prompt's tail in the
+                    # first; led, in any other, by the block before it
+                    # (clean, on the device), whose keys this step writes
+                    tail = row.tokens[step.start :]
+                    ids[lead : lead + len(tail)] = tail
+                    ids[lead + len(tail) : lead + step.size] = g.config.mask_token_id
+                    from_prev[work.start : lead] = True
+                commit_tokens += step.commit
+                denoise["keep"][work.slot] = step.keep
+                denoise["limit"][work.slot] = step.limit
+                denoise["low_confidence"][work.slot] = row.blocks.low_confidence
             else:  # prefill / finish
                 ids[span] = row.tokens[work.pos0 : work.pos0 + work.count]
                 pos[span] = np.arange(
@@ -1265,9 +1393,11 @@ class Scheduler:
             q_start[work.slot] = work.start
             q_count[work.slot] = work.count
             # first sampled position: the last NON-draft token (a verify
-            # row samples it and every draft after it)
+            # row samples it and every draft after it), or the first
+            # position of a denoising row's block
             sample_start[work.slot] = (
-                work.start + work.count - 1 - work.spec_len
+                work.start + work.block.commit if work.kind == "block"
+                else work.start + work.count - 1 - work.spec_len
             )
             spec_len[work.slot] = work.spec_len
             # optimistic: every draft accepted; the program corrects the
@@ -1284,7 +1414,7 @@ class Scheduler:
             ids=ids, rows=rows, pos=pos, valid=valid, in_row=in_row,
             from_prev=from_prev, q_start=q_start, q_count=q_count,
             sample_start=sample_start, spec_len=spec_len, temp=temp,
-            top_p=top_p, kv_len=kv_len, wide=wide,
+            top_p=top_p, kv_len=kv_len, denoise=denoise, wide=wide,
             counts={
                 "prefill_tokens": prefill_tokens,
                 "kv_pages_walked": int(pages.sum()),
@@ -1307,8 +1437,19 @@ class Scheduler:
                 # carries a draft.  sched/mixed.py branches on the same
                 # spec_len; this restates the predicate on the host and
                 # does not observe which branch the device took
-                "sampled_rows": b * (self.width if wide else 1),
+                # (a model that denoises blocks: the block's positions a
+                # slot, every step)
+                "sampled_rows": b * (
+                    self._block or (self.width if wide else 1)
+                ),
                 "passes": self._passes,
+                # rows in a denoising step, and the query tokens among
+                # theirs that only rewrite a finished block's keys; None
+                # for a model that does not denoise
+                "block_rows": plan.decode_rows if self._block else None,
+                "commit_tokens": commit_tokens if self._block else None,
+                # valid tokens the step's layers route to experts
+                "moe_tokens": plan.tokens_planned if self._moe else None,
             },
             qk_pairs=pairs,
         )
@@ -1335,8 +1476,16 @@ class Scheduler:
             )
             self._staged_tables.clear()
         if self._latest is None:
-            self._latest = jnp.zeros((g.max_slots,), jnp.int32)
-        new_paged, toks, accept, latest, rng = self._get_fn()(
+            # each slot's freshest token, or for a model that denoises
+            # blocks its block as the last step left it
+            self._latest = jnp.zeros(
+                (g.max_slots, self._block) if self._block else (g.max_slots,),
+                jnp.int32,
+            )
+        extra = ()
+        if p.denoise is not None:
+            extra = ({k: jnp.asarray(v) for k, v in p.denoise.items()},)
+        new_paged, toks, accept, latest, rng, *moe = self._get_fn()(
             g.params, paged,
             jnp.asarray(p.ids), jnp.asarray(p.rows), jnp.asarray(p.pos),
             jnp.asarray(p.valid), jnp.asarray(p.in_row),
@@ -1344,7 +1493,7 @@ class Scheduler:
             jnp.asarray(p.kv_len),
             self._latest, jnp.asarray(p.from_prev),
             jnp.asarray(p.sample_start), jnp.asarray(p.spec_len),
-            g._rng, jnp.asarray(p.temp), jnp.asarray(p.top_p),
+            g._rng, jnp.asarray(p.temp), jnp.asarray(p.top_p), *extra,
         )
         dispatch_t = g.step_clock.now()
         g.paged_cache = new_paged
@@ -1364,6 +1513,8 @@ class Scheduler:
                 row.pend_gen += 1
             elif work.kind == "verify":
                 row.pend_spec = True
+            elif work.kind == "block":
+                row.blocks.pend += 1
             elif work.kind == "finish":
                 row.pend_pos += work.count
                 row.pend_gen += 1  # the chunk's first sampled token
@@ -1371,7 +1522,7 @@ class Scheduler:
                 row.pend_pos += work.count
         return _InFlight(
             plan=plan, toks=toks, accept=accept, dispatch_t=dispatch_t,
-            counts=packed.counts,
+            counts=packed.counts, moe=moe[0] if moe else None,
         )
 
     # -- commit --------------------------------------------------------
@@ -1479,12 +1630,21 @@ class Scheduler:
                     continue
                 if work.kind == "verify":
                     accepted += int(accept[work.slot]) + 1
+                elif work.kind == "block":
+                    # the positions the step kept, a bit each
+                    accepted += int(accept[work.slot]).bit_count()
                 elif work.kind in ("decode", "finish"):
                     accepted += 1
             # rows are charged the interval so far (their own commit, a
             # per cent of a step, is still to come)
             elapsed_ms = clock.elapsed_ms()
             outcomes.extend(self._commit(plan, toks, accept, elapsed_ms))
+            experts = {"moe_experts_hit": None, "moe_assign_max": None}
+            if entry.moe is not None:
+                hit, fullest = np.asarray(entry.moe)
+                experts = {"moe_experts_hit": int(hit), "moe_assign_max": int(fullest)}
+            if self._block:
+                self.metrics.incr("unmasked_tokens", accepted)
         commit_t = clock.now()
         clock.add("commit", (commit_t - fetch_t) * 1e3)
         record = clock.observe(
@@ -1498,6 +1658,9 @@ class Scheduler:
             cached_tokens=(
                 plan.cached_tokens if self._kvstore is not None else None
             ),
+            # answer tokens the step's denoising rows kept
+            unmasked_tokens=accepted if self._block else None,
+            **experts,
             **entry.counts,
         )
         if plan.decode_rows and not plan.prefill_rows:
@@ -1507,8 +1670,10 @@ class Scheduler:
             # up to `chunk` prefill tokens' compute — folding that in
             # would make deadline clamping over-truncate every admission.
             # The record's wall: dispatch -> fetch spans two device steps
-            # at depth 2
-            self.metrics.record("decode_step", record.wall_ms)
+            # at depth 2.  A denoising step keeps several tokens a row:
+            # its wall is spread over what a row of it kept
+            per_row = accepted / plan.decode_rows if self._block and accepted else 1.0
+            self.metrics.record("decode_step", record.wall_ms / per_row)
 
     def _push_token(self, row: _Row, token: int) -> Optional[str]:
         """Append one committed token; returns the finish reason when
@@ -1524,6 +1689,39 @@ class Scheduler:
             # the NEXT decode token would write past the sequence cap
             return "length"
         return None
+
+    def _commit_block(
+        self, row: _Row, work: RowWork, toks: np.ndarray, accept: np.ndarray,
+    ) -> Optional[str]:
+        """Commit one denoising step of ``row``: the positions whose bit
+        ``accept`` has set take their token of ``toks`` (the block after
+        the step) and never change again; a position is streamed once
+        everything left of it is kept.  Returns the finish reason when the
+        answer is whole (``max_tokens`` positions: the last block's
+        positions past it were never unmasked) or met EOS."""
+        g = self.generator
+        blocks, step = row.blocks, work.block
+        blocks.pend -= 1
+        blocks.done += 1
+        if not row.started:
+            # the row's first step: the prompt is in the pool
+            row.started = time.perf_counter()
+            row.decode_cum0 = g.step_clock.decode_cum_ms
+            self.metrics.record("prefill", row.prefill_ms)
+        first = step.start - row.prompt_len  # the block's place in the answer
+        bits = int(accept[work.slot])
+        for j in range(step.size):
+            if bits >> j & 1:
+                blocks.answer[first + j] = int(toks[work.slot, j])
+        finished = None
+        while (
+            finished is None
+            and len(row.generated) < len(blocks.answer)
+            and blocks.answer[len(row.generated)] is not None
+        ):
+            finished = self._push_token(row, blocks.answer[len(row.generated)])
+            self._decode_committed += 1
+        return finished
 
     def _commit(
         self, plan: StepPlan, toks: np.ndarray, accept: np.ndarray,
@@ -1555,6 +1753,10 @@ class Scheduler:
                         row.chunked = True
                         self.metrics.incr("sched_chunked_prefill")
                     continue
+                if row.blocks is not None:
+                    # the prompt's whole blocks are written; the chunk
+                    # sampled nothing: the first block's steps follow
+                    continue
                 # prompt completed THIS step: the sampled token is the
                 # row's first generated token (wave-engine semantics:
                 # the prefill-sampled token counts toward max_tokens)
@@ -1573,6 +1775,11 @@ class Scheduler:
                 row.pend_gen -= 1
                 finished = self._push_token(row, int(toks[work.slot, 0]))
                 self._decode_committed += 1
+            elif work.kind == "block":
+                streamed = len(row.generated)
+                finished = self._commit_block(row, work, toks, accept)
+                if finished is None and len(row.generated) == streamed:
+                    continue  # nothing new to stream
             else:  # verify
                 row.pend_spec = False
                 a = int(accept[work.slot])

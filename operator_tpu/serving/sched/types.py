@@ -38,6 +38,92 @@ class SchedConfig:
     spec_lookup_k: int = 4
 
 
+@dataclass(frozen=True)
+class BlockStep:
+    """One denoising step of a row, as the schedule fixes it: step
+    ``step`` of block ``index``, whose ``size`` positions begin at
+    ``start``.  The step keeps ``keep`` of the block's masked positions
+    below ``limit`` (those at or past it lie beyond ``max_tokens`` and
+    stay masked).  A block's first step is led by ``commit`` query tokens,
+    the clean ids of the block before, which only write that block's
+    final keys."""
+
+    index: int
+    step: int
+    start: int
+    size: int
+    keep: int
+    limit: int
+    commit: int
+
+    @property
+    def count(self) -> int:
+        """Query tokens the step packs."""
+        return self.commit + self.size
+
+
+@dataclass
+class BlockSchedule:
+    """A request of a model that denoises blocks (models/sdar.py), as a
+    function of the prompt's length, ``max_tokens``, the block's length
+    and the request's ``denoise_steps`` alone: the whole blocks of the
+    prompt are prefilled (``prefill_len``), its tail opens the first
+    generated block already clean, and block ``k`` takes ``ceil(masked /
+    per_step)`` steps, where a whole block has ``size`` masked positions,
+    the first ``size`` less the tail's, and the last only those below
+    ``prompt_len + max_tokens``.  Every count the host packs follows, so a
+    step can be planned while the ones before it are still on the device.
+
+    ``done`` / ``pend`` are the row's place in it (steps committed, steps
+    in flight); ``answer`` holds the answer positions kept so far (None
+    where one is still masked): a position is streamed once everything to
+    its left is kept."""
+
+    size: int
+    per_step: int
+    prompt_len: int
+    max_tokens: int
+    low_confidence: bool
+    done: int = 0
+    pend: int = 0
+    answer: list = field(default_factory=list)
+
+    @property
+    def prefill_len(self) -> int:
+        return self.prompt_len - self.prompt_len % self.size
+
+    def _block(self, index: int) -> tuple[int, int, int]:
+        """``(start, clean positions at its start, limit)`` of a block."""
+        start = self.prefill_len + index * self.size
+        clean = self.prompt_len - self.prefill_len if index == 0 else 0
+        limit = min(self.size, self.prompt_len + self.max_tokens - start)
+        return start, clean, limit
+
+    def at(self, number: int) -> Optional[BlockStep]:
+        """Step ``number`` of the request (0-based), or None past its end."""
+        _, clean, limit = self._block(0)
+        first = -(-(limit - clean) // self.per_step)  # the first block's steps
+        index, step = 0, number
+        if number >= first:
+            whole = self.size // self.per_step
+            index = 1 + (number - first) // whole
+            step = (number - first) % whole
+        start, clean, limit = self._block(index)
+        left = limit - clean - step * self.per_step  # masked when the step begins
+        if left <= 0:
+            return None
+        return BlockStep(
+            index=index, step=step, start=start, size=self.size,
+            keep=min(self.per_step, left), limit=limit,
+            commit=self.size if step == 0 and index > 0 else 0,
+        )
+
+    @property
+    def next(self) -> Optional[BlockStep]:
+        """The next step to plan: past everything committed or in flight."""
+        return self.at(self.done + self.pend)
+
+
 @dataclass
 class _Row:
     """One live row of the running wave: a request at an arbitrary
@@ -82,14 +168,24 @@ class _Row:
     #: block hashes this row holds references on (acquired at admission
     #: + blocks it donated at prefill completion); released on finish
     cached_hashes: list[bytes] = field(default_factory=list)
+    #: the request's denoising schedule, for a model that denoises blocks
+    #: (models/sdar.py); None for a row that commits a token a step
+    blocks: Optional[BlockSchedule] = None
 
     @property
     def prompt_len(self) -> int:
         return len(self.tokens)
 
     @property
+    def prefill_len(self) -> int:
+        """Prompt tokens the prefill writes: all of them, or for a row
+        that denoises blocks the prompt's whole blocks (its tail opens
+        the first generated block)."""
+        return self.prompt_len if self.blocks is None else self.blocks.prefill_len
+
+    @property
     def decoding(self) -> bool:
-        return self.pos >= self.prompt_len
+        return self.pos >= self.prefill_len
 
     @property
     def kv_len(self) -> int:
@@ -108,7 +204,7 @@ class _Row:
 
     @property
     def pred_decoding(self) -> bool:
-        return self.pred_pos >= self.prompt_len
+        return self.pred_pos >= self.prefill_len
 
     @property
     def pred_gen(self) -> int:
@@ -136,7 +232,7 @@ class RowWork:
     req_id: int
     start: int  # flat offset of the row's first token this step
     count: int
-    kind: str  # "prefill" | "finish" | "decode" | "verify"
+    kind: str  # "prefill" | "finish" | "decode" | "verify" | "block"
     #: absolute position of the row's first token this step (prefill:
     #: the predicted prompt offset; decode/verify: the predicted kv len)
     pos0: int = 0
@@ -147,6 +243,9 @@ class RowWork:
     #: (chained decode) — the packed id is a placeholder the program
     #: replaces with its carried ``latest`` buffer
     from_prev: bool = False
+    #: a "block" row's denoising step (its ``start`` - ``commit`` is
+    #: ``pos0``, its ``count`` is ``count``)
+    block: Optional[BlockStep] = None
 
 
 @dataclass

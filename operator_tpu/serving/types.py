@@ -58,6 +58,40 @@ class SamplingParams:
     #: engine's per-class SLOBoard buckets attainment + goodput by it and
     #: /healthz carries the rollup.  None = the board's "default" bucket.
     slo_class: Optional[str] = None
+    #: for a model that denoises blocks of positions (``block_length``,
+    #: models/sdar.py): steps a block takes, each keeping ``block_length /
+    #: denoise_steps`` of its positions (a divisor of the block's length;
+    #: None = one position a step), and which a step keeps (``REMASK_RULES``:
+    #: those whose token had the highest probability among the sampler's
+    #: candidates, or the leftmost).  Refused at submit for a model that
+    #: does not denoise (:func:`check_denoise`)
+    denoise_steps: Optional[int] = None
+    remask: str = "low_confidence"
+
+
+#: which of a block's masked positions a denoising step keeps
+REMASK_RULES = ("low_confidence", "sequential")
+
+
+def check_denoise(params: SamplingParams, block_length: int) -> None:
+    """Raise ``ValueError`` unless ``params``' denoising fields suit a
+    model of ``block_length`` positions a block (0: a model that commits a
+    token a row a step, which takes neither field)."""
+    if not block_length:
+        if params.denoise_steps is not None or params.remask != REMASK_RULES[0]:
+            raise ValueError(
+                "denoise_steps and remask are for a model that denoises "
+                "blocks of positions; this one commits one token a row a step"
+            )
+        return
+    steps = block_length if params.denoise_steps is None else params.denoise_steps
+    if steps < 1 or block_length % steps:
+        raise ValueError(
+            f"denoise_steps={steps} does not divide the model's block of "
+            f"{block_length} positions"
+        )
+    if params.remask not in REMASK_RULES:
+        raise ValueError(f"remask={params.remask!r}: expected one of {REMASK_RULES}")
 
 
 @dataclass

@@ -33,6 +33,12 @@ CPU host finds them before chip time is spent.  Covered:
 - the whole mixed step of a model whose stack runs several times a token
   (``ouro-2.6b``, 10 slots, 112 pages of 192 planes: the pass loop round
   the layer loop), and the ragged kernel alone at its 16 MHA heads;
+- the grouped expert product (``ops/moe_experts.py``) alone at the
+  sparse-expert cell's sizes, the ragged kernel at its 32 / 4 heads under
+  the block-causal mask, and the whole mixed step of ``sdar-30b-a3b-12l``
+  (128 slots, 1,024 tokens, 4,096 pages, the denoising tail): the expert
+  stacks go into the kernel whole, so the temporaries hold no layer's
+  copy of one;
 - for each whole mixed step, ``kv_pool``: the stacked KV pools' bytes and
   the names of the optimised HLO's instructions that MOVE a pool — a
   ``copy``, ``dynamic-slice`` or ``dynamic-update-slice`` (or a fusion that
@@ -161,6 +167,9 @@ def _mixed_step_case(topo_device, model_id=None, slots=_SLOTS, t_budget=None,
         sample = staticmethod(
             functools.partial(sampler.sample, top_k=sampler.SAMPLE_TOP_K)
         )
+        sample_confident = staticmethod(functools.partial(
+            sampler.sample_with_confidence, top_k=sampler.SAMPLE_TOP_K
+        ))
 
     generator = _Shapes()
     generator.config = config
@@ -182,12 +191,23 @@ def _mixed_step_case(topo_device, model_id=None, slots=_SLOTS, t_budget=None,
     )
     flat_i, flat_b = shaped((t,), jnp.int32), shaped((t,), jnp.bool_)
     slot_i, slot_f = shaped((slots,), jnp.int32), shaped((slots,), jnp.float32)
+    # a model that denoises blocks carries each slot's block, and is told
+    # per slot what its step keeps (sched/mixed.py)
+    block = int(getattr(config, "block_length", 0))
+    latest = shaped((slots, block), jnp.int32) if block else slot_i
+    denoise = ()
+    if block:
+        denoise = ({
+            "keep": slot_i, "limit": slot_i,
+            "low_confidence": shaped((slots,), jnp.bool_),
+        },)
     args = (
         params, paged,
         flat_i, flat_i, flat_i, flat_b, flat_i,  # ids rows pos valid in_row
-        slot_i, slot_i, slot_i, slot_i, flat_b,  # q_start q_count kv_len latest from_prev
+        slot_i, slot_i, slot_i, latest, flat_b,  # q_start q_count kv_len latest from_prev
         slot_i, slot_i,  # sample_start spec_len
         shaped((2,), jnp.uint32), slot_f, slot_f,  # rng temp top_p
+        *denoise,
     )
     return (
         make_mixed_fn(generator, t, _CHUNK, spec_width=spec_width), args,
@@ -369,6 +389,8 @@ def main() -> int:
             fn = _ragged_attention_pallas
             if config.sliding_window is not None:
                 fn = functools.partial(fn, sliding_window=config.sliding_window)
+            if getattr(config, "block_length", 0):
+                fn = functools.partial(fn, attend_block=config.block_length)
             ragged_case(f"ragged_{name}_c{chunk}", fn, ragged_args(*geometry, chunk))
     # the kernel alone at the benchmark's cells (BENCHMARK.json: heads
     # x slots, bf16 pool, page 64, chunk 64): both rungs of the query tile
@@ -385,6 +407,12 @@ def main() -> int:
             f"ragged_cell_{tag}_b{slots}", _ragged_attention_pallas,
             ragged_args(heads, kv_heads, 128, _CHUNK, rows=slots),
         )
+    # eight query heads a kv head under the block-causal mask (blocks of 4)
+    ragged_case(
+        "ragged_cell_sdar-30b-a3b_b128",
+        functools.partial(_ragged_attention_pallas, attend_block=4),
+        ragged_args(32, 4, 128, _CHUNK, rows=128),
+    )
     # a window that actually bites inside max_seq (Mistral's 4096 is wider
     # than the serving cap, so its first-page term folds to zero above)
     ragged_case(
@@ -414,6 +442,30 @@ def main() -> int:
         ),
     ))
 
+    # the grouped expert product alone at the benchmark's cell: 1,024 flat
+    # tokens routed 8 ways over a layer's 128 int8 experts of 2048 x 768,
+    # the stacks whole (12 layers) and the layer a prefetched scalar
+    from operator_tpu.ops.moe_experts import _moe_experts_pallas
+
+    sdar = _REGISTRY["sdar-30b-a3b-12l"]
+    m_tokens, m_top = 1024, sdar.num_experts_per_tok
+
+    def expert_stack(rows, cols):
+        lead = (sdar.num_layers, sdar.num_experts)
+        return {"q": shaped((*lead, rows, cols), jnp.int8), "s": shaped((*lead, cols), jnp.float32)}
+
+    cases.append((
+        "moe_experts_cell_sdar-30b-a3b_t1024", _moe_experts_pallas,
+        (
+            shaped((m_tokens, sdar.hidden_size), jnp.bfloat16),
+            shaped((m_tokens, m_top), jnp.int32), shaped((m_tokens, m_top), jnp.float32),
+            expert_stack(sdar.hidden_size, sdar.moe_intermediate_size),
+            expert_stack(sdar.hidden_size, sdar.moe_intermediate_size),
+            expert_stack(sdar.moe_intermediate_size, sdar.hidden_size),
+            shaped((), jnp.int32),
+        ),
+    ))
+
     # whole programs: the dispatchers must pick the kernels although the
     # HOST backend is the CPU — the compile target is the TPU topology
     from operator_tpu.ops import _dispatch
@@ -439,13 +491,24 @@ def main() -> int:
             ("mixed_step_ouro-2.6b_b10", dict(
                 model_id="ouro-2.6b", slots=10, kv_pages=112,
             )),
+            # the sparse-expert cell: 12 layers of 128 int8 experts (7.25
+            # GB), 128 slots of two blocks of 4, the denoising tail, the
+            # pool at its worst case (AOT_SDAR_KV_PAGES sizes another)
+            ("mixed_step_sdar-30b-a3b-12l_b128", dict(
+                model_id="sdar-30b-a3b-12l", slots=128, t_budget=m_tokens,
+                kv_pages=int(os.environ.get("AOT_SDAR_KV_PAGES", "4096")),
+                spec_width=1,
+            )),
         ):
             fn, args, pools[name] = _mixed_step_case(topo.devices[0], **cell)
             cases.append((name, fn, args))
         if len(topo.devices) >= 4:
             cases.append(("mesh_tp4_paged_decode", *_mesh_decode_case(topo.devices[:4])))
 
+        only = os.environ.get("AOT_TPU_ONLY")  # a substring: those cases alone
         for name, fn, args in cases:
+            if only and only not in name:
+                continue
             try:
                 # a jitted step is lowered as it is called: a second
                 # jax.jit around it would drop its donation

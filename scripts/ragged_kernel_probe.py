@@ -39,6 +39,8 @@ from operator_tpu.ops import ragged_attention as ours  # noqa: E402
 SHAPES = {
     "1.5b": (12, 2, 128), "7b": (28, 4, 32),
     "ouro": (16, 16, 10), "falcon": (20, 4, 128),
+    # with --attend-block 4 --row-queries 1,4,8: rows that denoise a block
+    "sdar": (32, 4, 128),
 }
 PAGE, HEAD_DIM, CHUNK, LAYERS = 64, 128, 64, 2
 
@@ -91,6 +93,7 @@ def parity(kernel, heads, kv_heads, blocks):
         want = ours.ragged_attention_reference(
             *(x.astype(jnp.float32) for x in (q, k, v)), table,
             jnp.asarray(kv_len), jnp.asarray(q_count), jnp.int32(1),
+            **{k_: v_ for k_, v_ in blocks.items() if k_ == "attend_block"},
         )
     live = np.arange(CHUNK)[None, :] < q_count[:, None]
     g, w = np.asarray(got, np.float32)[live], np.asarray(want)[live]
@@ -140,6 +143,10 @@ def main() -> None:
     ap.add_argument("--chunk-pages", default="1,2,4,5,8,9,16,17")
     ap.add_argument("--calls", type=int, default=56)
     ap.add_argument("--parent", default=None)
+    ap.add_argument("--attend-block", type=int, default=1,
+                    help="the block-causal mask's block (models/sdar.py); 1 = causal")
+    ap.add_argument("--row-queries", default="1",
+                    help="queries of a row on the small tile, one fit for each")
     ap.add_argument("--slots", type=int, default=None)
     ap.add_argument("--interpret", action="store_true",
                     help="a CPU rehearsal of the script: its times mean nothing")
@@ -162,6 +169,8 @@ def main() -> None:
     )
     variants = []
     extra = {"interpret": True} if args.interpret else {}
+    if args.attend_block != 1:
+        extra["attend_block"] = args.attend_block
     for name in args.blocks.split(","):
         blocks = chosen if name == "auto" else tuple(
             int(n) for n in name.split("x")
@@ -181,6 +190,11 @@ def main() -> None:
         us_a_call = timer(kernel, case, args.calls, blocks)
         # the last page holds 47 of its 64 keys: a partial page, as a row's is
         decode = [us_a_call([p * PAGE - 17] * slots, [1] * slots) for p in pages]
+        for queries in (int(n) for n in args.row_queries.split(",") if int(n) != 1):
+            # rows of a few queries (a block, or a block led by the one before)
+            times = [us_a_call([p * PAGE - 16] * slots, [queries] * slots) for p in pages]
+            entry[f"rows_of_{queries}_us_a_call"] = dict(zip(map(str, pages), times))
+            entry[f"rows_of_{queries}_fit"] = line_fit(pages, times, slots)
         chunk = [us_a_call([p * PAGE - 17] * slots, [CHUNK] * slots)
                  for p in chunk_pages]
         idle = us_a_call([6 * PAGE - 17] * slots, [1] + [0] * (slots - 1))

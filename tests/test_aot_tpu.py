@@ -125,6 +125,8 @@ def test_every_ragged_case_names_the_kv_block_of_each_rung(record):
         "ragged_cell_qwen2.5-7b_b32": [8, 4],
         "ragged_cell_falcon-h1-34b_b128": [8, 4],
         "ragged_cell_ouro-2.6b_b10": [2, 2],
+        # eight query heads a kv head, under the block-causal mask
+        "ragged_cell_sdar-30b-a3b_b128": [8, 4],
     }, cells
 
 
@@ -163,6 +165,30 @@ def test_the_looped_models_pool_has_a_plane_a_pass_and_layer_and_fits(record):
     assert step["alias_bytes"] >= step["kv_pool"]["bytes"]
     assert step["output_bytes"] - step["alias_bytes"] < 1e6
     assert step["temp_bytes"] < 1e9, step
+    total = step["argument_bytes"] + step["output_bytes"] - step["alias_bytes"] + step["temp_bytes"]
+    assert total < 15.75 * 2**30 - 1e9
+
+
+def test_the_sparse_expert_models_step_holds_its_stacks_and_its_pool_once(record):
+    """The benchmark cell of the model with experts: the grouped product
+    alone and the whole mixed step at 128 slots of two blocks of 4.  The
+    expert stacks (7.25 GB int8) go into the kernel whole and the layer is
+    a prefetched scalar, so no layer of 604 MB is sliced out: the
+    temporaries stay far under one; the worst-case pool comes back aliased;
+    the tail has no conditional; and the step leaves the chip a GB."""
+    from operator_tpu.ops.moe_experts import KERNEL_NAME
+
+    kernels = record["kernels"]
+    alone = kernels["moe_experts_cell_sdar-30b-a3b_t1024"]
+    assert alone["ok"] and any(c.startswith(KERNEL_NAME) for c in alone["pallas_calls"]), alone
+    assert alone["temp_bytes"] < 200e6, alone  # the row gathers, no widened stack
+    step = kernels["mixed_step_sdar-30b-a3b-12l_b128"]
+    assert any(call.startswith(KERNEL_NAME) for call in step["pallas_calls"]), step
+    assert any(call.startswith("ragged_attention_kernel") for call in step["pallas_calls"])
+    assert step["kv_pool"]["shape"] == [12, 4096, 64, 4, 128]
+    assert step["kv_pool"]["moved_by"] == [] and step["conditionals"] == 0
+    assert step["alias_bytes"] >= step["kv_pool"]["bytes"]
+    assert step["argument_bytes"] > 15e9 and step["temp_bytes"] < 700e6, step
     total = step["argument_bytes"] + step["output_bytes"] - step["alias_bytes"] + step["temp_bytes"]
     assert total < 15.75 * 2**30 - 1e9
 

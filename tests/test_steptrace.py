@@ -420,15 +420,22 @@ class TestStepView:
                        occupancy=0.25, wall_ms=9.0, host_ms=0.0, wait_ms=9.0,
                        xfer_ms=0.0, kv_pages_walked=7, kv_blocks_walked=2,
                        prefill_tokens=16, q_tile_rows=64, state_rows=1,
-                       sampled_rows=20, passes=4),
+                       sampled_rows=20, passes=4, block_rows=3, unmasked_tokens=6,
+                       commit_tokens=8, moe_tokens=16, moe_experts_hit=31,
+                       moe_assign_max=9),
         ])
         lines = table.splitlines()
         assert lines[0].split() == [
             "seq", "kind", "tok", "pf_tok", "slots", "occ",
             "wall_ms", "host_ms", "wait_ms", "xfer_ms",
-            "plan", "pack", "commit", "turn", "passes",
+            "plan", "pack", "commit", "turn",
+            "blk_rows", "unmask", "cmt_tok", "moe_tok", "exp_hit", "exp_max", "passes",
             "st_rows", "smp_rows", "kv_pg", "pg_blk", "q_fill", "mfu",
         ]
+        # a denoising step's rows, what they kept and its commit tokens; the
+        # tokens routed to experts and the two counts the device brings back
+        assert lines[3].split()[-13:-7] == ["3", "6", "8", "16", "31", "9"]
+        assert lines[2].split()[-13:-7] == ["-"] * 6
         # passes a token took through the layer stack; "-" where not said
         assert lines[3].split()[-7] == "4" and lines[2].split()[-7] == "-"
         # slots whose recurrent state the step touched; "-" without such state
