@@ -9,16 +9,62 @@ binds ``top_k`` (``serving/runtime.py``).
 from __future__ import annotations
 
 #: nucleus-sampling candidate-set size (constructor: ``sample_top_k``).
-#: A full-vocab ``top_k`` is a 32k-128k element sort on the TPU vector
-#: units EVERY decode step, so sampling is truncated to the top-k
-#: candidates FIRST and the top-p cutoff computed within them — i.e.
-#: the served distribution is top-k AND top-p composed, the standard
-#: serving trade.  At this system's temperatures (0.3 default,
-#: aiprovider-crd.yaml:56-58) the top-64 hold ~all the nucleus mass; at
-#: temperatures ~1+ the truncation measurably narrows diversity vs true
-#: nucleus sampling — raise sample_top_k (e.g. 256) if that matters
-#: more than decode latency.
+#: The contract: a row is sampled from its ``SAMPLE_TOP_K`` largest
+#: temperature-scaled logits by value, the lowest id first among equals,
+#: and the top-p cutoff is computed inside them, in float32 — i.e. the
+#: served distribution is top-k AND top-p composed, the standard serving
+#: trade.  The selection is exact (:func:`_top_k`): it ranks only the
+#: vocabulary blocks that can hold a candidate, so a step pays one read
+#: of the row and a sort of ``top_k`` blocks, not a sort of the row.
+#: At this system's temperatures (0.3 default, aiprovider-crd.yaml:56-58)
+#: the top-64 hold ~all the nucleus mass; at temperatures ~1+ the
+#: truncation measurably narrows diversity vs true nucleus sampling —
+#: raise sample_top_k (e.g. 256) if that matters more than decode
+#: latency (the sort grows with it: ``top_k`` blocks of ``_LANE``).
 SAMPLE_TOP_K = 64
+
+#: columns of a vocabulary block: the TPU's lane width, which divides
+#: every served vocabulary (151,936 = 1187 x 128; 256 does not)
+_LANE = 128
+#: a row of fewer than this many times ``k`` blocks is sorted whole: the
+#: pruned form would rank most of it anyway
+_MIN_BLOCKS_PER_K = 4
+
+
+def _top_k(scaled, k: int):
+    """``jax.lax.top_k(scaled, k)`` over the last axis of ``[B, V]``,
+    bit for bit (values, ids, the lowest id first among equal values),
+    without sorting the row where its static shape allows.
+
+    The row is viewed as contiguous blocks of ``_LANE`` columns.  The
+    ``k`` blocks with the largest maxima hold every candidate: their
+    ``k``-th maximum is a lower bound of the row's ``k``-th value (``k``
+    elements stand at or above it), every element above it lies in one
+    of them, and among blocks whose maximum equals it ``lax.top_k``
+    keeps the lowest, as it would among the elements.  Those blocks,
+    gathered in ascending order so that equal values keep their order
+    by id, are what is sorted.  No fallback at run time: which form a
+    program holds follows from ``V`` and ``k`` alone.
+    """
+    import jax
+    import jax.numpy as jnp
+
+    rows, vocab = scaled.shape
+    blocks = vocab // _LANE
+    if vocab % _LANE or blocks < _MIN_BLOCKS_PER_K * k:
+        return jax.lax.top_k(scaled, k)
+    # [blocks, B, lane]: what the chip's gather reads its rows from, and
+    # its maxima, [blocks, B], lie as the chip's sort wants them (rows on
+    # the lanes): one re-laid-out copy of the row, not two (PERF.md §6)
+    blocked = scaled.reshape(rows, blocks, _LANE).transpose(1, 0, 2)
+    _, block_ids = jax.lax.top_k(jnp.max(blocked, axis=-1).T, k)
+    block_ids = jnp.sort(block_ids, axis=-1)  # [B, k], ascending
+    candidates = jnp.take_along_axis(blocked, block_ids.T[:, :, None], axis=0)
+    values, place = jax.lax.top_k(
+        candidates.transpose(1, 0, 2).reshape(rows, k * _LANE), k
+    )
+    block_of = jnp.take_along_axis(block_ids, place // _LANE, axis=-1)
+    return values, block_of * _LANE + place % _LANE
 
 
 def _nucleus(logits, temp, top_p, top_k: int):
@@ -31,7 +77,7 @@ def _nucleus(logits, temp, top_p, top_k: int):
     safe_temp = jnp.maximum(temp, 1e-4)[:, None]
     scaled = logits.astype(jnp.float32) / safe_temp
     k = min(top_k, logits.shape[-1])
-    top_logits, top_idx = jax.lax.top_k(scaled, k)
+    top_logits, top_idx = _top_k(scaled, k)
     probs = jax.nn.softmax(top_logits, axis=-1)
     cumulative = jnp.cumsum(probs, axis=-1) - probs  # exclusive prefix
     keep = cumulative < top_p[:, None]  # first token always kept

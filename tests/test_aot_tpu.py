@@ -147,9 +147,11 @@ def test_the_recurrent_models_step_compiles_with_its_state_pool_held_once(record
     state_bytes = 6 * 128 * 32 * 256 * 128 * 4
     assert step["alias_bytes"] > state_bytes  # the state and the KV pool, in place
     assert step["output_bytes"] - step["alias_bytes"] < 1e6
-    # 137 MB as found: no pool of either kind (the smallest, one KV pool,
-    # is 604 MB) is held a second time while the step runs
-    assert step["temp_bytes"] < 200e6, step
+    # 270 MB as found: the float32 logits and the sampler's one re-laid-out
+    # copy of them (134 MB each; serving/sampler.py); no pool of either
+    # kind (the smallest, one KV pool, is 604 MB) is held a second time
+    # while the step runs
+    assert step["temp_bytes"] < 300e6, step
     total = step["argument_bytes"] + step["output_bytes"] - step["alias_bytes"] + step["temp_bytes"]
     assert total < 15.75 * 2**30  # fits the chip
 
