@@ -14,11 +14,15 @@ engine had work.  The interval splits three ways:
   step's token array (the ONE sync the loop was about to perform anyway,
   so the clock adds zero new host syncs — GL001-gated): the host's slack
 - ``xfer_ms``  — the sampled-token device→host fetch
-- ``host_ms``  — the rest: what the host needed.  Its named parts are
-  ``plan_ms`` (scheduling + admission), ``pack_ms`` (packing the flat
-  token axis and enqueueing the program), ``commit_ms`` (row commits,
-  offload drains, outcomes) and ``turn_ms`` (between two ``step()``
-  calls while work was pending: the event loop's turn)
+- ``host_ms``  — the rest: what the host needed.  Its named parts
+  (:data:`HOST_PARTS`) TILE it — every instant of the interval belongs
+  to the part the worker was in: ``plan_ms`` (scheduling + admission),
+  ``pack_ms`` (packing the flat token axis), ``put_ms`` (the packed
+  arrays' host-to-device puts), ``launch_ms`` (the call of the compiled
+  step and the bookkeeping after it), ``commit_ms`` (row commits, offload
+  drains, outcomes, up to the next stamp; ``wake_ms`` of it inside the
+  ``wakeups`` hand-overs to the event loop) and ``turn_ms`` (between two
+  ``step()`` calls while work was pending: the event loop's turn)
 
 ``host_ms + wait_ms + xfer_ms == wall_ms`` by construction, so the
 attribution fractions always total 1.0; the analytic flops-per-token
@@ -28,6 +32,17 @@ pipelining (depth 2) the phases inside one interval belong to two step
 numbers — the commit of step N and the plan + dispatch of step N+2's
 predecessor — the record is of the interval, not of one dispatch.
 
+Beside the split a record says what else the PROCESS did in its
+interval, as differences of four monotonic cumulatives read at every
+commit: ``cpu_ms`` (the worker thread's own CPU time, less what it used
+inside the device wait), ``proc_cpu_ms`` (every thread's), ``gc_ms`` /
+``gc_gen2`` (the collector's pauses, and how many were of the oldest
+generation) and ``compile_ms`` (XLA compiles); and ``delivered`` /
+``deliver_lag_ms`` / ``deliver_lag_max_ms``: the streamed snapshots that
+reached the event loop in the interval and how long after their commit.
+An interval far longer than its neighbours is a STALL (:func:`stall_over_ms`):
+its record is marked and kept apart, where ordinary steps do not evict it.
+
 The ring is host-side bookkeeping only and is never reachable from a
 compiled program; ``STEP_RING_CAPACITY`` bounds it (default 512 steps).
 """
@@ -36,6 +51,7 @@ from __future__ import annotations
 
 import os
 import threading
+import statistics
 from dataclasses import dataclass
 from typing import Iterable, Optional, Sequence
 
@@ -44,6 +60,16 @@ from typing import Iterable, Optional, Sequence
 STEP_KINDS = ("prefill", "decode", "mixed")
 
 _DEFAULT_CAPACITY = 512
+
+#: the named parts of ``host_ms``, in the order the worker passes through
+#: them; they tile it.  The ONE list the clock, the record's fields, the
+#: attribution summary and the rendering read
+HOST_PARTS = ("plan", "pack", "put", "launch", "commit", "turn")
+
+#: a stall is an interval over BOTH this wall and this many times the
+#: median of recent walls
+STALL_MIN_MS = 50.0
+STALL_TIMES_MEDIAN = 3.0
 
 
 def _env_capacity(default: int = _DEFAULT_CAPACITY) -> int:
@@ -70,12 +96,30 @@ class StepRecord:
     host_ms: float  # wall_ms less wait_ms and xfer_ms
     wait_ms: float  # blocked on the device (block_until_ready)
     xfer_ms: float  # sampled-token device->host fetch
-    #: named parts of ``host_ms`` (they need not sum to it: the rest is
-    #: loop glue between the stamps)
+    #: named parts of ``host_ms`` (``HOST_PARTS``): a loop that stamps
+    #: through ``StepClock.begin`` makes them sum to it
     plan_ms: float = 0.0
     pack_ms: float = 0.0
+    put_ms: float = 0.0
+    launch_ms: float = 0.0
     commit_ms: float = 0.0
     turn_ms: float = 0.0
+    #: of ``commit_ms``: inside the ``wakeups`` calls that hand a row's
+    #: tokens to the event loop
+    wake_ms: float = 0.0
+    #: what the process did in the interval (0.0 where nobody measured):
+    #: the worker thread's CPU time less its CPU time inside the device
+    #: wait, every thread's CPU time, the collector's pauses, XLA compiles
+    cpu_ms: float = 0.0
+    proc_cpu_ms: float = 0.0
+    gc_ms: float = 0.0
+    compile_ms: float = 0.0
+    #: streamed snapshots that reached the event loop in the interval:
+    #: the sum and the largest of their ages since their commit began
+    deliver_lag_ms: float = 0.0
+    deliver_lag_max_ms: float = 0.0
+    #: the interval was a stall by the clock's rule when it was recorded
+    stall: bool = False
     #: work counts taken where the step's arrays are packed; None on
     #: engines that do not distinguish.  ``prefill_tokens`` of ``tokens``
     #: were prompt tokens (the rest decode and verify tokens)
@@ -139,6 +183,12 @@ class StepRecord:
     #: so MFU stays honest on compute actually performed; None on
     #: engines without a prefix cache
     cached_tokens: Optional[int] = None
+    #: hand-overs to the event loop in the commit, snapshots the loop
+    #: took in the interval, collections of the oldest generation that
+    #: ended in it; None on a bare ring
+    wakeups: Optional[int] = None
+    delivered: Optional[int] = None
+    gc_gen2: Optional[int] = None
 
     @property
     def total_ms(self) -> float:
@@ -156,6 +206,8 @@ class StepRecord:
             out[name] = round(getattr(self, name), 4)
         if self.mfu is not None:
             out["mfu"] = round(self.mfu, 6)
+        if self.stall:
+            out["stall"] = True
         for name in _COUNT_FIELDS:
             if getattr(self, name) is not None:
                 out[name] = getattr(self, name)
@@ -170,6 +222,7 @@ class StepRecord:
             slots=int(data.get("slots", 0)),
             occupancy=float(data.get("occupancy", 0.0)),
             mfu=(float(data["mfu"]) if data.get("mfu") is not None else None),
+            stall=bool(data.get("stall", False)),
             **{name: float(data.get(name, 0.0)) for name in _MS_FIELDS},
             **{
                 name: (int(data[name]) if data.get(name) is not None else None)
@@ -180,13 +233,16 @@ class StepRecord:
 
 _MS_FIELDS = (
     "wall_ms", "host_ms", "wait_ms", "xfer_ms",
-    "plan_ms", "pack_ms", "commit_ms", "turn_ms",
+    *(f"{part}_ms" for part in HOST_PARTS),
+    "wake_ms", "cpu_ms", "proc_cpu_ms", "gc_ms", "compile_ms",
+    "deliver_lag_ms", "deliver_lag_max_ms",
 )
 _COUNT_FIELDS = (
     "accepted", "cached_tokens", "prefill_tokens", "kv_pages_walked",
     "kv_blocks_walked", "q_tile_rows", "state_rows", "sampled_rows", "passes",
     "block_rows", "unmasked_tokens", "commit_tokens",
     "moe_tokens", "moe_experts_hit", "moe_assign_max",
+    "wakeups", "delivered", "gc_gen2",
 )
 
 
@@ -331,11 +387,12 @@ def attribution(
         "host_ms": round(host, 3),
         "wait_ms": round(wait, 3),
         "xfer_ms": round(xfer, 3),
-        # the host's named parts, summed (they need not add up to host_ms)
+        # the host's named parts, summed
         "host_parts_ms": {
             name: round(sum(getattr(r, f"{name}_ms") for r in records), 3)
-            for name in ("plan", "pack", "commit", "turn")
+            for name in HOST_PARTS
         },
+        "stalls": sum(1 for r in records if r.stall),
         "accepted_tokens": accepted_tokens,
         # prompt tokens the prefix cache spared from prefill compute
         "cached_tokens": sum(r.cached_tokens or 0 for r in records),
@@ -360,14 +417,62 @@ def attribution(
     return out
 
 
+def stall_over_ms(walls: "Sequence[float]") -> float:
+    """The wall above which an interval is a stall, from recent walls:
+    over ``STALL_MIN_MS`` and ``STALL_TIMES_MEDIAN`` times their median
+    (infinite with no walls to judge by)."""
+    if not walls:
+        return float("inf")
+    return max(STALL_MIN_MS, STALL_TIMES_MEDIAN * statistics.median(walls))
+
+
+def grown_part(record: StepRecord, typical: "Sequence[StepRecord]") -> str:
+    """The part of a stalled interval that grew: of ``HOST_PARTS`` and
+    the two waits, the one whose excess over its own median among
+    ``typical`` records is largest."""
+    def excess(part: str) -> float:
+        usual = [getattr(r, f"{part}_ms") for r in typical if r is not record]
+        return getattr(record, f"{part}_ms") - (
+            statistics.median(usual) if usual else 0.0
+        )
+
+    return max(HOST_PARTS + ("wait", "xfer"), key=excess)
+
+
+def render_stalls(records: "Sequence[StepRecord]") -> str:
+    """The stalls among ``records`` alone (``obs.view --stalls``): each
+    with the part that grew against the ordinary records beside it, and
+    what the process did meanwhile."""
+    ordinary = [r for r in records if not r.stall]
+    header = (
+        f"{'seq':>6}  {'kind':<7} {'wall_ms':>9} {'grew':<7} {'part_ms':>9} "
+        f"{'cpu_ms':>8} {'proc_cpu':>9} {'gc_ms':>8} {'gen2':>4} "
+        f"{'compile':>8} {'dlv':>4} {'lag_max':>8}"
+    )
+    lines = [header, "-" * len(header)]
+    for r in records:
+        if not r.stall:
+            continue
+        part = grown_part(r, ordinary)
+        lines.append(
+            f"{r.seq:>6}  {r.kind:<7} {r.wall_ms:>9.3f} {part:<7} "
+            f"{getattr(r, part + '_ms'):>9.3f} {r.cpu_ms:>8.3f} "
+            f"{r.proc_cpu_ms:>9.3f} {r.gc_ms:>8.3f} {r.gc_gen2 or 0:>4} "
+            f"{r.compile_ms:>8.3f} {r.delivered or 0:>4} "
+            f"{r.deliver_lag_max_ms:>8.3f}"
+        )
+    return "\n".join(lines)
+
+
 def render_steps(records: "Iterable[StepRecord]") -> str:
     """Compact fixed-width per-step timeline table (the ``obs.view
     --steps`` rendering; also readable when pasted from a black-box
-    dump)."""
+    dump).  A stall's row wears a ``*`` after its number."""
     header = (
-        f"{'seq':>5}  {'kind':<7} {'tok':>5} {'pf_tok':>6} {'slots':>5} {'occ':>5} "
+        f"{'seq':>6}  {'kind':<7} {'tok':>5} {'pf_tok':>6} {'slots':>5} {'occ':>5} "
         f"{'wall_ms':>8} {'host_ms':>8} {'wait_ms':>8} {'xfer_ms':>8} "
-        f"{'plan':>7} {'pack':>7} {'commit':>7} {'turn':>7} "
+        + "".join(f"{part:>7} " for part in HOST_PARTS)
+        + f"{'wake':>7} {'cpu':>7} {'proc':>7} {'gc':>6} {'comp':>6} {'lag':>6} "
         f"{'blk_rows':>8} {'unmask':>6} {'cmt_tok':>7} {'moe_tok':>7} {'exp_hit':>7} "
         f"{'exp_max':>7} {'passes':>6} "
         f"{'st_rows':>7} {'smp_rows':>8} {'kv_pg':>6} {'pg_blk':>6} {'q_fill':>6} "
@@ -388,11 +493,16 @@ def render_steps(records: "Iterable[StepRecord]") -> str:
         fill = f"{r.tokens / r.q_tile_rows:.3f}" if r.q_tile_rows else "-"
         pages, prompt = shown(r.kv_pages_walked), shown(r.prefill_tokens)
         state, sampled, passes = shown(r.state_rows), shown(r.sampled_rows), shown(r.passes)
+        # mean age of the snapshots the event loop took in the interval
+        lag = f"{r.deliver_lag_ms / r.delivered:.2f}" if r.delivered else "-"
         lines.append(
-            f"{r.seq:>5}  {r.kind:<7} {r.tokens:>5} {prompt:>6} {r.slots:>5} "
+            f"{r.seq:>5}{'*' if r.stall else ' '}  {r.kind:<7} {r.tokens:>5} "
+            f"{prompt:>6} {r.slots:>5} "
             f"{r.occupancy:>5.2f} {r.wall_ms:>8.3f} {r.host_ms:>8.3f} "
-            f"{r.wait_ms:>8.3f} {r.xfer_ms:>8.3f} {r.plan_ms:>7.3f} "
-            f"{r.pack_ms:>7.3f} {r.commit_ms:>7.3f} {r.turn_ms:>7.3f} "
+            f"{r.wait_ms:>8.3f} {r.xfer_ms:>8.3f} "
+            + "".join(f"{getattr(r, part + '_ms'):>7.3f} " for part in HOST_PARTS)
+            + f"{r.wake_ms:>7.3f} {r.cpu_ms:>7.3f} {r.proc_cpu_ms:>7.2f} "
+            f"{r.gc_ms:>6.2f} {r.compile_ms:>6.1f} {lag:>6} "
             # a denoising step's rows, what they kept, the tokens that only
             # rewrite a finished block's keys; the tokens routed, experts
             # given one (over the layers), the fullest expert's

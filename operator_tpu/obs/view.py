@@ -5,6 +5,7 @@
     python -m operator_tpu.obs.view dump.jsonl --all      # every tree
     python -m operator_tpu.obs.view dump.jsonl --blackbox # black-box only
     python -m operator_tpu.obs.view --steps dump.jsonl    # step timeline
+    python -m operator_tpu.obs.view --stalls dump.jsonl   # its stalls alone
     python -m operator_tpu.obs.view --slo ledger.jsonl    # SLO attainment
 
 Reads the journal written by :class:`..record.FlightRecorder` (or a
@@ -15,7 +16,10 @@ scaled to the root span — the laptop-side twin of ``GET /traces/{id}``.
 "Step clock") as a fixed-width table: the input is either a JSONL of raw
 step-record dicts, or a black-box dump whose records carry a last-N
 ``steps`` tail in their ``extra`` context (the engine attaches one
-automatically) — both are recognised line by line.
+automatically) — both are recognised line by line.  A stall's row wears
+a ``*``; ``--stalls`` prints the stalls alone (a black-box dump's
+``stalls``, which outlive the ring, among them), each with the part that
+grew and what the process did in the interval (CPU, collector, compiles).
 
 ``--slo`` renders an SLO-ledger journal (docs/OBSERVABILITY.md "SLO
 ledger"): the per-class attainment/goodput table plus the worst
@@ -31,7 +35,7 @@ import sys
 from typing import Optional
 
 from .record import FlightRecorder, TraceRecord, render_tree
-from .steptrace import StepRecord, attribution, render_steps
+from .steptrace import StepRecord, attribution, render_stalls, render_steps
 
 
 def load_steps(path: str) -> list[StepRecord]:
@@ -57,13 +61,18 @@ def load_steps(path: str) -> list[StepRecord]:
                 continue
             extra = data.get("extra")
             if isinstance(extra, dict):
-                for item in extra.get("steps") or []:
-                    if isinstance(item, dict):
-                        steps.append(StepRecord.from_dict(item))
+                tail = [s for s in extra.get("steps") or [] if isinstance(s, dict)]
+                in_tail = {s.get("seq") for s in tail}
+                # the stalls the clock kept from before the tail, then the tail
+                kept = [
+                    s for s in extra.get("stalls") or []
+                    if isinstance(s, dict) and s.get("seq") not in in_tail
+                ]
+                steps.extend(StepRecord.from_dict(s) for s in kept + tail)
     return steps
 
 
-def _print_steps(path: str) -> int:
+def _print_steps(path: str, *, stalls_only: bool = False) -> int:
     try:
         steps = load_steps(path)
     except FileNotFoundError as exc:
@@ -71,6 +80,10 @@ def _print_steps(path: str) -> int:
         return 2
     if not steps:
         print(f"no step records in {path}")
+        return 0
+    if stalls_only:
+        print(render_stalls(steps))
+        print(f"\n{sum(1 for s in steps if s.stall)} stalls in {len(steps)} steps")
         return 0
     print(render_steps(steps))
     summary = attribution(steps)
@@ -194,6 +207,10 @@ def main(argv: Optional[list[str]] = None) -> int:
                         help="render the step-clock timeline instead of "
                              "span trees (raw step JSONL or black-box "
                              "dumps with a steps tail)")
+    parser.add_argument("--stalls", action="store_true",
+                        help="of the step-clock timeline, the stalls "
+                             "alone: the part that grew, CPU, collector, "
+                             "compiles")
     parser.add_argument("--slo", action="store_true",
                         help="render an SLO-ledger journal: per-class "
                              "attainment table + worst-offender stage "
@@ -204,8 +221,8 @@ def main(argv: Optional[list[str]] = None) -> int:
     args = parser.parse_args(argv)
     if args.slo:
         return _print_slo(args.path, worst=max(0, args.worst))
-    if args.steps:
-        return _print_steps(args.path)
+    if args.steps or args.stalls:
+        return _print_steps(args.path, stalls_only=args.stalls)
     try:
         records = FlightRecorder.load(args.path)
     except FileNotFoundError as exc:

@@ -1123,15 +1123,15 @@ class BatchedGenerator(AdmissionMixin, ProgramBuilderMixin, Runtime):
             self.metrics.record(
                 "batch_occupancy", 100.0 * self.num_active / self.max_slots
             )
-            t_pack = self.step_clock.now()
+            # the wave loop's parts: everything before this is ``plan``,
+            # gathering the sampling tensors and enqueueing the block is
+            # ``launch``, processing a fetched block's tokens ``commit``
+            self.step_clock.begin("launch")
             with self._annotation(
                 "podmortem.decode",
                 [s.params for s in self.slots if s.active],
             ):
                 self._dispatch_block()
-            self.step_clock.add(
-                "pack", (self.step_clock.now() - t_pack) * 1e3
-            )
         finished: list[tuple[int, GenerationResult]] = []
         # keep at most depth-1 blocks in flight; once nothing is active the
         # leftovers are flushed (their tokens belong to finished epochs)
@@ -1203,27 +1203,20 @@ class BatchedGenerator(AdmissionMixin, ProgramBuilderMixin, Runtime):
         # method is host loop code, never reachable from a jitted
         # entry point — same legality as the asarray it times)
         clock = self.step_clock
-        t_wait = clock.now()
+        # the block may have been ready long before (pipelined depth > 1),
+        # in which case the wait is ~0
+        clock.begin("wait")
         try:
             toks.block_until_ready()
         except AttributeError:  # fake arrays in tests
             pass
-        t_ready = clock.now()
+        clock.begin("xfer")
         toks_np = np.asarray(toks)  # [K, B] — the ONE host sync per block
-        t_fetch = clock.now()
         live = len(snapshot)  # the slots live when the block was dispatched
-        clock.observe(
-            kind="decode",
-            tokens=block * live,
-            slots=live,
-            # the block may have been ready long before (pipelined
-            # depth > 1), in which case the wait is ~0
-            wait_ms=(t_ready - t_wait) * 1e3,
-            xfer_ms=(t_fetch - t_ready) * 1e3,
-            # the token-processing loop below runs AFTER the commit
-            # stamp, so its wall lands in the NEXT record's host_ms
-            commit_t=t_fetch,
-        )
+        # the token-processing loop below runs AFTER the record closes, so
+        # its wall lands in the NEXT record's commit_ms
+        clock.begin("commit")
+        clock.observe(kind="decode", tokens=block * live, slots=live)
         finished: list[tuple[int, GenerationResult]] = []
         eos = self.tokenizer.eos_id
         for i, (epoch, before) in snapshot.items():
@@ -1460,6 +1453,9 @@ class ServingEngine:
         #: None on directly-constructed engines (tests).
         self.device: Optional[Any] = None
         self.compile_watch: Optional[Any] = None
+        #: the collector's hook (serving/perf.py GcWatch) the step clock
+        #: reads: installed by start(), removed by close()
+        self._gc_watch: Optional[Any] = None
 
     def _unwrap(self, item: tuple) -> "_Request":
         """Pop bookkeeping for a queue entry: low-lane slots free on pop.
@@ -1572,6 +1568,17 @@ class ServingEngine:
                 leaks["pages"] = expected - allocator.available
         return leaks
 
+    def _step_tail(self) -> dict:
+        """What a black-box dump keeps of the step clock: the last step
+        records, and the stalls it kept from before them (``obs.view
+        --steps`` / ``--stalls`` render both); empty before any step."""
+        clock = self.generator.step_clock
+        tail = {
+            "steps": [r.to_dict() for r in clock.ring.records(last=32)],
+            "stalls": [r.to_dict() for r in list(clock.stalls)],
+        }
+        return {key: rows for key, rows in tail.items() if rows}
+
     def _dump_blackbox(self, reason: str, extra: dict) -> None:
         """Black-box flight-recorder dump for a supervisor event — a
         synthetic one-span trace (there is no ambient analysis trace on
@@ -1586,9 +1593,7 @@ class ServingEngine:
             # the stall's preceding timeline: the last step records BEFORE
             # the reset wipes the clock (obs.view --steps renders them)
             if "steps" not in extra:
-                steps = self.generator.step_clock.ring.records(last=32)
-                if steps:
-                    extra = {**extra, "steps": [r.to_dict() for r in steps]}
+                extra = {**extra, **self._step_tail()}
         except Exception:  # noqa: BLE001 - forensics must never block recovery
             pass
         try:
@@ -1688,12 +1693,9 @@ class ServingEngine:
         # the stall's preceding step timeline, captured BEFORE the device
         # reset wipes the step clock with the rest of decode state
         try:
-            step_tail = [
-                r.to_dict()
-                for r in self.generator.step_clock.ring.records(last=32)
-            ]
+            step_tail = self._step_tail()
         except Exception:  # noqa: BLE001 - forensics must never block recovery
-            step_tail = []
+            step_tail = {}
         retry, gaveup = self._collect_survivors()
         # parked here until requeued/failed: if close() interrupts this
         # restart, _fail_outstanding still reaches these futures
@@ -1789,7 +1791,7 @@ class ServingEngine:
             "resets_in_window": len(self._reset_times),
             "restart_ready_s": round(ready_s, 3),
             "aot_cache": aot.stats() if aot is not None else "off",
-            "steps": step_tail,
+            **step_tail,
         })
         log.warning(
             "supervised engine restart (%s) ready in %.2fs: %d requeued, "
@@ -1798,7 +1800,10 @@ class ServingEngine:
         )
 
     def _on_partial_from_worker(self, slot_id: int, token_ids: list) -> None:
-        """Generator hook (decode worker thread) -> event-loop callback."""
+        """Generator hook (decode worker thread) -> event-loop callback.
+        The hand-over carries the step clock's last stamp, where the
+        commit that holds these tokens began: one stamp a commit, read
+        here and not taken a row."""
         entry = self._partial_cbs.get(slot_id)
         if entry is None or self._loop is None:
             return
@@ -1806,12 +1811,13 @@ class ServingEngine:
         if future.done():  # streaming client cancelled; slot drains unheard
             return
         self._loop.call_soon_threadsafe(
-            self._deliver_partial, slot_id, callback, future, token_ids
+            self._deliver_partial, slot_id, callback, future, token_ids,
+            self.generator.step_clock.mark,
         )
 
     def _deliver_partial(
         self, key: int, callback: Any, future: "asyncio.Future",
-        token_ids: list,
+        token_ids: list, committed_t: Optional[float] = None,
     ) -> None:
         """Loop-side partial delivery with a per-request order guard.
 
@@ -1821,7 +1827,11 @@ class ServingEngine:
         slot key).  Re-checking here — and delivering only snapshots
         that strictly EXTEND what this key's stream already saw — makes
         the stream per-request monotonic in token order regardless of
-        how commits and cancellations interleave."""
+        how commits and cancellations interleave.  Whatever becomes of
+        the snapshot, its way from the commit to this thread is over: the
+        step clock takes its age (``StepRecord.deliver_lag_ms``)."""
+        if committed_t is not None:
+            self.generator.step_clock.delivered(committed_t)
         if future.done() or self._partial_cbs.get(key, (None, None))[1] is not future:
             return
         if len(token_ids) <= self._partial_sent.get(key, 0):
@@ -2017,6 +2027,16 @@ class ServingEngine:
         return encode_block(block_hash, entry[0], entry[1])
 
     async def start(self) -> None:
+        if self._gc_watch is None:
+            from .perf import GcWatch
+
+            # what else the process does in a step's interval: the step
+            # clock reads the collector's pauses and the compile log
+            clock = self.generator.step_clock
+            self._gc_watch = clock.gc_watch = GcWatch(
+                self.generator._annotation
+            ).install()
+            clock.compile_watch = self.compile_watch
         if self._task is None:
             self._loop = asyncio.get_running_loop()
             self._task = asyncio.create_task(self._run(), name="serving-engine")
@@ -2033,6 +2053,9 @@ class ServingEngine:
         self._closed = True
         if self.compile_watch is not None:
             self.compile_watch.close()  # take the tap off the jax logger
+        if self._gc_watch is not None:
+            self._gc_watch.remove()  # and the hook off the collector
+            self._gc_watch = None
         # the peer poller is pure index plumbing — first down, nothing
         # depends on it
         poll_task, self._fabric_poll_task = self._fabric_poll_task, None

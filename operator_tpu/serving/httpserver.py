@@ -449,6 +449,9 @@ class CompletionServer:
                 # cache hits marked: a compile after warm-up is a latency
                 # outlier somebody should be able to see from outside
                 "compiles": watch.report() if watch is not None else None,
+                # where the recent steps' wall went: the host's parts by
+                # name, and the stalls the clock kept (serving/perf.py)
+                "stepClock": self.engine.generator.step_clock.summary(),
             }
         if method == "GET" and path == "/metrics.json":
             # per-stage latency percentiles (prefill, decode_step, ...) from

@@ -162,7 +162,6 @@ class _InFlight:
     plan: StepPlan
     toks: Any  # device [B, W] sampled token ids
     accept: Any  # device [B] accepted-draft counts
-    dispatch_t: float  # step-clock stamp: the jitted call returned
     counts: dict  # the step record's work counts (_Packed.counts)
     #: the ``seq`` this step's record will get (commits are in order and
     #: each appends one record) — what its host spans carry as ``step``
@@ -682,17 +681,16 @@ class Scheduler:
         # the record this plan's step will write: commits land in order,
         # one record each
         seq = clock.ring.next_seq + len(self._inflight)
-        t0 = clock.now()
         with g._annotation("podmortem.sched.plan", step=seq):
             plan = self._schedule(outcomes)
-        t1 = clock.now()
-        clock.add("plan", (t1 - t0) * 1e3)
         held_rows = len(self._rows)  # snapshot BEFORE commit recycles
         if self.plan_log is not None:
             self.plan_log.append(plan.trace())
         if plan.work:
+            clock.begin("pack")
             with g._annotation("podmortem.sched.pack", step=seq):
                 packed = self._pack(plan)
+            clock.begin("put")
             with g._annotation(
                 "podmortem.sched.dispatch",
                 [row.params for row in self._rows.values()],
@@ -718,8 +716,7 @@ class Scheduler:
                     if packed.counts[name] is not None
                 },
             ):
-                entry = self._dispatch(plan, packed)
-            clock.add("pack", (entry.dispatch_t - t1) * 1e3)
+                entry = self._dispatch(plan, packed, seq)
             entry.seq = seq
             entry.held_rows = held_rows
             if self._inflight:
@@ -1454,74 +1451,84 @@ class Scheduler:
             qk_pairs=pairs,
         )
 
-    def _dispatch(self, plan: StepPlan, packed: _Packed) -> _InFlight:
+    def _dispatch(
+        self, plan: StepPlan, packed: _Packed, seq: int = 0
+    ) -> _InFlight:
         """ISSUE the one mixed program on the packed plan; commits the
         returned cache/rng/latest handles and returns the in-flight
         entry WITHOUT syncing — the sampled tokens stay on device until
         ``_commit_oldest`` fetches them (the pipelining point: at depth
-        >= 2 the next plan is dispatched before this fetch happens)."""
+        >= 2 the next plan is dispatched before this fetch happens).
+        Two parts of the step clock, a span each: ``put`` (the staged
+        page tables and every packed array go to the device) and
+        ``launch`` (the call of the compiled step until it returns its
+        handles, and the bookkeeping after it)."""
         g = self.generator
+        clock = g.step_clock
         jnp = g._jnp
         p = packed
         paged = g.paged_cache
-        if self._staged_tables:
-            idx = jnp.asarray(
-                [slot for slot, _ in self._staged_tables], jnp.int32
+        with g._annotation("podmortem.sched.put", step=seq):
+            if self._staged_tables:
+                idx = jnp.asarray(
+                    [slot for slot, _ in self._staged_tables], jnp.int32
+                )
+                tables = jnp.asarray(
+                    np.stack([tab for _, tab in self._staged_tables]), jnp.int32
+                )
+                paged = dataclasses.replace(
+                    paged, page_table=paged.page_table.at[idx].set(tables)
+                )
+                self._staged_tables.clear()
+            if self._latest is None:
+                # each slot's freshest token, or for a model that denoises
+                # blocks its block as the last step left it
+                self._latest = jnp.zeros(
+                    (g.max_slots, self._block) if self._block else (g.max_slots,),
+                    jnp.int32,
+                )
+            extra = ()
+            if p.denoise is not None:
+                extra = ({k: jnp.asarray(v) for k, v in p.denoise.items()},)
+            args = (
+                g.params, paged,
+                jnp.asarray(p.ids), jnp.asarray(p.rows), jnp.asarray(p.pos),
+                jnp.asarray(p.valid), jnp.asarray(p.in_row),
+                jnp.asarray(p.q_start), jnp.asarray(p.q_count),
+                jnp.asarray(p.kv_len),
+                self._latest, jnp.asarray(p.from_prev),
+                jnp.asarray(p.sample_start), jnp.asarray(p.spec_len),
+                g._rng, jnp.asarray(p.temp), jnp.asarray(p.top_p), *extra,
             )
-            tables = jnp.asarray(
-                np.stack([tab for _, tab in self._staged_tables]), jnp.int32
-            )
-            paged = dataclasses.replace(
-                paged, page_table=paged.page_table.at[idx].set(tables)
-            )
-            self._staged_tables.clear()
-        if self._latest is None:
-            # each slot's freshest token, or for a model that denoises
-            # blocks its block as the last step left it
-            self._latest = jnp.zeros(
-                (g.max_slots, self._block) if self._block else (g.max_slots,),
-                jnp.int32,
-            )
-        extra = ()
-        if p.denoise is not None:
-            extra = ({k: jnp.asarray(v) for k, v in p.denoise.items()},)
-        new_paged, toks, accept, latest, rng, *moe = self._get_fn()(
-            g.params, paged,
-            jnp.asarray(p.ids), jnp.asarray(p.rows), jnp.asarray(p.pos),
-            jnp.asarray(p.valid), jnp.asarray(p.in_row),
-            jnp.asarray(p.q_start), jnp.asarray(p.q_count),
-            jnp.asarray(p.kv_len),
-            self._latest, jnp.asarray(p.from_prev),
-            jnp.asarray(p.sample_start), jnp.asarray(p.spec_len),
-            g._rng, jnp.asarray(p.temp), jnp.asarray(p.top_p), *extra,
-        )
-        dispatch_t = g.step_clock.now()
-        g.paged_cache = new_paged
-        g._rng = rng
-        self._latest = latest
-        # shadow holds the OPTIMISTIC lengths (all drafts accepted) so
-        # the next plan's packing is consistent with pred_kv; a verify
-        # commit re-anchors the slot from the row's authoritative state
-        # when drafts were rejected
-        self._kv_shadow = p.kv_len
-        # NO block/fetch here: the commit side owns the step's one host
-        # sync (GL001: host loop code, not jit-reachable).  Record the
-        # in-flight deltas planning reads as pred_* until commit.
-        for work in plan.work:
-            row = self._rows[work.req_id]
-            if work.kind == "decode":
-                row.pend_gen += 1
-            elif work.kind == "verify":
-                row.pend_spec = True
-            elif work.kind == "block":
-                row.blocks.pend += 1
-            elif work.kind == "finish":
-                row.pend_pos += work.count
-                row.pend_gen += 1  # the chunk's first sampled token
-            else:  # prefill
-                row.pend_pos += work.count
+        clock.begin("launch")
+        with g._annotation("podmortem.sched.launch", step=seq):
+            new_paged, toks, accept, latest, rng, *moe = self._get_fn()(*args)
+            g.paged_cache = new_paged
+            g._rng = rng
+            self._latest = latest
+            # shadow holds the OPTIMISTIC lengths (all drafts accepted) so
+            # the next plan's packing is consistent with pred_kv; a verify
+            # commit re-anchors the slot from the row's authoritative state
+            # when drafts were rejected
+            self._kv_shadow = p.kv_len
+            # NO block/fetch here: the commit side owns the step's one host
+            # sync (GL001: host loop code, not jit-reachable).  Record the
+            # in-flight deltas planning reads as pred_* until commit.
+            for work in plan.work:
+                row = self._rows[work.req_id]
+                if work.kind == "decode":
+                    row.pend_gen += 1
+                elif work.kind == "verify":
+                    row.pend_spec = True
+                elif work.kind == "block":
+                    row.blocks.pend += 1
+                elif work.kind == "finish":
+                    row.pend_pos += work.count
+                    row.pend_gen += 1  # the chunk's first sampled token
+                else:  # prefill
+                    row.pend_pos += work.count
         return _InFlight(
-            plan=plan, toks=toks, accept=accept, dispatch_t=dispatch_t,
+            plan=plan, toks=toks, accept=accept,
             counts=packed.counts, moe=moe[0] if moe else None,
         )
 
@@ -1596,17 +1603,19 @@ class Scheduler:
         # the sync was always here (np.asarray); block_until_ready in
         # front only SPLITS it into device wait vs token-id transfer
         # — no new sync point (GL001: host loop code, not jit-reachable)
-        t_wait = clock.now()
+        clock.begin("wait")
         with g._annotation("podmortem.sched.wait", step=entry.seq):
             try:
                 entry.toks.block_until_ready()
             except AttributeError:
                 pass  # already a host array (fake-jax tests)
-        t_ready = clock.now()
-        with g._annotation("podmortem.sched.commit", step=entry.seq):
+        clock.begin("xfer")
+        with g._annotation("podmortem.sched.commit", step=entry.seq) as span:
             toks = np.asarray(entry.toks)
             accept = np.asarray(entry.accept)
-            fetch_t = clock.now()
+            # the commit runs on past the record's end, to the next stamp
+            # (the next plan, or the step's return): no glue is left over
+            clock.begin("commit")
             if self._pending_offload:
                 # the step just paid its host sync: piggyback the offload
                 # fetches on it (device→host page copies overlap the token
@@ -1645,15 +1654,15 @@ class Scheduler:
                 experts = {"moe_experts_hit": int(hit), "moe_assign_max": int(fullest)}
             if self._block:
                 self.metrics.incr("unmasked_tokens", accepted)
-        commit_t = clock.now()
-        clock.add("commit", (commit_t - fetch_t) * 1e3)
+            if span is not None and span.is_enabled():
+                # what the hand-overs to the event loop took of the commit
+                span.set_metadata(
+                    wake_us=int(clock.wake_ms * 1e3), wakeups=clock.wakeups
+                )
         record = clock.observe(
             kind=kind,
             tokens=plan.tokens_planned,
             slots=entry.held_rows,
-            wait_ms=(t_ready - t_wait) * 1e3,
-            xfer_ms=(fetch_t - t_ready) * 1e3,
-            commit_t=commit_t,
             accepted=accepted,
             cached_tokens=(
                 plan.cached_tokens if self._kvstore is not None else None
@@ -1729,6 +1738,8 @@ class Scheduler:
     ) -> list[StepOutcome]:
         outcomes: list[StepOutcome] = []
         g = self.generator
+        now = g.step_clock.now
+        wake_s, wakeups = 0.0, 0
         # the step's wall is attributed to its rows by token share —
         # good enough for the prefill/decode split the spans surface
         share = elapsed_ms / max(1, plan.tokens_planned)
@@ -1806,5 +1817,10 @@ class Scheduler:
                 and row.generated
             ):
                 # list COPY: the hook crosses into the event-loop thread
-                self.partial_hook(row.req_id, list(row.generated))
+                snapshot = list(row.generated)
+                t0 = now()
+                self.partial_hook(row.req_id, snapshot)
+                wake_s += now() - t0
+                wakeups += 1
+        g.step_clock.woke(wake_s * 1e3, wakeups)
         return outcomes

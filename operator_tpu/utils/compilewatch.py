@@ -86,6 +86,10 @@ class CompileWatcher:
         # by the paired "Finished" record (same name, last unfinished wins)
         self._events: List[List] = []
         self._dropped = 0
+        #: seconds of XLA compilation finished so far, monotonic (never
+        #: cut by the bound on ``_events``): the step clock writes its
+        #: difference over a step's interval (``StepRecord.compile_ms``)
+        self.compile_seconds = 0.0
         jax.config.update("jax_log_compiles", True)
         self._logger = logging.getLogger("jax")
         self._prior_level = self._logger.level
@@ -107,6 +111,7 @@ class CompileWatcher:
 
     def _record_finish(self, name: str, seconds: float) -> None:
         with self._lock:
+            self.compile_seconds += seconds
             for ev in reversed(self._events):
                 if ev[1] == name and ev[2] is None:
                     ev[2] = seconds

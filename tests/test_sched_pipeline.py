@@ -435,3 +435,41 @@ class TestStreamingOrder:
             final = snapshots[-1]
             assert result.token_ids[: len(final)] == final
         assert_no_leaks(generator)
+
+    @pytest.mark.parametrize("depth", [1, 2])
+    def test_every_partial_the_stream_saw_is_a_delivery_on_the_step_clock(
+        self, params, depth
+    ):
+        """The records count the snapshots that reached the event loop
+        (``delivered``) and their age since their commit began; over a run
+        with no cancellation that is every partial the streams saw, and
+        every hand-over the commits made (``wakeups``)."""
+        generator = make_generator(params)
+        sched = make_sched(generator, pipeline_depth=depth)
+        engine = ServingEngine(generator, scheduler=sched)
+
+        async def scenario():
+            await engine.start()
+            seen = []
+            sampling = SamplingParams(max_tokens=10, temperature=0.0,
+                                      stop_on_eos=False)
+            await asyncio.gather(*[
+                engine.generate(p, sampling, on_partial=seen.append)
+                for p in PROMPTS
+            ])
+            await asyncio.sleep(0.05)
+            await engine.close()
+            return len(seen)
+
+        seen = asyncio.run(scenario())
+        clock = generator.step_clock
+        records = clock.ring.records()
+        # what the loop took after the last commit is still with the clock
+        delivered = sum(r.delivered for r in records) + len(clock.lags)
+        assert delivered == seen == sum(r.wakeups for r in records) > 0
+        for r in records:
+            assert 0.0 <= r.deliver_lag_max_ms <= r.deliver_lag_ms
+            assert r.wake_ms <= r.commit_ms
+            if r.delivered:
+                assert r.deliver_lag_ms / r.delivered <= r.deliver_lag_max_ms
+        assert any(r.deliver_lag_ms > 0.0 for r in records)

@@ -26,6 +26,7 @@ from operator_tpu.models import TINY_TEST, init_params  # noqa: E402
 from operator_tpu.models.tokenizer import ByteTokenizer  # noqa: E402
 from operator_tpu.obs import Tracer  # noqa: E402
 from operator_tpu.obs.steptrace import (  # noqa: E402
+    HOST_PARTS,
     STEP_KINDS,
     StepRecord,
     StepRing,
@@ -276,10 +277,12 @@ def _one_step(clock, *, host=1.0, wait=2.0, xfer=1.0, kind="decode",
     planning, then ``wait`` and ``xfer``, commit, leave."""
     clock.enter()
     clock.now.advance(host)
-    clock.add("plan", host)
-    clock.now.advance(wait + xfer)
-    record = clock.observe(kind=kind, tokens=tokens, slots=slots,
-                           wait_ms=wait, xfer_ms=xfer, **counts)
+    clock.begin("wait")
+    clock.now.advance(wait)
+    clock.begin("xfer")
+    clock.now.advance(xfer)
+    clock.begin("commit")
+    record = clock.observe(kind=kind, tokens=tokens, slots=slots, **counts)
     clock.leave(busy=busy)
     return record
 
@@ -428,7 +431,8 @@ class TestStepView:
         assert lines[0].split() == [
             "seq", "kind", "tok", "pf_tok", "slots", "occ",
             "wall_ms", "host_ms", "wait_ms", "xfer_ms",
-            "plan", "pack", "commit", "turn",
+            "plan", "pack", "put", "launch", "commit", "turn",
+            "wake", "cpu", "proc", "gc", "comp", "lag",
             "blk_rows", "unmask", "cmt_tok", "moe_tok", "exp_hit", "exp_max", "passes",
             "st_rows", "smp_rows", "kv_pg", "pg_blk", "q_fill", "mfu",
         ]
@@ -631,6 +635,7 @@ def _drive_on_fake_device(params, depth, requests, **sched_kw):
     ``STEP_S`` after the later of their dispatch and the previous
     program's end.  Returns (generator, elapsed seconds with work,
     {req_id: (first token t, last token t, result)})."""
+    costs = sched_kw.pop("costs", {})  # seconds the host spends in a part
     generator = make_generator(params, **sched_kw.pop("generator_kw", {}))
     sched = Scheduler(generator, chunk=16, token_budget=32,
                       pipeline_depth=depth, **sched_kw)
@@ -641,14 +646,41 @@ def _drive_on_fake_device(params, depth, requests, **sched_kw):
     device = {"free_at": 0.0}
 
     def on_fake_device(*args):
+        clock.t += costs.get("launch", 0.0)
         new_paged, toks, accept, latest, rng = real(*args)
         device["free_at"] = max(device["free_at"], clock.t) + STEP_S
         handle = _DeviceHandle(toks, device["free_at"], clock)
         return new_paged, handle, accept, latest, rng
 
     sched._fn = on_fake_device
+    if "pack" in costs:
+        real_pack = sched._pack
+
+        def slow_pack(plan):
+            clock.t += costs["pack"]
+            return real_pack(plan)
+
+        sched._pack = slow_pack
+    if "put" in costs:
+        class SlowPuts:
+            """``jax.numpy`` whose every host-to-device put takes time."""
+
+            def __getattr__(self, name):
+                return getattr(jnp, name)
+
+            def asarray(self, *args, **kw):
+                clock.t += costs["put"]
+                return jnp.asarray(*args, **kw)
+
+        generator._jnp = SlowPuts()
     first, seen = {}, {}
-    sched.partial_hook = lambda req_id, ids: first.setdefault(req_id, clock.t)
+
+    def heard(req_id, ids):
+        clock.t += costs.get("wake", 0.0)
+        first.setdefault(req_id, clock.t)
+        sched.hook_calls = getattr(sched, "hook_calls", 0) + 1
+
+    sched.partial_hook = heard
     ids = [
         sched.enqueue(prompt, SamplingParams(
             max_tokens=n, temperature=0.0, stop_on_eos=False))
@@ -710,8 +742,9 @@ class TestSchedulerOnAnHonestClock:
         )
         for r in generator.step_clock.ring.records():
             assert r.host_ms + r.wait_ms + r.xfer_ms == pytest.approx(r.wall_ms)
-            parts = r.plan_ms + r.pack_ms + r.commit_ms + r.turn_ms
-            assert 0 < parts <= r.host_ms + 1e-9
+            # the six parts tile the host's time: no glue is left unnamed
+            parts = sum(getattr(r, f"{part}_ms") for part in HOST_PARTS)
+            assert 0 < parts == pytest.approx(r.host_ms, abs=1e-9)
             assert 0 <= r.prefill_tokens <= r.tokens
         steady = generator.step_clock.ring.records()[3:-3]
         # between two steps the loop took its turn while work was pending
@@ -769,11 +802,23 @@ class TestSchedulerOnAnHonestClock:
         names = {name for name, _ in spans}
         assert names == {
             "podmortem.sched.plan", "podmortem.sched.pack",
-            "podmortem.sched.dispatch", "podmortem.sched.wait",
+            "podmortem.sched.dispatch", "podmortem.sched.put",
+            "podmortem.sched.launch", "podmortem.sched.wait",
             "podmortem.sched.commit",
         }
         dispatched = [a for name, a in spans if name == "podmortem.sched.dispatch"]
         assert [a["step"] for a in dispatched] == sorted(records)
+        # the two halves of a dispatch open inside it, in order, with its step
+        inside = [
+            (name.rsplit(".", 1)[1], a["step"]) for name, a in spans
+            if name.endswith((".dispatch", ".put", ".launch"))
+        ]
+        # (precompile's dispatch came first, under no span of the loop's)
+        assert inside[:2] == [("put", 0), ("launch", 0)]
+        assert inside[2:] == [
+            (name, seq) for seq in sorted(records)
+            for name in ("dispatch", "put", "launch")
+        ]
         for args in dispatched:
             record = records[args["step"]]
             assert args["kv_pages"] == record.kv_pages_walked
@@ -782,6 +827,318 @@ class TestSchedulerOnAnHonestClock:
             assert args["q_tile_rows"] == record.q_tile_rows >= record.tokens
         committed = [a["step"] for name, a in spans if name == "podmortem.sched.commit"]
         assert committed == sorted(records)
+
+
+class TestThePartsOfHostMs:
+    """The step clock names every part of ``host_ms`` (PR 38)."""
+
+    COSTS = {"pack": 0.007, "put": 0.0005, "launch": 0.002, "wake": 0.0004}
+
+    @pytest.mark.parametrize("depth", [1, 2])
+    def test_six_parts_sum_to_host_ms(self, params, depth):
+        generator, _, _, _ = _drive_on_fake_device(
+            params, depth, _FAKE_DEVICE_REQUESTS, costs=self.COSTS
+        )
+        records = generator.step_clock.ring.records()
+        assert len(records) > 10
+        for r in records:
+            parts = [getattr(r, f"{part}_ms") for part in HOST_PARTS]
+            assert all(ms >= 0.0 for ms in parts)
+            assert sum(parts) == pytest.approx(r.host_ms, abs=1e-9)
+            assert r.host_ms + r.wait_ms + r.xfer_ms == pytest.approx(r.wall_ms)
+
+    @pytest.mark.parametrize("depth", [1, 2])
+    def test_pack_no_longer_holds_the_puts_nor_the_launch(self, params, depth):
+        generator, _, _, _ = _drive_on_fake_device(
+            params, depth, _FAKE_DEVICE_REQUESTS, costs=self.COSTS
+        )
+        few_ticks = 6 * TICK_S * 1e3
+        for r in generator.step_clock.ring.records()[3:-3]:
+            # one _pack a step; thirteen packed arrays at least go over
+            assert r.pack_ms == pytest.approx(7.0, abs=few_ticks)
+            assert r.put_ms >= 13 * 0.5
+            assert r.put_ms == pytest.approx(13 * 0.5, abs=2 * 0.5 + few_ticks)
+            # the compiled step's call and the bookkeeping after it
+            assert r.launch_ms == pytest.approx(2.0, abs=few_ticks)
+
+    def test_wake_is_inside_commit_and_counts_the_hooks_calls(self, params):
+        generator, _, _, sched = _drive_on_fake_device(
+            params, 2, _FAKE_DEVICE_REQUESTS, costs=self.COSTS
+        )
+        records = generator.step_clock.ring.records()
+        assert sum(r.wakeups for r in records) == sched.hook_calls > 10
+        for r in records:
+            assert r.wake_ms <= r.commit_ms
+            # a call costs what the hook took and the two reads around it
+            assert r.wake_ms == pytest.approx(
+                r.wakeups * (0.4 + TICK_S * 1e3), abs=1e-6
+            )
+
+    def test_parts_tile_after_an_idle_spell(self):
+        clock = _fake_clock()
+        _one_step(clock, busy=False)  # the last request finished here
+        clock.now.advance(60_000.0)  # a minute with nothing to do
+        clock.enter()
+        clock.now.advance(0.5)  # plan
+        clock.begin("pack")
+        clock.now.advance(1.5)
+        clock.begin("put")
+        clock.now.advance(0.25)
+        clock.begin("launch")
+        clock.now.advance(0.75)
+        clock.begin("wait")
+        clock.now.advance(9.0)
+        clock.begin("xfer")
+        clock.now.advance(0.125)
+        clock.begin("commit")
+        clock.now.advance(2.0)
+        record = clock.observe(kind="decode", tokens=1, slots=1)
+        assert (record.plan_ms, record.pack_ms, record.put_ms, record.launch_ms,
+                record.commit_ms, record.turn_ms) == (
+            pytest.approx(0.5), pytest.approx(1.5), pytest.approx(0.25),
+            pytest.approx(0.75), pytest.approx(2.0), 0.0,
+        )
+        assert record.host_ms == pytest.approx(5.0)
+        assert (record.wait_ms, record.xfer_ms) == (
+            pytest.approx(9.0), pytest.approx(0.125)
+        )
+        # the commit runs on to the next stamp: the glue after the record
+        # is the next interval's commit, then the loop's turn, then plan
+        clock.now.advance(0.3)
+        clock.leave(busy=True)
+        clock.now.advance(4.0)
+        following = _one_step(clock, host=1.0, wait=2.0, xfer=0.0)
+        assert (following.commit_ms, following.turn_ms, following.plan_ms) == (
+            pytest.approx(0.3), pytest.approx(4.0), pytest.approx(1.0),
+        )
+        assert following.host_ms == pytest.approx(5.3)
+
+    def test_a_wait_the_loop_timed_itself_comes_out_of_its_part(self):
+        """The wave engine's admission prefill hands its own wait in."""
+        clock = _fake_clock()
+        _one_step(clock)
+        clock.enter()
+        clock.now.advance(8.0)  # planning, 6 ms of it a prefill's compute
+        record = clock.observe(kind="prefill", tokens=8, slots=1, wait_ms=6.0)
+        assert (record.wait_ms, record.host_ms, record.plan_ms) == (
+            pytest.approx(6.0), pytest.approx(2.0), pytest.approx(2.0),
+        )
+
+
+class _FakeCpu:
+    """A CPU clock (seconds) that moves only when told to."""
+
+    def __init__(self):
+        self.t = 5.0
+
+    def __call__(self):
+        return self.t
+
+
+class TestWhatElseTheProcessDid:
+    def test_cpu_time_leaves_out_a_wait_that_spins(self):
+        clock = _fake_clock()
+        thread, process = _FakeCpu(), _FakeCpu()
+        clock.thread_cpu, clock.process_cpu = thread, process
+        clock.enter()
+        clock.now.advance(3.0)
+        thread.t += 0.002  # the worker worked 2 of its 3 ms
+        process.t += 0.005
+        clock.begin("wait")
+        clock.now.advance(10.0)
+        thread.t += 0.009  # a wait that spins is not work
+        process.t += 0.030
+        clock.begin("xfer")
+        clock.begin("commit")
+        clock.now.advance(1.0)
+        thread.t += 0.001
+        process.t += 0.001
+        record = clock.observe(kind="decode", tokens=1, slots=1)
+        assert record.cpu_ms == pytest.approx(3.0)
+        assert record.proc_cpu_ms == pytest.approx(36.0)
+        # 4 ms of host time, 3 of them on the CPU: 1 ms it was not running
+        assert record.host_ms + record.xfer_ms - record.cpu_ms == pytest.approx(1.0)
+        clock.leave(busy=True)
+        following = _one_step(clock)
+        assert following.cpu_ms == following.proc_cpu_ms == 0.0
+
+    def test_a_forced_collection_lands_in_its_interval_alone(self):
+        import gc
+
+        from operator_tpu.serving.perf import GcWatch
+
+        metrics = MetricsRegistry()
+        clock = _fake_clock(metrics=metrics)
+        clock.gc_watch = GcWatch().install()
+        was_enabled = gc.isenabled()
+        gc.disable()  # only the forced collection runs
+        try:
+            before = _one_step(clock)
+            clock.enter()
+            gc.collect()  # the oldest generation
+            gc.collect(0)  # and a young one
+            hit = clock.observe(kind="decode", tokens=1, slots=1)
+            clock.leave(busy=True)
+            after = _one_step(clock)
+        finally:
+            if was_enabled:
+                gc.enable()
+            clock.gc_watch.remove()
+        assert hit.gc_ms > 0.0 and hit.gc_gen2 == 1
+        assert hit.gc_ms == pytest.approx(clock.gc_watch.pause_ms)
+        for other in (before, after):
+            assert other.gc_ms == 0.0 and other.gc_gen2 == 0
+        pauses = metrics.histogram("gc_pause_milliseconds")
+        assert pauses.count == 2 and pauses.sum == pytest.approx(hit.gc_ms)
+        assert clock.gc_watch not in gc.callbacks
+
+    def test_compile_ms_follows_a_compile_watcher_event(self):
+        import logging
+
+        from operator_tpu.utils.compilewatch import CompileWatcher
+
+        clock = _fake_clock()
+        clock.compile_watch = watcher = CompileWatcher()
+        try:
+            before = _one_step(clock)
+            clock.enter()
+            jax_log = logging.getLogger("jax")
+            jax_log.warning("Compiling jit(cascade) with global shapes and types []")
+            jax_log.warning("Finished XLA compilation of jit(cascade) in 0.25 sec")
+            hit = clock.observe(kind="decode", tokens=1, slots=1)
+            clock.leave(busy=True)
+            after = _one_step(clock)
+        finally:
+            watcher.close()
+        assert watcher.compile_seconds == pytest.approx(0.25)
+        assert hit.compile_ms == pytest.approx(250.0)
+        assert before.compile_ms == after.compile_ms == 0.0
+
+    def test_the_collectors_hook_is_gone_after_close(self, params):
+        import gc
+
+        generator = make_generator(params)
+        engine = ServingEngine(
+            generator, scheduler=Scheduler(generator, chunk=16, token_budget=32)
+        )
+        hooks_before = list(gc.callbacks)
+
+        async def scenario():
+            await engine.start()
+            await engine.start()  # a supervised restart starts it again
+            installed = [h for h in gc.callbacks if h not in hooks_before]
+            assert installed == [generator.step_clock.gc_watch]
+            await engine.generate("one", SamplingParams(
+                max_tokens=4, temperature=0.0, stop_on_eos=False))
+            gc.collect()
+            await engine.close()
+
+        run(scenario())
+        assert gc.callbacks == hooks_before
+        assert generator.step_clock.gc_watch.gen2 >= 1
+
+    def test_deliveries_land_in_the_interval_the_loop_took_them_in(self):
+        clock = _fake_clock()
+        _one_step(clock)
+        clock.enter()
+        clock.begin("commit")
+        committed = clock.mark  # what the worker's hook hands over
+        clock.now.advance(1.0)
+        clock.delivered(committed)  # the event loop's side
+        clock.now.advance(2.0)
+        clock.delivered(committed)
+        record = clock.observe(kind="decode", tokens=2, slots=2)
+        assert record.delivered == 2
+        assert record.deliver_lag_ms == pytest.approx(4.0)
+        assert record.deliver_lag_max_ms == pytest.approx(3.0)
+        clock.leave(busy=True)
+        following = _one_step(clock)
+        assert (following.delivered, following.deliver_lag_ms) == (0, 0.0)
+
+
+class TestStalls:
+    def _steps(self, clock, count, **kw):
+        return [_one_step(clock, **kw) for _ in range(count)]
+
+    def test_a_200ms_interval_among_10ms_ones_is_kept_and_named(self, tmp_path, capsys):
+        from operator_tpu.obs import view
+        from operator_tpu.obs.steptrace import grown_part, render_stalls
+
+        metrics = MetricsRegistry()
+        clock = _fake_clock(metrics=metrics)  # a ring of 8
+        # nothing is a stall until 64 walls say what is usual
+        early = _one_step(clock, host=2.0, wait=197.0, xfer=1.0)
+        assert not early.stall
+        ordinary = self._steps(clock, 70, host=2.0, wait=7.0, xfer=1.0)
+        # four times the median, but under 50 ms: slow, not a stall
+        slow = _one_step(clock, host=2.0, wait=37.0, xfer=1.0)
+        assert not slow.stall and not any(r.stall for r in ordinary)
+        stalled = _one_step(clock, host=2.0, wait=197.0, xfer=1.0)
+        assert stalled.stall and stalled.wall_ms == pytest.approx(200.0)
+        later = self._steps(clock, 10, host=2.0, wait=7.0, xfer=1.0)
+        assert not any(r.stall for r in later)
+        # the ring evicted it; the clock kept it
+        assert stalled not in clock.ring.records()
+        assert list(clock.stalls) == [stalled]
+        assert metrics.counter("step_stall") == 1
+        summary = clock.summary()
+        assert (summary["stalls"], summary["stalls_kept"]) == (0, 1)
+        assert summary["last_stall"]["seq"] == stalled.seq
+        assert grown_part(stalled, later) == "wait"
+        # a dump of the kept stall beside ordinary steps, as obs.view reads it
+        journal = tmp_path / "steps.jsonl"
+        journal.write_text("".join(
+            json.dumps(r.to_dict()) + "\n" for r in [*later, stalled]
+        ))
+        assert view.main(["--stalls", str(journal)]) == 0
+        out = capsys.readouterr().out
+        assert "1 stalls in 11 steps" in out
+        [row] = [line for line in out.splitlines() if line.lstrip().startswith(
+            str(stalled.seq))]
+        assert row.split()[2:5] == ["200.000", "wait", "197.000"]
+        assert view.main(["--steps", str(journal)]) == 0
+        marked = [line for line in capsys.readouterr().out.splitlines()
+                  if line.lstrip().startswith(f"{stalled.seq}*")]
+        assert len(marked) == 1
+        assert render_stalls(later).count("\n") == 1  # header and rule alone
+
+    def test_the_part_that_grew_is_the_one_furthest_over_its_median(self):
+        from operator_tpu.obs.steptrace import grown_part
+
+        def record(**parts):
+            host = sum(parts.values())
+            return StepRecord(seq=0, kind="decode", tokens=1, slots=1,
+                              occupancy=1.0, wall_ms=host + 5.0, host_ms=host,
+                              wait_ms=5.0, xfer_ms=0.0, **parts)
+
+        usual = [record(plan_ms=1.0, commit_ms=8.0, turn_ms=2.0)] * 5
+        # commit is the largest part, turn the one that grew
+        stalled = record(plan_ms=1.5, commit_ms=30.0, turn_ms=120.0)
+        assert grown_part(stalled, usual) == "turn"
+        assert grown_part(record(plan_ms=90.0, commit_ms=9.0), usual) == "plan"
+
+    def test_a_reset_forgets_the_stalls_and_what_is_usual(self):
+        clock = _fake_clock()
+        self._steps(clock, 64, host=2.0, wait=7.0, xfer=1.0)
+        assert _one_step(clock, host=2.0, wait=300.0, xfer=0.0).stall
+        clock.reset()
+        assert not clock.stalls
+        assert not _one_step(clock, host=2.0, wait=300.0, xfer=0.0).stall
+
+    def test_a_dump_from_before_the_fields_still_loads(self):
+        old = {"seq": 3, "kind": "decode", "tokens": 4, "slots": 2,
+               "occupancy": 0.5, "wall_ms": 4.0, "host_ms": 1.0, "wait_ms": 2.0,
+               "xfer_ms": 1.0, "plan_ms": 0.5, "pack_ms": 0.25,
+               "commit_ms": 0.125, "turn_ms": 0.0, "accepted": 4}
+        record = StepRecord.from_dict(old)
+        assert (record.put_ms, record.launch_ms, record.wake_ms, record.cpu_ms,
+                record.gc_ms, record.compile_ms, record.deliver_lag_ms) == (0.0,) * 7
+        assert (record.wakeups, record.delivered, record.gc_gen2) == (None,) * 3
+        assert not record.stall and "stall" not in record.to_dict()
+        new = StepRecord.from_dict({**old, "put_ms": 0.75, "wakeups": 128,
+                                    "delivered": 120, "stall": True})
+        assert StepRecord.from_dict(new.to_dict()) == new
+        assert new.to_dict()["stall"] is True
 
 
 def _walk_by_the_references_rule(kv_len, q_count, page_size, window=None):
