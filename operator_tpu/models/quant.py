@@ -24,6 +24,17 @@ TP sharding composes cleanly: scales are per-output-channel, so they shard
 exactly like the matrix's output axis (parallel/mesh.py mirrors the
 {q, s} tree).
 
+The continuous step holds the three projections it splits into heads
+(``HEAD_PROJECTIONS``) transposed, ``{qt: int8 [..., out, in], s}``
+(:func:`hold_head_projections`): its consumer wants q, k and v
+heads-major, and XLA computes such a product batched over heads from a
+weight whose ``in`` axis is minor.  From ``[in, out]`` that was a
+transposing copy of every layer's matrix in every step (or of the whole
+stack, at the start of a step that loops its stack); from ``[out, in]``
+the layer's slice of the stack is read as it lies.  Every other reader
+(the wave engine, the mesh specs, LoRA, ``save_params``, the loader)
+keeps the canonical ``{q, s}``.
+
 The reference has no quantization (or any ML) — this is pure tpu-native
 performance work against the north-star throughput target (BASELINE.md).
 """
@@ -43,6 +54,10 @@ Params = dict[str, Any]
 #: given model quantises is its family's list: ``quantized_layer_matrices``
 QUANTIZED_LAYER_MATRICES = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
 
+#: the projections whose product ``sched/mixed.py attend`` splits into
+#: heads, in every family: what :func:`hold_head_projections` transposes
+HEAD_PROJECTIONS = ("wq", "wk", "wv")
+
 
 def quantized_layer_matrices(config: ModelConfig) -> tuple:
     """The layer matrices ``config``'s family holds int8 a column."""
@@ -56,7 +71,7 @@ def is_quantized(params: Params) -> bool:
     trees (e.g. LoRA merged into a quantized base, which dequantizes only
     its targets) count as quantized."""
     return any(
-        isinstance(leaf, dict) and "q" in leaf
+        isinstance(leaf, dict) and ("q" in leaf or "qt" in leaf)
         for leaf in params.get("layers", {}).values()
     )
 
@@ -92,6 +107,26 @@ def quantize_params(params: Params, config: ModelConfig) -> Params:
     return {**params, "layers": layers}
 
 
+@jax.jit
+def _transposed(q: jax.Array) -> jax.Array:
+    return jnp.swapaxes(q, -1, -2)
+
+
+def hold_head_projections(params: Params) -> Params:
+    """``params`` as the continuous step reads them: each int8
+    ``HEAD_PROJECTIONS`` leaf ``{q: [L, in, out], s: [L, out]}`` becomes
+    ``{qt: [L, out, in], s}``, the same values transposed, and every other
+    leaf is the one given.  A tree already held, or a float tree, comes
+    back as it is.  The given tree is not changed: a caller that rebinds
+    its name to the result frees each original matrix."""
+    layers = dict(params["layers"])
+    for name in HEAD_PROJECTIONS:
+        leaf = layers.get(name)
+        if isinstance(leaf, dict) and "q" in leaf:
+            layers[name] = {"qt": _transposed(leaf["q"]), "s": leaf["s"]}
+    return {**params, "layers": layers}
+
+
 def mm(
     x: jax.Array, w: "jax.Array | dict[str, jax.Array]", out_dtype: Any = None,
 ) -> jax.Array:
@@ -103,8 +138,15 @@ def mm(
     With ``out_dtype`` the product leaves the accumulator in that dtype
     and the scale is applied there: no rounding to the activations' dtype
     between the sum and whoever reads it (models/ouro.py norms every
-    branch in float32).
+    branch in float32).  A held ``{qt, s}`` (:func:`hold_head_projections`)
+    is contracted on its minor axis.
     """
+    if isinstance(w, dict) and "qt" in w:
+        y = jax.lax.dot_general(
+            x, w["qt"].astype(x.dtype), (((x.ndim - 1,), (1,)), ((), ())),
+            preferred_element_type=out_dtype,
+        )
+        return y * w["s"].astype(out_dtype or x.dtype)
     if out_dtype is not None:
         weight = w["q"] if isinstance(w, dict) else w
         y = jnp.matmul(x, weight.astype(x.dtype), preferred_element_type=out_dtype)

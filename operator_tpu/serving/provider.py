@@ -514,9 +514,14 @@ def build_serving_engine(
     )
     scheduler = None
     if config.sched_mode == "continuous":
+        from ..models.quant import hold_head_projections
         from .runtime import Runtime
         from .sched import Scheduler
 
+        # the mixed step reads q, k and v from [L, out, in] (models/
+        # quant.py): transposed once here, before the KV pool is placed,
+        # and rebound, so the canonical matrices are freed, not held too
+        params = hold_head_projections(params)
         # a Runtime and a Scheduler, and no wave engine: no decode block,
         # prefill grid, guided tables, LoRA stack or registered prefix
         generator = Runtime(params, model_config, tokenizer, **runtime_args)

@@ -44,6 +44,13 @@ CPU host finds them before chip time is spent.  Covered:
   ``copy``, ``dynamic-slice`` or ``dynamic-update-slice`` (or a fusion that
   holds one) whose result has the pool's shape or one layer's slice of it.  The pools ride the layer loop's carry and are written in place:
   the list is empty;
+- for each whole mixed step, compiled with the weights as the continuous
+  path holds them (``models/quant.py hold_head_projections``),
+  ``weight_moves``: the optimised HLO's ``copy`` instructions (or fusions
+  that hold one) whose result is an int8 layer matrix, one layer's slice
+  of a stack or a stack whole.  q, k and v are read from ``[L, out, in]``
+  as their heads-major product asks: the list is empty (three a step when
+  they were held ``[in, out]``: PR 39);
 - the sharded wave decode step over the 4-device topology (``tp=4``): the
   program ``SERVING_MESH=dp=1,tp=4`` runs, whose paged-attention kernel
   must sit inside a ``shard_map``.
@@ -95,16 +102,11 @@ def _memory(compiled) -> dict:
 _MOVES = ("copy", "dynamic-slice", "dynamic-update-slice")
 
 
-def _pool_moves(hlo: str, pool_shape: tuple) -> list[str]:
+def _moves(hlo: str, shapes: set, opcodes: tuple) -> list[str]:
     """Names, as a trace of the chip would print them, of the optimised
-    HLO's instructions that are a ``copy``, ``dynamic-slice`` or
-    ``dynamic-update-slice`` whose result has the stacked pool's shape or
-    one layer's slice of it (with or without the leading 1) — or a fusion
-    that holds one."""
-    shapes = {
-        "bf16[" + ",".join(str(d) for d in shape) + "]"
-        for shape in (pool_shape, pool_shape[1:], (1, *pool_shape[1:]))
-    }
+    HLO's instructions whose opcode is one of ``opcodes`` and whose result
+    has one of ``shapes`` (``"s8[1,3584,3584]"``, layout aside) — or of the
+    fusion that holds one."""
     header = re.compile(r"(?:ENTRY )?%?(\S+) \(.*\{$")
     instruction = re.compile(r"\s*(?:ROOT )?%?(\S+) = (\w+\[[\d,]*\])\S* ([\w-]+)\(")
     fusion_of = dict(  # fused computation -> the fusion instruction that calls it
@@ -119,20 +121,50 @@ def _pool_moves(hlo: str, pool_shape: tuple) -> list[str]:
             computation = opened.group(1)
             continue
         parsed = instruction.match(line)
-        if parsed and parsed.group(2) in shapes and parsed.group(3) in _MOVES:
+        if parsed and parsed.group(2) in shapes and parsed.group(3) in opcodes:
             found.add(fusion_of.get(computation, parsed.group(1)))
     return sorted(found)
 
 
-def _abstract_params(config, sharding_for):
-    """The int8 serving tree as ShapeDtypeStructs (``jax.eval_shape``: no
-    weight is ever allocated)."""
-    from operator_tpu.models.quant import init_params_quantized
+def _stack_shapes(dtype: str, stack: tuple) -> set:
+    """A stack's shape, one layer's slice of it, and the slice with its
+    leading 1, as the HLO writes them."""
+    return {
+        dtype + "[" + ",".join(str(d) for d in shape) + "]"
+        for shape in (stack, stack[1:], (1, *stack[1:]))
+    }
 
-    shapes = jax.eval_shape(
-        lambda key: init_params_quantized(config, key, dtype=jnp.bfloat16),
-        jax.ShapeDtypeStruct((2,), jnp.uint32),
-    )
+
+def _pool_moves(hlo: str, pool_shape: tuple) -> list[str]:
+    """The ``copy``, ``dynamic-slice`` or ``dynamic-update-slice``
+    instructions (or fusions holding one) whose result is the stacked
+    pool or one layer's slice of it."""
+    return _moves(hlo, _stack_shapes("bf16", tuple(pool_shape)), _MOVES)
+
+
+def _weight_moves(hlo: str, params) -> list[str]:
+    """The ``copy`` instructions (or fusions holding one) whose result is
+    an int8 layer matrix of ``params`` (abstract), one layer's slice of its
+    stack, or the stack whole.  The slices a layer's product reads its
+    weight through are not named: they are the weight stream."""
+    shapes = set()
+    for leaf in jax.tree_util.tree_leaves(params["layers"]):
+        if leaf.dtype == jnp.int8:
+            shapes |= _stack_shapes("s8", tuple(leaf.shape))
+    return _moves(hlo, shapes, ("copy",))
+
+
+def _abstract_params(config, sharding_for, held=False):
+    """The int8 serving tree as ShapeDtypeStructs (``jax.eval_shape``: no
+    weight is ever allocated); ``held``: as the continuous path holds it
+    (``models/quant.py hold_head_projections``)."""
+    from operator_tpu.models.quant import hold_head_projections, init_params_quantized
+
+    def tree(key):
+        params = init_params_quantized(config, key, dtype=jnp.bfloat16)
+        return hold_head_projections(params) if held else params
+
+    shapes = jax.eval_shape(tree, jax.ShapeDtypeStruct((2,), jnp.uint32))
     return jax.tree_util.tree_map(
         lambda leaf, sh: jax.ShapeDtypeStruct(leaf.shape, leaf.dtype, sharding=sh),
         shapes, sharding_for(shapes),
@@ -187,7 +219,8 @@ def _mixed_step_case(topo_device, model_id=None, slots=_SLOTS, t_budget=None,
     )
     t = t_budget or max(_CHUNK, slots)
     params = _abstract_params(
-        config, lambda tree: jax.tree_util.tree_map(lambda _: sharding, tree)
+        config, lambda tree: jax.tree_util.tree_map(lambda _: sharding, tree),
+        held=True,
     )
     flat_i, flat_b = shaped((t,), jnp.int32), shaped((t,), jnp.bool_)
     slot_i, slot_f = shaped((slots,), jnp.int32), shaped((slots,), jnp.float32)
@@ -538,6 +571,9 @@ def main() -> int:
                         "bytes": 2 * 2 * math.prod(shape),  # K and V, bf16
                         "moved_by": _pool_moves(compiled.as_text(), shape),
                     }
+                    results[name]["weight_moves"] = _weight_moves(
+                        compiled.as_text(), args[0]
+                    )
                 print(f"OK   {name}", file=sys.stderr)
             except Exception as exc:  # noqa: BLE001 - record and continue
                 failed += 1

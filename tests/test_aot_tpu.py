@@ -262,3 +262,69 @@ def test_the_pool_scan_names_the_old_ways_slice_stack_back_and_copy():
         "dynamic-slice_bitcast_fusion.5",
     ]
     assert aot_tpu_check._pool_moves(_OLD_WAY_HLO, (28, 3456, 64, 2, 128)) == []
+
+
+@pytest.mark.parametrize("case", [
+    "mixed_step_default_model",
+    "mixed_step_qwen2.5-1.5b_b128",
+    "mixed_step_qwen2.5-7b_b32",
+    "mixed_step_falcon-h1-34b-6l_b128",
+    "mixed_step_ouro-2.6b_b10",
+    "mixed_step_sdar-30b-a3b-12l_b128",
+])
+def test_no_layer_matrix_is_copied_in_the_mixed_step(record, case):
+    """The step is compiled with the weights as the continuous path holds
+    them (``models/quant.py hold_head_projections``): q, k and v are read
+    from ``[L, out, in]`` in the layout their heads-major product asks
+    for, so nothing copies an int8 layer matrix or a stack of them.  Held
+    ``[L, in, out]``, each case had three such copies (PR 39): one a layer
+    a step, or at Ouro the three 201 MB stacks at every step's head."""
+    step = record["kernels"][case]
+    assert step["weight_moves"] == [], step
+    if case == "mixed_step_ouro-2.6b_b10":
+        assert step["temp_bytes"] < 0.01e9, step  # 0.606 GB with the copies
+
+
+#: the parent's 7B step, cut from its optimised HLO: the layer's ``wq``
+#: sliced out of the stack into VMEM (the weight stream: not named) and
+#: copied a second time into the transposed layout the heads-major dot
+#: reads through a bitcast (named), and beside them a copy of another dtype
+_OLD_WEIGHT_HLO = """\
+%fused_computation.344.clone.clone (param_0.1492: s8[28,3584,3584], param_1.1892: s32[]) -> s8[1,3584,3584] {
+  %param_0.1492 = s8[28,3584,3584]{2,1,0:T(8,128)(4,1)} parameter(0)
+  %param_1.1892 = s32[]{:T(128)} parameter(1)
+  %constant.1570 = s32[]{:T(128)} constant(0)
+  ROOT %dynamic_slice.201 = s8[1,3584,3584]{2,1,0:T(8,128)(4,1)S(1)} dynamic-slice(%param_0.1492, %param_1.1892, %constant.1570, %constant.1570), dynamic_slice_sizes={1,3584,3584}
+}
+
+ENTRY %main.48 (wq: s8[28,3584,3584]) -> bf16[64,3584] {
+  %constant_dynamic-slice_fusion.10 = s8[1,3584,3584]{2,1,0:T(8,128)(4,1)S(1)} fusion(%get-tuple-element.1106, %get-tuple-element.1046), kind=kLoop, calls=%fused_computation.344.clone.clone
+  %copy.110 = s8[1,3584,3584]{1,2,0:T(8,128)(4,1)S(1)} copy(%constant_dynamic-slice_fusion.10)
+  %bitcast.290 = s8[28,128,3584]{2,1,0:T(8,128)(4,1)S(1)} bitcast(%copy.110)
+  ROOT %copy.7 = bf16[64,3584]{1,0:T(8,128)(2,1)} copy(%fusion.3)
+}
+"""
+
+
+def test_the_weight_scan_names_the_transposing_copy_and_not_the_slice():
+    """What ``weight_moves`` is read from: on the parent's HLO the scan
+    names the transposing copy of the layer's ``wq`` as a chip trace
+    would, and neither the slice that stages it nor a copy of another
+    dtype; for a stack it is not given, nothing."""
+    import jax
+    import jax.numpy as jnp
+
+    sys.path.insert(0, str(REPO / "scripts"))
+    import aot_tpu_check
+
+    def tree(*shapes):
+        return {"layers": {
+            f"w{i}": {
+                "q": jax.ShapeDtypeStruct(shape, jnp.int8),
+                "s": jax.ShapeDtypeStruct((shape[0], shape[2]), jnp.float32),
+            }
+            for i, shape in enumerate(shapes)
+        }}
+
+    assert aot_tpu_check._weight_moves(_OLD_WEIGHT_HLO, tree((28, 3584, 3584))) == ["copy.110"]
+    assert aot_tpu_check._weight_moves(_OLD_WEIGHT_HLO, tree((28, 3584, 512))) == []

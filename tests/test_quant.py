@@ -143,3 +143,112 @@ def test_init_params_quantized_matches_two_step():
             assert (af != bf).mean() < 0.05  # and only on rounding boundaries
         else:
             np.testing.assert_allclose(af, bf, rtol=1e-2, atol=1e-3)
+
+
+#: one tiny config of each family the continuous step runs, Qwen2's q/k/v
+#: biases counted as a family of their own
+HELD_FAMILIES = ["tiny-test", "tiny-qwen2-bias", "tiny-falcon-h1", "tiny-ouro", "tiny-sdar"]
+
+
+def family_config(name):
+    import dataclasses
+
+    from operator_tpu.models import get_config
+
+    if name == "tiny-qwen2-bias":
+        return dataclasses.replace(TINY_TEST, name=name, attention_bias=True)
+    return get_config(name)
+
+
+@pytest.fixture(scope="module")
+def int8_trees():
+    """``{family: (config, canonical int8 tree, held tree)}``, built once."""
+    from operator_tpu.models.quant import hold_head_projections, init_params_quantized
+
+    out = {}
+    for name in HELD_FAMILIES:
+        config = family_config(name)
+        tree = init_params_quantized(config, jax.random.PRNGKey(0), dtype=jnp.float32)
+        out[name] = (config, tree, hold_head_projections(tree))
+    return out
+
+
+@pytest.mark.parametrize("out_dtype", [None, jnp.float32])
+@pytest.mark.parametrize("family", HELD_FAMILIES)
+def test_mm_on_a_held_leaf_is_mm_on_the_canonical_leaf(int8_trees, family, out_dtype):
+    """A held ``{qt: [out, in], s}`` gives the product of ``{q: [in, out],
+    s}`` in the same dtype, to float32's accumulation order."""
+    from operator_tpu.models.quant import HEAD_PROJECTIONS
+
+    config, tree, held = int8_trees[family]
+    for dtype in (jnp.float32, jnp.bfloat16):
+        x = jax.random.normal(jax.random.PRNGKey(7), (1, 5, config.hidden_size), dtype)
+        for name in HEAD_PROJECTIONS:
+            layer = config.num_layers - 1
+            want = mm(x, jax.tree_util.tree_map(lambda a: a[layer], tree["layers"][name]), out_dtype)
+            got = mm(x, jax.tree_util.tree_map(lambda a: a[layer], held["layers"][name]), out_dtype)
+            assert got.dtype == want.dtype and got.shape == want.shape
+            np.testing.assert_allclose(
+                np.asarray(got, np.float32), np.asarray(want, np.float32),
+                rtol=1e-5, atol=1e-5,
+            )
+
+
+@pytest.mark.parametrize("family", HELD_FAMILIES)
+def test_holding_transposes_the_head_projections_alone(int8_trees, family):
+    """Exactly ``wq``, ``wk`` and ``wv`` change, each to its own values
+    transposed beside the same scale; every other leaf is the one given,
+    the bytes are the same, the given tree is untouched, and holding a
+    held tree changes nothing."""
+    from operator_tpu.models.quant import HEAD_PROJECTIONS, hold_head_projections
+
+    _, tree, held = int8_trees[family]
+    assert set(held) == set(tree) and set(held["layers"]) == set(tree["layers"])
+    for name, leaf in tree["layers"].items():
+        if name in HEAD_PROJECTIONS:
+            assert set(held["layers"][name]) == {"qt", "s"} and "q" in leaf
+            assert held["layers"][name]["s"] is leaf["s"]
+            assert np.array_equal(
+                np.asarray(held["layers"][name]["qt"]), np.swapaxes(np.asarray(leaf["q"]), -1, -2)
+            )
+        else:
+            assert held["layers"][name] is leaf
+    assert all(held[k] is tree[k] for k in tree if k != "layers")
+    assert quantized_bytes(held) == quantized_bytes(tree)
+    again = hold_head_projections(held)
+    assert jax.tree_util.tree_structure(again) == jax.tree_util.tree_structure(held)
+    assert all(a is b for a, b in zip(
+        jax.tree_util.tree_leaves(again), jax.tree_util.tree_leaves(held)
+    ))
+    assert is_quantized(held)
+
+
+@pytest.mark.parametrize("family", HELD_FAMILIES)
+def test_the_continuous_path_gives_the_same_greedy_tokens_held(int8_trees, family):
+    """Three requests, prompts in chunks, greedy on the continuous path
+    (a bare ``Runtime`` and the ``Scheduler``), over the canonical tree and
+    the held one: the same tokens."""
+    from operator_tpu.serving.runtime import Runtime
+    from operator_tpu.serving.sched import Scheduler
+    from operator_tpu.utils.timing import MetricsRegistry
+
+    config, tree, held = int8_trees[family]
+    greedy = SamplingParams(max_tokens=5, temperature=0.0, stop_on_eos=False)
+
+    def served(params):
+        runtime = Runtime(
+            params, config, ByteTokenizer(), max_slots=3, max_seq=128,
+            page_size=16, cache_dtype=jnp.float32, metrics=MetricsRegistry(),
+        )
+        sched = Scheduler(runtime, chunk=8)
+        ids = [sched.enqueue(p, greedy) for p in ("pod crashed", "exit 137 oom", "x")]
+        done = {}
+        while sched.total_work:
+            for outcome in sched.step():
+                assert outcome.error is None, outcome.error
+                done[outcome.req_id] = outcome.result.token_ids
+        return [done[i] for i in ids]
+
+    want = served(tree)
+    assert all(len(t) == 5 for t in want)
+    assert served(held) == want
