@@ -203,6 +203,17 @@ class FalconH1Config(ModelConfig):
         """The in-projection's width: z | x | B | C | dt."""
         return self.mamba_d_ssm + self.mamba_conv_dim + self.mamba_n_heads
 
+    def recurrent_state_shapes(self, batch_size: int) -> tuple:
+        """``(state shape, convolution tail shape)`` of the pools a slot's
+        mixers carry (``ops/paged_attention.PagedKVCache``): per layer and
+        slot, ``[heads, d_state, d_head]`` float32 and the last ``d_conv -
+        1`` inputs of the convolution's ``x | B | C``."""
+        n = self.num_layers
+        return (
+            (n, batch_size, self.mamba_n_heads, self.mamba_d_state, self.mamba_d_head),
+            (n, batch_size, self.mamba_d_conv - 1, self.mamba_conv_dim),
+        )
+
     @property
     def mlp_multipliers_list(self) -> list:
         """As JSON has it: a configuration file's ``architecture`` group
@@ -227,6 +238,125 @@ class FalconH1Config(ModelConfig):
                      or self.mlp_bias or self.projectors_bias or self.attention_bias)
             and self.mamba_expand * self.hidden_size >= self.mamba_d_ssm
         ), f"{self.name}: a Falcon-H1 variant the layer body does not implement"
+
+
+#: the two kinds of layer of ``OlmoHybridConfig.layer_types``
+LINEAR_ATTENTION, FULL_ATTENTION = "linear_attention", "full_attention"
+
+
+@dataclass(frozen=True)
+class OlmoHybridConfig(ModelConfig):
+    """Olmo Hybrid (allenai, ``model_type: olmo_hybrid``): a stack whose
+    ``layer_types`` repeat a period of gated delta-rule layers (Gated
+    DeltaNet, linear attention with a per-slot state) and full-attention
+    layers without positional rotation, every branch normed after it and
+    before its residual add (the Olmo 2 order); ``models/olmo_hybrid.py``
+    has the equations.  The KV pool holds planes for the full-attention
+    layers alone, the recurrent pools state for the linear ones alone.  A
+    sibling of ``ModelConfig`` as ``FalconH1Config`` is; field names follow
+    the published ``config.json`` (``rope_theta`` None: no RoPE)."""
+
+    layer_types: tuple = ()
+    linear_num_key_heads: int = 0
+    linear_num_value_heads: int = 0
+    linear_key_head_dim: int = 0
+    linear_value_head_dim: int = 0
+    linear_conv_kernel_dim: int = 4
+    #: ``beta = 2 * sigmoid(.)``: the state's transition may have a negative
+    #: eigenvalue (Grazzi et al., arXiv:2411.12537)
+    linear_allow_neg_eigval: bool = True
+    hidden_act: str = "silu"
+
+    family: ClassVar[str] = "olmo_hybrid"
+    recurrent_state: ClassVar[bool] = True
+    continuous_only: ClassVar[Optional[str]] = (
+        "keeps a recurrent state per slot in its linear-attention layers"
+    )
+
+    @property
+    def layer_types_list(self) -> list:
+        """As JSON has it: a configuration file's ``architecture`` group is
+        held to this."""
+        return list(self.layer_types)
+
+    @property
+    def period(self) -> tuple:
+        """The shortest prefix of ``layer_types`` that repeats to make it."""
+        types = tuple(self.layer_types)
+        for size in range(1, len(types) + 1):
+            if len(types) % size == 0 and types == types[:size] * (len(types) // size):
+                return types[:size]
+        return types
+
+    @property
+    def periods(self) -> int:
+        return len(self.layer_types) // len(self.period)
+
+    @property
+    def linear_per_period(self) -> int:
+        return self.period.count(LINEAR_ATTENTION)
+
+    @property
+    def full_per_period(self) -> int:
+        return self.period.count(FULL_ATTENTION)
+
+    @property
+    def delta_layers(self) -> int:
+        """Layers of the gated delta rule: what the state pool holds."""
+        return self.periods * self.linear_per_period
+
+    @property
+    def kv_planes(self) -> int:
+        """One plane a full-attention layer: the linear layers keep no KV."""
+        return self.periods * self.full_per_period
+
+    @property
+    def linear_key_dim(self) -> int:
+        return self.linear_num_key_heads * self.linear_key_head_dim
+
+    @property
+    def linear_value_dim(self) -> int:
+        return self.linear_num_value_heads * self.linear_value_head_dim
+
+    @property
+    def linear_conv_dim(self) -> int:
+        """Channels the causal convolution runs over: q | k | v."""
+        return 2 * self.linear_key_dim + self.linear_value_dim
+
+    def recurrent_state_shapes(self, batch_size: int) -> tuple:
+        """``(state shape, convolution tail shape)``: per linear layer and
+        slot, the heads' ``[key_dim, value_dim]`` float32 states two heads
+        side by side, ``[heads / 2, key_dim, 2 x value_dim]`` (a minor axis
+        of 2 x 192 = 384 lanes, where one head's 192 would be padded to
+        256), and the last ``conv_kernel - 1`` inputs of q | k | v."""
+        n, heads = self.delta_layers, self.linear_num_value_heads
+        return (
+            (n, batch_size, heads // 2, self.linear_key_head_dim,
+             2 * self.linear_value_head_dim),
+            (n, batch_size, self.linear_conv_kernel_dim - 1, self.linear_conv_dim),
+        )
+
+    def __post_init__(self) -> None:
+        super().__post_init__()
+        types = tuple(self.layer_types)
+        assert len(types) == self.num_layers and set(types) <= {
+            LINEAR_ATTENTION, FULL_ATTENTION,
+        }, f"{self.name}: layer_types must name each of the {self.num_layers} layers"
+        # the mixed step scans ONE period a step: a pattern that does not
+        # repeat is refused, not unrolled layer by layer
+        assert self.periods >= 2, f"{self.name}: layer_types repeat no period"
+        assert self.linear_per_period and self.full_per_period, (
+            f"{self.name}: a period of both kinds of layer"
+        )
+        assert self.linear_num_key_heads == self.linear_num_value_heads and (
+            self.linear_num_value_heads % 2 == 0
+        ), f"{self.name}: the state pool holds heads in pairs, one key head a value head"
+        assert (
+            self.hidden_act == "silu" and self.linear_allow_neg_eigval
+            and self.rope_theta is None and self.rope_scaling is None
+            and not self.attention_bias and not self.tie_embeddings
+            and self.sliding_window is None and self.num_heads == self.num_kv_heads
+        ), f"{self.name}: an Olmo Hybrid variant the layer body does not implement"
 
 
 TINYLLAMA_1_1B = ModelConfig(
@@ -511,6 +641,51 @@ TINY_SDAR = SdarConfig(
     mask_token_id=511,
 )
 
+# Olmo-Hybrid-7B as published (allenai/Olmo-Hybrid-7B, config.json): 32
+# layers, three gated delta-rule layers then one full-attention layer, eight
+# times; head_dim is hidden / heads (the file gives none)
+OLMO_HYBRID_7B = OlmoHybridConfig(
+    name="olmo-hybrid-7b",
+    vocab_size=100352,
+    hidden_size=3840,
+    intermediate_size=11008,
+    num_layers=32,
+    num_heads=30,
+    num_kv_heads=30,
+    head_dim=128,
+    rope_theta=None,
+    rms_norm_eps=1e-6,
+    max_seq_len=16384,  # serving cap; the model supports 64k
+    layer_types=((LINEAR_ATTENTION,) * 3 + (FULL_ATTENTION,)) * 8,
+    linear_num_key_heads=30,
+    linear_num_value_heads=30,
+    linear_key_head_dim=96,
+    linear_value_head_dim=192,
+    linear_conv_kernel_dim=4,
+)
+
+#: the family's small config for tests: two periods, two linear heads of
+#: key 16 and value 32
+TINY_OLMO_HYBRID = OlmoHybridConfig(
+    name="tiny-olmo-hybrid",
+    vocab_size=512,
+    hidden_size=128,
+    intermediate_size=256,
+    num_layers=8,
+    num_heads=2,
+    num_kv_heads=2,
+    head_dim=64,
+    rope_theta=None,
+    rms_norm_eps=1e-6,
+    max_seq_len=256,
+    layer_types=((LINEAR_ATTENTION,) * 3 + (FULL_ATTENTION,)) * 2,
+    linear_num_key_heads=2,
+    linear_num_value_heads=2,
+    linear_key_head_dim=16,
+    linear_value_head_dim=32,
+    linear_conv_kernel_dim=4,
+)
+
 _REGISTRY = {
     cfg.name: cfg
     for cfg in (
@@ -531,6 +706,8 @@ _REGISTRY = {
         SDAR_30B_A3B,
         SDAR_30B_A3B_12L,
         TINY_SDAR,
+        OLMO_HYBRID_7B,
+        TINY_OLMO_HYBRID,
     )
 }
 

@@ -38,6 +38,7 @@ from typing import Any, Callable, Optional
 import jax
 import jax.numpy as jnp
 
+from . import recurrent
 from .configs import FalconH1Config
 from .quant import mm
 
@@ -73,10 +74,6 @@ def scale(x: jax.Array, multiplier: Any) -> jax.Array:
     product taken in float32: a multiplier rounded to bfloat16 would be a
     systematic 2^-9 error on its branch."""
     return (x.astype(jnp.float32) * multiplier).astype(x.dtype)
-
-
-def _softplus_inverse(y: jax.Array) -> jax.Array:
-    return y + jnp.log(-jnp.expm1(-y))
 
 
 def init_params(
@@ -130,7 +127,7 @@ def init_params(
     layers["a_log"] = jnp.log(
         jax.random.uniform(k_a, (n, heads), jnp.float32, 1.0, 16.0)
     )
-    layers["dt_bias"] = _softplus_inverse(jnp.exp(
+    layers["dt_bias"] = recurrent.softplus_inverse(jnp.exp(
         jax.random.uniform(k_dt, (n, heads), jnp.float32, jnp.log(1e-3), jnp.log(1e-1))
     ))
     layers["d_skip"] = jnp.ones((n, heads), jnp.float32)
@@ -175,11 +172,7 @@ def conv_taps(config: FalconH1Config, weights: Params, taps: list) -> jax.Array:
     """``silu(sum_k w[k] * taps[k] + b)`` in float32, cast back: ``taps[k]``
     is the convolution's input ``d_conv - 1 - k`` tokens back (zeros
     before the sequence's start), oldest first."""
-    w = weights["conv_w"].astype(jnp.float32)
-    acc = weights["conv_b"].astype(jnp.float32)
-    for k, tap in enumerate(taps):
-        acc = acc + w[k] * tap.astype(jnp.float32)
-    return jax.nn.silu(acc).astype(taps[-1].dtype)
+    return recurrent.conv_taps(weights["conv_w"], weights["conv_b"], taps)
 
 
 def scan_inputs(config: FalconH1Config, weights: Params, xbc: jax.Array, dt_raw: jax.Array):
@@ -307,60 +300,31 @@ def mixed_layer(config: FalconH1Config, step: Any) -> Callable:
     ``pools`` the KV pools (``step.attend`` writes and reads them at the
     layer) and ``recurrent = {"ssm": [L, S, H, N, P] f32, "conv": [L, S,
     d_conv - 1, conv_dim]}``, all WHOLE: the scan kernel updates the
-    layer's state in place and the conv tail is one small row update, so
-    nothing slices a layer out of a pool or writes one back."""
+    layer's state in place and the conv tail is one small row update
+    (``models/recurrent.py flat_conv``), so nothing slices a layer out of
+    a pool or writes one back."""
     from ..ops.ssm_scan import ssm_scan
     from .llama import rms_norm
 
-    width = config.mamba_d_conv
-    tail = width - 1
-    t_budget = step.t_budget
-    # a row whose first token sits at position 0 starts from nothing: a
-    # slot's next tenant never reads what the last one left
-    slot_fresh = (step.pos[jnp.clip(step.q_start, 0, t_budget - 1)] == 0) & (
-        step.q_count > 0
-    )  # [S]
-
-    def conv(weights, xbc, tails):
-        """Causal depthwise convolution on the flat axis: a token's tap
-        ``s`` tokens back is the flat token before it while that is still
-        in its row, else the slot's carried tail.  Returns the activated
-        ``x|B|C`` and the slots' new tails."""
-        flat = xbc[0]  # [T, conv]
-        tails = jnp.where(slot_fresh[:, None, None], 0, tails).astype(flat.dtype)
-        taps = []
-        for back in range(tail, 0, -1):  # oldest first
-            inside = step.in_row >= back
-            from_flat = flat[jnp.clip(jnp.arange(t_budget) - back, 0, t_budget - 1)]
-            from_tail = tails[
-                step.rows, jnp.clip(tail + step.in_row - back, 0, tail - 1)
-            ]
-            taps.append(jnp.where(inside[:, None], from_flat, from_tail))
-        taps.append(flat)
-        out = conv_taps(config, weights, taps)
-        # the last `tail` inputs of [old tail | this step's tokens]: an
-        # idle slot's tail comes back as it was
-        at = step.q_count[:, None] + jnp.arange(tail, dtype=jnp.int32)[None]  # [S, tail]
-        from_flat = flat[jnp.clip(step.q_start[:, None] + at - tail, 0, t_budget - 1)]
-        from_tail = jnp.take_along_axis(
-            tails, jnp.clip(at, 0, tail - 1)[..., None], axis=1
-        )
-        return out[None], jnp.where((at >= tail)[..., None], from_flat, from_tail)
+    slot_fresh = recurrent.slot_fresh(step)  # [S]
 
     def layer_step(carry, scanned):
-        x, pools, recurrent = carry
+        x, pools, state = carry
         weights, layer = scanned["w"], scanned["layer"]
         u = rms_norm(x, weights["ln_attn"], config.rms_norm_eps)
         with jax.named_scope("ssm_conv"):
             z, xbc, dt_raw = mixer_in(config, weights, u)
-            xbc, new_tails = conv(weights, xbc, recurrent["conv"][layer])
+            xbc, new_tails = recurrent.flat_conv(
+                step, slot_fresh, xbc, state["conv"][layer],
+                weights["conv_w"], weights["conv_b"],
+            )
             conv_pool = jax.lax.dynamic_update_index_in_dim(
-                recurrent["conv"], new_tails.astype(recurrent["conv"].dtype), layer, 0
+                state["conv"], new_tails.astype(state["conv"].dtype), layer, 0
             )
         with jax.named_scope("ssm"):
             xs, bs, cs, dt, a = scan_inputs(config, weights, xbc, dt_raw)
             y, ssm_pool = ssm_scan(
-                xs[0], dt[0], a, bs[0], cs[0], recurrent["ssm"], layer,
+                xs[0], dt[0], a, bs[0], cs[0], state["ssm"], layer,
                 step.q_start, step.q_count, slot_fresh, chunk=step.chunk,
             )
             # the kernel leaves rows of no live slot unwritten

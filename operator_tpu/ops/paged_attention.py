@@ -52,12 +52,14 @@ class PagedKVCache:
     page_table: jax.Array  # [batch, pages_per_seq] int32
     lengths: jax.Array  # [batch] int32
     #: recurrent state beside the pages, for a model that has it
-    #: (``config.recurrent_state``; ops/ssm_scan.py): per layer and SLOT,
-    #: not per page -- allocated, donated, reset and freed with the pool,
-    #: because it is part of the one cache object.  None for the others:
-    #: an absent child of the pytree, so their programs see no change
-    ssm_state: Optional[jax.Array] = None  # [layers, batch, heads, d_state, head_dim] f32
-    conv_state: Optional[jax.Array] = None  # [layers, batch, d_conv - 1, conv_dim]
+    #: (``config.recurrent_state``; ops/ssm_scan.py, ops/gated_delta.py):
+    #: per recurrent layer and SLOT, not per page -- allocated, donated,
+    #: reset and freed with the pool, because it is part of the one cache
+    #: object.  None for the others: an absent child of the pytree, so
+    #: their programs see no change.  Shapes: the config's
+    #: ``recurrent_state_shapes``
+    ssm_state: Optional[jax.Array] = None  # [layers, batch, ...] f32
+    conv_state: Optional[jax.Array] = None  # [layers, batch, kernel - 1, channels]
 
     @property
     def page_size(self) -> int:
@@ -66,15 +68,11 @@ class PagedKVCache:
     @staticmethod
     def recurrent_shapes(config, batch_size: int) -> Optional[tuple]:
         """``(ssm_state shape, conv_state shape)`` for ``config``, or None
-        for a model without recurrent state."""
+        for a model without recurrent state: the config says what its
+        layers carry (``recurrent_state_shapes``)."""
         if not getattr(config, "recurrent_state", False):
             return None
-        n = config.num_layers
-        return (
-            (n, batch_size, config.mamba_n_heads, config.mamba_d_state,
-             config.mamba_d_head),
-            (n, batch_size, config.mamba_d_conv - 1, config.mamba_conv_dim),
-        )
+        return config.recurrent_state_shapes(batch_size)
 
     @classmethod
     def create(
@@ -90,11 +88,15 @@ class PagedKVCache:
         recurrent: Optional[tuple] = None,
     ) -> "PagedKVCache":
         """``num_layers`` is the pool's planes, the model's ``kv_planes``
-        (a looped model's passes x layers).  ``recurrent`` is
+        (a looped model's passes x layers).  ``kv_heads`` is the model's; a
+        page holds ``ragged_attention.pool_heads`` of them (30 held as the
+        chip's tile holds them, 32).  ``recurrent`` is
         :meth:`recurrent_shapes`' pair: the state is
         float32 (a sum over every token the row has seen), the conv tail
         the activations' ``dtype``."""
-        shape = (num_layers, num_pages, page_size, kv_heads, head_dim)
+        from .ragged_attention import pool_heads
+
+        shape = (num_layers, num_pages, page_size, pool_heads(kv_heads), head_dim)
         return cls(
             k_pages=jnp.zeros(shape, dtype),
             v_pages=jnp.zeros(shape, dtype),

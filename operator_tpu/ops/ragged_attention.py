@@ -117,7 +117,13 @@ else (``sched/mixed.py`` masks its padding tokens).
 
 The page DMA moves one ``[page_size, KH, D]`` page per copy, and Mosaic
 requires the minor dimension of a DMA slice to fill the 128-lane tile, so
-the kernel serves ``head_dim`` in multiples of 128 only.  There is no
+the kernel serves ``head_dim`` in multiples of 128 only.  The slice's
+second-minor dimension, the page's heads, must fill the chip's tile of 8
+rows too where there are more than 8: a page holds :func:`pool_heads` of
+the model's KV heads (30 held as 32, which is how the chip stores 30
+anyway), the pool is written through :func:`write_rows`, and the kernel
+and the reference read the model's ``kv_heads`` of each page and never
+the rest.  There is no
 second attention for the continuous scheduler: a model the kernel cannot
 serve is refused at engine build (:func:`require_ragged_kernel_support`),
 never routed to the reference.  Serving head_dim 64 needs a lane-dense
@@ -170,16 +176,38 @@ def query_tile_rows(q_count: np.ndarray, chunk: int) -> np.ndarray:
     return np.where(q_count > small, chunk, np.where(q_count > 0, small, 0))
 
 
+def pool_heads(kv_heads: int) -> int:
+    """KV heads a page of the pool holds for a model of ``kv_heads``: its
+    own, rounded up to a multiple of 8 where there are more than 8 and not
+    a multiple (30 -> 32).  A page is copied whole and the chip tiles its
+    head axis by 8, so the extra heads cost no memory; they are written
+    with zeros and read by nothing."""
+    if kv_heads <= 8 or kv_heads % 8 == 0:
+        return kv_heads
+    return -(-kv_heads // 8) * 8
+
+
+def write_rows(pages, layer, page_ids, page_slots, rows):
+    """``pages [L, num_pages, page_size, KH', D]`` with ``rows [T, KH, D]``
+    written in place at ``(layer, page_ids[t], page_slots[t])``, each row
+    widened with zero heads to the pool's ``KH'`` (:func:`pool_heads`)."""
+    extra = pages.shape[3] - rows.shape[1]
+    if extra:
+        rows = jnp.pad(rows, ((0, 0), (0, extra), (0, 0)))
+    return pages.at[layer, page_ids, page_slots].set(rows.astype(pages.dtype))
+
+
 def kv_block_pages(
     tile: int, *, q_per_kv: int, kv_heads: int, head_dim: int,
     page_size: int, itemsize: int,
 ) -> int:
     """Pages one flash update folds in at the rung of query tile ``tile``:
     the largest of ``KV_BLOCK_PAGES`` at which neither the page buffers
-    (K and V, two slots) nor one ``[flash rows, keys]`` float32 block
-    passes ``VMEM_BLOCK_BUDGET``.  Static shapes in, so the kernel's
-    trace and the scheduler's ``kv_blocks_walked`` agree by construction."""
-    buffers = 4 * page_size * kv_heads * head_dim * itemsize
+    (K and V, two slots, of the pool's :func:`pool_heads`) nor one ``[flash
+    rows, keys]`` float32 block passes ``VMEM_BLOCK_BUDGET``.  Static
+    shapes in, so the kernel's trace and the scheduler's
+    ``kv_blocks_walked`` agree by construction."""
+    buffers = 4 * page_size * pool_heads(kv_heads) * head_dim * itemsize
     scores = tile * q_per_kv * kv_heads * page_size * 4
     return max(
         [n for n in KV_BLOCK_PAGES if n * max(buffers, scores) <= VMEM_BLOCK_BUDGET],
@@ -242,14 +270,18 @@ def ragged_attention_reference(
     layer: jax.Array,  # [] int32
     sliding_window: Optional[int] = None,
     attend_block: int = 1,
+    kv_heads: Optional[int] = None,
 ) -> jax.Array:
     """Gather-then-attend oracle.  Returns [B, C, QH, D] in q.dtype.
+    ``kv_heads``: the model's, of the pool's ``KH`` (None: all of them).
 
     Rows past ``q_count`` (and rows of inactive slots) produce garbage —
     callers gather only the valid rows, exactly as the kernel's
     flash-state finalize leaves NaN in fully-masked rows."""
     b, c, qh, d = q.shape
-    kh = k_pages.shape[3]
+    kh = kv_heads or k_pages.shape[3]
+    if kh < k_pages.shape[3]:
+        k_pages, v_pages = k_pages[..., :kh, :], v_pages[..., :kh, :]
     g = qh // kh
     page_size = k_pages.shape[2]
     max_seq = page_table.shape[1] * page_size
@@ -291,11 +323,11 @@ def _ragged_attn_kernel(
     layer_ref,  # [1] int32: the layer whose pages are read
     # blocks
     q_ref,  # [1, C, QH, D] (VMEM)
-    k_hbm,  # [L, num_pages, page_size, KH, D] (stays in HBM)
+    k_hbm,  # [L, num_pages, page_size, KH', D] (stays in HBM; KH' >= KH)
     v_hbm,
     out_ref,  # [1, C, QH, D] in q's dtype
     # scratch
-    k_buf,  # [2, max(blocks) * page_size, KH, D] VMEM double buffer
+    k_buf,  # [2, max(blocks) * page_size, KH', D] VMEM double buffer
     v_buf,
     sem,  # DMA semaphores [2, 2]: block slot x (k, v)
     *,
@@ -517,7 +549,9 @@ def _ragged_attn_kernel(
 
 @functools.partial(
     jax.jit,
-    static_argnames=("interpret", "sliding_window", "block_pages", "attend_block"),
+    static_argnames=(
+        "interpret", "sliding_window", "block_pages", "attend_block", "kv_heads",
+    ),
 )
 def _ragged_attention_pallas(
     q: jax.Array,
@@ -532,15 +566,20 @@ def _ragged_attention_pallas(
     sliding_window: Optional[int] = None,
     block_pages: Optional[tuple[int, ...]] = None,
     attend_block: int = 1,
+    kv_heads: Optional[int] = None,
 ) -> jax.Array:
     """``block_pages`` names the KV block of every rung, smallest tile
     first (a test's and a probe's argument: the serving path leaves it
-    to :func:`kv_block_pages`)."""
+    to :func:`kv_block_pages`).  ``kv_heads``: the model's, of the pool's
+    ``KH`` (None: all of them); the heads a page holds past them are
+    copied with the page and never computed with."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, c, qh, d = q.shape
-    _, _, page_size, kh, _ = k_pages.shape
+    _, _, page_size, page_heads, _ = k_pages.shape
+    kh = kv_heads or page_heads
+    assert qh % kh == 0 and kh <= page_heads, (qh, kh, page_heads)
 
     tiles = query_tiles(c)
     blocks = block_pages or tuple(
@@ -582,8 +621,8 @@ def _ragged_attention_pallas(
         in_specs=[pl.BlockSpec((1, c, qh, d), row_block), any_space, any_space],
         out_specs=pl.BlockSpec((1, c, qh, d), row_block),
         scratch_shapes=[
-            pltpu.VMEM((2, buffer_rows, kh, d), k_pages.dtype),
-            pltpu.VMEM((2, buffer_rows, kh, d), v_pages.dtype),
+            pltpu.VMEM((2, buffer_rows, page_heads, d), k_pages.dtype),
+            pltpu.VMEM((2, buffer_rows, page_heads, d), v_pages.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
         ],
     )
@@ -609,16 +648,21 @@ def ragged_paged_attention(
     layer: jax.Array,
     sliding_window: Optional[int] = None,
     attend_block: int = 1,
+    kv_heads: Optional[int] = None,
 ) -> jax.Array:
-    """Dispatch: Pallas kernel on TPU, dense reference elsewhere."""
+    """Dispatch: Pallas kernel on TPU, dense reference elsewhere.
+    ``kv_heads``: the model's KV heads, where a page holds more
+    (:func:`pool_heads`)."""
     from ._dispatch import on_tpu
 
     if on_tpu():
         return _ragged_attention_pallas(
             q, k_pages, v_pages, page_table, kv_len, q_count, layer,
             sliding_window=sliding_window, attend_block=attend_block,
+            kv_heads=kv_heads,
         )
     return ragged_attention_reference(
         q, k_pages, v_pages, page_table, kv_len, q_count, layer,
         sliding_window=sliding_window, attend_block=attend_block,
+        kv_heads=kv_heads,
     )

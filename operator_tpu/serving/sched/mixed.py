@@ -58,7 +58,9 @@ class StepView:
     """What a family's layer body (``models.family_of(config).mixed_layer``)
     is given of one dispatch: the flat tokens' packing and ``attend``, the
     attention every family shares.  The body is a ``lax.scan`` step over
-    ``{"w": the layer's weights, "layer": its index}`` whose carry is
+    ``{"w": the layer's weights, "layer": its index}`` (for a family with
+    ``scan_steps``, a period's index, its weights read from ``stacks``)
+    whose carry is
     ``(x, pools, recurrent)``: the residual stream, the KV pools (through
     ``attend``) and the family's recurrent pools, or ``None``."""
 
@@ -161,7 +163,10 @@ def make_mixed_fn(runtime: Any, t_budget: int, chunk: int,
     jax, jnp = runtime._jax, runtime._jnp
     config = runtime.config
     b_slots = runtime.max_slots
-    inv_freq = rope_frequencies(config)
+    # a model without positional rotation (``rope_theta`` None:
+    # models/olmo_hybrid.py) is traced without it: decided here, statically
+    rope = config.rope_theta is not None
+    inv_freq = rope_frequencies(config) if rope else None
     lax = jax.lax
     width = max(1, int(spec_width))
     # muP models scale the embedding and the logits (models/falcon_h1.py)
@@ -175,8 +180,14 @@ def make_mixed_fn(runtime: Any, t_budget: int, chunk: int,
     # parameters' (models/ouro.py: float32 through 384 additions a token)
     family = family_of(config)
     stream_dtype = getattr(family, "STREAM_DTYPE", None)
-    # leaves of the layers the loop does not scan (a family's expert stacks)
+    # leaves of the layers the loop does not scan (a family's expert stacks,
+    # or every leaf of a family whose body reads its layers itself)
     whole = tuple(getattr(family, "WHOLE_STACKS", ()))
+    # steps of the layer loop: one a layer, or one a PERIOD for a family
+    # whose stack repeats a period of layers of several kinds
+    # (models/olmo_hybrid.py: its body is a period, and it maps a step's
+    # index to its layers' weights, planes and states itself)
+    steps = family.scan_steps(config) if hasattr(family, "scan_steps") else config.num_layers
     # positions of a block a model denoises a step (models/sdar.py); 0 for
     # a model that commits one token a row
     block = int(getattr(config, "block_length", 0))
@@ -185,7 +196,7 @@ def make_mixed_fn(runtime: Any, t_budget: int, chunk: int,
     def mixed_fn(params, paged, ids, rows, pos, valid, in_row,
                  q_start, q_count, kv_len, latest, from_prev,
                  sample_start, spec_len, rng, temp, top_p, denoise=None):
-        from ...ops.ragged_attention import ragged_paged_attention
+        from ...ops.ragged_attention import ragged_paged_attention, write_rows
 
         page_size = paged.page_size
         # decode-ahead chaining: a token flagged from_prev takes its id
@@ -219,26 +230,23 @@ def make_mixed_fn(runtime: Any, t_budget: int, chunk: int,
             q = q.reshape(1, t_budget, config.num_heads, config.head_dim)
             k = k.reshape(1, t_budget, config.num_kv_heads, config.head_dim)
             v = v.reshape(1, t_budget, config.num_kv_heads, config.head_dim)
-            q = apply_rope(q, positions, inv_freq)
-            k = apply_rope(k, positions, inv_freq)
+            if rope:
+                q = apply_rope(q, positions, inv_freq)
+                k = apply_rope(k, positions, inv_freq)
             # scatter this step's K/V into the layer's pages FIRST — the
             # ragged kernel then reads a cache that already holds every
             # token a causal query may attend to (its own included).  The
             # pools are the loop's carry: the rows land in place
             with jax.named_scope("kv_write"):
-                k_pages = pools["k"].at[layer, page_ids, page_slots].set(
-                    k[0].astype(pools["k"].dtype)
-                )
-                v_pages = pools["v"].at[layer, page_ids, page_slots].set(
-                    v[0].astype(pools["v"].dtype)
-                )
+                k_pages = write_rows(pools["k"], layer, page_ids, page_slots, k[0])
+                v_pages = write_rows(pools["v"], layer, page_ids, page_slots, v[0])
             with jax.named_scope("attn"):
                 q_pack = q[0][pack_idx]  # [B, chunk, QH, D]
                 attn_pack = ragged_paged_attention(
                     q_pack.astype(k_pages.dtype), k_pages, v_pages,
                     paged.page_table, kv_len, q_count, layer,
                     sliding_window=config.sliding_window,
-                    attend_block=max(1, block),
+                    attend_block=max(1, block), kv_heads=config.num_kv_heads,
                 )
                 # back to flat [T, QH, D].  The kernel leaves what it was
                 # not asked for unwritten (idle slots, rows past q_count):
@@ -285,13 +293,12 @@ def make_mixed_fn(runtime: Any, t_budget: int, chunk: int,
         # same weights, each pass writing and reading its own planes.  The
         # pools stay in the carry through both loops.  One pass is the
         # plain layer loop, under no second loop
-        planes = jnp.arange(passes * config.num_layers, dtype=jnp.int32)
+        planes = jnp.arange(passes * steps, dtype=jnp.int32)
         if passes == 1:
             (x, pools, recurrent), per_layer = one_pass((x, pools, recurrent), planes)
         else:
             (x, pools, recurrent), per_layer = lax.scan(
-                one_pass, (x, pools, recurrent),
-                planes.reshape(passes, config.num_layers),
+                one_pass, (x, pools, recurrent), planes.reshape(passes, steps),
             )
 
         def logits_at(samp_idx):

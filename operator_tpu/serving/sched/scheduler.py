@@ -253,6 +253,9 @@ class Scheduler:
         #: a model with experts: its steps count ``moe_tokens`` and bring
         #: two expert counters back
         self._moe = bool(getattr(model, "num_experts", 0))
+        #: layers of the gated delta rule (models/olmo_hybrid.py): the
+        #: depth the delta kernel's cost is counted over; 0 for the others
+        self._delta_layers = int(getattr(model, "delta_layers", 0))
         if self._block:
             if spec_decode:
                 self.switched_off["spec_decode"] = (
@@ -707,6 +710,10 @@ class Scheduler:
                 **(
                     {"state_rows": packed.counts["state_rows"]}
                     if self._recurrent else {}  # no such argument without the state
+                ),
+                **(
+                    {"delta_layers": self._delta_layers}
+                    if self._delta_layers else {}
                 ),
                 # a model that denoises blocks, a model with experts: the
                 # same rule

@@ -151,6 +151,10 @@ def _weight_moves(hlo: str, params) -> list[str]:
     for leaf in jax.tree_util.tree_leaves(params["layers"]):
         if leaf.dtype == jnp.int8:
             shapes |= _stack_shapes("s8", tuple(leaf.shape))
+            if leaf.ndim == 4:
+                # stacked by period and layer (models/olmo_hybrid.py), or
+                # by layer and expert: one layer's (one expert's) slice too
+                shapes |= _stack_shapes("s8", tuple(leaf.shape[1:]))
     return _moves(hlo, shapes, ("copy",))
 
 
@@ -181,6 +185,7 @@ def _mixed_step_case(topo_device, model_id=None, slots=_SLOTS, t_budget=None,
 
     from operator_tpu.models import get_config
     from operator_tpu.ops.paged_attention import PagedKVCache
+    from operator_tpu.ops.ragged_attention import pool_heads
     from operator_tpu.serving import sampler
     from operator_tpu.serving.sched.mixed import make_mixed_fn
     from operator_tpu.utils.config import OperatorConfig
@@ -207,7 +212,7 @@ def _mixed_step_case(topo_device, model_id=None, slots=_SLOTS, t_budget=None,
     generator.config = config
     pages_per_seq = _MAX_SEQ // _PAGE
     num_pages = kv_pages or slots * pages_per_seq + 1
-    pool = (num_pages, _PAGE, config.num_kv_heads, config.head_dim)
+    pool = (num_pages, _PAGE, pool_heads(config.num_kv_heads), config.head_dim)
     recurrent = PagedKVCache.recurrent_shapes(config, slots)
     paged = PagedKVCache(
         k_pages=shaped((config.kv_planes, *pool), jnp.bfloat16),
@@ -326,6 +331,7 @@ def main() -> int:
         UnsupportedHeadDim,
         _ragged_attention_pallas,
         kv_block_pages,
+        pool_heads,
         query_tiles,
         require_ragged_kernel_support,
     )
@@ -353,7 +359,8 @@ def main() -> int:
 
     def ragged_args(heads, kv_heads, head_dim, chunk, rows=4):
         pages = _MAX_SEQ // _PAGE
-        pool = (2, rows * pages + 1, _PAGE, kv_heads, head_dim)  # two layers
+        # two layers, each page of the heads the pool holds for kv_heads
+        pool = (2, rows * pages + 1, _PAGE, pool_heads(kv_heads), head_dim)
         return (
             shaped((rows, chunk, heads, head_dim), jnp.bfloat16),
             shaped(pool, jnp.bfloat16),
@@ -392,22 +399,20 @@ def main() -> int:
     results, failed = {}, 0
     kv_blocks = {}  # ragged cases: the KV block each rung of the tile walks
 
-    def ragged_case(name, fn, args):
+    def ragged_case(name, fn, heads, kv_heads, head_dim, chunk, rows=4):
         """A case of the ragged kernel alone, and the pages a flash update
         folds in at each of its rungs (small tile first), by the module's
         own rule from the very shapes compiled."""
-        q, k_pool = args[0], args[1]
-        _, chunk, heads, head_dim = q.shape
-        _, _, page_size, kv_heads, _ = k_pool.shape
+        args = ragged_args(heads, kv_heads, head_dim, chunk, rows=rows)
         kv_blocks[name] = [
             kv_block_pages(
                 tile, q_per_kv=heads // kv_heads, kv_heads=kv_heads,
-                head_dim=head_dim, page_size=page_size,
-                itemsize=k_pool.dtype.itemsize,
+                head_dim=head_dim, page_size=_PAGE,
+                itemsize=args[1].dtype.itemsize,
             )
             for tile in query_tiles(chunk)
         ]
-        cases.append((name, fn, args))
+        cases.append((name, functools.partial(fn, kv_heads=kv_heads), args))
 
     # ragged kernel x every registered config: compile, or a NAMED refusal
     for name, config in sorted(_REGISTRY.items()):
@@ -424,7 +429,7 @@ def main() -> int:
                 fn = functools.partial(fn, sliding_window=config.sliding_window)
             if getattr(config, "block_length", 0):
                 fn = functools.partial(fn, attend_block=config.block_length)
-            ragged_case(f"ragged_{name}_c{chunk}", fn, ragged_args(*geometry, chunk))
+            ragged_case(f"ragged_{name}_c{chunk}", fn, *geometry, chunk)
     # the kernel alone at the benchmark's cells (BENCHMARK.json: heads
     # x slots, bf16 pool, page 64, chunk 64): both rungs of the query tile
     # at 6, 7 and 5 queries a kv head, which the 4-row cases above lower
@@ -435,23 +440,25 @@ def main() -> int:
         ("falcon-h1-34b", 20, 4, 128),
         # one query head a kv head: sixteen [tile, 1, D] slabs
         ("ouro-2.6b", 16, 16, 10),
+        # the hybrid's 30 heads, a page holding 32 (pool_heads)
+        ("olmo-hybrid-7b", 30, 30, 48),
     ):
         ragged_case(
             f"ragged_cell_{tag}_b{slots}", _ragged_attention_pallas,
-            ragged_args(heads, kv_heads, 128, _CHUNK, rows=slots),
+            heads, kv_heads, 128, _CHUNK, rows=slots,
         )
     # eight query heads a kv head under the block-causal mask (blocks of 4)
     ragged_case(
         "ragged_cell_sdar-30b-a3b_b128",
         functools.partial(_ragged_attention_pallas, attend_block=4),
-        ragged_args(32, 4, 128, _CHUNK, rows=128),
+        32, 4, 128, _CHUNK, rows=128,
     )
     # a window that actually bites inside max_seq (Mistral's 4096 is wider
     # than the serving cap, so its first-page term folds to zero above)
     ragged_case(
         "ragged_window",
         functools.partial(_ragged_attention_pallas, sliding_window=256),
-        ragged_args(32, 8, 128, _CHUNK),
+        32, 8, 128, _CHUNK,
     )
 
     # the state-space scan kernel alone at the benchmark's cell: 32 heads x
@@ -472,6 +479,27 @@ def main() -> int:
             shaped(state_shape, jnp.float32), shaped((), jnp.int32),
             shaped((f_slots,), jnp.int32), shaped((f_slots,), jnp.int32),
             shaped((f_slots,), jnp.bool_),
+        ),
+    ))
+
+    # the gated delta-rule kernel alone at the benchmark's cell: 30 heads of
+    # 96 x 192 float32 held in pairs, 24 linear layers, 48 slots, 96 tokens
+    from operator_tpu.ops.gated_delta import _gated_delta_pallas
+
+    olmo = _REGISTRY["olmo-hybrid-7b"]
+    o_slots, o_tokens = 48, 96
+    delta_state, _ = PagedKVCache.recurrent_shapes(olmo, o_slots)
+    o_heads = olmo.linear_num_value_heads
+    cases.append((
+        "gated_delta_cell_olmo-hybrid-7b_b48", _gated_delta_pallas,
+        (
+            shaped((o_tokens, o_heads, olmo.linear_key_head_dim), jnp.float32),
+            shaped((o_tokens, o_heads, olmo.linear_key_head_dim), jnp.float32),
+            shaped((o_tokens, o_heads, olmo.linear_value_head_dim), jnp.bfloat16),
+            shaped((o_tokens, o_heads), jnp.float32), shaped((o_tokens, o_heads), jnp.float32),
+            shaped(delta_state, jnp.float32), shaped((), jnp.int32),
+            shaped((o_slots,), jnp.int32), shaped((o_slots,), jnp.int32),
+            shaped((o_slots,), jnp.bool_),
         ),
     ))
 
@@ -530,6 +558,13 @@ def main() -> int:
             ("mixed_step_sdar-30b-a3b-12l_b128", dict(
                 model_id="sdar-30b-a3b-12l", slots=128, t_budget=m_tokens,
                 kv_pages=int(os.environ.get("AOT_SDAR_KV_PAGES", "4096")),
+                spec_width=1,
+            )),
+            # the hybrid's cell: a period a scan step (3 delta-rule layers,
+            # 1 full), 8 KV planes of 544 pages, 2.55 GB of state in 24 layers
+            ("mixed_step_olmo-hybrid-7b_b48", dict(
+                model_id="olmo-hybrid-7b", slots=o_slots, t_budget=o_tokens,
+                kv_pages=544,
                 spec_width=1,
             )),
         ):

@@ -41,6 +41,8 @@ SHAPES = {
     "ouro": (16, 16, 10), "falcon": (20, 4, 128),
     # with --attend-block 4 --row-queries 1,4,8: rows that denoise a block
     "sdar": (32, 4, 128),
+    # the hybrid's 30 MHA heads, a page holding 32 (``pool_heads``)
+    "olmo": (30, 30, 48),
 }
 PAGE, HEAD_DIM, CHUNK, LAYERS = 64, 128, 64, 2
 
@@ -60,7 +62,7 @@ def load_parent(root: str):
 def make_case(heads, kv_heads, slots, pages_a_row, seed=0):
     keys = jax.random.split(jax.random.PRNGKey(seed), 3)
     num_pages = slots * pages_a_row + 1
-    pool = (LAYERS, num_pages, PAGE, kv_heads, HEAD_DIM)
+    pool = (LAYERS, num_pages, PAGE, ours.pool_heads(kv_heads), HEAD_DIM)
     q = jax.random.normal(keys[0], (slots, CHUNK, heads, HEAD_DIM), jnp.bfloat16)
     k = jax.random.normal(keys[1], pool, jnp.bfloat16)
     v = jax.random.normal(keys[2], pool, jnp.bfloat16)
@@ -93,6 +95,7 @@ def parity(kernel, heads, kv_heads, blocks):
         want = ours.ragged_attention_reference(
             *(x.astype(jnp.float32) for x in (q, k, v)), table,
             jnp.asarray(kv_len), jnp.asarray(q_count), jnp.int32(1),
+            kv_heads=kv_heads,
             **{k_: v_ for k_, v_ in blocks.items() if k_ == "attend_block"},
         )
     live = np.arange(CHUNK)[None, :] < q_count[:, None]
@@ -176,7 +179,7 @@ def main() -> None:
             int(n) for n in name.split("x")
         )
         variants.append((name, ours._ragged_attention_pallas,
-                         {"block_pages": blocks, **extra}))
+                         {"block_pages": blocks, "kv_heads": kv_heads, **extra}))
     if args.parent:
         variants.append(
             ("parent", load_parent(args.parent)._ragged_attention_pallas, extra)

@@ -127,6 +127,9 @@ def test_every_ragged_case_names_the_kv_block_of_each_rung(record):
         "ragged_cell_ouro-2.6b_b10": [2, 2],
         # eight query heads a kv head, under the block-causal mask
         "ragged_cell_sdar-30b-a3b_b128": [8, 4],
+        # 32 heads a page (the hybrid's 30, held as the tile holds them):
+        # K and V in two slots are the 2 MiB budget at one page
+        "ragged_cell_olmo-hybrid-7b_b48": [1, 1],
     }, cells
 
 
@@ -195,6 +198,31 @@ def test_the_sparse_expert_models_step_holds_its_stacks_and_its_pool_once(record
     assert total < 15.75 * 2**30 - 1e9
 
 
+def test_the_hybrid_models_step_sizes_its_pools_by_layer_kind_and_fits(record):
+    """The benchmark cell of the model whose layers are of two kinds: the
+    delta-rule kernel alone and the whole mixed step at 48 slots.  The KV
+    pool has a plane for each of the 8 full-attention layers (32 heads a
+    page: the 30 the model has, as the chip's tile holds them), the state
+    pool 24 linear layers of 15 head pairs x 96 x 384 float32; both come
+    back aliased.  The loop makes a step a period and reads every layer
+    from the whole stacks, so no period's weights are copied out (a
+    scanned period's slice was: 683 MB of temporaries); the step leaves
+    the chip over a GB."""
+    from operator_tpu.ops.gated_delta import KERNEL_NAME
+
+    kernels = record["kernels"]
+    assert kernels["gated_delta_cell_olmo-hybrid-7b_b48"]["ok"], record
+    step = kernels["mixed_step_olmo-hybrid-7b_b48"]
+    assert sum(call.startswith(KERNEL_NAME) for call in step["pallas_calls"]) == 3, step
+    assert step["kv_pool"]["shape"] == [8, 544, 64, 32, 128]
+    state_bytes = 24 * 48 * 15 * 96 * 384 * 4
+    assert step["alias_bytes"] >= state_bytes + step["kv_pool"]["bytes"], step
+    assert step["output_bytes"] - step["alias_bytes"] < 1e6
+    assert step["temp_bytes"] < 100e6, step
+    total = step["argument_bytes"] + step["output_bytes"] - step["alias_bytes"] + step["temp_bytes"]
+    assert total < 15.75 * 2**30 - 1e9
+
+
 @pytest.mark.parametrize("case, conditionals", [
     ("mixed_step_default_model", 1),
     ("mixed_step_qwen2.5-1.5b_b128", 1),
@@ -202,6 +230,8 @@ def test_the_sparse_expert_models_step_holds_its_stacks_and_its_pool_once(record
     ("mixed_step_falcon-h1-34b-6l_b128", 0),
     # 192 planes, through the pass loop and the layer loop inside it
     ("mixed_step_ouro-2.6b_b10", 1),
+    # planes for the 8 full-attention layers alone, a period a loop step
+    ("mixed_step_olmo-hybrid-7b_b48", 0),
 ])
 def test_the_kv_pool_is_held_once_and_never_copied(record, case, conditionals):
     """The stacked KV pools ride the layer loop's carry and are written in

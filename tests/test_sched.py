@@ -183,6 +183,12 @@ class TestRaggedKernel:
         # one page a turn and four serve the same rows alike
         dict(id="block-1-and-4-agree", blocks=(4, 4), agree_with=(1, 1),
              **_BLOCK_ROWS),
+        # pages that hold more heads than the model has (ten held as
+        # sixteen: ragged_attention.pool_heads), NaN in the six past them:
+        # one query head a kv head, and two
+        dict(id="heads-held-wider", qh=10, kh=10, held=16, **_EDGES),
+        dict(_BLOCK_ROWS, id="heads-held-wider-gqa", qh=20, kh=10, held=16,
+             blocks=(2, 2)),
     ], ids=lambda case: case["id"])
     def test_parity_by_rung(self, case):
         """The kernel against the reference where what it works follows
@@ -198,6 +204,10 @@ class TestRaggedKernel:
             ps=ps, pps=pps,
         )
         q, k, v = (x.astype(dtype) for x in (q, k, v))
+        kv_heads = case["kh"]
+        if "held" in case:
+            wide = ((0, 0),) * 3 + ((0, case["held"] - kv_heads), (0, 0))
+            k, v = (jnp.pad(x, wide, constant_values=jnp.nan) for x in (k, v))
         kv_len = jnp.asarray(case["kv_len"], jnp.int32)
         q_count = jnp.asarray(case["q_count"], jnp.int32)
         assert max(case["kv_len"]) <= ps * pps
@@ -213,6 +223,7 @@ class TestRaggedKernel:
         run = functools.partial(
             _ragged_attention_pallas, q, *given, table, kv_len, q_count,
             jnp.int32(layer), interpret=True, sliding_window=window,
+            kv_heads=kv_heads,
         )
         got = run(block_pages=case.get("blocks"))
         assert got.shape == q.shape and got.dtype == dtype
@@ -229,12 +240,14 @@ class TestRaggedKernel:
             q.astype(jnp.float32),
             *(x[layer][None].astype(jnp.float32) for x in (k, v)),
             table, kv_len, q_count, jnp.int32(0), sliding_window=window,
+            kv_heads=kv_heads,
         )
         tol = 2e-5 if dtype == jnp.float32 else 2e-2
         others = [
             ragged_attention_reference(
                 *(x.astype(jnp.float32) for x in (q, k, v)), table, kv_len,
                 q_count, jnp.int32(other), sliding_window=window,
+                kv_heads=kv_heads,
             )
             for other in range(self.LAYERS)
         ]
