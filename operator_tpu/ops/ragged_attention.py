@@ -62,7 +62,20 @@ it computes follows that row's ``q_count``, not the compiled chunk ``C``:
   ``min(SMALL_TILE, C)`` query tokens, any other row all ``C``.  Flash
   state, score block, mask, ``exp`` and both products have the tile's
   rows (:func:`query_tile_rows` is the rule, and what the scheduler's
-  ``q_tile_rows`` counts).  Both rungs are branches of the one kernel;
+  ``q_tile_rows`` counts).  The rungs are branches of the one kernel;
+- *the rung of one query*, built only at one query head a KV head (MHA,
+  a static shape: :func:`query_tiles`): a row with ``q_count == 1`` does
+  not take a product a KV head, which at MHA streams 8 query rows to
+  serve one and pays a product's fixed cost once a head and page (1.25
+  us a page of 16 heads, twice its copies, on a v5e).  It reads
+  the block's ``[keys, KH', D]`` buffer as ``keys x KH'`` rows in its
+  own layout and makes ONE score product of the row's ``[QH, D]``
+  query against all of them and ONE value product, each query head's
+  flash row keeping its own head's keys alone (the rest masked to
+  probability 0, and a value row of a head past the model's selected
+  out as past ``kv_len`` is).  Operands as on the other rungs: bf16
+  scores into float32, probabilities and the value product float32;
+  its block ``N`` is the small tile's;
 - *the KV block*: a live row's pages ``first .. num_live`` are walked
   ``N`` to a turn of the loop, and a turn scores, masks and folds its
   ``N x page_size`` keys into the flash state in ONE update (one wait
@@ -163,17 +176,24 @@ KV_BLOCK_PAGES = (1, 2, 4, 8)
 VMEM_BLOCK_BUDGET = 2 * 2**20
 
 
-def query_tiles(chunk: int) -> tuple[int, ...]:
-    """The kernel's rungs at a compiled chunk: the query tiles, ascending."""
-    return tuple(sorted({min(SMALL_TILE, chunk), chunk}))
+def query_tiles(chunk: int, *, q_per_kv: int) -> tuple[int, ...]:
+    """The kernel's rungs at a compiled chunk: the query tiles, ascending.
+    One query head a KV head (MHA) adds the tile of one query, whose rung
+    scores a row against every head of a block at once (the module doc)."""
+    tiles = {min(SMALL_TILE, chunk), chunk}
+    if q_per_kv == 1:
+        tiles.add(1)
+    return tuple(sorted(tiles))
 
 
-def query_tile_rows(q_count: np.ndarray, chunk: int) -> np.ndarray:
+def query_tile_rows(q_count: np.ndarray, chunk: int, *, q_per_kv: int) -> np.ndarray:
     """The query tile the kernel works for each slot (0 for a slot it
-    skips), by the rule ``_ragged_attn_kernel`` branches on.  On the
-    host: the scheduler counts its ``q_tile_rows`` with it."""
-    small = min(SMALL_TILE, chunk)
-    return np.where(q_count > small, chunk, np.where(q_count > 0, small, 0))
+    skips): the smallest of :func:`query_tiles` that holds the slot's
+    queries, the rule ``_ragged_attn_kernel`` branches on.  On the host:
+    the scheduler counts its ``q_tile_rows`` with it."""
+    tiles = np.asarray(query_tiles(chunk, q_per_kv=q_per_kv))
+    fit = tiles[np.minimum(np.searchsorted(tiles, q_count), len(tiles) - 1)]
+    return np.where(q_count > 0, fit, 0)
 
 
 def pool_heads(kv_heads: int) -> int:
@@ -354,7 +374,8 @@ def _ragged_attn_kernel(
     out head-major — row ``h*T*G + i*G + j`` is query token ``i`` of q
     head ``h*G + j`` at tile ``T`` — so the per-kv-head GQA dots write
     contiguous slabs; the finalize transposes back to [T, QH, D] and
-    casts to the output's dtype once."""
+    casts to the output's dtype once.  The rung of one query at one
+    query head a kv head (``all_heads``) keeps one flash row a head."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -374,6 +395,9 @@ def _ragged_attn_kernel(
     def rung(tile: int, pages: int):
         """The whole of a live row's work at a static query tile, its
         pages walked ``pages`` to a block."""
+        # MHA's rung of one query takes every head of a block at once
+        # (``all_heads``); every other rung a product a KV head
+        one_query = tile == 1 and q_per_kv == 1
         slab = tile * q_per_kv  # flash rows per kv head (token-major within)
         total = kv_heads * slab
         walked = num_live - first  # >= 1 for a live row
@@ -416,9 +440,11 @@ def _ragged_attn_kernel(
                         sem.at[slot, 1],
                     ))
 
-        def walk(m_scratch, l_scratch, acc_scratch):
-            each_live_page(0, lambda copy: copy.start())
-            init_state(m_scratch, l_scratch, acc_scratch)
+        def head_products(m_scratch, l_scratch, acc_scratch):
+            """A KV head's query rows against its keys on the MXU, one
+            score and one value product a head: what the rungs of several
+            query rows a head fold with.  Returns the fold's positions,
+            the fold and the finish."""
             q = q_ref[0, :tile].astype(jnp.float32)  # [tile, QH, D]
             # the score product's operands keep the pool's dtype (bf16
             # values multiply exactly into the f32 accumulator); the
@@ -498,6 +524,86 @@ def _ragged_attn_kernel(
 
                 update_state(m_scratch, l_scratch, acc_scratch, s, values)
 
+            def finish():
+                out = finalize(l_scratch, acc_scratch)  # [KH*tile*G, D]
+                # slab h holds [tile, G, D]: the head band of [tile, QH, D]
+                for h in range(kv_heads):
+                    out_ref[0, :tile, h * q_per_kv : (h + 1) * q_per_kv, :] = (
+                        out[h * slab : (h + 1) * slab]
+                        .reshape(tile, q_per_kv, head_dim).astype(out_ref.dtype)
+                    )
+
+            return positions, fold, finish
+
+        def all_heads(m_scratch, l_scratch, acc_scratch):
+            """One query against every head of a block at once, in the
+            page's own layout (MHA's rung of one query): the block's
+            ``[width, KH', D]`` keys read as ``width x KH'`` rows, ONE
+            score product of the row's query heads against all of them and
+            ONE value product, where each query head keeps its own head's
+            keys alone (the others masked out: probability 0).  No head is
+            sliced out of the buffer and no product streams rows that
+            serve nothing; flash state is one row a head.  Returns what
+            ``head_products`` does."""
+            held = k_buf.shape[2]  # the page's heads (pool_heads)
+            q_heads = q_ref[0, 0].astype(k_buf.dtype)  # [KH, D]
+            q_end = _block_end(q_base, attend_block)
+
+            def positions(width):
+                """Score column and value row ``j`` are key ``j // held``
+                of head ``j % held``: each column's key, whether it is the
+                query head's own, and each value row's key and whether its
+                head is the model's."""
+                col = jax.lax.broadcasted_iota(jnp.int32, (kv_heads, width * held), 1)
+                row = jax.lax.broadcasted_iota(jnp.int32, (kv_heads, width * held), 0)
+                val = jax.lax.broadcasted_iota(jnp.int32, (width * held, head_dim), 0)
+                return col // held, col % held == row, val // held, val % held < kv_heads
+
+            def fold(t, width, key_of, own, value_key, value_head):
+                slot = t % 2
+                each_live_page(
+                    t, lambda copy: copy.wait(), upto=width // page_size
+                )
+                k = k_buf[slot, :width].reshape(width * held, head_dim)
+                v = v_buf[slot, :width].reshape(width * held, head_dim)
+                block_pos = (first + t * pages) * page_size
+                s = jax.lax.dot_general(
+                    q_heads, k, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                ) * scale  # [KH, width x KH']
+                kv_pos = block_pos + key_of
+                mask = own & (kv_pos <= q_end) & (kv_pos < seq_len)
+                if window is not None:
+                    mask = mask & (kv_pos > q_base - window)
+                s = jnp.where(mask, s, _NEG_INF)
+                # as in head_products: a key at or past ``kv_len``, and a
+                # head past the model's, are selected out of the values,
+                # not multiplied by their zero probability
+                value_live = value_key < seq_len - block_pos
+                if held > kv_heads:
+                    value_live = value_live & value_head
+
+                def values(p):
+                    return jax.lax.dot_general(
+                        p, jnp.where(value_live, v.astype(jnp.float32), 0.0),
+                        (((1,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32,
+                    )
+
+                update_state(m_scratch, l_scratch, acc_scratch, s, values)
+
+            def finish():
+                out_ref[0, 0] = finalize(l_scratch, acc_scratch).astype(out_ref.dtype)
+
+            return positions, fold, finish
+
+        def walk(m_scratch, l_scratch, acc_scratch):
+            each_live_page(0, lambda copy: copy.start())
+            init_state(m_scratch, l_scratch, acc_scratch)
+            positions, fold, finish = (all_heads if one_query else head_products)(
+                m_scratch, l_scratch, acc_scratch
+            )
+
             @pl.when(n_turns > 0)
             def _whole_blocks():
                 at_width = positions(pages * page_size)
@@ -522,13 +628,7 @@ def _ragged_attn_kernel(
 
                 below = fit
 
-            out = finalize(l_scratch, acc_scratch)  # [KH*tile*G, D]
-            # slab h holds [tile, G, D]: the head band of [tile, QH, D]
-            for h in range(kv_heads):
-                out_ref[0, :tile, h * q_per_kv : (h + 1) * q_per_kv, :] = (
-                    out[h * slab : (h + 1) * slab]
-                    .reshape(tile, q_per_kv, head_dim).astype(out_ref.dtype)
-                )
+            finish()
 
         pl.run_scoped(
             walk,
@@ -581,7 +681,7 @@ def _ragged_attention_pallas(
     kh = kv_heads or page_heads
     assert qh % kh == 0 and kh <= page_heads, (qh, kh, page_heads)
 
-    tiles = query_tiles(c)
+    tiles = query_tiles(c, q_per_kv=qh // kh)
     blocks = block_pages or tuple(
         kv_block_pages(
             tile, q_per_kv=qh // kh, kv_heads=kh, head_dim=d,

@@ -288,16 +288,19 @@ class Scheduler:
                 feature, model.name, model.family, why,
             )
         self.chunk = max(1, min(chunk, generator.max_seq))
+        #: query heads a KV head: with the chunk, what decides the ragged
+        #: kernel's rungs (``query_tiles``)
+        self._q_per_kv = model.num_heads // model.num_kv_heads
         #: pages a flash update of the ragged kernel folds in, by query
         #: tile: the kernel's own rule on the static shapes it is given
         self._kv_block_of = {
             tile: kv_block_pages(
-                tile, q_per_kv=model.num_heads // model.num_kv_heads,
+                tile, q_per_kv=self._q_per_kv,
                 kv_heads=model.num_kv_heads, head_dim=model.head_dim,
                 page_size=generator.page_size,
                 itemsize=np.dtype(generator.cache_dtype).itemsize,
             )
-            for tile in query_tiles(self.chunk)
+            for tile in query_tiles(self.chunk, q_per_kv=self._q_per_kv)
         }
         #: the most query tokens a generating row packs a step: one, or a
         #: block led by the block before it
@@ -1412,7 +1415,7 @@ class Scheduler:
         pages, pairs = _kv_walk(
             kv_len, q_count, g.page_size, g.config.sliding_window
         )
-        tile_rows = query_tile_rows(q_count, self.chunk)
+        tile_rows = query_tile_rows(q_count, self.chunk, q_per_kv=self._q_per_kv)
         wide = bool(spec_len.any())
         return _Packed(
             ids=ids, rows=rows, pos=pos, valid=valid, in_row=in_row,
@@ -1429,7 +1432,8 @@ class Scheduler:
                 ),
                 # the query-tile rows one layer's kernel call works, by
                 # the kernel's own rule: nothing for a slot without
-                # queries, the small tile or the whole chunk for the rest
+                # queries, else the smallest rung that holds them (one at
+                # MHA for a row of one query, the small tile, the chunk)
                 "q_tile_rows": int(tile_rows.sum()),
                 # slots whose recurrent state a layer's scan call reads
                 # and rewrites (the others it skips); no such state, no count
@@ -1516,8 +1520,10 @@ class Scheduler:
             # shadow holds the OPTIMISTIC lengths (all drafts accepted) so
             # the next plan's packing is consistent with pred_kv; a verify
             # commit re-anchors the slot from the row's authoritative state
-            # when drafts were rejected
-            self._kv_shadow = p.kv_len
+            # when drafts were rejected.  A copy: on the CPU ``jnp.asarray``
+            # may share ``p.kv_len``'s memory with the step's argument, and
+            # the shadow is written in place (a recycled slot's 0)
+            self._kv_shadow = p.kv_len.copy()
             # NO block/fetch here: the commit side owns the step's one host
             # sync (GL001: host loop code, not jit-reachable).  Record the
             # in-flight deltas planning reads as pred_* until commit.

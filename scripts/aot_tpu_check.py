@@ -11,11 +11,13 @@ CPU host finds them before chip time is spent.  Covered:
 - the ragged mixed-phase kernel — the continuous scheduler's ONLY
   attention on a TPU — at the ``(QH, KH, D)`` of every registered model
   config, bf16, page 64, chunk widths 5 (verify) and 64 (prefill), plus a
-  sliding-window case, and alone at the benchmark's cells (12/2 heads
-  x 128 slots, 28/4 x 32, 20/4 x 128, 16/16 x 10): both rungs of its
-  query tile, each with the KV block ``kv_block_pages`` gives it (the
-  record's ``kv_block_pages``: pages a flash update folds in, small tile
-  then chunk).  A config the kernel cannot serve must be REFUSED
+  sliding-window case at 8 and at 1 query head a kv head, and alone at
+  the benchmark's cells (12/2 heads x 128 slots, 28/4 x 32, 20/4 x 128,
+  16/16 x 10, 30/30 x 48): every rung of its query tile, each with the
+  KV block ``kv_block_pages`` gives it (the record's ``query_tiles``,
+  smallest first: at one query head a kv head the rung of one query
+  leads; and ``kv_block_pages``: pages a flash update folds in at each).
+  A config the kernel cannot serve must be REFUSED
   by ``require_ragged_kernel_support`` (a named error at engine build),
   never silently routed elsewhere — the check asserts which of the two
   happens for each config;
@@ -397,20 +399,22 @@ def main() -> int:
     ))
 
     results, failed = {}, 0
-    kv_blocks = {}  # ragged cases: the KV block each rung of the tile walks
+    #: ragged cases: each rung's query tile, and the KV block it walks
+    rungs = {}
 
     def ragged_case(name, fn, heads, kv_heads, head_dim, chunk, rows=4):
-        """A case of the ragged kernel alone, and the pages a flash update
-        folds in at each of its rungs (small tile first), by the module's
-        own rule from the very shapes compiled."""
+        """A case of the ragged kernel alone, its rungs' query tiles and
+        the pages a flash update folds in at each (smallest tile first),
+        by the module's own rules from the very shapes compiled."""
         args = ragged_args(heads, kv_heads, head_dim, chunk, rows=rows)
-        kv_blocks[name] = [
+        tiles = query_tiles(chunk, q_per_kv=heads // kv_heads)
+        rungs[name] = list(tiles), [
             kv_block_pages(
                 tile, q_per_kv=heads // kv_heads, kv_heads=kv_heads,
                 head_dim=head_dim, page_size=_PAGE,
                 itemsize=args[1].dtype.itemsize,
             )
-            for tile in query_tiles(chunk)
+            for tile in tiles
         ]
         cases.append((name, functools.partial(fn, kv_heads=kv_heads), args))
 
@@ -431,14 +435,15 @@ def main() -> int:
                 fn = functools.partial(fn, attend_block=config.block_length)
             ragged_case(f"ragged_{name}_c{chunk}", fn, *geometry, chunk)
     # the kernel alone at the benchmark's cells (BENCHMARK.json: heads
-    # x slots, bf16 pool, page 64, chunk 64): both rungs of the query tile
-    # at 6, 7 and 5 queries a kv head, which the 4-row cases above lower
+    # x slots, bf16 pool, page 64, chunk 64): every rung of the query tile
+    # at 6, 7, 5 and 1 queries a kv head, which the 4-row cases above lower
     # too, at the slot counts the cells run, each rung with the KV block
     # its VMEM budget gives it
     for tag, heads, kv_heads, slots in (
         ("qwen2.5-1.5b", 12, 2, 128), ("qwen2.5-7b", 28, 4, 32),
         ("falcon-h1-34b", 20, 4, 128),
-        # one query head a kv head: sixteen [tile, 1, D] slabs
+        # one query head a kv head: a decode row's one query against
+        # every head of a block at once, other rows sixteen [tile, 1, D] slabs
         ("ouro-2.6b", 16, 16, 10),
         # the hybrid's 30 heads, a page holding 32 (pool_heads)
         ("olmo-hybrid-7b", 30, 30, 48),
@@ -459,6 +464,13 @@ def main() -> int:
         "ragged_window",
         functools.partial(_ragged_attention_pallas, sliding_window=256),
         32, 8, 128, _CHUNK,
+    )
+    # the same window at one query head a kv head: the rung of one query
+    # masks by it too
+    ragged_case(
+        "ragged_window_mha",
+        functools.partial(_ragged_attention_pallas, sliding_window=256),
+        16, 16, 128, _CHUNK,
     )
 
     # the state-space scan kernel alone at the benchmark's cell: 32 heads x
@@ -591,8 +603,9 @@ def main() -> int:
                         compiled.as_text(),
                     ))),
                 }
-                if name in kv_blocks:
-                    results[name]["kv_block_pages"] = kv_blocks[name]
+                if name in rungs:
+                    (results[name]["query_tiles"],
+                     results[name]["kv_block_pages"]) = rungs[name]
                 if name in pools:
                     shape = pools[name]
                     # the tail's one conditional (sched/mixed.py: the head

@@ -6,7 +6,8 @@ cost at a cell's geometry, for each KV block the walk could take.
         --blocks auto,1x1,2x1,4x1,8x1 [--parent _archive/parent]
 
 For every ``--blocks`` entry (``auto``: what ``kv_block_pages`` chooses;
-``AxB``: A pages a block at the small query tile, B at the whole chunk)
+``AxB``: A pages a block at the small query tile, B at the whole chunk;
+at one query head a kv head the rung of one query takes A too)
 the kernel is compiled once and timed over ``--calls`` calls inside one
 jitted loop (the dispatch is paid once), with every slot a decode row of
 P pages, then every slot a whole chunk over P pages, for each P of
@@ -14,7 +15,10 @@ P pages, then every slot a whole chunk over P pages, for each P of
 PERF.md quotes: us a row and us a page.  ``--parent`` times the kernel
 of another checkout's ``ops/ragged_attention.py`` the same way.  Before
 any timing: parity against the dense reference with every page no table
-names, and the keys past ``kv_len`` of every last page, poisoned with NaN.
+names, the keys past ``kv_len`` of every last page and the heads a page
+holds past the model's (``pool_heads``) poisoned with NaN, on decode rows
+of 1, 4 and 12 pages (the last partial), a verify row, whole chunks and
+an idle slot in one call.
 
 A probe, not a cell: it is read by no metric.  Its numbers are device
 numbers only when it ran on the chip (the first line it prints).
@@ -87,7 +91,9 @@ def parity(kernel, heads, kv_heads, blocks):
         if kv_len[row] % PAGE:
             dead[table_np[row, live - 1], kv_len[row] % PAGE:] = True
     dead[~named] = True
-    bad = jnp.asarray(dead)[None, :, :, None, None]
+    # and the heads a page holds past the model's
+    bad = (jnp.asarray(dead)[None, :, :, None, None]
+           | (jnp.arange(k.shape[3]) >= kv_heads)[None, None, None, :, None])
     k_bad, v_bad = (jnp.where(bad, jnp.nan, x) for x in (k, v))
     got = kernel(q, k_bad, v_bad, table, jnp.asarray(kv_len),
                  jnp.asarray(q_count), jnp.int32(1), **blocks)
@@ -167,9 +173,8 @@ def main() -> None:
         q_per_kv=heads // kv_heads, kv_heads=kv_heads, head_dim=HEAD_DIM,
         page_size=PAGE, itemsize=2,
     )
-    chosen = tuple(
-        ours.kv_block_pages(tile, **geometry) for tile in ours.query_tiles(CHUNK)
-    )
+    tiles = ours.query_tiles(CHUNK, q_per_kv=heads // kv_heads)
+    chosen = tuple(ours.kv_block_pages(tile, **geometry) for tile in tiles)
     variants = []
     extra = {"interpret": True} if args.interpret else {}
     if args.attend_block != 1:
@@ -178,15 +183,17 @@ def main() -> None:
         blocks = chosen if name == "auto" else tuple(
             int(n) for n in name.split("x")
         )
+        blocks = blocks[:1] * (len(tiles) - len(blocks)) + blocks
         variants.append((name, ours._ragged_attention_pallas,
                          {"block_pages": blocks, "kv_heads": kv_heads, **extra}))
     if args.parent:
         variants.append(
-            ("parent", load_parent(args.parent)._ragged_attention_pallas, extra)
+            ("parent", load_parent(args.parent)._ragged_attention_pallas,
+             {"kv_heads": kv_heads, **extra})
         )
 
     result = {"shape": args.shape, "device": device.device_kind, "slots": slots,
-              "auto": list(chosen), "variants": {}}
+              "tiles": list(tiles), "auto": list(chosen), "variants": {}}
     for name, kernel, blocks in variants:
         entry = {"blocks": list(blocks.get("block_pages", ())),
                  "parity": parity(kernel, heads, kv_heads, blocks)}

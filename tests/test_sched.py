@@ -211,8 +211,12 @@ class TestRaggedKernel:
         kv_len = jnp.asarray(case["kv_len"], jnp.int32)
         q_count = jnp.asarray(case["q_count"], jnp.int32)
         assert max(case["kv_len"]) <= ps * pps
-        tiles = set(query_tile_rows(np.asarray(q_count), case["c"]).tolist())
-        assert tiles <= {0, SMALL_TILE, case["c"]}
+        q_per_kv = case["qh"] // kv_heads
+        tiles = set(
+            query_tile_rows(np.asarray(q_count), case["c"], q_per_kv=q_per_kv).tolist()
+        )
+        # a row of one query at one query head a kv head takes a rung of its own
+        assert tiles <= {0, SMALL_TILE, case["c"]} | ({1} if q_per_kv == 1 else set())
         if case["id"].startswith("edges"):
             assert tiles == {0, SMALL_TILE, case["c"]}
         window = case.get("window")

@@ -1262,7 +1262,9 @@ class TestKvPagesWalked:
             pages, pairs = _walk_by_the_references_rule(kv_len, q_count, 16, window)
             assert (record.kv_pages_walked, span_pairs[record.seq]) == (pages, pairs)
             assert record.tokens == int(q_count.sum())
-            rows = query_tile_rows(q_count, 16)
+            rows = query_tile_rows(
+                q_count, 16, q_per_kv=TINY_TEST.num_heads // TINY_TEST.num_kv_heads
+            )
             assert record.q_tile_rows == span_rows[record.seq] == int(rows.sum())
             # flash updates: each walking slot's pages, counted by the
             # reference's rule, in blocks of what its rung takes
@@ -1325,23 +1327,68 @@ class TestKvBlocksWalked:
         # the 7B cells' geometry: the rungs' blocks the cases are written for
         block_of = {t: kv_block_pages(t, **self.GEOMETRY) for t in (8, 64)}
         assert block_of == {8: 8, 64: 4}
-        tile_rows = query_tile_rows(np.asarray(q_count, np.int32), 64)
+        tile_rows = query_tile_rows(np.asarray(q_count, np.int32), 64, q_per_kv=7)
         assert kv_blocks_walked(np.asarray(pages), tile_rows, block_of) == blocks
+
+    @pytest.mark.parametrize("name, geometry", [
+        ("qwen2.5-1.5b", dict(q_per_kv=6, kv_heads=2)),
+        ("qwen2.5-7b", dict(q_per_kv=7, kv_heads=4)),
+        ("falcon-h1-34b", dict(q_per_kv=5, kv_heads=4)),
+        ("sdar-30b-a3b", dict(q_per_kv=8, kv_heads=4)),
+        ("ouro-2.6b", dict(q_per_kv=1, kv_heads=16)),
+        ("olmo-hybrid-7b", dict(q_per_kv=1, kv_heads=30)),
+    ])
+    def test_the_rung_of_one_query_walks_the_small_tiles_blocks(self, name, geometry):
+        """At MHA a row of one query takes a rung of its own; the flash
+        updates a call makes are the same as if it took the small tile's,
+        at every geometry: the rung's block is the small tile's."""
+        import numpy as np
+
+        from operator_tpu.ops.ragged_attention import (
+            SMALL_TILE,
+            kv_block_pages,
+            kv_blocks_walked,
+            query_tile_rows,
+            query_tiles,
+        )
+
+        shapes = dict(head_dim=128, page_size=64, itemsize=2, **geometry)
+        tiles = query_tiles(64, q_per_kv=geometry["q_per_kv"])
+        block_of = {t: kv_block_pages(t, **shapes) for t in tiles}
+        pages = np.asarray([1, 6, 9, 17, 24, 3, 0, 11])
+        q_count = np.asarray([1, 1, 1, 5, 64, 8, 0, 9], np.int32)
+        rows = query_tile_rows(q_count, 64, q_per_kv=geometry["q_per_kv"])
+        # the GQA rule: the small tile for every row of at most 8 queries
+        small = np.where(q_count > SMALL_TILE, 64, np.where(q_count > 0, SMALL_TILE, 0))
+        assert kv_blocks_walked(pages, rows, block_of) == kv_blocks_walked(
+            pages, small, {t: kv_block_pages(t, **shapes) for t in (8, 64)}
+        )
+        assert (1 in tiles) is (geometry["q_per_kv"] == 1)
+        if 1 in tiles:
+            assert block_of[1] == block_of[SMALL_TILE]
 
     @pytest.mark.parametrize("name, geometry, small, chunk", [
         ("qwen2.5-1.5b", dict(q_per_kv=6, kv_heads=2), 8, 8),
         ("qwen2.5-7b", dict(q_per_kv=7, kv_heads=4), 8, 4),
         ("falcon-h1-34b", dict(q_per_kv=5, kv_heads=4), 8, 4),
         ("ouro-2.6b", dict(q_per_kv=1, kv_heads=16), 2, 2),
+        # 30 heads held 32 a page: K and V of one page fill the budget
+        ("olmo-hybrid-7b", dict(q_per_kv=1, kv_heads=30), 1, 1),
     ])
     def test_the_cells_blocks(self, name, geometry, small, chunk):
-        """The block each rung takes at the four served geometries: a
-        change of the budget or of the rule shows here first."""
+        """The block each rung takes at the served geometries: a change
+        of the budget or of the rule shows here first.  At one query head
+        a kv head the rung of one query leads and walks the small tile's
+        block."""
         from operator_tpu.ops.ragged_attention import kv_block_pages, query_tiles
 
         shapes = dict(head_dim=128, page_size=64, itemsize=2, **geometry)
-        assert query_tiles(64) == (8, 64)
-        assert [kv_block_pages(t, **shapes) for t in query_tiles(64)] == [small, chunk]
+        mha = geometry["q_per_kv"] == 1
+        tiles = query_tiles(64, q_per_kv=geometry["q_per_kv"])
+        assert tiles == (1,) * mha + (8, 64)
+        assert [kv_block_pages(t, **shapes) for t in tiles] == (
+            [small] * mha + [small, chunk]
+        )
 
     def test_a_block_never_outgrows_the_budget_and_one_page_always_goes(self):
         from operator_tpu.ops import ragged_attention
@@ -1373,12 +1420,41 @@ class TestQTileRows:
 
         assert SMALL_TILE == 8
         counts = np.asarray(q_count, np.int32)
-        assert int(query_tile_rows(counts, chunk).sum()) == rows
-        # slot by slot: nothing, the small tile, or the chunk
+        # several query heads a kv head (GQA): nothing, the small tile, or
+        # the chunk, slot by slot
         small = min(SMALL_TILE, chunk)
-        assert query_tile_rows(counts, chunk).tolist() == [
-            0 if c == 0 else small if c <= small else chunk for c in q_count
-        ]
+        for q_per_kv in (2, 7):
+            got = query_tile_rows(counts, chunk, q_per_kv=q_per_kv)
+            assert int(got.sum()) == rows
+            assert got.tolist() == [
+                0 if c == 0 else small if c <= small else chunk for c in q_count
+            ]
+
+    @pytest.mark.parametrize("name, q_count, chunk, tiles", [
+        # decode rows take the rung of one; a verify row the small tile
+        ("decode-only", [1, 1, 0, 1, 5, 0], 64, [1, 1, 0, 1, 8, 0]),
+        # 2 to 8 queries the small tile, more the chunk, as at GQA
+        ("mixed", [1, 2, 8, 9, 64, 0], 64, [1, 8, 8, 64, 64, 0]),
+        ("empty", [0, 0, 0, 0], 64, [0, 0, 0, 0]),
+        ("tiny-chunk", [1, 4, 0, 2], 4, [1, 4, 0, 4]),
+        # a chunk of one: the rung of one is the only rung
+        ("chunk-of-one", [1, 0, 1], 1, [1, 0, 1]),
+    ])
+    def test_a_row_of_one_query_at_mha_counts_one(self, name, q_count, chunk, tiles):
+        """One query head a kv head: a row of one query is worked on the
+        rung of one (``q_tile_rows`` counts 1 for it, where the small tile
+        counted 8); a row of more queries takes what it took."""
+        import numpy as np
+
+        from operator_tpu.ops.ragged_attention import query_tile_rows, query_tiles
+
+        counts = np.asarray(q_count, np.int32)
+        assert query_tile_rows(counts, chunk, q_per_kv=1).tolist() == tiles
+        assert query_tiles(chunk, q_per_kv=1)[0] == 1
+        # ten decode rows at Ouro count 10 where they counted 80
+        ten = np.ones(10, np.int32)
+        assert int(query_tile_rows(ten, 64, q_per_kv=1).sum()) == 10
+        assert int(query_tile_rows(ten, 64, q_per_kv=7).sum()) == 80
 
 
 class TestChaosReplayStepRecords:
