@@ -8,8 +8,9 @@ theta.  Sizes are from the public model cards / HF config.json files.
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass, replace
-from typing import ClassVar, Optional
+from typing import ClassVar, Optional, Union
 
 
 @dataclass(frozen=True)
@@ -22,6 +23,42 @@ class RopeScaling:
     low_freq_factor: float = 1.0
     high_freq_factor: float = 4.0
     original_max_positions: int = 8192
+
+    rope_type: ClassVar[str] = "llama3"
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's magnitude correction for a context stretched ``factor`` times
+    (``0.1 x mscale x ln(factor) + 1``; 1 for no stretch)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+@dataclass(frozen=True)
+class YarnScaling:
+    """YaRN RoPE scaling (Peng et al., arXiv:2309.00071; ``rope_scaling``
+    ``type: yarn`` as DeepSeek-V2 publishes it): the rotary bands whose
+    wavelength fits fewer than ``beta_slow`` turns in the original context
+    are divided by ``factor``, those with more than ``beta_fast`` kept, a
+    linear ramp between; cos and sin scaled by ``mscale(factor, mscale) /
+    mscale(factor, mscale_all_dim)`` and the attention's softmax scale by
+    ``mscale(factor, mscale_all_dim)`` squared (``models/llama.py
+    rope_frequencies``, ``configs.DeepseekV2Config.softmax_scale``)."""
+
+    factor: float = 40.0
+    original_max_positions: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 0.707
+    mscale_all_dim: float = 0.707
+
+    rope_type: ClassVar[str] = "yarn"
+
+    @property
+    def rotary_magnitude(self) -> float:
+        """What cos and sin are multiplied by."""
+        return yarn_mscale(self.factor, self.mscale) / yarn_mscale(
+            self.factor, self.mscale_all_dim
+        )
 
 
 @dataclass(frozen=True)
@@ -39,7 +76,8 @@ class ModelConfig:
     max_seq_len: int = 2048
     sliding_window: Optional[int] = None  # Mistral-style local attention
     tie_embeddings: bool = False
-    rope_scaling: Optional[RopeScaling] = None  # Llama-3.1+ long context
+    #: Llama-3.1+ long context, or YaRN (``YarnScaling``)
+    rope_scaling: Optional[Union[RopeScaling, YarnScaling]] = None
     attention_bias: bool = False  # Qwen2-style bias on the q/k/v projections
 
     #: which module of this package builds and runs the model
@@ -357,6 +395,129 @@ class OlmoHybridConfig(ModelConfig):
             and not self.attention_bias and not self.tie_embeddings
             and self.sliding_window is None and self.num_heads == self.num_kv_heads
         ), f"{self.name}: an Olmo Hybrid variant the layer body does not implement"
+
+
+@dataclass(frozen=True)
+class DeepseekV2Config(ModelConfig):
+    """DeepSeek-V2 (``model_type: deepseek_v2``): multi-head latent
+    attention (MLA: keys and values decompressed per head from one
+    ``kv_lora_rank`` latent a token, a rotary key of ``qk_rope_head_dim``
+    shared by the heads) and a feed-forward layer that is a dense gated MLP
+    in the first ``first_k_dense_replace`` layers and, after them, a router
+    over ``n_routed_experts`` gated MLPs beside ``n_shared_experts`` that
+    every token takes (``models/deepseek_v2.py`` has the equations).  The
+    cache holds the latent and the rotary key a token and layer and no
+    per-head K or V (``ops/latent_attention.py``).  A sibling of
+    ``ModelConfig`` as ``SdarConfig`` is; field names follow the published
+    ``config.json``.  ``head_dim`` is a query head's width, ``qk_nope_head_dim
+    + qk_rope_head_dim``: what the softmax scale is taken from."""
+
+    kv_lora_rank: int = 512
+    q_lora_rank: Optional[int] = None
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    first_k_dense_replace: int = 1
+    moe_layer_freq: int = 1
+    n_routed_experts: int = 64
+    n_shared_experts: int = 2
+    num_experts_per_tok: int = 6
+    moe_intermediate_size: int = 1408
+    norm_topk_prob: bool = False
+    routed_scaling_factor: float = 1.0
+    scoring_func: str = "softmax"
+    topk_method: str = "greedy"
+    n_group: int = 1
+    topk_group: int = 1
+    hidden_act: str = "silu"
+
+    family: ClassVar[str] = "deepseek_v2"
+    continuous_only: ClassVar[Optional[str]] = (
+        "caches one latent a token and layer, read by the latent attention "
+        "kernel, and routes every token to a few of its experts"
+    )
+
+    @property
+    def num_experts(self) -> int:
+        """The routed experts, under the name the expert counters read."""
+        return self.n_routed_experts
+
+    @property
+    def moe_layers(self) -> int:
+        """The layers after them, each with experts (``moe_layer_freq`` 1)."""
+        return self.num_layers - self.first_k_dense_replace
+
+    @property
+    def latent_dims(self) -> tuple:
+        """``(latent width, rotary key width)`` of a cached token."""
+        return self.kv_lora_rank, self.qk_rope_head_dim
+
+    @property
+    def shared_intermediate_size(self) -> int:
+        """The shared experts as ONE gated MLP, as the public code builds
+        them: ``n_shared_experts x moe_intermediate_size`` wide."""
+        return self.n_shared_experts * self.moe_intermediate_size
+
+    @property
+    def rope_scaling_published(self) -> Optional[dict]:
+        """``rope_scaling`` as the published ``config.json`` writes it: a
+        configuration file's ``architecture`` group is held to this."""
+        scaling = self.rope_scaling
+        if scaling is None:
+            return None
+        return {
+            "beta_fast": scaling.beta_fast, "beta_slow": scaling.beta_slow,
+            "factor": scaling.factor, "mscale": scaling.mscale,
+            "mscale_all_dim": scaling.mscale_all_dim,
+            "original_max_position_embeddings": scaling.original_max_positions,
+            "type": scaling.rope_type,
+        }
+
+    @property
+    def softmax_scale(self) -> float:
+        """``head_dim^-0.5``, times YaRN's ``mscale(factor,
+        mscale_all_dim)`` squared where the rotary is stretched."""
+        scale = self.head_dim ** -0.5
+        scaling = self.rope_scaling
+        if isinstance(scaling, YarnScaling) and scaling.mscale_all_dim:
+            scale *= yarn_mscale(scaling.factor, scaling.mscale_all_dim) ** 2
+        return scale
+
+    def __post_init__(self) -> None:
+        super().__post_init__()
+        assert self.head_dim == self.qk_nope_head_dim + self.qk_rope_head_dim, (
+            f"{self.name}: head_dim is a query head's nope + rope width"
+        )
+        # the values the layer body (models/deepseek_v2.py) is written for;
+        # a config that states another is refused here, at build
+        unsupported = [
+            text for bad, text in (
+                (self.q_lora_rank is not None,
+                 f"q_lora_rank={self.q_lora_rank} (no q compression is implemented)"),
+                (self.topk_method != "greedy", f"topk_method={self.topk_method!r}"),
+                (self.scoring_func != "softmax", f"scoring_func={self.scoring_func!r}"),
+                (self.n_group > 1, f"n_group={self.n_group} (group-limited routing)"),
+                (self.moe_layer_freq != 1, f"moe_layer_freq={self.moe_layer_freq}"),
+                (self.hidden_act != "silu", f"hidden_act={self.hidden_act!r}"),
+                (self.attention_bias or self.tie_embeddings
+                 or self.sliding_window is not None,
+                 "attention bias, tied embeddings or a sliding window"),
+                (self.rope_scaling is not None and not (
+                    isinstance(self.rope_scaling, YarnScaling)
+                    and abs(self.rope_scaling.rotary_magnitude - 1.0) < 1e-12
+                ), "rope scaling other than YaRN with cos/sin unscaled"),
+            ) if bad
+        ]
+        assert not unsupported, (
+            f"{self.name}: a DeepSeek-V2 variant the layer body does not "
+            f"implement: {'; '.join(unsupported)}"
+        )
+        assert 0 <= self.first_k_dense_replace < self.num_layers
+        assert 1 <= self.num_experts_per_tok <= self.n_routed_experts
+        assert 128 % self.qk_rope_head_dim == 0, (
+            f"{self.name}: the cache packs {128 // self.qk_rope_head_dim} rotary keys "
+            "to a 128-lane row"
+        )
 
 
 TINYLLAMA_1_1B = ModelConfig(
@@ -686,6 +847,72 @@ TINY_OLMO_HYBRID = OlmoHybridConfig(
     linear_conv_kernel_dim=4,
 )
 
+# DeepSeek-V2-Lite as published (deepseek-ai/DeepSeek-V2-Lite, config.json):
+# one dense layer, then 26 layers of 64 routed experts (6 a token) beside 2
+# shared ones, MLA at a latent of 512 and no q compression.
+# ``deepseek-v2-lite-14l`` is the same model cut in depth only, the first
+# of two pipeline stages (the dense layer and 13 expert layers) with the
+# embedding and the head (benchmark/configs/deepseek-v2-lite-int8.json)
+DEEPSEEK_V2_LITE = DeepseekV2Config(
+    name="deepseek-v2-lite",
+    vocab_size=102400,
+    hidden_size=2048,
+    intermediate_size=10944,
+    num_layers=27,
+    num_heads=16,
+    num_kv_heads=16,
+    head_dim=192,
+    rope_theta=10_000.0,
+    rms_norm_eps=1e-6,
+    max_seq_len=16384,  # serving cap; the model supports 163,840
+    rope_scaling=YarnScaling(
+        factor=40.0, original_max_positions=4096, beta_fast=32.0, beta_slow=1.0,
+        mscale=0.707, mscale_all_dim=0.707,
+    ),
+    kv_lora_rank=512,
+    qk_nope_head_dim=128,
+    qk_rope_head_dim=64,
+    v_head_dim=128,
+    first_k_dense_replace=1,
+    n_routed_experts=64,
+    n_shared_experts=2,
+    num_experts_per_tok=6,
+    moe_intermediate_size=1408,
+    norm_topk_prob=False,
+)
+DEEPSEEK_V2_LITE_14L = replace(DEEPSEEK_V2_LITE, name="deepseek-v2-lite-14l", num_layers=14)
+
+#: the family's small config for tests: one dense layer and two expert
+#: layers, four heads, a latent of 32 and a rotary key of 16, eight experts
+#: two a token beside one shared, YaRN as published
+TINY_DEEPSEEK_V2 = DeepseekV2Config(
+    name="tiny-deepseek-v2",
+    vocab_size=512,
+    hidden_size=64,
+    intermediate_size=192,
+    num_layers=3,
+    num_heads=4,
+    num_kv_heads=4,
+    head_dim=48,
+    rope_theta=10_000.0,
+    rms_norm_eps=1e-6,
+    max_seq_len=256,
+    rope_scaling=YarnScaling(
+        factor=40.0, original_max_positions=4096, beta_fast=32.0, beta_slow=1.0,
+        mscale=0.707, mscale_all_dim=0.707,
+    ),
+    kv_lora_rank=32,
+    qk_nope_head_dim=32,
+    qk_rope_head_dim=16,
+    v_head_dim=32,
+    first_k_dense_replace=1,
+    n_routed_experts=8,
+    n_shared_experts=1,
+    num_experts_per_tok=2,
+    moe_intermediate_size=32,
+    norm_topk_prob=False,
+)
+
 _REGISTRY = {
     cfg.name: cfg
     for cfg in (
@@ -708,6 +935,9 @@ _REGISTRY = {
         TINY_SDAR,
         OLMO_HYBRID_7B,
         TINY_OLMO_HYBRID,
+        DEEPSEEK_V2_LITE,
+        DEEPSEEK_V2_LITE_14L,
+        TINY_DEEPSEEK_V2,
     )
 }
 

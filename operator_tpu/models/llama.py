@@ -24,6 +24,7 @@ run time; the HF checkpoint loader transposes once at load).
 
 from __future__ import annotations
 
+import math
 import os
 from dataclasses import dataclass
 from typing import Any, Optional
@@ -166,18 +167,26 @@ def rms_norm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
     return (normed * scale.astype(jnp.float32)).astype(x.dtype)
 
 
-def rope_frequencies(config: ModelConfig) -> jax.Array:
-    """Inverse frequencies [head_dim // 2] (HF half-rotation convention),
-    with Llama-3.1-style NTK-by-parts scaling when configured: wavelengths
-    beyond the original training context are slowed by ``factor``, short
-    wavelengths kept, the band between linearly interpolated (matches HF
-    ``rope_type: llama3``)."""
-    d = config.head_dim
+def rope_frequencies(config: ModelConfig, width: Optional[int] = None) -> jax.Array:
+    """Inverse frequencies [width // 2] (HF half-rotation convention) over
+    a rotary ``width`` (``head_dim`` unless given: DeepSeek-V2 rotates 64
+    of a 192-wide head), with the configured scaling: Llama-3.1-style
+    NTK-by-parts (``rope_type: llama3``): wavelengths beyond the original
+    training context are slowed by ``factor``, short wavelengths kept, the
+    band between linearly interpolated; or YaRN (``rope_type: yarn``, the
+    published DeepSeek-V2 ``yarn_find_correction_range`` and
+    ``yarn_linear_ramp_mask``): band ``i`` is kept where it turns more than
+    ``beta_fast`` times over the original context, divided by ``factor``
+    where it turns fewer than ``beta_slow`` times, a linear ramp in
+    between."""
+    d = width or config.head_dim
     exponents = jnp.arange(0, d, 2, dtype=jnp.float32) / d
     inv_freq = 1.0 / (config.rope_theta**exponents)
     scaling = config.rope_scaling
     if scaling is None:
         return inv_freq
+    if scaling.rope_type == "yarn":
+        return _yarn_frequencies(inv_freq, scaling, d, config.rope_theta)
     wavelen = 2.0 * jnp.pi / inv_freq
     low_freq_wavelen = scaling.original_max_positions / scaling.low_freq_factor
     high_freq_wavelen = scaling.original_max_positions / scaling.high_freq_factor
@@ -189,6 +198,25 @@ def rope_frequencies(config: ModelConfig) -> jax.Array:
     out = jnp.where(wavelen > low_freq_wavelen, scaled, inv_freq)
     mid = (wavelen <= low_freq_wavelen) & (wavelen >= high_freq_wavelen)
     return jnp.where(mid, smoothed, out)
+
+
+def _yarn_frequencies(inv_freq: jax.Array, scaling: Any, d: int, theta: float) -> jax.Array:
+    """YaRN's blend of the kept and the stretched frequencies."""
+
+    def band(turns: float) -> float:
+        # the band index whose wavelength fits ``turns`` turns in the
+        # original context
+        return d * math.log(scaling.original_max_positions / (turns * 2 * math.pi)) / (
+            2 * math.log(theta)
+        )
+
+    low = max(math.floor(band(scaling.beta_fast)), 0)
+    high = min(math.ceil(band(scaling.beta_slow)), d - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(d // 2, dtype=jnp.float32) - low) / (high - low), 0.0, 1.0)
+    kept = 1.0 - ramp
+    return (inv_freq / scaling.factor) * (1.0 - kept) + inv_freq * kept
 
 
 def apply_rope(x: jax.Array, positions: jax.Array, inv_freq: jax.Array) -> jax.Array:

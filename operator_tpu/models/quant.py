@@ -57,6 +57,9 @@ QUANTIZED_LAYER_MATRICES = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
 #: the projections whose product ``sched/mixed.py attend`` splits into
 #: heads, in every family: what :func:`hold_head_projections` transposes
 HEAD_PROJECTIONS = ("wq", "wk", "wv")
+#: the same of the layers a family runs ahead of its loop: the queries of
+#: models/deepseek_v2.py's dense layers
+LEAD_HEAD_PROJECTIONS = ("dense_wq",)
 
 
 def quantized_layer_matrices(config: ModelConfig) -> tuple:
@@ -114,13 +117,14 @@ def _transposed(q: jax.Array) -> jax.Array:
 
 def hold_head_projections(params: Params) -> Params:
     """``params`` as the continuous step reads them: each int8
-    ``HEAD_PROJECTIONS`` leaf ``{q: [L, in, out], s: [L, out]}`` becomes
+    ``HEAD_PROJECTIONS`` (and ``LEAD_HEAD_PROJECTIONS``) leaf ``{q: [L,
+    in, out], s: [L, out]}`` becomes
     ``{qt: [L, out, in], s}``, the same values transposed, and every other
     leaf is the one given.  A tree already held, or a float tree, comes
     back as it is.  The given tree is not changed: a caller that rebinds
     its name to the result frees each original matrix."""
     layers = dict(params["layers"])
-    for name in HEAD_PROJECTIONS:
+    for name in HEAD_PROJECTIONS + LEAD_HEAD_PROJECTIONS:
         leaf = layers.get(name)
         if isinstance(leaf, dict) and "q" in leaf:
             layers[name] = {"qt": _transposed(leaf["q"]), "s": leaf["s"]}

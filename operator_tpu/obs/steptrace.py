@@ -139,6 +139,11 @@ class StepRecord:
     #: kernel chooses for them (``ops/ragged_attention.query_tile_rows``);
     #: ``tokens`` over it is how full the tiles were
     q_tile_rows: Optional[int] = None
+    #: latent pages ONE layer's latent attention call copies in this step
+    #: (a model with a latent cache, ``ops/latent_attention.py``: one
+    #: ``[page_size, rank]`` latent and one rope copy a live page of a
+    #: slot with queries); None for a model with a K/V cache
+    latent_pages_walked: Optional[int] = None
     #: slots whose recurrent state ONE layer's scan call reads and
     #: rewrites this step (``q_count > 0``; ``ops/ssm_scan.py`` skips the
     #: others); None for a model without recurrent state
@@ -239,7 +244,8 @@ _MS_FIELDS = (
 )
 _COUNT_FIELDS = (
     "accepted", "cached_tokens", "prefill_tokens", "kv_pages_walked",
-    "kv_blocks_walked", "q_tile_rows", "state_rows", "sampled_rows", "passes",
+    "kv_blocks_walked", "q_tile_rows", "latent_pages_walked", "state_rows",
+    "sampled_rows", "passes",
     "block_rows", "unmasked_tokens", "commit_tokens",
     "moe_tokens", "moe_experts_hit", "moe_assign_max",
     "wakeups", "delivered", "gc_gen2",

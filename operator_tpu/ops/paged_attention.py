@@ -48,7 +48,8 @@ class PagedKVCache:
     id names one page in every plane."""
 
     k_pages: jax.Array  # [planes, num_pages, page_size, kv_heads, head_dim]
-    v_pages: jax.Array
+    #: None for a latent cache, which holds no values
+    v_pages: Optional[jax.Array]
     page_table: jax.Array  # [batch, pages_per_seq] int32
     lengths: jax.Array  # [batch] int32
     #: recurrent state beside the pages, for a model that has it
@@ -60,6 +61,12 @@ class PagedKVCache:
     #: ``recurrent_state_shapes``
     ssm_state: Optional[jax.Array] = None  # [layers, batch, ...] f32
     conv_state: Optional[jax.Array] = None  # [layers, batch, kernel - 1, channels]
+    #: a LATENT cache (multi-head latent attention: ``config.latent_dims``,
+    #: ops/latent_attention.py owns the layout): ``k_pages`` holds one
+    #: latent row a token ``[planes, pages, page_size, rank]``, this the
+    #: rotary keys ``g`` tokens to a 128-lane row, and ``v_pages`` is None.
+    #: None for every other model, an absent child of the pytree
+    rope_pages: Optional[jax.Array] = None  # [planes, pages, page_size / g, 128]
 
     @property
     def page_size(self) -> int:
@@ -86,6 +93,7 @@ class PagedKVCache:
         pages_per_seq: int,
         dtype: jnp.dtype = jnp.bfloat16,
         recurrent: Optional[tuple] = None,
+        latent: Optional[tuple] = None,
     ) -> "PagedKVCache":
         """``num_layers`` is the pool's planes, the model's ``kv_planes``
         (a looped model's passes x layers).  ``kv_heads`` is the model's; a
@@ -93,24 +101,36 @@ class PagedKVCache:
         chip's tile holds them, 32).  ``recurrent`` is
         :meth:`recurrent_shapes`' pair: the state is
         float32 (a sum over every token the row has seen), the conv tail
-        the activations' ``dtype``."""
+        the activations' ``dtype``.  ``latent`` is a latent model's
+        ``config.latent_dims``, (latent width, rotary key width): the pool
+        is then a latent one (``kv_heads`` and ``head_dim``
+        unused)."""
         from .ragged_attention import pool_heads
 
-        shape = (num_layers, num_pages, page_size, pool_heads(kv_heads), head_dim)
+        rope = None
+        if latent is not None:
+            from .latent_attention import pool_shapes
+
+            shape, rope_shape = pool_shapes(num_layers, num_pages, page_size, *latent)
+            rope = jnp.zeros(rope_shape, dtype)
+        else:
+            shape = (num_layers, num_pages, page_size, pool_heads(kv_heads), head_dim)
         return cls(
             k_pages=jnp.zeros(shape, dtype),
-            v_pages=jnp.zeros(shape, dtype),
+            v_pages=None if latent is not None else jnp.zeros(shape, dtype),
             page_table=jnp.zeros((batch_size, pages_per_seq), jnp.int32),
             lengths=jnp.zeros((batch_size,), jnp.int32),
             ssm_state=None if recurrent is None else jnp.zeros(recurrent[0], jnp.float32),
             conv_state=None if recurrent is None else jnp.zeros(recurrent[1], dtype),
+            rope_pages=rope,
         )
 
 
 jax.tree_util.register_pytree_node(
     PagedKVCache,
     lambda c: (
-        (c.k_pages, c.v_pages, c.page_table, c.lengths, c.ssm_state, c.conv_state),
+        (c.k_pages, c.v_pages, c.page_table, c.lengths, c.ssm_state, c.conv_state,
+         c.rope_pages),
         None,
     ),
     lambda _, ch: PagedKVCache(*ch),

@@ -404,7 +404,9 @@ def build_serving_engine(
                 + "; ".join(blockers)
                 + ". Set SCHED_MODE=wave to run the wave engine, which can."
             )
-        if device.platform == "tpu":
+        # a model with a latent cache is served by the latent attention
+        # kernel (ops/latent_attention.py), never the ragged one
+        if device.platform == "tpu" and getattr(model_config, "latent_dims", None) is None:
             from ..ops.ragged_attention import require_ragged_kernel_support
 
             require_ragged_kernel_support(model_config)

@@ -176,6 +176,8 @@ class Runtime:
                 recurrent=PagedKVCache.recurrent_shapes(
                     self.config, self.max_slots
                 ),
+                # a model of latent attention caches one latent a token
+                latent=getattr(self.config, "latent_dims", None),
             ),
             "paged",
         )

@@ -86,6 +86,14 @@ class StepView:
     #: the body indexes them with ``scanned["layer"]``.  Empty for a family
     #: that names none
     stacks: Any = None
+    #: a model with a LATENT cache (models/deepseek_v2.py): ``attend_latent(
+    #: q_lat [T, H, R], q_rope [T, H, dr], c [T, R], k_rope [T, dr], pools,
+    #: plane) -> (o_lat [T, H, R], pools)``, the queries and the rotary key
+    #: rotated already: the step's rows into plane ``plane`` of the latent
+    #: pool (``pools = {"k": latent, "v": None, "rope": rope}``) in place,
+    #: the latent attention kernel over it, the outputs on the flat axis
+    #: (zero at padding tokens).  None for every other model
+    attend_latent: Any = None
 
 
 def make_mixed_fn(runtime: Any, t_budget: int, chunk: int,
@@ -155,10 +163,19 @@ def make_mixed_fn(runtime: Any, t_budget: int, chunk: int,
     confident, or under the sequential rule the leftmost, ties to the
     left.  ``toks [B, N]`` is the block after the step, ``accept [B]`` the
     bits of the positions this step kept, and ``latest_out`` the block of
-    every slot that had a block row.  A model with experts returns a sixth
-    value, ``[2]``: experts given a token, summed over layers
+    every slot that had a block row.
+
+    A model with experts (``config.num_experts``: models/sdar.py,
+    models/deepseek_v2.py), whichever tail it has, returns a sixth value,
+    ``[2]``: experts given a token, summed over the loop's layers
     (``StepRecord.moe_experts_hit``), and the fullest expert's tokens, the
-    largest over layers (``moe_assign_max``).
+    largest over them (``moe_assign_max``).
+
+    **A model with a latent cache** (``config.latent_dims``: models/
+    deepseek_v2.py) has ``paged.rope_pages`` beside ``paged.k_pages`` and
+    no ``v_pages``; its layers attend through ``StepView.attend_latent``
+    (``ops/latent_attention.py``), and the layers its family runs ahead of
+    the loop (``mixed_lead``: the dense layers) take the carry first.
     """
     jax, jnp = runtime._jax, runtime._jnp
     config = runtime.config
@@ -192,6 +209,11 @@ def make_mixed_fn(runtime: Any, t_budget: int, chunk: int,
     # a model that commits one token a row
     block = int(getattr(config, "block_length", 0))
     assert not (block and width > 1), "a denoising row carries no draft"
+    # a model with experts brings two expert counters back beside its tokens
+    experts = bool(getattr(config, "num_experts", 0))
+    # layers a family runs ahead of its loop, each with leaves of its own
+    # (models/deepseek_v2.py: the dense layers before the expert ones)
+    lead = getattr(family, "mixed_lead", None)
 
     def mixed_fn(params, paged, ids, rows, pos, valid, in_row,
                  q_start, q_count, kv_len, latest, from_prev,
@@ -259,22 +281,46 @@ def make_mixed_fn(runtime: Any, t_budget: int, chunk: int,
                 {"k": k_pages, "v": v_pages},
             )
 
+        def attend_latent(q_lat, q_rope, c, k_rope, pools, plane):
+            from ...ops.latent_attention import latent_attention, write_latent
+
+            with jax.named_scope("kv_write"):
+                latent, rope_rows = write_latent(
+                    pools["k"], pools["rope"], plane, page_ids, page_slots, c, k_rope,
+                    rows, pos, valid,
+                )
+            with jax.named_scope("latent_attn"):
+                o_lat = latent_attention(
+                    q_lat.astype(latent.dtype), q_rope, latent, rope_rows,
+                    paged.page_table, kv_len, q_start, q_count, plane,
+                    scale=config.softmax_scale, chunk=chunk,
+                )
+                # the kernel leaves tokens of no live slot unwritten
+                o_lat = jnp.where(valid[:, None, None], o_lat, 0)
+            return o_lat, {**pools, "k": latent, "rope": rope_rows}
+
         # what differs by family is the layer body; the model's config
         # selects it, no option does
-        layer_step = family_of(config).mixed_layer(config, StepView(
+        view = StepView(
             t_budget=t_budget, chunk=chunk, rows=rows, in_row=in_row, pos=pos,
             valid=valid, q_start=q_start, q_count=q_count, attend=attend,
             stacks={name: params["layers"][name] for name in whole},
-        ))
+            attend_latent=attend_latent if paged.rope_pages is not None else None,
+        )
+        layer_step = family.mixed_layer(config, view)
         # the pools ride the layer loop's carry WHOLE, beside the residual
         # stream: the KV pools every model has, and the recurrent pools of
         # a model with state-space layers (updated in place,
         # ops/ssm_scan.py).  A layer gets its weights and its index;
         # nothing slices a layer's pages out of a pool or stacks them back
         pools = {"k": paged.k_pages, "v": paged.v_pages}
+        if paged.rope_pages is not None:
+            pools["rope"] = paged.rope_pages
         recurrent = None
         if paged.ssm_state is not None:
             recurrent = {"ssm": paged.ssm_state, "conv": paged.conv_state}
+        if lead is not None:
+            x, pools, recurrent = lead(config, view, (x, pools, recurrent))
 
         def one_pass(carry, planes):
             """The layer stack once, over the pool planes ``planes [L]``,
@@ -406,20 +452,24 @@ def make_mixed_fn(runtime: Any, t_budget: int, chunk: int,
                 bits = jnp.sum(kept.astype(jnp.int32) << place, axis=-1)
             return after, bits, rng
 
+        def expert_stats():
+            """``[2]``: experts given a token summed over the layers, the
+            fullest expert's tokens the largest over them, from the
+            loop's ``[layers, E]`` counts"""
+            return jnp.stack([
+                jnp.sum(per_layer > 0, dtype=jnp.int32),
+                jnp.max(per_layer).astype(jnp.int32),
+            ])
+
         if block:
             toks, accept, rng = denoise_block(rng)
             block_row = denoise["keep"] > 0
             new_paged = dataclasses.replace(
                 paged, k_pages=pools["k"], v_pages=pools["v"], lengths=kv_len,
             )
-            counts = per_layer  # [L, E]: tokens each layer's experts took
             return (
                 new_paged, toks, accept,
-                jnp.where(block_row[:, None], toks, latest), rng,
-                jnp.stack([
-                    jnp.sum(counts > 0, dtype=jnp.int32),
-                    jnp.max(counts).astype(jnp.int32),
-                ]),
+                jnp.where(block_row[:, None], toks, latest), rng, expert_stats(),
             )
         if width > 1:
             # the head and the sampler cost rows x vocabulary: they run
@@ -449,6 +499,10 @@ def make_mixed_fn(runtime: Any, t_budget: int, chunk: int,
             new_paged = dataclasses.replace(
                 new_paged, ssm_state=recurrent["ssm"], conv_state=recurrent["conv"],
             )
+        if "rope" in pools:
+            new_paged = dataclasses.replace(new_paged, rope_pages=pools["rope"])
+        if experts:
+            return new_paged, toks, accept, latest_out, rng, expert_stats()
         return new_paged, toks, accept, latest_out, rng
 
     assert b_slots <= t_budget, (b_slots, t_budget)

@@ -105,6 +105,7 @@ from typing import Any, Optional
 
 import numpy as np
 
+from ...ops import latent_attention
 from ...ops.ragged_attention import (
     kv_block_pages,
     kv_blocks_walked,
@@ -256,6 +257,15 @@ class Scheduler:
         #: layers of the gated delta rule (models/olmo_hybrid.py): the
         #: depth the delta kernel's cost is counted over; 0 for the others
         self._delta_layers = int(getattr(model, "delta_layers", 0))
+        #: a model with a latent cache (models/deepseek_v2.py): its steps
+        #: walk the latent attention kernel's pages, not the ragged one's
+        self._latent = getattr(model, "latent_dims", None) is not None
+        #: layers with experts, the depth the expert kernel's cost is
+        #: counted over (every layer of SDAR; DeepSeek-V2's dense layers
+        #: have none); 0 for a model without experts
+        self._moe_layers = (
+            int(getattr(model, "moe_layers", model.num_layers)) if self._moe else 0
+        )
         if self._block:
             if spec_decode:
                 self.switched_off["spec_decode"] = (
@@ -293,15 +303,24 @@ class Scheduler:
         self._q_per_kv = model.num_heads // model.num_kv_heads
         #: pages a flash update of the ragged kernel folds in, by query
         #: tile: the kernel's own rule on the static shapes it is given
-        self._kv_block_of = {
-            tile: kv_block_pages(
-                tile, q_per_kv=self._q_per_kv,
-                kv_heads=model.num_kv_heads, head_dim=model.head_dim,
-                page_size=generator.page_size,
-                itemsize=np.dtype(generator.cache_dtype).itemsize,
-            )
-            for tile in query_tiles(self.chunk, q_per_kv=self._q_per_kv)
-        }
+        #: (the latent attention kernel's, for a model with a latent cache)
+        if self._latent:
+            self._kv_block_of = {
+                tile: latent_attention.block_pages(
+                    tile, heads=model.num_heads, page_size=generator.page_size,
+                )
+                for tile in latent_attention.query_tiles(self.chunk)
+            }
+        else:
+            self._kv_block_of = {
+                tile: kv_block_pages(
+                    tile, q_per_kv=self._q_per_kv,
+                    kv_heads=model.num_kv_heads, head_dim=model.head_dim,
+                    page_size=generator.page_size,
+                    itemsize=np.dtype(generator.cache_dtype).itemsize,
+                )
+                for tile in query_tiles(self.chunk, q_per_kv=self._q_per_kv)
+            }
         #: the most query tokens a generating row packs a step: one, or a
         #: block led by the block before it
         row_tokens = 2 * self._block or 1
@@ -718,6 +737,11 @@ class Scheduler:
                     {"delta_layers": self._delta_layers}
                     if self._delta_layers else {}
                 ),
+                # the planes the latent attention kernel reads a step, and
+                # the layers with experts: the depths their costs are
+                # counted over
+                **({"latent_layers": int(g.config.kv_planes)} if self._latent else {}),
+                **({"moe_layers": self._moe_layers} if self._moe else {}),
                 # a model that denoises blocks, a model with experts: the
                 # same rule
                 **{
@@ -1415,7 +1439,10 @@ class Scheduler:
         pages, pairs = _kv_walk(
             kv_len, q_count, g.page_size, g.config.sliding_window
         )
-        tile_rows = query_tile_rows(q_count, self.chunk, q_per_kv=self._q_per_kv)
+        if self._latent:
+            tile_rows = latent_attention.query_tile_rows(q_count, self.chunk)
+        else:
+            tile_rows = query_tile_rows(q_count, self.chunk, q_per_kv=self._q_per_kv)
         wide = bool(spec_len.any())
         return _Packed(
             ids=ids, rows=rows, pos=pos, valid=valid, in_row=in_row,
@@ -1425,6 +1452,10 @@ class Scheduler:
             counts={
                 "prefill_tokens": prefill_tokens,
                 "kv_pages_walked": int(pages.sum()),
+                # the latent pages one layer's latent attention call copies
+                # in (the ragged kernel's rule: every live page of a slot
+                # with queries); None for a model with a K/V cache
+                "latent_pages_walked": int(pages.sum()) if self._latent else None,
                 # flash updates one layer's kernel call makes: each slot's
                 # pages in blocks of what its rung walks at a time
                 "kv_blocks_walked": kv_blocks_walked(

@@ -41,6 +41,12 @@ CPU host finds them before chip time is spent.  Covered:
   (128 slots, 1,024 tokens, 4,096 pages, the denoising tail): the expert
   stacks go into the kernel whole, so the temporaries hold no layer's
   copy of one;
+- the latent attention kernel (``ops/latent_attention.py``) alone at the
+  DeepSeek-V2-Lite cell's sizes and the whole mixed step of
+  ``deepseek-v2-lite-14l`` (128 slots, 1,024 tokens, a LATENT pool of 14
+  planes x 4,096 pages: ``kv_pool.shape`` the latent rows, ``rope_shape``
+  the rope rows, no V), its dense layer ahead of the loop over 13 expert
+  layers;
 - for each whole mixed step, ``kv_pool``: the stacked KV pools' bytes and
   the names of the optimised HLO's instructions that MOVE a pool — a
   ``copy``, ``dynamic-slice`` or ``dynamic-update-slice`` (or a fusion that
@@ -179,7 +185,8 @@ def _abstract_params(config, sharding_for, held=False):
 
 def _mixed_step_case(topo_device, model_id=None, slots=_SLOTS, t_budget=None,
                      kv_pages=None, spec_width=_SPEC_WIDTH):
-    """(fn, args, stacked KV pool shape) for the continuous scheduler's
+    """(fn, args, the stacked pools' shapes: K and V, or a latent pool's
+    latent and rope rows) for the continuous scheduler's
     one program: by default at the server's default shape for the default
     model, else at a benchmark cell's (its slots, token budget, pool pages
     and sampled width)."""
@@ -214,15 +221,29 @@ def _mixed_step_case(topo_device, model_id=None, slots=_SLOTS, t_budget=None,
     generator.config = config
     pages_per_seq = _MAX_SEQ // _PAGE
     num_pages = kv_pages or slots * pages_per_seq + 1
-    pool = (num_pages, _PAGE, pool_heads(config.num_kv_heads), config.head_dim)
     recurrent = PagedKVCache.recurrent_shapes(config, slots)
+    latent = getattr(config, "latent_dims", None)
+    if latent is not None:
+        # a latent pool (ops/latent_attention.py): latent rows and rope
+        # rows, no V
+        from operator_tpu.ops.latent_attention import pool_shapes
+
+        pool_shape, rope_shape = pool_shapes(config.kv_planes, num_pages, _PAGE, *latent)
+        pools = (pool_shape, rope_shape)
+    else:
+        pool_shape = (
+            config.kv_planes, num_pages, _PAGE, pool_heads(config.num_kv_heads),
+            config.head_dim,
+        )
+        pools = (pool_shape, pool_shape)
     paged = PagedKVCache(
-        k_pages=shaped((config.kv_planes, *pool), jnp.bfloat16),
-        v_pages=shaped((config.kv_planes, *pool), jnp.bfloat16),
+        k_pages=shaped(pool_shape, jnp.bfloat16),
+        v_pages=None if latent is not None else shaped(pool_shape, jnp.bfloat16),
         page_table=shaped((slots, pages_per_seq), jnp.int32),
         lengths=shaped((slots,), jnp.int32),
         ssm_state=None if recurrent is None else shaped(recurrent[0], jnp.float32),
         conv_state=None if recurrent is None else shaped(recurrent[1], jnp.bfloat16),
+        rope_pages=None if latent is None else shaped(pools[1], jnp.bfloat16),
     )
     t = t_budget or max(_CHUNK, slots)
     params = _abstract_params(
@@ -249,10 +270,7 @@ def _mixed_step_case(topo_device, model_id=None, slots=_SLOTS, t_budget=None,
         shaped((2,), jnp.uint32), slot_f, slot_f,  # rng temp top_p
         *denoise,
     )
-    return (
-        make_mixed_fn(generator, t, _CHUNK, spec_width=spec_width), args,
-        (config.kv_planes, *pool),
-    )
+    return make_mixed_fn(generator, t, _CHUNK, spec_width=spec_width), args, pools
 
 
 def _mesh_decode_case(topo_devices):
@@ -420,6 +438,8 @@ def main() -> int:
 
     # ragged kernel x every registered config: compile, or a NAMED refusal
     for name, config in sorted(_REGISTRY.items()):
+        if getattr(config, "latent_dims", None) is not None:
+            continue  # a latent cache: the latent attention kernel's (below)
         geometry = (config.num_heads, config.num_kv_heads, config.head_dim)
         try:
             require_ragged_kernel_support(config)
@@ -539,6 +559,43 @@ def main() -> int:
         ),
     ))
 
+    # the latent attention kernel alone at the benchmark's cell: 1,024 flat
+    # tokens over 128 slots of 16 heads, latent 512 and rope 64 in pages of
+    # 64 over 14 planes of 4,096 pages (4.23 GB), chunk 64
+    from operator_tpu.ops.latent_attention import (
+        _latent_attention_pallas,
+        block_pages as latent_block_pages,
+        pool_shapes as latent_pool_shapes,
+        query_tiles as latent_query_tiles,
+    )
+
+    deepseek = _REGISTRY["deepseek-v2-lite-14l"]
+    d_slots, d_tokens, d_pages = 128, 1024, 4096
+    d_latent, d_rope = latent_pool_shapes(
+        deepseek.kv_planes, d_pages, _PAGE, *deepseek.latent_dims,
+    )
+    d_heads, d_rank = deepseek.num_heads, deepseek.kv_lora_rank
+    d_name = "latent_attention_cell_deepseek-v2-lite_b128"
+    rungs[d_name] = list(latent_query_tiles(_CHUNK)), [
+        latent_block_pages(tile, heads=d_heads, page_size=_PAGE)
+        for tile in latent_query_tiles(_CHUNK)
+    ]
+    cases.append((
+        d_name,
+        functools.partial(
+            _latent_attention_pallas, scale=deepseek.softmax_scale, chunk=_CHUNK,
+        ),
+        (
+            shaped((d_tokens, d_heads, d_rank), jnp.bfloat16),
+            shaped((d_rope[-1] // deepseek.qk_rope_head_dim, d_tokens, d_heads, d_rope[-1]),
+                   jnp.bfloat16),
+            shaped(d_latent, jnp.bfloat16), shaped(d_rope, jnp.bfloat16),
+            shaped((d_slots, _MAX_SEQ // _PAGE), jnp.int32),
+            shaped((d_slots,), jnp.int32), shaped((d_slots,), jnp.int32),
+            shaped((d_slots,), jnp.int32), shaped((), jnp.int32),
+        ),
+    ))
+
     # whole programs: the dispatchers must pick the kernels although the
     # HOST backend is the CPU — the compile target is the TPU topology
     from operator_tpu.ops import _dispatch
@@ -579,6 +636,13 @@ def main() -> int:
                 kv_pages=544,
                 spec_width=1,
             )),
+            # the latent-attention cell: one dense layer ahead of the loop,
+            # 13 expert layers of 64 int8 experts scanned, a latent pool of
+            # 14 planes x 4,096 pages (4.23 GB, no lane padded)
+            ("mixed_step_deepseek-v2-lite-14l_b128", dict(
+                model_id="deepseek-v2-lite-14l", slots=d_slots, t_budget=d_tokens,
+                kv_pages=d_pages, spec_width=1,
+            )),
         ):
             fn, args, pools[name] = _mixed_step_case(topo.devices[0], **cell)
             cases.append((name, fn, args))
@@ -607,7 +671,7 @@ def main() -> int:
                     (results[name]["query_tiles"],
                      results[name]["kv_block_pages"]) = rungs[name]
                 if name in pools:
-                    shape = pools[name]
+                    shape, second = pools[name]
                     # the tail's one conditional (sched/mixed.py: the head
                     # and the sampler at one row a slot, or at the verify
                     # width); a step compiled at width 1 has none
@@ -616,9 +680,15 @@ def main() -> int:
                     ))
                     results[name]["kv_pool"] = {
                         "shape": list(shape),
-                        "bytes": 2 * 2 * math.prod(shape),  # K and V, bf16
-                        "moved_by": _pool_moves(compiled.as_text(), shape),
+                        # K and V, or a latent pool's latent and rope rows: bf16
+                        "bytes": 2 * (math.prod(shape) + math.prod(second)),
+                        "moved_by": sorted(set(
+                            _pool_moves(compiled.as_text(), shape)
+                            + _pool_moves(compiled.as_text(), second)
+                        )),
                     }
+                    if second != shape:
+                        results[name]["kv_pool"]["rope_shape"] = list(second)
                     results[name]["weight_moves"] = _weight_moves(
                         compiled.as_text(), args[0]
                     )

@@ -74,6 +74,11 @@ def test_all_kernels_aot_compile_for_v5e(record):
     # ragged kernel either compiled at both chunk widths (verify rows and
     # prefill chunks) or was refused by name — never anything in between
     for name, config in _REGISTRY.items():
+        if getattr(config, "latent_dims", None) is not None:
+            # a latent cache: the latent attention kernel serves it (its
+            # cell's case below), never the ragged one
+            assert f"ragged_{name}" not in kernels and f"ragged_{name}_c64" not in kernels
+            continue
         if config.head_dim % 128:
             assert "head_dim" in kernels[f"ragged_{name}"]["refused"], record
         else:
@@ -231,6 +236,44 @@ def test_the_hybrid_models_step_sizes_its_pools_by_layer_kind_and_fits(record):
     assert total < 15.75 * 2**30 - 1e9
 
 
+def test_the_latent_models_step_holds_an_unpadded_latent_pool_once_and_fits(record):
+    """The benchmark cell of the model with latent attention: the latent
+    kernel alone and the whole mixed step at 128 slots, 1,024 tokens and
+    4,096 pages.  The pool is 14 planes of latent rows [64, 512] and rope
+    rows [32, 128], two tokens' 64-lane rotary keys to a row: 1,152 B a
+    token and layer, 4.23 GB, nothing padded and no V, written in place and
+    back aliased.  The dense layer runs ahead of the loop over the 13
+    expert layers and its queries are read as held, so no int8 matrix is
+    copied; the kernel keeps a name the attention share counts; the step
+    leaves the chip over a GB."""
+    import re
+
+    from operator_tpu.ops.latent_attention import KERNEL_NAME
+
+    sys.path.insert(0, str(REPO))
+    from benchmark.layer_metrics import attn_kernel_share
+
+    kernels = record["kernels"]
+    alone = kernels["latent_attention_cell_deepseek-v2-lite_b128"]
+    assert alone["ok"] and alone["query_tiles"] == [1, 64], alone
+    step = kernels["mixed_step_deepseek-v2-lite-14l_b128"]
+    latent = [
+        call for call in step["pallas_calls"]
+        if re.fullmatch(re.escape(KERNEL_NAME) + r"(\.\d+)?", call)
+    ]
+    # the dense layer's call ahead of the loop, the loop body's
+    assert len(latent) == 2 and all(attn_kernel_share.PATTERN.search(c) for c in latent), step
+    assert any(call.startswith("moe_experts_kernel") for call in step["pallas_calls"])
+    pool = step["kv_pool"]
+    assert pool["shape"] == [14, 4096, 64, 512] and pool["rope_shape"] == [14, 4096, 32, 128]
+    assert pool["bytes"] == 14 * 4096 * 64 * 1152 == 4_227_858_432
+    assert pool["moved_by"] == [] and step["weight_moves"] == [] and step["conditionals"] == 0
+    assert step["alias_bytes"] >= pool["bytes"]
+    assert step["temp_bytes"] < 100e6, step
+    total = step["argument_bytes"] + step["output_bytes"] - step["alias_bytes"] + step["temp_bytes"]
+    assert total < 15.75 * 2**30 - 1e9
+
+
 @pytest.mark.parametrize("case, conditionals", [
     ("mixed_step_default_model", 1),
     ("mixed_step_qwen2.5-1.5b_b128", 1),
@@ -240,6 +283,8 @@ def test_the_hybrid_models_step_sizes_its_pools_by_layer_kind_and_fits(record):
     ("mixed_step_ouro-2.6b_b10", 1),
     # planes for the 8 full-attention layers alone, a period a loop step
     ("mixed_step_olmo-hybrid-7b_b48", 0),
+    # a latent pool: latent and rope rows, the dense layer ahead of the loop
+    ("mixed_step_deepseek-v2-lite-14l_b128", 0),
 ])
 def test_the_kv_pool_is_held_once_and_never_copied(record, case, conditionals):
     """The stacked KV pools ride the layer loop's carry and are written in
@@ -309,6 +354,8 @@ def test_the_pool_scan_names_the_old_ways_slice_stack_back_and_copy():
     "mixed_step_falcon-h1-34b-6l_b128",
     "mixed_step_ouro-2.6b_b10",
     "mixed_step_sdar-30b-a3b-12l_b128",
+    # the dense layer's queries too (``quant.LEAD_HEAD_PROJECTIONS``)
+    "mixed_step_deepseek-v2-lite-14l_b128",
 ])
 def test_no_layer_matrix_is_copied_in_the_mixed_step(record, case):
     """The step is compiled with the weights as the continuous path holds
