@@ -63,19 +63,19 @@ it computes follows that row's ``q_count``, not the compiled chunk ``C``:
   state, score block, mask, ``exp`` and both products have the tile's
   rows (:func:`query_tile_rows` is the rule, and what the scheduler's
   ``q_tile_rows`` counts).  The rungs are branches of the one kernel;
-- *the rung of one query*, built only at one query head a KV head (MHA,
-  a static shape: :func:`query_tiles`): a row with ``q_count == 1`` does
-  not take a product a KV head, which at MHA streams 8 query rows to
-  serve one and pays a product's fixed cost once a head and page (1.25
-  us a page of 16 heads, twice its copies, on a v5e).  It reads
-  the block's ``[keys, KH', D]`` buffer as ``keys x KH'`` rows in its
-  own layout and makes ONE score product of the row's ``[QH, D]``
-  query against all of them and ONE value product, each query head's
-  flash row keeping its own head's keys alone (the rest masked to
+- *the rung of one query*, at every geometry (:func:`query_tiles`): a
+  row with ``q_count == 1`` does not take a product a KV head, which
+  streams 8 x ``q_per_kv`` query rows to serve ``q_per_kv`` and pays a
+  product's fixed cost once a KV head and page (1.25 us a page of 16
+  heads at MHA, twice its copies, and 0.47 us a page of 4 heads at 28 /
+  4, three times its copies, on a v5e).  It reads the block's ``[keys,
+  KH', D]`` buffer as ``keys x KH'`` rows in its own layout and makes
+  ONE score product of the row's ``[QH, D]`` query against all of them
+  and ONE value product, query head ``i``'s flash row keeping the keys
+  of its own KV head ``i // q_per_kv`` alone (the rest masked to
   probability 0, and a value row of a head past the model's selected
   out as past ``kv_len`` is).  Operands as on the other rungs: bf16
   scores into float32, probabilities and the value product float32;
-  its block ``N`` is the small tile's;
 - *the KV block*: a live row's pages ``first .. num_live`` are walked
   ``N`` to a turn of the loop, and a turn scores, masks and folds its
   ``N x page_size`` keys into the flash state in ONE update (one wait
@@ -87,12 +87,13 @@ it computes follows that row's ``q_count``, not the compiled chunk ``C``:
   block's buffer slot, the next block's copies run while this one
   computes, and ONLY LIVE PAGES MOVE: no copy starts for a page at or
   past ``num_live``, and a window's walk starts at page ``first``, not
-  at a block's edge below it.  On the small tile's rung a partial last
-  block is folded at the narrowest of the widths 1, 2, 4 .. ``N`` pages
-  that holds its live pages, so a decode row of one page pays for one
-  (the whole chunk's rung folds every block at ``N``: its fold is the
-  kernel's largest code, and copies of it at other widths outgrew the
-  core's instruction memory at 28 / 4 heads).  What a buffer holds past
+  at a block's edge below it.  On the rungs of one query and of the
+  small tile a partial last block is folded at the narrowest of the
+  widths 1, 2, 4 .. ``N`` pages that holds its live pages, so a decode
+  row of one page pays for one (the whole chunk's rung folds every
+  block at ``N``: its fold is the kernel's largest code, and copies of
+  it at other widths outgrew the core's instruction memory at 28 / 4
+  heads).  What a buffer holds past
   ``kv_len`` (rows no copy fetched, the tail of a last page) is selected
   out of the value product: the mask makes its probability 0, and 0 x
   NaN is NaN.  ``N`` follows from static shapes, a rung at a time
@@ -105,15 +106,16 @@ it computes follows that row's ``q_count``, not the compiled chunk ``C``:
   flash update holds four or so ``[tile x QH, N x page_size]`` float32
   blocks at once (scores, the mask's positions, probabilities), so 2 MiB
   of one is ~8 MiB of them beside the flash state and the q / out
-  blocks.  The 7B whole-chunk rung (64 x 28 = 1,792 flash rows): a score
-  block is 1,792 x 256 x 4 B = 1.84 MB at four pages and 3.67 MB at
-  eight, so it takes 4; its small tile (8 x 28 = 224 rows) 8.  An Ouro
-  page (16 KV heads) is 64 x 16 x 128 x 2 B = 256 KiB of K alone, so
-  K and V in two slots are 2 MiB at two pages: both its rungs take 2.
-  The 1.5B (12 / 2 heads) takes 8 on both rungs.  A longer block also
-  delays a row's first update behind more copies (nothing of the row
-  before overlaps them), which is the other reason the buffers are
-  capped;
+  blocks (the rung of one query's is ``[QH, N x page_size x KH']``).
+  The 7B whole-chunk rung (64 x 28 = 1,792 flash rows): a score block
+  is 1,792 x 256 x 4 B = 1.84 MB at four pages and 3.67 MB at eight, so
+  it takes 4; its small tile (8 x 28 = 224 rows) 8, and its rung of one
+  query (28 rows x 8 x 64 x 4 columns, 229 KB) 8.  An Ouro page (16 KV
+  heads) is 64 x 16 x 128 x 2 B = 256 KiB of K alone, so K and V in two
+  slots are 2 MiB at two pages: all its rungs take 2.  The 1.5B (12 / 2
+  heads) takes 8 on every rung.  A longer block also delays a row's
+  first update behind more copies (nothing of the row before overlaps
+  them), which is the other reason the buffers are capped;
 - *the idle rule*: a row with ``q_count == 0`` (an empty slot, or a live
   row the step's token budget left out) runs nothing — no state, no page,
   no finalise — and moves no block: its grid step's q / out index is the
@@ -176,22 +178,19 @@ KV_BLOCK_PAGES = (1, 2, 4, 8)
 VMEM_BLOCK_BUDGET = 2 * 2**20
 
 
-def query_tiles(chunk: int, *, q_per_kv: int) -> tuple[int, ...]:
+def query_tiles(chunk: int) -> tuple[int, ...]:
     """The kernel's rungs at a compiled chunk: the query tiles, ascending.
-    One query head a KV head (MHA) adds the tile of one query, whose rung
-    scores a row against every head of a block at once (the module doc)."""
-    tiles = {min(SMALL_TILE, chunk), chunk}
-    if q_per_kv == 1:
-        tiles.add(1)
-    return tuple(sorted(tiles))
+    The tile of one query leads, whose rung scores a row against every KV
+    head of a block at once (the module doc)."""
+    return tuple(sorted({1, min(SMALL_TILE, chunk), chunk}))
 
 
-def query_tile_rows(q_count: np.ndarray, chunk: int, *, q_per_kv: int) -> np.ndarray:
+def query_tile_rows(q_count: np.ndarray, chunk: int) -> np.ndarray:
     """The query tile the kernel works for each slot (0 for a slot it
     skips): the smallest of :func:`query_tiles` that holds the slot's
     queries, the rule ``_ragged_attn_kernel`` branches on.  On the host:
     the scheduler counts its ``q_tile_rows`` with it."""
-    tiles = np.asarray(query_tiles(chunk, q_per_kv=q_per_kv))
+    tiles = np.asarray(query_tiles(chunk))
     fit = tiles[np.minimum(np.searchsorted(tiles, q_count), len(tiles) - 1)]
     return np.where(q_count > 0, fit, 0)
 
@@ -224,11 +223,14 @@ def kv_block_pages(
     """Pages one flash update folds in at the rung of query tile ``tile``:
     the largest of ``KV_BLOCK_PAGES`` at which neither the page buffers
     (K and V, two slots, of the pool's :func:`pool_heads`) nor one ``[flash
-    rows, keys]`` float32 block passes ``VMEM_BLOCK_BUDGET``.  Static
-    shapes in, so the kernel's trace and the scheduler's
-    ``kv_blocks_walked`` agree by construction."""
-    buffers = 4 * page_size * pool_heads(kv_heads) * head_dim * itemsize
-    scores = tile * q_per_kv * kv_heads * page_size * 4
+    rows, keys]`` float32 block passes ``VMEM_BLOCK_BUDGET`` (the rung of
+    one query: ``[query heads, keys x pool heads]``).  Static shapes in,
+    so the kernel's trace and the scheduler's ``kv_blocks_walked`` agree
+    by construction."""
+    held = pool_heads(kv_heads)
+    buffers = 4 * page_size * held * head_dim * itemsize
+    columns = page_size * (held if tile == 1 else 1)
+    scores = tile * q_per_kv * kv_heads * columns * 4
     return max(
         [n for n in KV_BLOCK_PAGES if n * max(buffers, scores) <= VMEM_BLOCK_BUDGET],
         default=KV_BLOCK_PAGES[0],
@@ -374,8 +376,8 @@ def _ragged_attn_kernel(
     out head-major — row ``h*T*G + i*G + j`` is query token ``i`` of q
     head ``h*G + j`` at tile ``T`` — so the per-kv-head GQA dots write
     contiguous slabs; the finalize transposes back to [T, QH, D] and
-    casts to the output's dtype once.  The rung of one query at one
-    query head a kv head (``all_heads``) keeps one flash row a head."""
+    casts to the output's dtype once.  The rung of one query
+    (``all_heads``) keeps one flash row a query head."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -395,9 +397,9 @@ def _ragged_attn_kernel(
     def rung(tile: int, pages: int):
         """The whole of a live row's work at a static query tile, its
         pages walked ``pages`` to a block."""
-        # MHA's rung of one query takes every head of a block at once
+        # the rung of one query takes every head of a block at once
         # (``all_heads``); every other rung a product a KV head
-        one_query = tile == 1 and q_per_kv == 1
+        one_query = tile == 1
         slab = tile * q_per_kv  # flash rows per kv head (token-major within)
         total = kv_heads * slab
         walked = num_live - first  # >= 1 for a live row
@@ -537,26 +539,29 @@ def _ragged_attn_kernel(
 
         def all_heads(m_scratch, l_scratch, acc_scratch):
             """One query against every head of a block at once, in the
-            page's own layout (MHA's rung of one query): the block's
+            page's own layout (the rung of one query): the block's
             ``[width, KH', D]`` keys read as ``width x KH'`` rows, ONE
             score product of the row's query heads against all of them and
-            ONE value product, where each query head keeps its own head's
-            keys alone (the others masked out: probability 0).  No head is
-            sliced out of the buffer and no product streams rows that
-            serve nothing; flash state is one row a head.  Returns what
-            ``head_products`` does."""
+            ONE value product, where each query head keeps its own KV
+            head's keys alone (the others masked out: probability 0).  No
+            head is sliced out of the buffer and no product streams rows
+            that serve nothing; flash state is one row a query head.
+            Returns what ``head_products`` does."""
             held = k_buf.shape[2]  # the page's heads (pool_heads)
-            q_heads = q_ref[0, 0].astype(k_buf.dtype)  # [KH, D]
+            q_heads = q_ref[0, 0].astype(k_buf.dtype)  # [QH, D]
             q_end = _block_end(q_base, attend_block)
 
             def positions(width):
                 """Score column and value row ``j`` are key ``j // held``
-                of head ``j % held``: each column's key, whether it is the
-                query head's own, and each value row's key and whether its
-                head is the model's."""
-                col = jax.lax.broadcasted_iota(jnp.int32, (kv_heads, width * held), 1)
-                row = jax.lax.broadcasted_iota(jnp.int32, (kv_heads, width * held), 0)
+                of KV head ``j % held``: each column's key, whether it is
+                the query head's own KV head's, and each value row's key
+                and whether its head is the model's."""
+                col = jax.lax.broadcasted_iota(jnp.int32, (total, width * held), 1)
+                row = jax.lax.broadcasted_iota(jnp.int32, (total, width * held), 0)
                 val = jax.lax.broadcasted_iota(jnp.int32, (width * held, head_dim), 0)
+                # graftlint: disable=GL002 reason=q_per_kv is a static int, bound with functools.partial before the pallas_call
+                if q_per_kv > 1:
+                    row = row // q_per_kv  # query head i reads KV head i // G
                 return col // held, col % held == row, val // held, val % held < kv_heads
 
             def fold(t, width, key_of, own, value_key, value_head):
@@ -570,7 +575,7 @@ def _ragged_attn_kernel(
                 s = jax.lax.dot_general(
                     q_heads, k, (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32,
-                ) * scale  # [KH, width x KH']
+                ) * scale  # [QH, width x KH']
                 kv_pos = block_pos + key_of
                 mask = own & (kv_pos <= q_end) & (kv_pos < seq_len)
                 if window is not None:
@@ -681,7 +686,7 @@ def _ragged_attention_pallas(
     kh = kv_heads or page_heads
     assert qh % kh == 0 and kh <= page_heads, (qh, kh, page_heads)
 
-    tiles = query_tiles(c, q_per_kv=qh // kh)
+    tiles = query_tiles(c)
     blocks = block_pages or tuple(
         kv_block_pages(
             tile, q_per_kv=qh // kh, kv_heads=kh, head_dim=d,

@@ -298,9 +298,6 @@ class Scheduler:
                 feature, model.name, model.family, why,
             )
         self.chunk = max(1, min(chunk, generator.max_seq))
-        #: query heads a KV head: with the chunk, what decides the ragged
-        #: kernel's rungs (``query_tiles``)
-        self._q_per_kv = model.num_heads // model.num_kv_heads
         #: pages a flash update of the ragged kernel folds in, by query
         #: tile: the kernel's own rule on the static shapes it is given
         #: (the latent attention kernel's, for a model with a latent cache)
@@ -314,12 +311,12 @@ class Scheduler:
         else:
             self._kv_block_of = {
                 tile: kv_block_pages(
-                    tile, q_per_kv=self._q_per_kv,
+                    tile, q_per_kv=model.num_heads // model.num_kv_heads,
                     kv_heads=model.num_kv_heads, head_dim=model.head_dim,
                     page_size=generator.page_size,
                     itemsize=np.dtype(generator.cache_dtype).itemsize,
                 )
-                for tile in query_tiles(self.chunk, q_per_kv=self._q_per_kv)
+                for tile in query_tiles(self.chunk)
             }
         #: the most query tokens a generating row packs a step: one, or a
         #: block led by the block before it
@@ -1442,7 +1439,7 @@ class Scheduler:
         if self._latent:
             tile_rows = latent_attention.query_tile_rows(q_count, self.chunk)
         else:
-            tile_rows = query_tile_rows(q_count, self.chunk, q_per_kv=self._q_per_kv)
+            tile_rows = query_tile_rows(q_count, self.chunk)
         wide = bool(spec_len.any())
         return _Packed(
             ids=ids, rows=rows, pos=pos, valid=valid, in_row=in_row,
@@ -1463,8 +1460,8 @@ class Scheduler:
                 ),
                 # the query-tile rows one layer's kernel call works, by
                 # the kernel's own rule: nothing for a slot without
-                # queries, else the smallest rung that holds them (one at
-                # MHA for a row of one query, the small tile, the chunk)
+                # queries, else the smallest rung that holds them (one
+                # for a row of one query, the small tile, the chunk)
                 "q_tile_rows": int(tile_rows.sum()),
                 # slots whose recurrent state a layer's scan call reads
                 # and rewrites (the others it skips); no such state, no count

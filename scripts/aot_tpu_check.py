@@ -15,8 +15,8 @@ CPU host finds them before chip time is spent.  Covered:
   the benchmark's cells (12/2 heads x 128 slots, 28/4 x 32, 20/4 x 128,
   16/16 x 10, 30/30 x 48): every rung of its query tile, each with the
   KV block ``kv_block_pages`` gives it (the record's ``query_tiles``,
-  smallest first: at one query head a kv head the rung of one query
-  leads; and ``kv_block_pages``: pages a flash update folds in at each).
+  smallest first: the rung of one query leads; and ``kv_block_pages``:
+  pages a flash update folds in at each).
   A config the kernel cannot serve must be REFUSED
   by ``require_ragged_kernel_support`` (a named error at engine build),
   never silently routed elsewhere — the check asserts which of the two
@@ -425,7 +425,7 @@ def main() -> int:
         the pages a flash update folds in at each (smallest tile first),
         by the module's own rules from the very shapes compiled."""
         args = ragged_args(heads, kv_heads, head_dim, chunk, rows=rows)
-        tiles = query_tiles(chunk, q_per_kv=heads // kv_heads)
+        tiles = query_tiles(chunk)
         rungs[name] = list(tiles), [
             kv_block_pages(
                 tile, q_per_kv=heads // kv_heads, kv_heads=kv_heads,
@@ -462,8 +462,8 @@ def main() -> int:
     for tag, heads, kv_heads, slots in (
         ("qwen2.5-1.5b", 12, 2, 128), ("qwen2.5-7b", 28, 4, 32),
         ("falcon-h1-34b", 20, 4, 128),
-        # one query head a kv head: a decode row's one query against
-        # every head of a block at once, other rows sixteen [tile, 1, D] slabs
+        # one query head a kv head: a chunk or verify row takes sixteen
+        # [tile, 1, D] slabs
         ("ouro-2.6b", 16, 16, 10),
         # the hybrid's 30 heads, a page holding 32 (pool_heads)
         ("olmo-hybrid-7b", 30, 30, 48),

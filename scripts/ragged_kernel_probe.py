@@ -7,7 +7,7 @@ cost at a cell's geometry, for each KV block the walk could take.
 
 For every ``--blocks`` entry (``auto``: what ``kv_block_pages`` chooses;
 ``AxB``: A pages a block at the small query tile, B at the whole chunk;
-at one query head a kv head the rung of one query takes A too)
+the rung of one query takes A too)
 the kernel is compiled once and timed over ``--calls`` calls inside one
 jitted loop (the dispatch is paid once), with every slot a decode row of
 P pages, then every slot a whole chunk over P pages, for each P of
@@ -39,14 +39,15 @@ import numpy as np  # noqa: E402
 
 from operator_tpu.ops import ragged_attention as ours  # noqa: E402
 
-#: heads, KV heads, slots of the benchmark's cells (BENCHMARK.json)
+#: heads, KV heads, slots of the benchmark's cells (BENCHMARK.json), and
+#: the block of the cell's block-causal mask (models/sdar.py; 1 = causal)
 SHAPES = {
-    "1.5b": (12, 2, 128), "7b": (28, 4, 32),
-    "ouro": (16, 16, 10), "falcon": (20, 4, 128),
-    # with --attend-block 4 --row-queries 1,4,8: rows that denoise a block
-    "sdar": (32, 4, 128),
+    "1.5b": (12, 2, 128, 1), "7b": (28, 4, 32, 1),
+    "ouro": (16, 16, 10, 1), "falcon": (20, 4, 128, 1),
+    # with --row-queries 1,4,8: rows that denoise a block as well
+    "sdar": (32, 4, 128, 4),
     # the hybrid's 30 MHA heads, a page holding 32 (``pool_heads``)
-    "olmo": (30, 30, 48),
+    "olmo": (30, 30, 48, 1),
 }
 PAGE, HEAD_DIM, CHUNK, LAYERS = 64, 128, 64, 2
 
@@ -152,8 +153,9 @@ def main() -> None:
     ap.add_argument("--chunk-pages", default="1,2,4,5,8,9,16,17")
     ap.add_argument("--calls", type=int, default=56)
     ap.add_argument("--parent", default=None)
-    ap.add_argument("--attend-block", type=int, default=1,
-                    help="the block-causal mask's block (models/sdar.py); 1 = causal")
+    ap.add_argument("--attend-block", type=int, default=None,
+                    help="the block-causal mask's block (models/sdar.py); 1 = "
+                    "causal; default: the shape's")
     ap.add_argument("--row-queries", default="1",
                     help="queries of a row on the small tile, one fit for each")
     ap.add_argument("--slots", type=int, default=None)
@@ -164,7 +166,9 @@ def main() -> None:
 
     device = jax.devices()[0]
     print("device", device.platform, device.device_kind, flush=True)
-    heads, kv_heads, slots = SHAPES[args.shape]
+    heads, kv_heads, slots, attend_block = SHAPES[args.shape]
+    if args.attend_block is not None:
+        attend_block = args.attend_block
     slots = args.slots or slots
     pages = [int(p) for p in args.pages.split(",")]
     chunk_pages = [int(p) for p in args.chunk_pages.split(",")]
@@ -173,12 +177,12 @@ def main() -> None:
         q_per_kv=heads // kv_heads, kv_heads=kv_heads, head_dim=HEAD_DIM,
         page_size=PAGE, itemsize=2,
     )
-    tiles = ours.query_tiles(CHUNK, q_per_kv=heads // kv_heads)
+    tiles = ours.query_tiles(CHUNK)
     chosen = tuple(ours.kv_block_pages(tile, **geometry) for tile in tiles)
     variants = []
     extra = {"interpret": True} if args.interpret else {}
-    if args.attend_block != 1:
-        extra["attend_block"] = args.attend_block
+    if attend_block != 1:
+        extra["attend_block"] = attend_block
     for name in args.blocks.split(","):
         blocks = chosen if name == "auto" else tuple(
             int(n) for n in name.split("x")
@@ -193,6 +197,7 @@ def main() -> None:
         )
 
     result = {"shape": args.shape, "device": device.device_kind, "slots": slots,
+              "attend_block": attend_block,
               "tiles": list(tiles), "auto": list(chosen), "variants": {}}
     for name, kernel, blocks in variants:
         entry = {"blocks": list(blocks.get("block_pages", ())),

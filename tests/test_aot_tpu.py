@@ -116,28 +116,27 @@ def test_every_ragged_case_names_the_kv_block_of_each_rung(record):
     for name, k in ragged.items():
         assert k["ok"], (name, k)
         tiles, blocks = k["query_tiles"], k["kv_block_pages"]
-        # one rung at a verify width under the small tile, two at a chunk,
-        # and at one query head a kv head the rung of one query before them
-        mha = tiles[0] == 1
-        assert tiles[mha:] == ([5] if name.endswith("_c5") else [8, 64]), (name, tiles)
+        # the rung of one query, then one rung at a verify width under the
+        # small tile, two at a chunk
+        assert tiles == [1] + ([5] if name.endswith("_c5") else [8, 64]), (name, tiles)
         assert len(blocks) == len(tiles), (name, blocks)
         assert set(blocks) <= set(KV_BLOCK_PAGES), (name, blocks)
         # the small tile never walks narrower than the chunk, and the rung
         # of one query walks the small tile's block
         assert blocks == sorted(blocks, reverse=True), (name, blocks)
-        assert blocks[0] == blocks[mha], (name, blocks)
+        assert blocks[0] == blocks[1], (name, blocks)
     cells = {
         name: (k["query_tiles"], k["kv_block_pages"]) for name, k in ragged.items()
         if name.startswith("ragged_cell_")
     }
     assert cells == {
-        "ragged_cell_qwen2.5-1.5b_b128": ([8, 64], [8, 8]),
-        "ragged_cell_qwen2.5-7b_b32": ([8, 64], [8, 4]),
-        "ragged_cell_falcon-h1-34b_b128": ([8, 64], [8, 4]),
-        # one query head a kv head: a decode row takes the rung of one
+        # a decode row takes the rung of one query at every geometry
+        "ragged_cell_qwen2.5-1.5b_b128": ([1, 8, 64], [8, 8, 8]),
+        "ragged_cell_qwen2.5-7b_b32": ([1, 8, 64], [8, 8, 4]),
+        "ragged_cell_falcon-h1-34b_b128": ([1, 8, 64], [8, 8, 4]),
         "ragged_cell_ouro-2.6b_b10": ([1, 8, 64], [2, 2, 2]),
         # eight query heads a kv head, under the block-causal mask
-        "ragged_cell_sdar-30b-a3b_b128": ([8, 64], [8, 4]),
+        "ragged_cell_sdar-30b-a3b_b128": ([1, 8, 64], [8, 8, 4]),
         # 32 heads a page (the hybrid's 30, held as the tile holds them):
         # K and V in two slots are the 2 MiB budget at one page
         "ragged_cell_olmo-hybrid-7b_b48": ([1, 8, 64], [1, 1, 1]),

@@ -154,7 +154,8 @@ class TestRaggedKernel:
         dict(id="layer-middle", qh=4, kh=2, layer=1, **_EDGES),
         dict(id="layer-last", qh=4, kh=2, layer=2, **_EDGES),
         # the KV block's edges, on both rungs (blocks: pages a flash
-        # update folds in, small tile then chunk)
+        # update folds in, small tile then chunk; the rung of one query
+        # walks the small tile's)
         dict(id="block-edges-4x4", blocks=(4, 4), **_BLOCK_ROWS),
         dict(id="block-edges-2x2", blocks=(2, 2), **_BLOCK_ROWS_2),
         # each rung its own block; 7 pages are one partial block of 8
@@ -211,14 +212,11 @@ class TestRaggedKernel:
         kv_len = jnp.asarray(case["kv_len"], jnp.int32)
         q_count = jnp.asarray(case["q_count"], jnp.int32)
         assert max(case["kv_len"]) <= ps * pps
-        q_per_kv = case["qh"] // kv_heads
-        tiles = set(
-            query_tile_rows(np.asarray(q_count), case["c"], q_per_kv=q_per_kv).tolist()
-        )
-        # a row of one query at one query head a kv head takes a rung of its own
-        assert tiles <= {0, SMALL_TILE, case["c"]} | ({1} if q_per_kv == 1 else set())
+        tiles = set(query_tile_rows(np.asarray(q_count), case["c"]).tolist())
+        # a row of one query takes a rung of its own
+        assert tiles <= {0, 1, SMALL_TILE, case["c"]}
         if case["id"].startswith("edges"):
-            assert tiles == {0, SMALL_TILE, case["c"]}
+            assert tiles == {0, 1, SMALL_TILE, case["c"]}
         window = case.get("window")
         layer = case.get("layer", self.LAYERS - 1)
         given = (k, v)
@@ -229,10 +227,11 @@ class TestRaggedKernel:
             jnp.int32(layer), interpret=True, sliding_window=window,
             kv_heads=kv_heads,
         )
-        got = run(block_pages=case.get("blocks"))
+        # the rung of one query walks the small tile's block
+        got = run(block_pages=case.get("blocks") and case["blocks"][:1] + case["blocks"])
         assert got.shape == q.shape and got.dtype == dtype
         if "agree_with" in case:
-            other = run(block_pages=case["agree_with"])
+            other = run(block_pages=case["agree_with"][:1] + case["agree_with"])
             for row, n in enumerate(case["q_count"]):
                 np.testing.assert_allclose(
                     np.asarray(got[row, :n]), np.asarray(other[row, :n]),

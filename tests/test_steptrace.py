@@ -1223,8 +1223,8 @@ class TestKvPagesWalked:
             page_size=16, itemsize=4,
         )
         monkeypatch.setattr(ragged_attention, "VMEM_BLOCK_BUDGET", 16_384)
-        block_of = {tile: kv_block_pages(tile, **geometry) for tile in (8, 16)}
-        assert block_of == {8: 2, 16: 2}
+        block_of = {tile: kv_block_pages(tile, **geometry) for tile in (1, 8, 16)}
+        assert block_of == {1: 2, 8: 2, 16: 2}
 
         config = dataclasses.replace(TINY_TEST, sliding_window=window)
         generator = BatchedGenerator(
@@ -1262,9 +1262,7 @@ class TestKvPagesWalked:
             pages, pairs = _walk_by_the_references_rule(kv_len, q_count, 16, window)
             assert (record.kv_pages_walked, span_pairs[record.seq]) == (pages, pairs)
             assert record.tokens == int(q_count.sum())
-            rows = query_tile_rows(
-                q_count, 16, q_per_kv=TINY_TEST.num_heads // TINY_TEST.num_kv_heads
-            )
+            rows = query_tile_rows(q_count, 16)
             assert record.q_tile_rows == span_rows[record.seq] == int(rows.sum())
             # flash updates: each walking slot's pages, counted by the
             # reference's rule, in blocks of what its rung takes
@@ -1282,7 +1280,9 @@ class TestKvPagesWalked:
                 "unscheduled" for kv, c in zip(kv_len, q_count) if kv > 0 and c == 0
             )
         assert {"decode", "mixed"} <= kinds
-        assert tiles == {0, 8, 16}  # idle slots, decode rows, prompt chunks
+        # idle slots, decode rows, prompt chunks (and the rows of a few
+        # queries: a verify row, a short prompt's chunk)
+        assert {0, 1, 16} <= tiles <= {0, 1, 8, 16}
         assert several  # some block held more than one page
         assert [r.sampled_rows for r in records] == [
             4 * (sched.width if wide else 1) for wide in drafted
@@ -1325,9 +1325,9 @@ class TestKvBlocksWalked:
         )
 
         # the 7B cells' geometry: the rungs' blocks the cases are written for
-        block_of = {t: kv_block_pages(t, **self.GEOMETRY) for t in (8, 64)}
-        assert block_of == {8: 8, 64: 4}
-        tile_rows = query_tile_rows(np.asarray(q_count, np.int32), 64, q_per_kv=7)
+        block_of = {t: kv_block_pages(t, **self.GEOMETRY) for t in (1, 8, 64)}
+        assert block_of == {1: 8, 8: 8, 64: 4}
+        tile_rows = query_tile_rows(np.asarray(q_count, np.int32), 64)
         assert kv_blocks_walked(np.asarray(pages), tile_rows, block_of) == blocks
 
     @pytest.mark.parametrize("name, geometry", [
@@ -1339,9 +1339,10 @@ class TestKvBlocksWalked:
         ("olmo-hybrid-7b", dict(q_per_kv=1, kv_heads=30)),
     ])
     def test_the_rung_of_one_query_walks_the_small_tiles_blocks(self, name, geometry):
-        """At MHA a row of one query takes a rung of its own; the flash
-        updates a call makes are the same as if it took the small tile's,
-        at every geometry: the rung's block is the small tile's."""
+        """A row of one query takes a rung of its own at every geometry;
+        the flash updates a call makes are the same as if it took the
+        small tile's, at every served geometry: the rung's block is the
+        small tile's."""
         import numpy as np
 
         from operator_tpu.ops.ragged_attention import (
@@ -1353,19 +1354,18 @@ class TestKvBlocksWalked:
         )
 
         shapes = dict(head_dim=128, page_size=64, itemsize=2, **geometry)
-        tiles = query_tiles(64, q_per_kv=geometry["q_per_kv"])
+        tiles = query_tiles(64)
         block_of = {t: kv_block_pages(t, **shapes) for t in tiles}
         pages = np.asarray([1, 6, 9, 17, 24, 3, 0, 11])
         q_count = np.asarray([1, 1, 1, 5, 64, 8, 0, 9], np.int32)
-        rows = query_tile_rows(q_count, 64, q_per_kv=geometry["q_per_kv"])
+        rows = query_tile_rows(q_count, 64)
         # the GQA rule: the small tile for every row of at most 8 queries
         small = np.where(q_count > SMALL_TILE, 64, np.where(q_count > 0, SMALL_TILE, 0))
         assert kv_blocks_walked(pages, rows, block_of) == kv_blocks_walked(
             pages, small, {t: kv_block_pages(t, **shapes) for t in (8, 64)}
         )
-        assert (1 in tiles) is (geometry["q_per_kv"] == 1)
-        if 1 in tiles:
-            assert block_of[1] == block_of[SMALL_TILE]
+        assert tiles[0] == 1 and rows.tolist()[:3] == [1, 1, 1]
+        assert block_of[1] == block_of[SMALL_TILE]
 
     @pytest.mark.parametrize("name, geometry, small, chunk", [
         ("qwen2.5-1.5b", dict(q_per_kv=6, kv_heads=2), 8, 8),
@@ -1377,18 +1377,49 @@ class TestKvBlocksWalked:
     ])
     def test_the_cells_blocks(self, name, geometry, small, chunk):
         """The block each rung takes at the served geometries: a change
-        of the budget or of the rule shows here first.  At one query head
-        a kv head the rung of one query leads and walks the small tile's
-        block."""
+        of the budget or of the rule shows here first.  The rung of one
+        query leads at every geometry and walks the small tile's block."""
         from operator_tpu.ops.ragged_attention import kv_block_pages, query_tiles
 
         shapes = dict(head_dim=128, page_size=64, itemsize=2, **geometry)
-        mha = geometry["q_per_kv"] == 1
-        tiles = query_tiles(64, q_per_kv=geometry["q_per_kv"])
-        assert tiles == (1,) * mha + (8, 64)
-        assert [kv_block_pages(t, **shapes) for t in tiles] == (
-            [small] * mha + [small, chunk]
+        tiles = query_tiles(64)
+        assert tiles == (1, 8, 64)
+        assert [kv_block_pages(t, **shapes) for t in tiles] == [small, small, chunk]
+
+    @pytest.mark.parametrize("name, geometry, one, small", [
+        # the cells' groups: the page buffers decide every rung's block
+        ("qwen2.5-1.5b", dict(q_per_kv=6, kv_heads=2), 8, 8),
+        ("qwen2.5-7b", dict(q_per_kv=7, kv_heads=4), 8, 8),
+        ("falcon-h1-34b", dict(q_per_kv=5, kv_heads=4), 8, 8),
+        ("ouro-2.6b", dict(q_per_kv=1, kv_heads=16), 2, 2),
+        # 512 query heads over 16 KV heads: the rung of one query's score
+        # block, [512, 64 x 16] float32, is 2 MiB a page where the small
+        # tile's, [8 x 512, 64], is 1 MiB: the rung takes one page
+        ("wide-group", dict(q_per_kv=32, kv_heads=16), 1, 2),
+    ])
+    def test_the_rung_of_one_query_counts_its_own_score_block(
+        self, name, geometry, one, small
+    ):
+        """The rung of one query scores ``[query heads, N x page x held
+        heads]``, not ``[tile x query heads, N x page]``: its block is the
+        largest ``N`` at which neither that float32 block nor the page
+        buffers pass the budget."""
+        from operator_tpu.ops.ragged_attention import (
+            KV_BLOCK_PAGES,
+            VMEM_BLOCK_BUDGET,
+            kv_block_pages,
+            pool_heads,
         )
+
+        shapes = dict(head_dim=128, page_size=64, itemsize=2, **geometry)
+        heads = geometry["q_per_kv"] * geometry["kv_heads"]
+        held = pool_heads(geometry["kv_heads"])
+        per_page = max(4 * 64 * held * 128 * 2, heads * 64 * held * 4)
+        assert one == max(
+            n for n in KV_BLOCK_PAGES if n * per_page <= VMEM_BLOCK_BUDGET
+        )
+        assert kv_block_pages(1, **shapes) == one
+        assert kv_block_pages(8, **shapes) == small
 
     def test_a_block_never_outgrows_the_budget_and_one_page_always_goes(self):
         from operator_tpu.ops import ragged_attention
@@ -1406,12 +1437,13 @@ class TestQTileRows:
 
     @pytest.mark.parametrize("name, q_count, chunk, rows", [
         # 3 decode rows and a verify row, each on the small tile of 8
-        ("decode-only", [1, 1, 0, 1, 5, 0], 64, 4 * 8),
+        ("decode-only", [1, 1, 0, 1, 5, 0], 64, 3 * 1 + 8),
         # the small tile's edge, one past it, a whole chunk, two idle
-        ("mixed", [1, 8, 9, 64, 0, 0], 64, 8 + 8 + 64 + 64),
+        ("mixed", [1, 8, 9, 64, 0, 0], 64, 1 + 8 + 64 + 64),
         ("empty", [0, 0, 0, 0], 64, 0),
-        # a chunk no larger than the small tile has one rung: the chunk
-        ("tiny-chunk", [1, 4, 0], 4, 4 + 4),
+        # a chunk no larger than the small tile has two rungs: one query,
+        # and the chunk
+        ("tiny-chunk", [1, 4, 0], 4, 1 + 4),
     ])
     def test_count_is_the_kernels_rule(self, name, q_count, chunk, rows):
         import numpy as np
@@ -1420,15 +1452,15 @@ class TestQTileRows:
 
         assert SMALL_TILE == 8
         counts = np.asarray(q_count, np.int32)
-        # several query heads a kv head (GQA): nothing, the small tile, or
-        # the chunk, slot by slot
+        # nothing, the rung of one query, the small tile, or the chunk,
+        # slot by slot, whatever the group of query heads a kv head
         small = min(SMALL_TILE, chunk)
-        for q_per_kv in (2, 7):
-            got = query_tile_rows(counts, chunk, q_per_kv=q_per_kv)
-            assert int(got.sum()) == rows
-            assert got.tolist() == [
-                0 if c == 0 else small if c <= small else chunk for c in q_count
-            ]
+        got = query_tile_rows(counts, chunk)
+        assert int(got.sum()) == rows
+        assert got.tolist() == [
+            0 if c == 0 else 1 if c == 1 else small if c <= small else chunk
+            for c in q_count
+        ]
 
     @pytest.mark.parametrize("name, q_count, chunk, tiles", [
         # decode rows take the rung of one; a verify row the small tile
@@ -1441,20 +1473,19 @@ class TestQTileRows:
         ("chunk-of-one", [1, 0, 1], 1, [1, 0, 1]),
     ])
     def test_a_row_of_one_query_at_mha_counts_one(self, name, q_count, chunk, tiles):
-        """One query head a kv head: a row of one query is worked on the
-        rung of one (``q_tile_rows`` counts 1 for it, where the small tile
-        counted 8); a row of more queries takes what it took."""
+        """A row of one query is worked on the rung of one at MHA as at
+        GQA (``q_tile_rows`` counts 1 for it, where the small tile counted
+        8); a row of more queries takes what it took."""
         import numpy as np
 
         from operator_tpu.ops.ragged_attention import query_tile_rows, query_tiles
 
         counts = np.asarray(q_count, np.int32)
-        assert query_tile_rows(counts, chunk, q_per_kv=1).tolist() == tiles
-        assert query_tiles(chunk, q_per_kv=1)[0] == 1
-        # ten decode rows at Ouro count 10 where they counted 80
+        assert query_tile_rows(counts, chunk).tolist() == tiles
+        assert query_tiles(chunk)[0] == 1
+        # ten decode rows count ten rows, not ten small tiles of 8
         ten = np.ones(10, np.int32)
-        assert int(query_tile_rows(ten, 64, q_per_kv=1).sum()) == 10
-        assert int(query_tile_rows(ten, 64, q_per_kv=7).sum()) == 80
+        assert int(query_tile_rows(ten, 64).sum()) == 10
 
 
 class TestChaosReplayStepRecords:
