@@ -21,8 +21,10 @@ engine had work.  The interval splits three ways:
   arrays' host-to-device puts), ``launch_ms`` (the call of the compiled
   step and the bookkeeping after it), ``commit_ms`` (row commits, offload
   drains, outcomes, up to the next stamp; ``wake_ms`` of it inside the
-  ``wakeups`` hand-overs to the event loop) and ``turn_ms`` (between two
-  ``step()`` calls while work was pending: the event loop's turn)
+  hand-over of the streamed rows' tokens to the event loop: ``wakeups``
+  calls, at most one a commit, carrying ``wake_rows`` snapshots) and
+  ``turn_ms`` (between two ``step()`` calls while work was pending: the
+  event loop's turn)
 
 ``host_ms + wait_ms + xfer_ms == wall_ms`` by construction, so the
 attribution fractions always total 1.0; the analytic flops-per-token
@@ -104,8 +106,8 @@ class StepRecord:
     launch_ms: float = 0.0
     commit_ms: float = 0.0
     turn_ms: float = 0.0
-    #: of ``commit_ms``: inside the ``wakeups`` calls that hand a row's
-    #: tokens to the event loop
+    #: of ``commit_ms``: inside the ``wakeups`` calls that hand the
+    #: streamed rows' tokens to the event loop
     wake_ms: float = 0.0
     #: what the process did in the interval (0.0 where nobody measured):
     #: the worker thread's CPU time less its CPU time inside the device
@@ -188,10 +190,12 @@ class StepRecord:
     #: so MFU stays honest on compute actually performed; None on
     #: engines without a prefix cache
     cached_tokens: Optional[int] = None
-    #: hand-overs to the event loop in the commit, snapshots the loop
-    #: took in the interval, collections of the oldest generation that
-    #: ended in it; None on a bare ring
+    #: hand-overs to the event loop in the commit (one call carries
+    #: every row that streamed: at most 1), the snapshots they carried,
+    #: snapshots the loop took in the interval, collections of the
+    #: oldest generation that ended in it; None on a bare ring
     wakeups: Optional[int] = None
+    wake_rows: Optional[int] = None
     delivered: Optional[int] = None
     gc_gen2: Optional[int] = None
 
@@ -248,7 +252,7 @@ _COUNT_FIELDS = (
     "sampled_rows", "passes",
     "block_rows", "unmasked_tokens", "commit_tokens",
     "moe_tokens", "moe_experts_hit", "moe_assign_max",
-    "wakeups", "delivered", "gc_gen2",
+    "wakeups", "wake_rows", "delivered", "gc_gen2",
 )
 
 
@@ -478,7 +482,7 @@ def render_steps(records: "Iterable[StepRecord]") -> str:
         f"{'seq':>6}  {'kind':<7} {'tok':>5} {'pf_tok':>6} {'slots':>5} {'occ':>5} "
         f"{'wall_ms':>8} {'host_ms':>8} {'wait_ms':>8} {'xfer_ms':>8} "
         + "".join(f"{part:>7} " for part in HOST_PARTS)
-        + f"{'wake':>7} {'cpu':>7} {'proc':>7} {'gc':>6} {'comp':>6} {'lag':>6} "
+        + f"{'wake':>7} {'wk_rows':>7} {'cpu':>7} {'proc':>7} {'gc':>6} {'comp':>6} {'lag':>6} "
         f"{'blk_rows':>8} {'unmask':>6} {'cmt_tok':>7} {'moe_tok':>7} {'exp_hit':>7} "
         f"{'exp_max':>7} {'passes':>6} "
         f"{'st_rows':>7} {'smp_rows':>8} {'kv_pg':>6} {'pg_blk':>6} {'q_fill':>6} "
@@ -507,7 +511,7 @@ def render_steps(records: "Iterable[StepRecord]") -> str:
             f"{r.occupancy:>5.2f} {r.wall_ms:>8.3f} {r.host_ms:>8.3f} "
             f"{r.wait_ms:>8.3f} {r.xfer_ms:>8.3f} "
             + "".join(f"{getattr(r, part + '_ms'):>7.3f} " for part in HOST_PARTS)
-            + f"{r.wake_ms:>7.3f} {r.cpu_ms:>7.3f} {r.proc_cpu_ms:>7.2f} "
+            + f"{r.wake_ms:>7.3f} {shown(r.wake_rows):>7} {r.cpu_ms:>7.3f} {r.proc_cpu_ms:>7.2f} "
             f"{r.gc_ms:>6.2f} {r.compile_ms:>6.1f} {lag:>6} "
             # a denoising step's rows, what they kept, the tokens that only
             # rewrite a finished block's keys; the tokens routed, experts

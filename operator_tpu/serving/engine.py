@@ -293,10 +293,11 @@ class BatchedGenerator(AdmissionMixin, ProgramBuilderMixin, Runtime):
                 f"stop margin — generations would truncate immediately"
             )
         self.pipeline_depth = pipeline_depth
-        #: optional ``hook(slot_id, token_ids_so_far)`` called after each
-        #: processed block for slots that are still generating — the
-        #: streaming feed (ServingEngine marshals it onto the event loop).
-        #: Called from the decode worker thread; must not block.
+        #: optional ``hook([(slot_id, token_ids_so_far), ...])`` called
+        #: once a processed block with every slot still generating that
+        #: grew in it — the streaming feed (ServingEngine marshals it onto
+        #: the event loop).  Called from the decode worker thread; must
+        #: not block; never with an empty list.
         self.partial_hook: Optional[Any] = None
         self._inflight_blocks: list[tuple[Any, dict]] = []
 
@@ -1218,6 +1219,8 @@ class BatchedGenerator(AdmissionMixin, ProgramBuilderMixin, Runtime):
         clock.begin("commit")
         clock.observe(kind="decode", tokens=block * live, slots=live)
         finished: list[tuple[int, GenerationResult]] = []
+        # the slots that stream, handed to the event loop in ONE call
+        handed: list[tuple[int, list]] = []
         eos = self.tokenizer.eos_id
         for i, (epoch, before) in snapshot.items():
             slot = self.slots[i]
@@ -1260,7 +1263,9 @@ class BatchedGenerator(AdmissionMixin, ProgramBuilderMixin, Runtime):
             ):
                 # list COPY: the hook crosses into the event-loop thread
                 # while this worker keeps appending
-                self.partial_hook(i, list(slot.generated))
+                handed.append((i, list(slot.generated)))
+        if handed:
+            self.partial_hook(handed)
         return finished
 
     def _finish(self, slot_id: int, *, reason: str) -> GenerationResult:
@@ -1799,21 +1804,38 @@ class ServingEngine:
             reason, ready_s, len(retry), gaveup, leaks or "none",
         )
 
-    def _on_partial_from_worker(self, slot_id: int, token_ids: list) -> None:
-        """Generator hook (decode worker thread) -> event-loop callback.
+    def _on_partial_from_worker(self, handed: list) -> None:
+        """Generator hook (decode worker thread) -> event-loop callback:
+        ONE ``call_soon_threadsafe`` carries every ``(key, token_ids)``
+        pair of the commit that still has a live stream, in commit order.
         The hand-over carries the step clock's last stamp, where the
         commit that holds these tokens began: one stamp a commit, read
         here and not taken a row."""
-        entry = self._partial_cbs.get(slot_id)
-        if entry is None or self._loop is None:
+        if self._loop is None:
             return
-        callback, future = entry
-        if future.done():  # streaming client cancelled; slot drains unheard
-            return
-        self._loop.call_soon_threadsafe(
-            self._deliver_partial, slot_id, callback, future, token_ids,
-            self.generator.step_clock.mark,
-        )
+        live = []
+        for key, token_ids in handed:
+            entry = self._partial_cbs.get(key)
+            if entry is None:
+                continue
+            callback, future = entry
+            if future.done():  # streaming client cancelled; slot drains unheard
+                continue
+            live.append((key, callback, future, token_ids))
+        if live:
+            self._loop.call_soon_threadsafe(
+                self._deliver_partials, live, self.generator.step_clock.mark
+            )
+
+    def _deliver_partials(self, live: list, committed_t: float) -> None:
+        """Loop side of one hand-over: each snapshot in commit order.  A
+        stream whose callback raises is logged, as the loop would log a
+        callback of its own, and the others are still delivered."""
+        for key, callback, future, token_ids in live:
+            try:
+                self._deliver_partial(key, callback, future, token_ids, committed_t)
+            except Exception:
+                log.exception("streaming delivery to %s failed", key)
 
     def _deliver_partial(
         self, key: int, callback: Any, future: "asyncio.Future",

@@ -241,11 +241,12 @@ class StepClock:
         self._part, self.mark = part, now
         return now
 
-    def woke(self, ms: float, calls: int) -> None:
-        """The commit handed rows' tokens to the event loop ``calls``
-        times and spent ``ms`` inside those calls (part of ``commit``)."""
+    def woke(self, ms: float, rows: int) -> None:
+        """The commit handed ``rows`` rows' tokens to the event loop in
+        one call and spent ``ms`` inside it (part of ``commit``)."""
         self.wake_ms += ms
-        self.wakeups += calls
+        self.wakeups += 1
+        self.wake_rows += rows
 
     def delivered(self, committed_t: float) -> None:
         """EVENT LOOP thread: a snapshot whose commit began at
@@ -276,7 +277,7 @@ class StepClock:
         self.mark = t0 if t0 is not None else self.now()
         self._parts = dict.fromkeys(self._PARTS, 0.0)
         #: the open interval's hand-overs to the event loop so far
-        self.wake_ms, self.wakeups = 0.0, 0
+        self.wake_ms, self.wakeups, self.wake_rows = 0.0, 0, 0
         self._wait_cpu = 0.0
         self._base = base if base is not None else self._cumulatives()
 
@@ -331,6 +332,7 @@ class StepClock:
             measured.update(
                 wake_ms=self.wake_ms,
                 wakeups=self.wakeups,
+                wake_rows=self.wake_rows,
                 cpu_ms=max(0.0, now[0] - base[0] - self._wait_cpu) * 1e3,
                 proc_cpu_ms=max(0.0, now[1] - base[1]) * 1e3,
                 gc_ms=now[2] - base[2],

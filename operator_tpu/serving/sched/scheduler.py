@@ -361,9 +361,10 @@ class Scheduler:
         self._host_syncs = 0
         self._decode_committed = 0
         self.metrics = generator.metrics
-        #: ``hook(req_id, token_ids_so_far)`` after each step for rows
-        #: still generating — the streaming feed (ServingEngine marshals
-        #: it onto the event loop).  Called from the decode worker.
+        #: ``hook([(req_id, token_ids_so_far), ...])`` once a commit, with
+        #: every row still generating that streamed in it, in commit order
+        #: — the streaming feed (ServingEngine marshals it onto the event
+        #: loop).  Called from the decode worker; never with an empty list.
         self.partial_hook: Optional[Any] = None
         # (req_id, tokens, params, submitted, priority) — admission order
         # is priority class first, then earliest deadline (EDF) within a
@@ -1696,9 +1697,10 @@ class Scheduler:
             if self._block:
                 self.metrics.incr("unmasked_tokens", accepted)
             if span is not None and span.is_enabled():
-                # what the hand-overs to the event loop took of the commit
+                # what the hand-over to the event loop took of the commit
                 span.set_metadata(
-                    wake_us=int(clock.wake_ms * 1e3), wakeups=clock.wakeups
+                    wake_us=int(clock.wake_ms * 1e3), wakeups=clock.wakeups,
+                    wake_rows=clock.wake_rows,
                 )
         record = clock.observe(
             kind=kind,
@@ -1779,8 +1781,8 @@ class Scheduler:
     ) -> list[StepOutcome]:
         outcomes: list[StepOutcome] = []
         g = self.generator
-        now = g.step_clock.now
-        wake_s, wakeups = 0.0, 0
+        # the rows that stream, handed to the event loop in ONE call
+        handed: list[tuple[int, list]] = []
         # the step's wall is attributed to its rows by token share —
         # good enough for the prefill/decode split the spans surface
         share = elapsed_ms / max(1, plan.tokens_planned)
@@ -1858,10 +1860,10 @@ class Scheduler:
                 and row.generated
             ):
                 # list COPY: the hook crosses into the event-loop thread
-                snapshot = list(row.generated)
-                t0 = now()
-                self.partial_hook(row.req_id, snapshot)
-                wake_s += now() - t0
-                wakeups += 1
-        g.step_clock.woke(wake_s * 1e3, wakeups)
+                handed.append((row.req_id, list(row.generated)))
+        if handed:
+            clock = g.step_clock
+            t0 = clock.now()
+            self.partial_hook(handed)
+            clock.woke((clock.now() - t0) * 1e3, len(handed))
         return outcomes

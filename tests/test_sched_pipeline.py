@@ -443,7 +443,8 @@ class TestStreamingOrder:
         """The records count the snapshots that reached the event loop
         (``delivered``) and their age since their commit began; over a run
         with no cancellation that is every partial the streams saw, and
-        every hand-over the commits made (``wakeups``)."""
+        every snapshot the commits handed over (``wake_rows``), in one
+        hand-over a commit at most (``wakeups``)."""
         generator = make_generator(params)
         sched = make_sched(generator, pipeline_depth=depth)
         engine = ServingEngine(generator, scheduler=sched)
@@ -466,10 +467,105 @@ class TestStreamingOrder:
         records = clock.ring.records()
         # what the loop took after the last commit is still with the clock
         delivered = sum(r.delivered for r in records) + len(clock.lags)
-        assert delivered == seen == sum(r.wakeups for r in records) > 0
+        assert delivered == seen == sum(r.wake_rows for r in records) > 0
         for r in records:
+            assert r.wakeups <= 1
             assert 0.0 <= r.deliver_lag_max_ms <= r.deliver_lag_ms
             assert r.wake_ms <= r.commit_ms
             if r.delivered:
                 assert r.deliver_lag_ms / r.delivered <= r.deliver_lag_max_ms
         assert any(r.deliver_lag_ms > 0.0 for r in records)
+
+    def test_one_hand_over_delivers_the_live_snapshots_in_commit_order(
+        self, params
+    ):
+        """One hand-over holding a request whose future is done, a stale
+        snapshot and a key restarted while the delivery was queued is ONE
+        call onto the loop; it delivers the others in commit order, and
+        every stream stays monotonic."""
+        generator = make_generator(params)
+        engine = ServingEngine(generator, scheduler=make_sched(generator))
+
+        async def scenario():
+            loop = asyncio.get_running_loop()
+            engine._loop = loop
+            scheduled = []
+            real_call = loop.call_soon_threadsafe
+
+            def counted(callback, *args):
+                scheduled.append(callback)
+                return real_call(callback, *args)
+
+            loop.call_soon_threadsafe = counted
+            heard = []
+
+            def stream(name):
+                return lambda ids: heard.append((name, list(ids)))
+
+            for key in (1, 2, 3, 4, 5):
+                engine._partial_cbs[key] = (stream(key), loop.create_future())
+            engine._partial_cbs[2][1].cancel()  # the client went away
+            engine._partial_sent[3] = 4  # that stream already holds 4 tokens
+            engine._on_partial_from_worker(
+                [(5, [7]), (1, [1, 2]), (2, [3]), (3, [4, 5]), (4, [6])]
+            )
+            # key 4 restarts: its fresh registration lands before the turn
+            engine._partial_cbs[4] = (stream("4'"), loop.create_future())
+            await asyncio.sleep(0)
+            first = list(heard)
+            engine._on_partial_from_worker(
+                [(1, [1]), (5, [7, 8]), (3, [4, 5, 6, 7, 8]), (4, [9])]
+            )
+            await asyncio.sleep(0)
+            return scheduled, first, heard
+
+        scheduled, first, heard = asyncio.run(scenario())
+        assert len(scheduled) == 2  # one call onto the loop a hand-over
+        assert first == [(5, [7]), (1, [1, 2])]
+        assert heard[2:] == [(5, [7, 8]), (3, [4, 5, 6, 7, 8]), ("4'", [9])]
+        # the snapshots that crossed, stale or orphaned ones too, are
+        # deliveries on the step clock; the cancelled one never crossed
+        assert len(generator.step_clock.lags) == 4 + 4
+        for name in {name for name, _ in heard}:
+            stream = [ids for n, ids in heard if n == name]
+            for earlier, later in zip(stream, stream[1:]):
+                assert len(later) > len(earlier)
+                assert later[: len(earlier)] == earlier
+
+    def test_the_wave_loop_hands_over_once_a_block(self, params):
+        """The wave engine's decode loop gathers its slots' snapshots and
+        makes one hook call a processed block."""
+        generator = make_generator(params)
+        calls, blocks = [], []
+        generator.partial_hook = lambda handed: calls.append(
+            [(slot, list(ids)) for slot, ids in handed]
+        )
+        real_block = generator._process_block
+
+        def counted(toks, snapshot):
+            blocks.append(len(snapshot))
+            return real_block(toks, snapshot)
+
+        generator._process_block = counted
+        slots = generator.admit(
+            ["pod crashed with exit code 137", "three", "node not ready"],
+            [SamplingParams(max_tokens=n, temperature=0.0, stop_on_eos=False)
+             for n in (6, 9, 12)],
+        )
+        results = {}
+        for _ in range(200):
+            for slot, result in generator.step():
+                results[slot] = result
+            if len(results) == len(slots):
+                break
+        assert sorted(results) == sorted(slots)
+        assert 0 < len(calls) <= len(blocks)
+        assert all(calls)  # never an empty call
+        assert max(len(handed) for handed in calls) == len(slots)
+        for slot in slots:
+            stream = [ids for handed in calls for s, ids in handed if s == slot]
+            assert stream
+            for earlier, later in zip(stream, stream[1:]):
+                assert len(later) > len(earlier)
+                assert later[: len(earlier)] == earlier
+            assert results[slot].token_ids[: len(stream[-1])] == stream[-1]

@@ -123,7 +123,11 @@ def serve(tree, steps, *, config=TINY, remask="sequential", temperature=0.0, dep
     sched.plan_log = []
     seen = capture_steps(generator, sched)
     partials = {}
-    sched.partial_hook = lambda req_id, ids: partials.setdefault(req_id, []).append(ids)
+    def heard(handed):
+        for req_id, ids in handed:
+            partials.setdefault(req_id, []).append(ids)
+
+    sched.partial_hook = heard
     rng = np.random.default_rng(5)
     sent, done = {}, {}
     for length, max_tokens in REQUESTS:

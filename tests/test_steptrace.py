@@ -425,17 +425,19 @@ class TestStepView:
                        prefill_tokens=16, q_tile_rows=64, state_rows=1,
                        sampled_rows=20, passes=4, block_rows=3, unmasked_tokens=6,
                        commit_tokens=8, moe_tokens=16, moe_experts_hit=31,
-                       moe_assign_max=9),
+                       moe_assign_max=9, wakeups=1, wake_rows=5),
         ])
         lines = table.splitlines()
         assert lines[0].split() == [
             "seq", "kind", "tok", "pf_tok", "slots", "occ",
             "wall_ms", "host_ms", "wait_ms", "xfer_ms",
             "plan", "pack", "put", "launch", "commit", "turn",
-            "wake", "cpu", "proc", "gc", "comp", "lag",
+            "wake", "wk_rows", "cpu", "proc", "gc", "comp", "lag",
             "blk_rows", "unmask", "cmt_tok", "moe_tok", "exp_hit", "exp_max", "passes",
             "st_rows", "smp_rows", "kv_pg", "pg_blk", "q_fill", "mfu",
         ]
+        # the snapshots the commit's hand-over carried, beside its time
+        assert lines[3].split()[17] == "5" and lines[2].split()[17] == "-"
         # a denoising step's rows, what they kept and its commit tokens; the
         # tokens routed to experts and the two counts the device brings back
         assert lines[3].split()[-13:-7] == ["3", "6", "8", "16", "31", "9"]
@@ -674,11 +676,14 @@ def _drive_on_fake_device(params, depth, requests, **sched_kw):
 
         generator._jnp = SlowPuts()
     first, seen = {}, {}
+    #: each call of the hook: the (req_id, token_ids) pairs it carried
+    sched.handed = []
 
-    def heard(req_id, ids):
+    def heard(handed):
         clock.t += costs.get("wake", 0.0)
-        first.setdefault(req_id, clock.t)
-        sched.hook_calls = getattr(sched, "hook_calls", 0) + 1
+        for req_id, _ in handed:
+            first.setdefault(req_id, clock.t)
+        sched.handed.append([(req_id, list(ids)) for req_id, ids in handed])
 
     sched.partial_hook = heard
     ids = [
@@ -866,13 +871,43 @@ class TestThePartsOfHostMs:
             params, 2, _FAKE_DEVICE_REQUESTS, costs=self.COSTS
         )
         records = generator.step_clock.ring.records()
-        assert sum(r.wakeups for r in records) == sched.hook_calls > 10
+        assert sum(r.wakeups for r in records) == len(sched.handed) > 10
         for r in records:
             assert r.wake_ms <= r.commit_ms
             # a call costs what the hook took and the two reads around it
             assert r.wake_ms == pytest.approx(
                 r.wakeups * (0.4 + TICK_S * 1e3), abs=1e-6
             )
+
+    @pytest.mark.parametrize("depth", [1, 2])
+    def test_one_hand_over_a_commit_carries_every_row_that_streamed(
+        self, params, depth
+    ):
+        """The commit hands its streaming rows to the event loop in ONE
+        call: ``wakeups`` is at most 1 a record, ``wake_rows`` the rows
+        that call carried, and a row streams at every commit that gave it
+        a token and did not finish it."""
+        generator, _, seen, sched = _drive_on_fake_device(
+            params, depth, _FAKE_DEVICE_REQUESTS, costs=self.COSTS
+        )
+        records = generator.step_clock.ring.records()
+        assert all(r.wakeups in (0, 1) for r in records)
+        assert all(r.wake_rows == 0 for r in records if not r.wakeups)
+        assert [r.wake_rows for r in records if r.wakeups] == [
+            len(handed) for handed in sched.handed
+        ]
+        assert all(handed for handed in sched.handed)  # never an empty call
+        # several rows streamed in one commit, and went over in one call
+        assert max(r.wake_rows for r in records) == len(_FAKE_DEVICE_REQUESTS)
+        lengths = {}
+        for handed in sched.handed:
+            ids = [req_id for req_id, _ in handed]
+            assert len(set(ids)) == len(ids)
+            for req_id, tokens in handed:
+                lengths.setdefault(req_id, []).append(len(tokens))
+        for req_id, (_, _, result) in seen.items():
+            # one token a commit, streamed at each but the last
+            assert lengths[req_id] == list(range(1, len(result.token_ids)))
 
     def test_parts_tile_after_an_idle_spell(self):
         clock = _fake_clock()
@@ -1133,10 +1168,12 @@ class TestStalls:
         record = StepRecord.from_dict(old)
         assert (record.put_ms, record.launch_ms, record.wake_ms, record.cpu_ms,
                 record.gc_ms, record.compile_ms, record.deliver_lag_ms) == (0.0,) * 7
-        assert (record.wakeups, record.delivered, record.gc_gen2) == (None,) * 3
+        assert (record.wakeups, record.wake_rows, record.delivered,
+                record.gc_gen2) == (None,) * 4
         assert not record.stall and "stall" not in record.to_dict()
-        new = StepRecord.from_dict({**old, "put_ms": 0.75, "wakeups": 128,
-                                    "delivered": 120, "stall": True})
+        new = StepRecord.from_dict({**old, "put_ms": 0.75, "wakeups": 1,
+                                    "wake_rows": 128, "delivered": 120,
+                                    "stall": True})
         assert StepRecord.from_dict(new.to_dict()) == new
         assert new.to_dict()["stall"] is True
 
